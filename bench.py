@@ -410,15 +410,10 @@ def mesh_bench() -> int:
     # tiny chunks make every round host-overhead-dominated: wall /
     # rounds then measures the DRIVE cost per launch boundary, the
     # quantity that scaled with device count on the threaded drive.
-    # Each cell runs TWICE under a shared persistent compilation cache
-    # and reports the second (warm) run — a cold cell would measure
-    # XLA compile-time scaling, not drive overhead.
-    import tempfile
-
-    cache_dir = tempfile.mkdtemp(prefix="mesh-bench-jit-cache-")
-    prev_cache = jax.config.jax_compilation_cache_dir
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
-
+    # Each cell runs TWICE under the persistent compilation cache
+    # (batch.ensure_jax_backend) and reports the second (warm) run — a
+    # cold cell would measure XLA compile-time scaling, not drive
+    # overhead.
     def overhead_once(drive: str, n: int):
         conf = Configure()
         conf.batch.steps_per_launch = 64
@@ -453,12 +448,9 @@ def mesh_bench() -> int:
         return overhead_once(drive, n)   # the warm measurement
 
     matrix = {}
-    try:
-        for drive in ("shard", "threaded"):
-            for n in (2, 4, 8):
-                matrix[f"{drive}_{n}dev"] = overhead(drive, n)
-    finally:
-        jax.config.update("jax_compilation_cache_dir", prev_cache)
+    for drive in ("shard", "threaded"):
+        for n in (2, 4, 8):
+            matrix[f"{drive}_{n}dev"] = overhead(drive, n)
     shard_growth = matrix["shard_8dev"]["ms_per_round"] \
         / max(matrix["shard_2dev"]["ms_per_round"], 1e-9)
     threaded_growth = matrix["threaded_8dev"]["ms_per_round"] \

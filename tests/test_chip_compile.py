@@ -1,0 +1,232 @@
+"""Ask the chip's compiler about the main path's device programs, with
+no chip: each test lowers one program at its real width and compiles it
+for one described TPU v5e (jax.experimental.topologies).  Nothing runs,
+so this says nothing about results or times — it catches what interpret
+mode and the CPU backend cannot: scoped-VMEM and SMEM limits, slices
+that miss the tiling, a lowering the installed JAX no longer accepts.
+
+The topology is described inside a module-scoped fixture of THIS file
+(never at import, never in conftest.py): only the xdist worker that is
+handed the file loads the TPU's library.  Everything stays in this one
+file and in the test's own process for the same reason.
+"""
+
+import os
+
+import numpy as np
+import pytest
+
+from tests.helpers import instantiate
+
+LANES = 4096
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    """One described chip's sharding, with the program steered onto its
+    chip branch for the life of the module: code that asks
+    jax.default_backend() (donation, interpret mode, engine pick) sees
+    "tpu", the kernel export cache (which lowers for the process's own
+    backend and writes to disk) is bypassed, and the persistent compile
+    cache is off — an entry compiled for a described chip cannot be read
+    back without one."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    from wasmedge_tpu.batch.pallas_engine import PallasUniformEngine
+
+    mp = pytest.MonkeyPatch()
+    mp.setattr(jax, "default_backend", lambda: "tpu")
+    mp.setattr(PallasUniformEngine, "_with_export_cache",
+               lambda self, build: build())
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        yield SingleDeviceSharding(topo.devices[0])
+    finally:
+        mp.undo()
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+        compilation_cache.reset_cache()
+
+
+def _on(sharding, tree):
+    import jax
+
+    return jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding),
+        tree)
+
+
+def _simd_wasm():
+    import bench_simd
+    from wasmedge_tpu.utils.wat import parse_wat
+
+    return parse_wat(bench_simd._SRC)
+
+
+def _fib_wasm():
+    from wasmedge_tpu.models import build_fib
+
+    return build_fib()
+
+
+def _memory_wasm():
+    from wasmedge_tpu.models import build_memory_workload
+
+    return build_memory_workload(passes=64)
+
+
+def _pallas_engine(wasm, depth, call_depth, mem_hbm=None, blk_cap=None):
+    """The engine UniformBatchEngine picks for a TPU backend, built at
+    4096 lanes (what VM.execute_batch holds; bench.py / bench_memory.py
+    / bench_simd.py geometries)."""
+    from wasmedge_tpu.batch.uniform import UniformBatchEngine
+    from wasmedge_tpu.common.configure import Configure
+
+    conf = Configure()
+    conf.batch.steps_per_launch = 50_000_000
+    conf.batch.value_stack_depth = depth
+    conf.batch.call_stack_depth = call_depth
+    conf.batch.mem_hbm = mem_hbm
+    _ex, store, inst = instantiate(wasm, conf)
+    eng = UniformBatchEngine(inst, store=store, conf=conf,
+                             lanes=LANES).pallas
+    assert eng is not None and eng.eligible
+    assert eng._interpret() is False
+    if blk_cap is not None:
+        # the block scheduler's geometry for grouped arguments
+        # (batch/scheduler.py _plan): same lanes, smaller lane blocks
+        eng._blk_cap = blk_cap
+    eng._build()
+    return eng
+
+
+def _compile_kernel(eng, fn, one_chip):
+    compiled = fn.lower(*_on(one_chip, eng._arg_specs())).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    # the state planes are donated and aliased in place
+    assert compiled.memory_analysis().alias_size_in_bytes > 0
+    return compiled
+
+
+# (wasm, value stack, call stack, mem_hbm, lane-block cap,
+#  careful kernel?) -> expected (lane block, mem_hbm mode)
+_KERNELS = {
+    "fib-optimistic": (_fib_wasm, 256, 256, None, None, False,
+                       (4096, False)),
+    "fib-careful": (_fib_wasm, 256, 256, None, None, True,
+                    (4096, False)),
+    "fib-grouped-blocks": (_fib_wasm, 256, 256, None, 256, False,
+                           (256, False)),
+    "memory-resident": (_memory_wasm, 128, 64, False, None, False,
+                        (128, False)),
+    "memory-hbm-window": (_memory_wasm, 128, 64, True, None, False,
+                          (4096, True)),
+    "v128": (_simd_wasm, 64, 16, None, None, False, (4096, True)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_KERNELS))
+def test_pallas_kernel_compiles_for_v5e(case, one_chip):
+    wasm, depth, cdepth, mem_hbm, cap, careful, expect = _KERNELS[case]
+    eng = _pallas_engine(wasm(), depth, cdepth, mem_hbm=mem_hbm,
+                         blk_cap=cap)
+    assert (eng._geom[3], eng._mem_mode()) == expect
+    fn = eng._fn_careful() if careful else eng._fn
+    _compile_kernel(eng, fn, one_chip)
+
+
+def test_exported_kernel_compiles_for_v5e(one_chip):
+    """The warm-start path (_with_export_cache): export for the TPU,
+    serialize, deserialize, compile what came back."""
+    import jax
+    import jax.export as jexport
+
+    eng = _pallas_engine(_fib_wasm(), 256, 256)
+    exp = jexport.export(eng._fn, platforms=["tpu"])(*eng._arg_specs())
+    back = jexport.deserialize(bytearray(exp.serialize()))
+    compiled = jax.jit(back.call).lower(
+        *_on(one_chip, eng._arg_specs())).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.fixture(scope="module")
+def served(one_chip):
+    """The gateway's serving generation for fib at 4096 lanes: what
+    `wasmedge-tpu gateway fib.wasm --lanes 4096` builds."""
+    from wasmedge_tpu.common.configure import Configure, HostRegistration
+    from wasmedge_tpu.gateway import GatewayService
+
+    conf = Configure()
+    conf.host_registrations.add(HostRegistration.Wasi)
+    svc = GatewayService(conf=conf, lanes=LANES)
+    try:
+        svc.preload([("main", _fib_wasm())])
+        gen = svc.current
+        rec = gen.server.recycler
+        fidx = rec.func_idx("fib")
+        yield gen.engine, rec, fidx, _on(one_chip, rec.idle_state(fidx))
+    finally:
+        svc.shutdown(drain=False)
+
+
+def test_served_simt_chunk_compiles_for_v5e(served, one_chip):
+    import jax
+
+    engine, _rec, _fidx, state = served
+    engine._build()
+    tt = jax.ShapeDtypeStruct((2, 2), np.int32, sharding=one_chip)
+    compiled = engine._run_chunk.lower(state, tt).compile()
+    mem = compiled.memory_analysis()
+    # the whole carried state is donated: the chunk runs in place
+    assert mem.alias_size_in_bytes >= mem.argument_size_in_bytes - 1024
+
+
+@pytest.mark.parametrize("width", [1, 64, LANES])
+def test_recycler_install_compiles_for_v5e(served, one_chip, width):
+    import jax
+
+    _engine, rec, fidx, state = served
+    idx = jax.ShapeDtypeStruct((width,), np.int32, sharding=one_chip)
+    rows = jax.ShapeDtypeStruct((1, width), np.int32, sharding=one_chip)
+    rec._install_fn(fidx, 1).lower(state, idx, rows, rows).compile()
+
+
+def test_shard_drive_chunk_compiles_for_four_v5e(topo, one_chip):
+    """The --devices 4 path (parallel/shard_drive.py): ONE program over
+    a lane mesh of the four described chips, every lane plane sharded."""
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from wasmedge_tpu.batch.engine import BatchEngine
+    from wasmedge_tpu.common.configure import Configure
+    from wasmedge_tpu.parallel.mesh import lane_mesh, state_shardings
+
+    conf = Configure()
+    _ex, store, inst = instantiate(_fib_wasm(), conf)
+    mesh = lane_mesh(devices=list(topo.devices))
+    eng = BatchEngine(inst, store=store, conf=conf, lanes=LANES, mesh=mesh)
+    eng._build()
+    state = eng.initial_state(0, [])
+    state = jax.tree_util.tree_map(
+        lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
+        state, state_shardings(mesh, state))
+    tt = jax.ShapeDtypeStruct(
+        (2, 2), np.int32, sharding=NamedSharding(mesh, PartitionSpec()))
+    compiled = eng._run_chunk.lower(state, tt).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= mem.argument_size_in_bytes - 1024

@@ -1,5 +1,5 @@
 """Spawn-time regression tests: scalar/native paths never pay the JAX
-import tax (AOT_r05.json python_spawn_floor attribution).
+import tax (r5's python_spawn_floor attribution).
 
 The assertions run fresh interpreters, so the suite marks them slow;
 tier-1 CI keeps the cheap in-process guard at the bottom.

@@ -15,8 +15,8 @@ __version__ = "0.1.0"
 
 # Import-tax discipline: this module (and everything it pulls in) must
 # stay free of jax/jaxlib/numpy so `import wasmedge_tpu` and the
-# scalar/native CLI paths never pay the JAX import tax (~1s of the
-# AOT_r05 python_spawn_floor).  Heavy entry points are exposed lazily
+# scalar/native CLI paths never pay the JAX import tax (~1s of
+# r5's python_spawn_floor).  Heavy entry points are exposed lazily
 # below; tests/test_spawn_time.py asserts the invariant in a fresh
 # interpreter.
 from wasmedge_tpu.common.configure import Configure, EngineKind

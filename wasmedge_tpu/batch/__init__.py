@@ -20,46 +20,42 @@ parity suite runs on the CPU backend where XLA is IEEE-strict; a softfloat
 rare-path for denormals is planned (tracked in SURVEY.md §7 hard part (b)).
 """
 
+import os
+
 from wasmedge_tpu.batch.engine import BatchEngine, BatchResult
 from wasmedge_tpu.batch.image import DeviceImage, batchability
 from wasmedge_tpu.batch.uniform import UniformBatchEngine
 
 
+def compile_cache_dir() -> str:
+    """Where compiled executables (and the exported kernels, under
+    `kexport/`) persist: JAX_COMPILATION_CACHE_DIR where set, else the
+    one fixed `.jax_cache` of the checkout (listed in .gitignore).  The
+    path is part of the cache key, so it never moves: no temporary name,
+    pid or time.  Needs no JAX."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or os.path.join(
+        os.path.dirname(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__)))), ".jax_cache")
+
+
 def ensure_jax_backend():
-    """Initialize the JAX backend, falling back to CPU when the configured
-    platform (e.g. a TPU plugin named by JAX_PLATFORMS) is unavailable in
-    this process — keeps the CLI/batch path usable off-accelerator.
+    """Initialize the JAX backend and the persistent XLA compilation
+    cache (content-addressed on-disk, like the reference's AOT cache
+    lib/aot/cache.cpp:36-61): a fresh process re-running a previously
+    compiled kernel geometry loads the compiled executable from disk
+    instead of re-running XLA/Mosaic.
 
-    Also enables the persistent XLA compilation cache (content-addressed
-    on-disk, like the reference's AOT cache lib/aot/cache.cpp:36-61):
-    a fresh process re-running a previously compiled kernel geometry
-    loads the compiled executable from disk instead of re-running
-    XLA/Mosaic.  Directory: $WASMEDGE_TPU_CACHE or
-    ~/.cache/wasmedge_tpu/xla; set WASMEDGE_TPU_CACHE=off to disable."""
-    import os
-
+    A device that will not initialise raises here: there is no CPU
+    fallback (JAX_PLATFORMS=cpu is the only way onto the CPU).  The
+    cache lives where JAX_COMPILATION_CACHE_DIR says; where nothing has
+    set a directory yet it is `.jax_cache` in the checkout."""
     import jax
 
-    cache_dir = os.environ.get("WASMEDGE_TPU_CACHE")
-    if cache_dir != "off":
-        if not cache_dir:
-            cache_dir = os.path.join(
-                os.path.expanduser("~"), ".cache", "wasmedge_tpu", "xla")
-        try:
-            os.makedirs(cache_dir, exist_ok=True)
-            jax.config.update("jax_compilation_cache_dir", cache_dir)
-            jax.config.update("jax_persistent_cache_min_entry_size_bytes",
-                              -1)
-            jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                              0.1)
-        except Exception:  # cache is an optimization, never a failure
-            pass
-
-    try:
-        jax.devices()
-    except RuntimeError:
-        jax.config.update("jax_platforms", "cpu")
-        jax.devices()
+    if not jax.config.jax_compilation_cache_dir:
+        jax.config.update("jax_compilation_cache_dir", compile_cache_dir())
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.1)
+    jax.devices()
 
 
 def make_engine(inst, store=None, conf=None, lanes=None, mesh=None):

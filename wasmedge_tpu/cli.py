@@ -737,6 +737,7 @@ def gateway_command(argv: List[str], out=None, err=None) -> int:
         "listening": f"http://{gw.host}:{gw.port}",
         "modules": svc.registry.names,
         "lanes": svc.lanes,
+        "device": svc.device_info(),
         "tenants": sorted(svc.tenants.policies),
         "health": health["status"],
         "durable": svc.durable is not None,

@@ -374,6 +374,16 @@ class GatewayService:
         with self._lock:
             return self._gens[-1].gen_id if self._gens else 0
 
+    def device_info(self) -> dict:
+        """Where the serving state lives (serve/server.py device_info);
+        with no generation built yet, where its state will land."""
+        from wasmedge_tpu.serve.server import device_info
+
+        gen = self.current
+        if gen is not None:
+            return gen.server.device_info()
+        return device_info(self.devices)
+
     def _make_generation(self, gen_id: int, serve_dir: Optional[str],
                          resume: bool) -> _Generation:
         """Pure build of generation `gen_id` (no shared-state commit
@@ -1502,6 +1512,7 @@ class GatewayService:
                 out["queue_depth"] = len(gen.server.queue)
                 out["in_flight"] = gen.server.in_flight
                 out["serve"] = dict(gen.server.counters)
+        out["device"] = self.device_info()
         if self.fleet is not None:
             out["fleet"] = dict(self.fleet.stats(),
                                 peer_states=self.fleet.peer_states())

@@ -74,6 +74,21 @@ from wasmedge_tpu.serve.queue import (
 from wasmedge_tpu.serve.recycle import LaneRecycler
 
 
+def device_info(devices=None) -> dict:
+    """The `device` object of the gateway's `listening` line and of
+    `GET /v1/status`: platform, kind and count of `devices`, the ones
+    the serving state lives on (BatchServer.device_info); None means
+    where an uncommitted array lands.  Never from
+    jax.default_backend()."""
+    if devices is None:
+        import jax.numpy as jnp
+
+        devices = jnp.zeros((), jnp.int32).sharding.device_set
+    devs = sorted(devices, key=lambda d: d.id)
+    return {"platform": devs[0].platform, "kind": devs[0].device_kind,
+            "count": len(devs)}
+
+
 class BatchServer:
     """Continuous-batching server over one instantiated module.
 
@@ -490,6 +505,16 @@ class BatchServer:
                         self.counters.get("migrated", 0) - 1
                     self.counters["submitted"] -= 1
         return fut
+
+    def device_info(self) -> dict:
+        """Read off a state plane's own sharding; before the first
+        admission (no state yet) off the mesh the idle state will be
+        placed on."""
+        state = self.state
+        mesh = getattr(self.engine, "mesh", None)
+        if state is not None:
+            return device_info(state.trap.sharding.device_set)
+        return device_info(mesh.devices.flat if mesh is not None else None)
 
     # -- serving loop ------------------------------------------------------
     @property
