@@ -115,9 +115,23 @@ def _pallas_engine(wasm, depth, call_depth, mem_hbm=None, blk_cap=None):
     return eng
 
 
-def _compile_kernel(eng, fn, one_chip):
+def _kernel_event_names(hlo_text):
+    """The names a device trace gives the program's Mosaic kernels:
+    the left-hand sides of its tpu_custom_call instructions."""
+    import re
+
+    return re.findall(r"(%[\w.\-]+) = [^\n]*custom-call\([^\n]*"
+                      r"tpu_custom_call", hlo_text)
+
+
+def _compile_kernel(eng, fn, one_chip, careful=False):
     compiled = fn.lower(*_on(one_chip, eng._arg_specs())).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    # the kernel's event in a profiler trace: the optimistic and the
+    # careful kernel can be told apart by name
+    assert _kernel_event_names(compiled.as_text()) == [
+        "%wasm_kernel_careful.1" if careful
+        else "%wasm_kernel_optimistic.1"]
     # the state planes are donated and aliased in place
     assert compiled.memory_analysis().alias_size_in_bytes > 0
     return compiled
@@ -147,7 +161,7 @@ def test_pallas_kernel_compiles_for_v5e(case, one_chip):
                          blk_cap=cap)
     assert (eng._geom[3], eng._mem_mode()) == expect
     fn = eng._fn_careful() if careful else eng._fn
-    _compile_kernel(eng, fn, one_chip)
+    _compile_kernel(eng, fn, one_chip, careful=careful)
 
 
 def test_exported_kernel_compiles_for_v5e(one_chip):
@@ -162,6 +176,9 @@ def test_exported_kernel_compiles_for_v5e(one_chip):
     compiled = jax.jit(back.call).lower(
         *_on(one_chip, eng._arg_specs())).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    # the name survives the export: the benchmark's batch cell runs this
+    assert _kernel_event_names(compiled.as_text()) == [
+        "%wasm_kernel_optimistic.1"]
 
 
 @pytest.fixture(scope="module")
@@ -191,6 +208,8 @@ def test_served_simt_chunk_compiles_for_v5e(served, one_chip):
     engine._build()
     tt = jax.ShapeDtypeStruct((2, 2), np.int32, sharding=one_chip)
     compiled = engine._run_chunk.lower(state, tt).compile()
+    # the step's operations carry their scope into the chip's program
+    assert "wasm_simt_step" in compiled.as_text()
     mem = compiled.memory_analysis()
     # the whole carried state is donated: the chunk runs in place
     assert mem.alias_size_in_bytes >= mem.argument_size_in_bytes - 1024

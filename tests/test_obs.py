@@ -114,16 +114,50 @@ def test_disabled_obs_is_null_recorder():
         pass
 
 
-def test_obs_enabled_output_bit_identical_to_disabled():
+@pytest.mark.parametrize("traced", [False, True])
+@pytest.mark.parametrize("pallas", [False, True])
+def test_obs_enabled_output_bit_identical_to_disabled(pallas, traced,
+                                                      tmp_path):
     """The recorder must observe, never perturb: identical BatchResults
-    with obs on and off (the seed-engine bit-identical contract)."""
-    args = [(np.arange(LANES) % 11).astype(np.int64)]
-    r_off = make_engine(build_fib(), make_conf(obs=False)).run(
-        "fib", args, max_steps=500_000)
-    r_on = make_engine(
-        build_fib(), make_conf(obs=True, opcode_histogram=True)).run(
-        "fib", args, max_steps=500_000)
+    with obs on and off (the seed-engine bit-identical contract), on
+    the SIMT engine and on the Pallas batch path, and with a profiler
+    session running, which makes every obs.timed() span a live
+    TraceAnnotation."""
+    import jax
+
+    # the Pallas path in interpret mode pays seconds per argument
+    # group: two groups there, eleven on the SIMT engine
+    args = [(np.arange(LANES) % (2 if pallas else 11) + 8 * pallas)
+            .astype(np.int64)]
+
+    def run(obs, **kw):
+        conf = make_conf(obs=obs, **kw)
+        if not pallas:
+            eng = make_engine(build_fib(), conf)
+        else:
+            from wasmedge_tpu.batch.uniform import UniformBatchEngine
+
+            conf.batch.interpret = True
+            conf.batch.steps_per_launch = 10_000
+            _ex, store, inst = instantiate(build_fib(), conf)
+            eng = UniformBatchEngine(inst, store=store, conf=conf,
+                                     lanes=LANES)
+            assert eng.pallas is not None
+        return eng.run("fib", args, max_steps=500_000)
+
+    r_off = run(False)
+    if traced:
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        r_on = run(True, opcode_histogram=True)
+        r_off_traced = run(False) if traced else r_off
+    finally:
+        if traced:
+            jax.profiler.stop_trace()
     assert_results_identical(r_off, r_on)
+    assert_results_identical(r_off, r_off_traced)
 
 
 def test_shared_recorder_identity_across_deepcopy():

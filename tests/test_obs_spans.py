@@ -1,0 +1,298 @@
+"""The program's phase spans (`obs.timed`): on the profiler's clock with
+the recorder off, on the ring with their parent when it is on, and read
+by the benchmark's `trace_program_span` reader.
+
+Everything here runs on the CPU (Pallas in interpret mode) at tiny sizes
+and under `jax.profiler` with the Python tracer off, as the benchmark's
+traced slice does.
+"""
+
+import glob
+import importlib.util
+import os
+
+import numpy as np
+import pytest
+
+from wasmedge_tpu.batch.engine import BatchEngine
+from wasmedge_tpu.batch.uniform import UniformBatchEngine
+from wasmedge_tpu.common.configure import Configure
+from wasmedge_tpu.models import build_fib
+from wasmedge_tpu.obs import (
+    NULL_RECORDER,
+    chrome_trace,
+    recorder_of,
+    validate_chrome_trace,
+)
+from wasmedge_tpu.obs.recorder import SPAN_PREFIX
+from wasmedge_tpu.serve import BatchServer
+from tests.helpers import instantiate
+
+pytestmark = pytest.mark.obs
+
+BENCH = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "benchmark")
+LANES = 16
+ROUNDS = 3
+
+BATCH_SPANS = {"batch/run", "batch/plan", "batch/launch", "batch/sync",
+               "batch/statuses", "batch/result"}
+SERVE_SPANS = {"serve/round", "serve/lock_wait", "serve/admit",
+               "serve/install", "serve/launch", "serve/enforce",
+               "serve/harvest", "serve/park", "simt/chunk"}
+# child -> the span it must lie inside
+PARENTS = {"batch/plan": "batch/run", "batch/launch": "batch/run",
+           "batch/sync": "batch/run", "batch/statuses": "batch/run",
+           "batch/result": "batch/run",
+           "serve/lock_wait": "serve/round", "serve/admit": "serve/round",
+           "serve/launch": "serve/round", "serve/enforce": "serve/round",
+           "serve/harvest": "serve/round",
+           "serve/install": "serve/admit", "serve/park": "serve/harvest",
+           "simt/chunk": "serve/launch"}
+
+
+def _conf(obs=False, pallas=False):
+    conf = Configure()
+    conf.batch.steps_per_launch = 256
+    conf.batch.value_stack_depth = 128
+    conf.batch.call_stack_depth = 64
+    conf.batch.interpret = pallas   # opts the Pallas path in on the CPU
+    conf.obs.enabled = obs
+    return conf
+
+
+def _batch_job(obs=False):
+    conf = _conf(obs, pallas=True)
+    conf.batch.steps_per_launch = 10_000
+    _ex, store, inst = instantiate(build_fib(), conf)
+    eng = UniformBatchEngine(inst, store=store, conf=conf, lanes=LANES)
+    assert eng.pallas is not None and eng.pallas.eligible
+    res = eng.run("fib", [np.full(LANES, 10, np.int64)],
+                  max_steps=500_000)
+    assert not eng.fell_back_to_simt
+    return eng, res
+
+
+def _serve_rounds(obs=False):
+    """ROUNDS serving rounds: fib(5) answers within the first, fib(12)
+    (4879 instructions) is still running after the last."""
+    conf = _conf(obs)
+    _ex, store, inst = instantiate(build_fib(), conf)
+    srv = BatchServer(inst, store=store, conf=conf, lanes=4)
+    futs = [srv.submit("fib", [n]) for n in (5, 12, 6)]
+    for _ in range(ROUNDS):
+        srv.step()
+    assert srv.counters["rounds"] == ROUNDS
+    assert futs[0].result(0)[0] == 5 and futs[2].result(0)[0] == 8
+    return srv, futs
+
+
+def _profiled(tmp_path, work):
+    """Run `work()` under a profiler session as the benchmark's traced
+    slice starts one; -> (work's result, {thread line: [(name, start,
+    end)]} of the `wasm/` events on the host plane)."""
+    import jax
+    from jax.profiler import ProfileData
+
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        out = work()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(os.path.join(
+        str(tmp_path), "plugins", "profile", "*", "*.xplane.pb"))
+    lines = {}
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith(SPAN_PREFIX):
+                    lines.setdefault(line.name, []).append(
+                        (ev.name[len(SPAN_PREFIX):], ev.start_ns,
+                         ev.start_ns + ev.duration_ns))
+    return out, lines
+
+
+def _assert_nested(events):
+    """Every child lies inside a span of its parent's name, on one
+    thread's line."""
+    for name, a, b in events:
+        parent = PARENTS.get(name)
+        if parent is not None:
+            assert any(p == parent and pa <= a and b <= pb
+                       for p, pa, pb in events), (name, parent)
+
+
+def test_profiler_trace_holds_the_spans_with_obs_off(tmp_path):
+    def work():
+        eng, res = _batch_job()
+        srv, _futs = _serve_rounds()
+        assert eng.obs is NULL_RECORDER and srv.obs is NULL_RECORDER
+        return res
+
+    res, lines = _profiled(tmp_path, work)
+    (events,) = lines.values()      # all on the calling thread
+    names = [name for name, _a, _b in events]
+    assert BATCH_SPANS | SERVE_SPANS <= set(names)
+    _assert_nested(events)
+    assert names.count("serve/round") == ROUNDS
+    assert names.count("batch/run") == names.count("batch/plan") == 1
+    # both locked sections of every round wait for the lock in a span
+    assert names.count("serve/lock_wait") == 2 * ROUNDS
+    # opened only where the subsystem is configured
+    assert not {"serve/hv", "serve/effects", "serve/compact",
+                "serve/checkpoint", "batch/residue"} & set(names)
+    # (c) a running trace changes no result
+    _eng, plain = _batch_job()
+    for got, want in zip(res.results, plain.results):
+        assert (got == want).all()
+    assert (res.trap == plain.trap).all()
+    assert (res.retired == plain.retired).all()
+
+
+def test_ring_holds_the_spans_with_their_parents():
+    eng, _res = _batch_job(obs=True)
+    rec = eng.obs
+    assert rec is recorder_of(eng.simt.conf) and rec.enabled
+    srv, _futs = _serve_rounds(obs=True)
+    events = [e for r in (rec, srv.obs) for e in r.events
+              if e["ph"] == "X" and "parent" in e["args"]]
+    by_name = {}
+    for e in events:
+        by_name.setdefault(e["name"], []).append(e)
+    assert BATCH_SPANS | SERVE_SPANS <= set(by_name)
+    for name, evs in by_name.items():
+        want = PARENTS.get(name)
+        if name == "simt/chunk":    # also the batch engines' own loop
+            continue
+        assert {e["args"]["parent"] for e in evs} == {want}, name
+    # a child lands on its parent's track, so the Chrome export nests it
+    assert {e["track"] for n in SERVE_SPANS - {"simt/chunk"}
+            for e in by_name[n]} == {"serve/phases"}
+    # what is known at the end rides the ring event
+    assert [e["args"]["admitted"] for e in by_name["serve/admit"]] \
+        == [3, 0, 0]
+    assert by_name["serve/round"][0]["args"]["round"] == 1
+    assert by_name["serve/launch"][0]["args"]["steps"] == 256
+    assert sum(e["args"]["harvested"]
+               for e in by_name["serve/harvest"]) == 2
+    assert by_name["batch/launch"][0]["args"]["blocks"] == 1
+    # the ring spans that were there keep their names
+    assert "kernel_round" in rec.event_names()
+    assert "launch" in srv.obs.event_names()
+    for r in (rec, srv.obs):
+        assert validate_chrome_trace(chrome_trace(r)) == []
+
+
+def test_null_recorder_timed_keeps_no_state():
+    assert recorder_of(Configure()) is NULL_RECORDER
+    span = NULL_RECORDER.timed("x", lanes=3)
+    assert not hasattr(span, "__dict__")
+    with span as inside:
+        inside.set(harvested=2)     # reaches no sink, raises nothing
+    assert not vars(NULL_RECORDER)
+    assert span is not NULL_RECORDER.timed("x")
+
+
+def test_simt_step_carries_its_named_scope():
+    conf = _conf()
+    _ex, store, inst = instantiate(build_fib(), conf)
+    eng = BatchEngine(inst, store=store, conf=conf, lanes=4)
+    eng._build()
+    state = eng.initial_state(eng.export_func_idx("fib"),
+                              [np.full(4, 5, np.int64)])
+    text = eng._run_chunk.lower(
+        state, np.zeros((2, 2), np.int32)).as_text(debug_info=True)
+    assert "wasm_simt_step" in text
+
+
+@pytest.mark.parametrize("careful", [False, True])
+def test_pallas_kernels_are_named_apart(careful):
+    eng, _res = _batch_job()
+    inner = next(iter(eng.simt._sched_cache.values()))
+    fn = inner._fn_careful() if careful else inner._fn
+    name = "wasm_kernel_careful" if careful else "wasm_kernel_optimistic"
+    import jax
+
+    specs = [jax.ShapeDtypeStruct(s.shape, s.dtype)
+             for s in inner._arg_specs()]
+    assert f"@jit_{name}" in fn.lower(*specs).as_text()
+
+
+# -- the benchmark's reader ------------------------------------------------
+def _bench_module(*parts):
+    path = os.path.join(BENCH, *parts) + ".py"
+    spec = importlib.util.spec_from_file_location(
+        "bench_" + parts[-1], path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+@pytest.fixture(scope="module")
+def hand_built_obs():
+    """A slice of 10 s; the device busy 1..2 s and 5..7 s; two
+    `wasm/batch/run` spans inside it, one `wasm/serve/round` across its
+    end, one `wasm/batch/plan` before it."""
+    reduce_trace = _bench_module("reduce_trace")
+    spans = [(0.5, 2.5, "wasm/batch/run"), (4.0, 8.0, "wasm/batch/run"),
+             (4.5, 7.5, "PjitFunction(add)"),
+             (9.0, 12.0, "wasm/serve/round"),
+             (-2.0, -1.0, "wasm/batch/plan")]
+    host = (np.asarray([s[0] for s in spans]),
+            np.asarray([s[1] for s in spans]), [s[2] for s in spans])
+    trace = reduce_trace.Trace((0.0, 10.0), [[[1.0, 2.0], [5.0, 7.0]]],
+                               {}, {}, {}, host)
+    return {"trace": trace, "samples": {},
+            "counters": {"trace_jobs": 2, "none": 0}}
+
+
+@pytest.mark.parametrize("args, want", [
+    (dict(span="wasm/batch/run", stat="median"), 3.0),
+    (dict(span="wasm/batch/run", stat="median", host_only=True,
+          scale=1000.0), 1500.0),
+    (dict(span="wasm/batch/run", stat="sum", per="trace_jobs"), 3.0),
+    (dict(span="wasm/batch/run", stat="sum", per="trace_jobs",
+          host_only=True), 1.5),
+    # across the slice's end: the part inside counts in a sum, and the
+    # span is no sample of a median
+    (dict(span="wasm/serve/round", stat="sum", per="trace_jobs"), 0.5),
+    (dict(span="wasm/serve/round", stat="median"), None),
+    # nothing to read: outside the slice, no such span, no such counter
+    (dict(span="wasm/batch/plan", stat="median"), None),
+    (dict(span="wasm/batch/result", stat="median"), None),
+    (dict(span="wasm/batch/run", stat="sum", per="none"), None),
+    (dict(span="wasm/batch/run", stat="sum", per="absent"), None),
+])
+def test_trace_program_span_reader(hand_built_obs, args, want):
+    reader = _bench_module("readers", "trace_program_span")
+    got = reader.read(hand_built_obs, **args)
+    assert got == (want if want is None else pytest.approx(want))
+
+
+def test_trace_program_span_reader_without_a_trace(hand_built_obs):
+    reader = _bench_module("readers", "trace_program_span")
+    obs = dict(hand_built_obs, trace=None)
+    assert reader.read(obs, span="wasm/batch/run", stat="median") is None
+    with pytest.raises(ValueError):
+        reader.read(hand_built_obs, span="wasm/batch/run", stat="mean")
+
+
+def test_span_metrics_name_spans_the_program_opens():
+    """Every `program_span` layer metric reads a span of this file's
+    lists, so a renamed span cannot leave its metric silent unseen."""
+    import json
+
+    known = {SPAN_PREFIX + n for n in BATCH_SPANS | SERVE_SPANS}
+    seen = 0
+    for path in glob.glob(os.path.join(BENCH, "layer_metrics", "*.json")):
+        with open(path) as f:
+            spec = json.load(f)
+        if spec["source"] == "program_span":
+            assert spec["reader"] == "trace_program_span"
+            assert spec["args"]["span"] in known, spec["name"]
+            seen += 1
+    assert seen == 12

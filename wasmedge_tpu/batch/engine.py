@@ -1958,7 +1958,8 @@ def _make_step(img: DeviceImage, cfg: BatchConfigure, lanes: int,
             tu_ctr=tu_ctr_p,
         )
 
-    return step
+    # a stable name for the step's operations in a device trace
+    return jax.named_scope("wasm_simt_step")(step)
 
 
 class BatchEngine:
@@ -2471,26 +2472,29 @@ class BatchEngine:
             t_launch = obs.now()
             run_chunk = self._run_chunk if comp is None \
                 else comp.chunk_fn(self)
-            done_steps, state = run_chunk(state, tt)
-            total += int(done_steps)
-            if flip is not None:
-                state = flip("corrupt_plane", state, lanes=self.lanes,
-                             total=total)
-            if audit_tok is not None:
-                auditor.post(self, audit_tok, state, int(done_steps))
-            if comp is not None:
-                comp.note_launch(int(done_steps))
-            trap_host = np.asarray(state.trap)
+            with obs.timed("simt/chunk", cat="engine") as chunk_span:
+                done_steps, state = run_chunk(state, tt)
+                done_steps = int(done_steps)    # waits for the chunk
+                total += done_steps
+                if flip is not None:
+                    state = flip("corrupt_plane", state, lanes=self.lanes,
+                                 total=total)
+                if audit_tok is not None:
+                    auditor.post(self, audit_tok, state, done_steps)
+                if comp is not None:
+                    comp.note_launch(done_steps)
+                trap_host = np.asarray(state.trap)
+                chunk_span.set(steps=done_steps)
             parked = int((trap_host == TRAP_HOSTCALL).sum())
             if round_hook is not None:
-                round_hook(int(done_steps), trap_host, t_launch)
+                round_hook(done_steps, trap_host, t_launch)
             if obs.enabled:
                 # per-launch span with lane occupancy + retired delta
                 # (one extra device read per LAUNCH, never per step)
                 live = int((trap_host == 0).sum())
                 ret = int(np.asarray(state.retired, np.int64).sum())
                 obs.span("launch", t_launch, cat="engine", track=track,
-                         steps=int(done_steps), live_lanes=live,
+                         steps=done_steps, live_lanes=live,
                          parked_lanes=parked,
                          retired_delta=ret - prev_ret)
                 prev_ret = ret
@@ -2508,21 +2512,26 @@ class BatchEngine:
                 if fault is not None:
                     fault("serve", total=total)
                 t_serve = obs.now()
-                state = serve_batch_state(self, state)
+                with obs.timed("simt/hostcalls", cat="engine",
+                               lanes=parked):
+                    state = serve_batch_state(self, state)
                 obs.span("serve", t_serve, cat="engine", track=track,
                          lanes=parked)
                 continue
             if not (trap_host == 0).any():
                 break
-            if int(done_steps) == 0:
+            if done_steps == 0:
                 break
         # Never leak the internal TRAP_HOSTCALL sentinel to callers: if the
         # step budget ran out with lanes parked at a stub, serve those
         # pending calls once — the lanes come back as trap == 0 ("still
         # running when max_steps ran out"), the documented semantic.
-        if (np.asarray(state.trap) == TRAP_HOSTCALL).any():
+        parked_end = int((np.asarray(state.trap) == TRAP_HOSTCALL).sum())
+        if parked_end:
             t_serve = obs.now()
-            state = serve_batch_state(self, state)
+            with obs.timed("simt/hostcalls", cat="engine",
+                           lanes=parked_end):
+                state = serve_batch_state(self, state)
             obs.span("serve", t_serve, cat="engine", track=track)
         state = flush_stdout_buffers(self, state)
         state = self._fold_op_hist(state)

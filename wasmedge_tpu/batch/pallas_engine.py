@@ -3818,6 +3818,10 @@ def _build_kernel(used_hids: tuple, D: int, CD: int, W: int, L: int,
     # plane inputs (operands: 15 prefetch args, frames_in at 15, planes
     # from 16) alias the plane outputs (after ctrl/frames)
     aliases = {16 + k: 2 + k for k in range(n_planes)}
+    # a stable name per kernel kind: it names the Mosaic kernel, the
+    # jitted program and the device event of a profiler trace, so the
+    # optimistic and the careful kernel can be told apart there
+    kname = "wasm_kernel_optimistic" if optimistic else "wasm_kernel_careful"
     fn = pl.pallas_call(
         kernel,
         grid_spec=spec,
@@ -3826,8 +3830,10 @@ def _build_kernel(used_hids: tuple, D: int, CD: int, W: int, L: int,
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
+        name=kname,
     )
     if not three_d:
+        fn.__name__ = kname
         return jax.jit(fn, donate_argnums=tuple(
             range(16, 16 + n_planes)))
 
@@ -3845,6 +3851,7 @@ def _build_kernel(used_hids: tuple, D: int, CD: int, W: int, L: int,
             res.append(x.reshape(x.shape[0], -1) if x.ndim == 3 else x)
         return tuple(res)
 
+    run.__name__ = kname
     return jax.jit(run, donate_argnums=tuple(
         range(16, 16 + n_planes)))
 

@@ -211,6 +211,30 @@ class BlockScheduler:
     def __init__(self, outer: PallasUniformEngine, func_name: str,
                  args_lanes: List, max_steps: int):
         self.outer = outer
+        # flight recorder shared with the outer engine (obs/): the
+        # scheduler reports launches, serves, splits, frees, residue
+        # handoffs and live-lane occupancy; NULL_RECORDER when off
+        self.obs = outer.obs
+        # per-device trace attribution (parallel/mesh.py sets obs_track
+        # on each device's engine so multi-chip runs keep their devices'
+        # events on separate tracks instead of one interleaved "pallas")
+        self._track = getattr(outer, "obs_track", "pallas")
+        self._track_simt = "simt" if self._track == "pallas" \
+            else self._track
+        # phase spans (obs.timed): under a caller's open span they land
+        # on its track; a device thread of a mesh drive has none open
+        self._track_phases = None if self._track == "pallas" \
+            else self._track + "/phases"
+        with self._phase("batch/plan"):
+            self._setup(func_name, args_lanes, max_steps)
+
+    def _phase(self, name, **args):
+        return self.obs.timed(name, cat="scheduler",
+                              track=self._track_phases, **args)
+
+    def _setup(self, func_name, args_lanes, max_steps):
+        """Entry grouping and the initial planes on the device."""
+        outer = self.outer
         self.inst = outer.inst
         self.cfg = outer.cfg
         self.func_name = func_name
@@ -239,16 +263,6 @@ class BlockScheduler:
         self.fell_back_to_simt = False
         self.splits = 0
         self.quarantined = 0
-        # flight recorder shared with the outer engine (obs/): the
-        # scheduler reports launches, serves, splits, frees, residue
-        # handoffs and live-lane occupancy; NULL_RECORDER when off
-        self.obs = outer.obs
-        # per-device trace attribution (parallel/mesh.py sets obs_track
-        # on each device's engine so multi-chip runs keep their devices'
-        # events on separate tracks instead of one interleaved "pallas")
-        self._track = getattr(outer, "obs_track", "pallas")
-        self._track_simt = "simt" if self._track == "pallas" \
-            else self._track
         self._t_launch = 0.0
         self._plane_idx = _PLANE_IDX_SIMD if outer.img.has_simd \
             else _PLANE_IDX
@@ -460,25 +474,26 @@ class BlockScheduler:
         import jax.numpy as jnp
 
         ctrl_np = self._ctrl()
-        if self._ctrl_dirty:
-            self.state[0] = jnp.asarray(ctrl_np)
-            self._ctrl_dirty = False
-        if self._frames_dirty:
-            self.state[1] = jnp.asarray(self._frames_cache)
-            self._frames_dirty = False
         live = self.block_state == _B_LIVE
         runnable = live & (ctrl_np[:, _C_STATUS] == ST_RUNNING) & \
             (self.block_steps < self.max_steps)
         self._launched = bool(runnable.any())
-        if self._launched:
-            self._live_at_launch = live
-            self._t_launch = self.obs.now()
-            self._launch_blocks = int(runnable.sum())
-            out = self.eng._fn(*self.eng._tables, self.state[0],
-                               self.state[1], *self.state[2:])
-            self.state = list(out)
-            self._ctrl_cache = None   # kernel wrote fresh ctrl/frames
-            self._frames_cache = None
+        self._launch_blocks = int(runnable.sum())
+        with self._phase("batch/launch", blocks=self._launch_blocks):
+            if self._ctrl_dirty:
+                self.state[0] = jnp.asarray(ctrl_np)
+                self._ctrl_dirty = False
+            if self._frames_dirty:
+                self.state[1] = jnp.asarray(self._frames_cache)
+                self._frames_dirty = False
+            if self._launched:
+                self._live_at_launch = live
+                self._t_launch = self.obs.now()
+                out = self.eng._fn(*self.eng._tables, self.state[0],
+                                   self.state[1], *self.state[2:])
+                self.state = list(out)
+                self._ctrl_cache = None   # kernel wrote fresh ctrl/frames
+                self._frames_cache = None
 
     def _ctrl(self) -> np.ndarray:
         """Host mirror of the ctrl plane: ONE transfer per kernel round.
@@ -504,7 +519,8 @@ class BlockScheduler:
         # host-side WASI work runs now, before we sync on the launch
         # dispatched in between — CPU drain overlapping device compute
         self._finish_pending_serve()
-        ctrl_np = self._ctrl()
+        with self._phase("batch/sync"):
+            ctrl_np = self._ctrl()   # waits for the launched kernel
         served = False
         if self._serve_rearms:
             # fold the overlapped serve's re-arms into the fresh mirror
@@ -532,7 +548,8 @@ class BlockScheduler:
                 obs.counter("live_lanes", int(
                     valid[self.block_state == _B_LIVE].sum()))
             if (live & (ctrl_np[:, _C_STATUS] == ST_RECHECK)).any():
-                ctrl_np = self._run_recheck(live)
+                with self._phase("batch/statuses", splits=self.splits):
+                    ctrl_np = self._run_recheck(live)
             else:
                 # adaptive-window growth (careful_recheck halves):
                 # clean launches double a shrunken snapshot interval
@@ -578,6 +595,10 @@ class BlockScheduler:
     def _handle_statuses(self, ctrl_np) -> bool:
         """Harvest/serve/split each live block by its status.  Returns
         True if progress was made that could unblock another pass."""
+        with self._phase("batch/statuses", splits=self.splits):
+            return self._statuses(ctrl_np)
+
+    def _statuses(self, ctrl_np) -> bool:
         progress = False
         hostcall_blocks = []
         # classify first so the downloads below batch into single
@@ -1035,6 +1056,10 @@ class BlockScheduler:
     def _run_simt_residue(self):
         if not self._simt_queue:
             return
+        with self._phase("batch/residue", groups=len(self._simt_queue)):
+            self._simt_residue()
+
+    def _simt_residue(self):
         import jax.numpy as jnp
 
         from wasmedge_tpu.batch.engine import BatchState
@@ -1196,8 +1221,10 @@ class BlockScheduler:
         from wasmedge_tpu.batch.engine import BatchResult
         from wasmedge_tpu.batch.pallas_engine import decode_result_rows
 
-        results = decode_result_rows(self.res_lo, self.res_hi, self.nres)
-        steps = max(int(self.block_steps.max(initial=0)),
-                    getattr(self, "_residue_steps", 0))
-        return BatchResult(results=results, trap=self.trap,
-                           retired=self.retired, steps=steps)
+        with self._phase("batch/result"):
+            results = decode_result_rows(self.res_lo, self.res_hi,
+                                         self.nres)
+            steps = max(int(self.block_steps.max(initial=0)),
+                        getattr(self, "_residue_steps", 0))
+            return BatchResult(results=results, trap=self.trap,
+                               retired=self.retired, steps=steps)

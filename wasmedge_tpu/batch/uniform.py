@@ -981,6 +981,10 @@ class UniformBatchEngine:
         )
 
     def run(self, func_name, args_lanes, max_steps: int = 10_000_000):
+        with self.obs.timed("batch/run", cat="engine", lanes=self.lanes):
+            return self._run(func_name, args_lanes, max_steps)
+
+    def _run(self, func_name, args_lanes, max_steps):
         import numpy as np
 
         from wasmedge_tpu.batch.engine import BatchResult
@@ -1029,8 +1033,9 @@ class UniformBatchEngine:
             tt = jnp.asarray(t0_time_planes() if t0_active
                              else dummy_time)
             t_launch = obs.now()
-            ust = self._uchunk(ust, tt)
-            status = int(ust.status)
+            with obs.timed("simt/chunk", cat="engine"):
+                ust = self._uchunk(ust, tt)
+                status = int(ust.status)    # waits for the chunk
             if obs.enabled:
                 # converged path: every lane shares one pc, so
                 # occupancy is all-or-nothing

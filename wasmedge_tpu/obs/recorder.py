@@ -6,8 +6,14 @@ supervisor retries); when a 4096-lane run misbehaves, aggregate G/s
 numbers say nothing about *where* the time or the lanes went.  The
 recorder is the single sink every layer reports into:
 
-  span(name, t0)    a timed phase (kernel launch, hostcall drain,
-                    checkpoint save, SIMT residue pass)
+  timed(name)       a phase of the calling thread, as a context
+                    manager: a jax.profiler.TraceAnnotation named
+                    "wasm/<name>" on BOTH recorders (so a profiler
+                    trace holds the program's phases with obs off), and
+                    on the FlightRecorder a ring span with its parent
+  span(name, t0)    a timed phase closed after the fact, ring only
+                    (kernel launch, hostcall drain, checkpoint save,
+                    and what crosses threads: request/<tenant>)
   instant(name)     a point incident (block split, quarantine, retry,
                     every FailureRecord)
   counter(name, v)  a sampled value series (live-lane occupancy,
@@ -28,16 +34,22 @@ epoch + (mono - mono0), so the trace timeline is still wall-anchored.
 Overhead discipline (guard-object pattern): when observability is off,
 every instrumented component holds NULL_RECORDER, whose hooks are
 no-ops and whose `enabled` is False — hot paths pay one attribute check
-(`if obs.enabled:`) per *launch/serve round*, never per step, and the
-disabled configuration allocates nothing.
+(`if obs.enabled:`) per *launch/serve round*, never per step.  A
+timed() span is the one thing the guard object does not make a no-op:
+it builds one TraceAnnotation and its wrapper; the annotation is an
+inactive TraceMe (one flag test) while no profiler session runs.
 """
 
 from __future__ import annotations
 
 import bisect
+import functools
+import threading
 import time
 from collections import deque
 from typing import Optional
+
+SPAN_PREFIX = "wasm/"   # the program's spans in a jax.profiler trace
 
 # Log-spaced latency bucket upper bounds (seconds) for the hostcall
 # drain histograms; the +Inf bucket is implicit.  10us..30s covers
@@ -79,6 +91,8 @@ class LatencyHistogram:
 
 
 class _NullSpan:
+    """What timed() returns where jax cannot be imported."""
+
     __slots__ = ()
 
     def __enter__(self):
@@ -87,8 +101,44 @@ class _NullSpan:
     def __exit__(self, *exc):
         return False
 
+    def set(self, **args):
+        pass
+
 
 _NULL_SPAN = _NullSpan()
+
+
+@functools.cache
+def _trace_annotation():
+    """jax.profiler.TraceAnnotation, imported once, on the first span;
+    None where jax cannot be imported (the scalar-only install)."""
+    try:
+        from jax.profiler import TraceAnnotation
+    except ImportError:
+        return None
+    return TraceAnnotation
+
+
+class _ProfilerSpan:
+    """NullRecorder.timed(): the profiler's annotation and no state."""
+
+    __slots__ = ("_ann",)
+
+    def __init__(self, ann):
+        self._ann = ann
+
+    def __enter__(self):
+        self._ann.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        self._ann.__exit__(*exc)
+        return False
+
+    def set(self, **args):
+        """Args known only at the end of the span: a TraceAnnotation
+        took its own when it was entered, so these reach the ring event
+        alone, and nothing here."""
 
 
 class NullRecorder:
@@ -108,8 +158,11 @@ class NullRecorder:
     def span(self, name, t0, cat="", track="main", **args):
         pass
 
-    def timed(self, name, cat="", track="main", **args):
-        return _NULL_SPAN
+    def timed(self, name, cat="", track=None, **args):
+        ann = _trace_annotation()
+        if ann is None:
+            return _NULL_SPAN
+        return _ProfilerSpan(ann(SPAN_PREFIX + name, **args))
 
     def instant(self, name, cat="", track="main", **args):
         pass
@@ -157,26 +210,43 @@ class NullRecorder:
 NULL_RECORDER = NullRecorder()
 
 
-class _Span:
-    """Context manager from FlightRecorder.timed()."""
+class _Span(_ProfilerSpan):
+    """FlightRecorder.timed(): the annotation, and a ring span that
+    names as `parent` the span open on this thread when it was entered.
+    Without a track of its own it lands on its parent's, so the Chrome
+    export nests it there."""
 
-    __slots__ = ("_rec", "_name", "_cat", "_track", "_args", "_t0")
+    __slots__ = ("_rec", "name", "_cat", "track", "_args", "_t0",
+                 "_parent")
 
-    def __init__(self, rec, name, cat, track, args):
+    def __init__(self, rec, ann, name, cat, track, args):
         self._rec = rec
-        self._name = name
+        self._ann = ann
+        self.name = name
         self._cat = cat
-        self._track = track
+        self.track = track
         self._args = args
 
     def __enter__(self):
+        stack = self._rec._open_spans()
+        parent = stack[-1] if stack else None
+        self._parent = parent and parent.name
+        if self.track is None:
+            self.track = parent.track if parent else "phases"
+        stack.append(self)
         self._t0 = self._rec.now()
-        return self
+        return super().__enter__()
 
     def __exit__(self, *exc):
-        self._rec.span(self._name, self._t0, cat=self._cat,
-                       track=self._track, **self._args)
+        super().__exit__(*exc)
+        self._rec._open_spans().pop()
+        self._rec.span(self.name, self._t0, cat=self._cat,
+                       track=self.track, parent=self._parent,
+                       **self._args)
         return False
+
+    def set(self, **args):
+        self._args.update(args)
 
 
 class FlightRecorder:
@@ -195,6 +265,7 @@ class FlightRecorder:
         self.dropped = 0
         self._epoch = time.time()       # wall anchor, sampled once
         self._mono0 = time.monotonic()  # duration clock zero
+        self._open = threading.local()  # .stack: this thread's open spans
         self.hostcalls = {}        # kind -> LatencyHistogram
         self.admission = LatencyHistogram()  # serve submit -> install
         self.hv_swaps = {}         # "in"/"out" -> LatencyHistogram
@@ -253,8 +324,16 @@ class FlightRecorder:
                     "ts": self._ts(t0), "dur": max(t1 - t0, 0.0),
                     "track": track, "args": args})
 
-    def timed(self, name, cat="", track="main", **args):
-        return _Span(self, name, cat, track, args)
+    def timed(self, name, cat="", track=None, **args):
+        ann = _trace_annotation()
+        return _Span(self, ann(SPAN_PREFIX + name, **args) if ann
+                     else _NULL_SPAN, name, cat, track, args)
+
+    def _open_spans(self) -> list:
+        stack = getattr(self._open, "stack", None)
+        if stack is None:
+            stack = self._open.stack = []
+        return stack
 
     def instant(self, name, cat="", track="main", **args):
         self._push({"name": name, "ph": "i", "cat": cat,

@@ -153,12 +153,16 @@ class LaneRecycler:
         """Re-initialize `lanes` in place for `func_idx` with per-lane
         argument cells (`args_rows[i][k]` = arg i of the request going
         into lanes[k]).  Returns the updated state."""
-        import jax.numpy as jnp
-
         lanes = np.asarray(lanes, np.int64)
         n = int(lanes.size)
         if n == 0:
             return state
+        with self.engine.obs.timed("serve/install", cat="serve", lanes=n):
+            return self._install(state, lanes, n, func_idx, args_rows)
+
+    def _install(self, state, lanes, n, func_idx, args_rows):
+        import jax.numpy as jnp
+
         # imagestore observability: when the engine carries a
         # pre-initialized overlay for this function's module, these
         # lanes are snapshot-admitted (the template the column-set
@@ -209,5 +213,8 @@ class LaneRecycler:
         lanes = np.asarray(lanes, np.int64)
         if lanes.size == 0:
             return state
-        return state._replace(trap=state.trap.at[jnp.asarray(lanes)].set(
-            jnp.int32(TRAP_DONE)))
+        with self.engine.obs.timed("serve/park", cat="serve",
+                                   lanes=int(lanes.size)):
+            return state._replace(
+                trap=state.trap.at[jnp.asarray(lanes)].set(
+                    jnp.int32(TRAP_DONE)))

@@ -599,16 +599,36 @@ class BatchServer:
                 time.sleep(nap)
 
     def _step_body(self) -> bool:
-        with self._lock:
+        with self.obs.timed("serve/round", cat="serve",
+                            track="serve/phases",
+                            round=self.counters["rounds"] + 1):
+            return self._round()
+
+    def _lock_timed(self):
+        """Acquire the server lock inside a `serve/lock_wait` span (a
+        thousand handler threads contend for it); the caller releases
+        it in a `finally`."""
+        with self.obs.timed("serve/lock_wait", cat="serve"):
+            self._lock.acquire()
+
+    def _round(self) -> bool:
+        timed = self.obs.timed
+        self._lock_timed()
+        try:
             now = time.monotonic()
-            self._expire_queued(now)
-            if self.effects is not None:
-                self._effects_boundary(now)
-            admitted = self._admit(now)
+            with timed("serve/admit", cat="serve") as span:
+                self._expire_queued(now)
+                if self.effects is not None:
+                    with timed("serve/effects", cat="serve"):
+                        self._effects_boundary(now)
+                admitted = self._admit(now)
+                span.set(admitted=admitted)
             if self.hv is not None:
-                admitted += self._hv_boundary(now)
+                with timed("serve/hv", cat="serve"):
+                    admitted += self._hv_boundary(now)
             if self._compactor is not None and self._bindings:
-                self._compact_round()
+                with timed("serve/compact", cat="serve"):
+                    self._compact_round()
             if self.effects is not None:
                 # lane -> request id snapshot for the launch slice's
                 # intercept (bindings are boundary-stable, so the
@@ -619,6 +639,8 @@ class BatchServer:
             run_from = (self.state, self.total) if self._bindings else None
             self._snap_stdout()   # pre-launch pairing for checkpoint()
             self._inflight = run_from is not None
+        finally:
+            self._lock.release()
         # the device launch slice runs OUTSIDE the lock — submit()/
         # shutdown() from other threads must not block for a whole
         # round's wall time.  Safe because only the serving thread
@@ -637,8 +659,10 @@ class BatchServer:
                     eng._fault_hook = self.faults.fire
                     if hasattr(self.faults, "flip"):
                         eng._flip_hook = self.faults.flip
-                launched = eng.run_from_state(run_from[0], run_from[1],
-                                              run_from[1] + chunk)
+                with timed("serve/launch", cat="serve") as span:
+                    launched = eng.run_from_state(
+                        run_from[0], run_from[1], run_from[1] + chunk)
+                    span.set(steps=launched[1] - run_from[1])
             except (KeyboardInterrupt, SystemExit):
                 raise
             except Exception as e:
@@ -647,7 +671,8 @@ class BatchServer:
                 eng._fault_hook = None
                 eng._flip_hook = None
             t_launch = time.monotonic() - t0
-        with self._lock:
+        self._lock_timed()
+        try:
             self._inflight = False
             self._wake.notify_all()   # unblock a waiting checkpoint()
             if self.failed is not None:
@@ -664,17 +689,22 @@ class BatchServer:
                     if self.k.autotune:
                         self._autotune_observe(t_launch, stats0)
                 now = time.monotonic()
-                self._enforce(now)
+                with timed("serve/enforce", cat="serve") as span:
+                    self._enforce(now)
+                    span.set(killed=len(self._kills))
             self.counters["rounds"] += 1
-            harvested = self._harvest()
+            with timed("serve/harvest", cat="serve") as span:
+                harvested = self._harvest()
+                span.set(harvested=harvested)
             if self.effects is not None and self._bindings \
                     and self.state is not None:
                 # the park half of the suspend boundary: serialize
                 # every TRAP_PARKED lane out through the SwapStore and
                 # free its physical lane for the recycler
-                self.state = self.effects.park_boundary(
-                    self.engine, self.state, self._bindings,
-                    self.recycler, self._effects_on_free)
+                with timed("serve/effects", cat="serve"):
+                    self.state = self.effects.park_boundary(
+                        self.engine, self.state, self._bindings,
+                        self.recycler, self._effects_on_free)
             self.obs.counter("serve_live_lanes", len(self._bindings),
                              track="serve")
             self.obs.counter("serve_queue_depth", len(self.queue),
@@ -683,7 +713,9 @@ class BatchServer:
                 self.obs.counter("serve_parked_sessions",
                                  self.effects.in_flight(),
                                  track="serve")
-            self._maybe_checkpoint()
+            if self.k.checkpoint_every_rounds:
+                with timed("serve/checkpoint", cat="serve"):
+                    self._maybe_checkpoint()
             if not (admitted or progressed or harvested) \
                     and not self._bindings and len(self.queue) \
                     and not (self.hv is not None and self.hv.waiting) \
@@ -715,6 +747,8 @@ class BatchServer:
                         f"(tenant {req.tenant!r} admission-blocked)"))
                 return False
             return self._runnable_work()
+        finally:
+            self._lock.release()
 
     def run_until_idle(self, max_rounds: Optional[int] = None) -> int:
         """Drive step() until no work remains; returns rounds executed."""
