@@ -193,3 +193,22 @@ def test_snapshot_interval_commits():
         expect = s_ex.invoke(s_store, s_inst.find_func("f"), [n])[0]
         got = np.asarray(res.results[0], np.int64) & 0xFFFFFFFF
         assert (got == (int(expect) & 0xFFFFFFFF)).all(), (hbm, got[0])
+
+
+@pytest.mark.parametrize("hbm", [False, True])
+def test_rollbacks_across_commits_stay_lane_exact(hbm, monkeypatch):
+    """The whole path with a short build-time interval: lanes leave the
+    loop at different iterations, every divergence rolls back across
+    one or more periodic commits, the careful kernel and the splitter
+    take over, and each lane still returns its own count with its own
+    memory."""
+    from wasmedge_tpu.batch.pallas_engine import PallasUniformEngine
+    from tests.test_dispatch_tree import counting_loop
+
+    monkeypatch.setattr(PallasUniformEngine, "SNAP_STEPS", 64)
+    ex, store, inst, eng = make_engine(counting_loop(True), hbm=hbm)
+    ns = np.array([90, 90, 17, 90, 55, 55, 90, 3], np.int64)
+    res = eng.run("f", [ns], max_steps=2_000_000)
+    assert np.asarray(res.results[0]).tolist() == ns.tolist()
+    assert (np.asarray(res.trap) == -1).all()
+    assert eng.recheck_rounds >= 1

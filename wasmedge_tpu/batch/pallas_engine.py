@@ -30,16 +30,29 @@ code almost always computes identical addresses in every lane) and, when the
 memory is small enough, fall back to a masked compare-reduce gather/scatter
 over the whole [W, block] array for divergent addresses.
 
-Dispatch is a balanced binary tree of `lax.cond` over *densely renumbered*
-handler ids (Mosaic lowers `lax.switch` to a linear if-chain, ~15ns per
-position walked; the tree is ~log2(N) branches, uniform across ids): only
-the handlers a module actually uses are compiled into its kernel, so small
-modules get small, fast-compiling kernels.  Kernels are cached by
-(used-handler set, state geometry); modules sharing both share a compile.
+Dispatch is a binary tree of `lax.cond` over *densely renumbered* handler
+ids (Mosaic lowers `lax.switch` to a linear if-chain): only the handlers a
+module actually uses are compiled into its kernel, so small modules get
+small, fast-compiling kernels.  The time of a dispatch is mostly the scalar
+skeleton around a few dozen vector operations, and every scf.if region it
+walks, taken or not, costs the scalar core 6-9 ns on a v5e (measured by
+PR 27 on fib(30) x 4096 lanes: 11 fewer regions in a leaf + inner call
+pair saved 103 ns, the 18 of the per-dispatch commit check with its SMEM
+reads 107 ns, both together 247 ns of 771; PERF.md section 5).  So the
+tree is planned (plan_dispatch_tree) from ENTRY-SLOT weights: a handler
+weighs the number of slots at which a converged dispatch can start with
+its id (block heads, unfused slots, jump targets), dense ids are numbered
+hot-first, and the handlers that only a resume can reach hang in one cold
+subtree.  The optimistic kernel's periodic commit is one more leaf of
+that subtree, taken by the loop's next iteration when `steps` says it is
+due, so a dispatch pays one scalar compare for it and no region.  Kernels
+are cached by (used-handler order, state geometry); modules sharing both
+share a compile.
 """
 
 from __future__ import annotations
 
+import collections
 import functools
 from typing import List, Optional
 
@@ -266,6 +279,19 @@ def decode_result_rows(stack_lo: np.ndarray, stack_hi: np.ndarray,
     return results
 
 
+def _jump_targets(img) -> set:
+    """Every pc a jump can land on: function entries, br/br_if targets
+    and br_table entries.  Fusion never absorbs past one of these."""
+    targets = set(int(x) for x in img.f_entry)
+    for pc in range(img.code_len):
+        cl = int(img.cls[pc])
+        if cl in (CLS_BR, CLS_BRZ, CLS_BRNZ):
+            targets.add(int(img.a[pc]))
+    for e in range(img.br_table.shape[0]):
+        targets.add(int(img.br_table[e, 0]))
+    return targets
+
+
 def fuse_image(hid, a, b, c, ilo, ihi, img):
     """Peephole superinstruction fusion over the flat-hid planes.
 
@@ -278,13 +304,7 @@ def fuse_image(hid, a, b, c, ilo, ihi, img):
     Returns rewritten copies; the originals (and every other engine's
     image) are untouched — this is a pallas-private encoding."""
     n = img.code_len
-    targets = set(int(x) for x in img.f_entry)
-    for pc in range(n):
-        cl = int(img.cls[pc])
-        if cl in (CLS_BR, CLS_BRZ, CLS_BRNZ):
-            targets.add(int(img.a[pc]))
-    for e in range(img.br_table.shape[0]):
-        targets.add(int(img.br_table[e, 0]))
+    targets = _jump_targets(img)
     hid = hid.copy()
     a = a.copy()
     b = b.copy()
@@ -395,9 +415,10 @@ def fuse_image(hid, a, b, c, ilo, ihi, img):
 # every maximal straight-line run of *pure* stack ops (const, local/
 # global traffic, drop/select, non-trapping alu) fuses into ONE handler
 # that keeps intermediate values in vector registers — dispatch cost
-# (measured ~150ns/dispatch: the lax.cond tree walk plus the VMEM
-# dependency chain between consecutive stack ops) is paid once per
-# block instead of once per instruction.  Any non-pure op (branch,
+# (128 ns a dispatch on a v5e before PR 27 and 87 ns after, fib(30):
+# the lax.cond tree walk plus the VMEM dependency chain between
+# consecutive stack ops) is paid once per block instead of once per
+# instruction.  Any non-pure op (branch,
 # call, return, load/store, div/rem, memory.*, hostcall) is absorbed as
 # the block's TERMINAL: the handler flushes its virtual stack to the
 # VMEM rows the op expects and delegates to the op's ORIGINAL handler,
@@ -455,13 +476,7 @@ def fuse_blocks(hid, img):
     pick the compute fn.  Deterministic: tpu.aot artifacts verify the
     persisted hid plane by regeneration (aot/__init__.py)."""
     n = img.code_len
-    targets = set(int(x) for x in img.f_entry)
-    for pc in range(n):
-        cl = int(img.cls[pc])
-        if cl in (CLS_BR, CLS_BRZ, CLS_BRNZ):
-            targets.add(int(img.a[pc]))
-    for e in range(img.br_table.shape[0]):
-        targets.add(int(img.br_table[e, 0]))
+    targets = _jump_targets(img)
     # call-return / hostcall-re-arm / trap-partial-resume addresses need
     # no seeding: a non-pure op always ends its block, so the next block
     # starts at its pc+1 anyway, and absorbed slots keep their original
@@ -567,6 +582,103 @@ def fuse_blocks(hid, img):
         else:
             pc += 1
     return hid, tuple(shapes)
+
+
+def entry_slots(hid, shapes, img) -> np.ndarray:
+    """Mask of the slots at which a dispatch can START while a block's
+    lanes stay converged, for the plane fuse_blocks wrote: block heads,
+    the slots it stepped over unfused, and absorbed slots a jump lands
+    on (a non-pure terminal may itself be a jump target and then
+    dispatches its own untouched hid).  Every other absorbed slot keeps
+    its hid only for a resume (a bail, a split child, a SIMT handoff
+    coming back), so its handler is compiled but cold."""
+    n = img.code_len
+    entry = np.zeros(n, bool)
+    pc = 0
+    while pc < n:
+        entry[pc] = True
+        h = int(hid[pc])
+        pc += len(shapes[h - H_BLOCK_BASE]) if h >= H_BLOCK_BASE else 1
+    for t in _jump_targets(img):
+        if 0 <= t < n:
+            entry[t] = True
+    return entry
+
+
+def plan_dispatch_tree(weights):
+    """Plan the kernel's dispatch tree over dense handler ids 0..n-1,
+    given in hot-first order (weights non-increasing, zeros last).
+
+    Returns (tree, depths): tree is a leaf `i` or a node
+    `(mid, left, right)` read as "id < mid ? left : right" over a
+    contiguous id range; depths[i] is the number of branches walked to
+    reach handler i.  The split point of a range is the one that best
+    halves its WEIGHT, ties going to the one that best halves its
+    COUNT (equal weights give the midpoint tree).  The zero-weight tail
+    is one leaf of that tree, so cold handlers never sit between hot
+    ones, and is itself split by count alone, so it cannot degenerate
+    into a chain.  No leaf lies deeper than ceil(log2 n) + 2, however
+    skewed the weights: the chip's compiler survives only so many
+    nested regions (tests/test_chip_compile.py), and a handler's own
+    nesting comes on top of its depth here."""
+    n = len(weights)
+    w = [int(x) for x in weights]
+    if n == 0 or any(x < 0 for x in w) or \
+            any(w[i] < w[i + 1] for i in range(n - 1)):
+        raise ValueError(f"weights must be hot-first: {weights!r}")
+    nhot = sum(1 for x in w if x > 0)
+    cap = (n - 1).bit_length() + 2
+    depths = [0] * n
+
+    def by_count(lo, hi, d):
+        if hi - lo == 1:
+            depths[lo] = d
+            return lo
+        mid = lo + (hi - lo) // 2
+        return (mid, by_count(lo, mid, d + 1), by_count(mid, hi, d + 1))
+
+    # items of the weighted part: one per hot handler, then the whole
+    # cold tail (if any) as a single zero-weight item
+    items = [(i, i + 1, w[i]) for i in range(nhot)]
+    if nhot < n:
+        items.append((nhot, n, 0))
+
+    def by_weight(a, b, d):
+        lo, hi = items[a][0], items[b - 1][1]
+        if b - a == 1:
+            return by_count(lo, hi, d)
+        room = 1 << (cap - d - 1)    # leaves a child may still hold
+        total = sum(it[2] for it in items[a:b])
+        best, acc = None, 0
+        for m in range(a + 1, b):
+            acc += items[m - 1][2]
+            mid = items[m][0]
+            if mid - lo > room or hi - mid > room:
+                continue
+            key = (abs(2 * acc - total), abs((m - a) - (b - m)))
+            if best is None or key < best[0]:
+                best = (key, m)
+        if best is None:
+            return by_count(lo, hi, d)
+        m = best[1]
+        return (items[m][0], by_weight(a, m, d + 1), by_weight(m, b, d + 1))
+
+    return by_weight(0, len(items), 0), tuple(depths)
+
+
+def kernel_dispatch_plan(hid_weights, optimistic):
+    """The tree one kernel is built with: its handlers and, in the
+    optimistic kernel, the periodic commit as one more cold leaf (the
+    dense id after the last handler)."""
+    return plan_dispatch_tree(
+        tuple(hid_weights) + ((0,) if optimistic else ()))
+
+
+def expected_and_max_depth(weights, depths):
+    """(expected, max) depth of a planned tree; expected over the
+    weights, which are static entry counts, not a profile."""
+    return (sum(w * d for w, d in zip(weights, depths))
+            / max(sum(weights), 1), max(depths))
 
 
 # SMEM budget for the 7 code planes — the ONE code-size limit shared by
@@ -676,7 +788,10 @@ def _build_kernel(used_hids: tuple, D: int, CD: int, W: int, L: int,
     ~1.7µs at Lblk=4096) is replaced by a lane-0 decision plus a pure
     vector *canary* accumulation (canary |= lane ^ lane0).  The canary
     is validated by ONE reduction per commit point: every `snap_steps`
-    dispatches, before any dirty-window writeback, and at kernel exit.
+    steps (the commit is a cold leaf of the dispatch tree that the loop
+    takes in place of an instruction when `steps - ls` reaches the
+    interval; see commit_due/commit below), before any dirty-window
+    writeback, and at kernel exit.
     A clean validation writes a snapshot (stacks/globals/trap → shadow
     HBM planes, frames/carry → SMEM); a dirty one rolls back to the
     previous snapshot and exits with ST_RECHECK, and the driver re-runs
@@ -687,7 +802,12 @@ def _build_kernel(used_hids: tuple, D: int, CD: int, W: int, L: int,
     the careful kernel.  Convergence validation thus costs O(1)
     reductions per ~snap_steps instructions instead of O(1) per
     instruction, which is what lets one TensorCore retire thousands of
-    converged lanes per dispatch at row-op cost."""
+    converged lanes per dispatch at row-op cost.
+
+    used_hids is the dense handler order (hot-first) and hid_weights
+    its entry-slot weights: dispatch() follows
+    kernel_dispatch_plan(hid_weights, optimistic), so both are part of
+    what a kernel is (and of the export-cache key)."""
     import jax
     import jax.numpy as jnp
     from jax import lax
@@ -3554,101 +3674,107 @@ def _build_kernel(used_hids: tuple, D: int, CD: int, W: int, L: int,
 
         handlers = [handler_for(h) for h in used_hids]
 
-        def dispatch(hid, c):
-            """Weight-balanced binary tree of lax.cond over the dense
-            handler ids.  Mosaic lowers lax.switch to a LINEAR if-chain
-            (~15ns per position walked), so the tree keeps dispatch at
-            ~log branches; splitting on cumulative STATIC OPCODE
-            FREQUENCY instead of id count puts the handlers that
-            actually run at shallow depth (expected depth approaches
-            the hid distribution's entropy — a concatenated
-            multi-tenant image with dozens of live handlers gains the
-            most).  Bit-exact vs lax.switch; plain midpoint split when
-            no weights are known."""
-            w = list(hid_weights) if hid_weights else [1] * len(handlers)
+        # ---- the periodic commit (optimistic mode) --------------------
+        # One canary validation + snapshot per snap_steps steps is the
+        # whole point of the mode: per-step cross-lane reductions become
+        # per-interval.  The commit point is a function of `steps`
+        # alone, so a dispatch pays a scalar compare for it and nothing
+        # else: when it falls due, the loop's next iteration dispatches
+        # the commit in place of an instruction, as one more (cold) leaf
+        # of the dispatch tree.  It is not an outer loop around the
+        # dispatch loop because Mosaic's layout inference recurses over
+        # nested regions on a small stack: the hbm kernels already sit
+        # at the depth it survives, and one more level crashes the
+        # compiler (tests/test_chip_compile.py is the guard).
+        def commit_due(c):
+            """The FIRST interval after launch is short: genuinely
+            divergent blocks (mixed entries the scheduler could not
+            group) diverge within a few hundred steps, and a short
+            first window bounds the optimistic run-up their rollback
+            discards."""
+            ls = c[IDX["ls"]]
+            interval = jnp.where(ls == 0,
+                                 jnp.minimum(I32(min(512, snap_steps)),
+                                             snap_dyn),
+                                 snap_dyn)
+            return (c[0] - ls) >= interval
 
-            def tree(lo, hi):
-                if hi - lo == 1:
-                    return handlers[lo](c)
-                total = sum(w[lo:hi])
-                best_mid, best_bal, acc = lo + 1, None, 0
-                for m in range(lo + 1, hi):
-                    acc += w[m - 1]
-                    bal = abs(2 * acc - total)
-                    if best_bal is None or bal < best_bal:
-                        best_bal, best_mid = bal, m
-                mid = best_mid
+        def commit(c):
+            """A dirty validation rolls back to the last snapshot and
+            leaves with ST_RECHECK; a clean one records the current
+            state as the next rollback point."""
+            flag[0] = jnp.any(srow(canr, 0) != 0).astype(jnp.int32)
+
+            def rolled():
+                do_restore()
+                return rolled_carry()
+
+            def clean():
+                kw = {"ls": c[0]}
+                if mem_hbm:
+                    # publish dirty windows before the snapshot so the
+                    # HBM plane IS the snapshot's memory state
+                    @pl.when(c[IDX["wd0"]] != 0)
+                    def _():
+                        _wb_way0(c[IDX["wb0"]])
+
+                    @pl.when(c[IDX["wd1"]] != 0)
+                    def _():
+                        _wb_way1(c[IDX["wb1"]])
+
+                    kw.update(wd0=I32(0), wd1=I32(0))
+                do_snapshot(c)
+                return keep(c, **kw)
+
+            return lax.cond(flag[0] != 0, rolled, clean)
+
+        if optimistic:
+            H_COMMIT = len(handlers)     # dense id of the commit leaf
+            handlers.append(commit)
+        plan, _depths = kernel_dispatch_plan(
+            hid_weights if hid_weights else (1,) * len(used_hids),
+            optimistic)
+
+        def dispatch(hid, c):
+            """Binary tree of lax.cond over the dense handler ids,
+            following plan_dispatch_tree.  Mosaic lowers lax.switch to
+            a LINEAR if-chain, and every scf.if region a dispatch walks
+            costs the scalar core 6-9 ns on a v5e (PR 27, PERF.md
+            section 5; the r4/r5 estimate was ~15 ns), so the plan puts
+            the handlers a converged dispatch can start at near the
+            root and the resume-only ones, with the commit, in one cold
+            subtree: fib's four dispatched handlers sit at depth 2, 2,
+            2 and 3 where the slot-count weights had them at 4.
+            Bit-exact vs lax.switch; the midpoint tree when no weights
+            are known."""
+            def tree(node):
+                if isinstance(node, int):
+                    return handlers[node](c)
+                mid, left, right = node
                 return lax.cond(hid < mid,
-                                lambda: tree(lo, mid),
-                                lambda: tree(mid, hi))
-            return tree(0, len(handlers))
+                                lambda: tree(left),
+                                lambda: tree(right))
+            return tree(plan)
 
         def cond(c):
             return (c[0] < chunk_eff) & (c[7] == ST_RUNNING)
 
         def body(c):
             pc = jnp.clip(c[1], 0, code_len - 1)
-            nc = dispatch(hid_r[pc], c)
+            hid = hid_r[pc]
+            if optimistic:
+                due = commit_due(c)
+                hid = jnp.where(due, I32(H_COMMIT), hid)
+            nc = dispatch(hid, c)
             # un-advanced stops rewind the step count (the next engine
             # re-executes the instruction): divergence, regrow, and
-            # optimistic rollbacks (whose steps were already rewound)
-            counted = jnp.where((nc[7] == I32(ST_DIVERGED)) |
-                                (nc[7] == I32(ST_REGROW)) |
-                                (nc[7] == I32(ST_RECHECK)), I32(0), I32(1))
-            nc = (nc[0] + counted,) + nc[1:]
-            if not optimistic:
-                return nc
-            # periodic commit: one canary validation + snapshot per
-            # snap_steps dispatches (the whole point — per-step
-            # cross-lane reductions become per-interval).  The FIRST
-            # interval after launch is short: genuinely divergent blocks
-            # (mixed entries the scheduler could not group) diverge
-            # within a few hundred steps, and a short first window
-            # bounds the optimistic run-up their rollback discards.
-            interval = jnp.where(nc[IDX["ls"]] == 0,
-                                 jnp.minimum(I32(min(512, snap_steps)),
-                                             snap_dyn),
-                                 snap_dyn)
-            due = ((nc[0] - nc[IDX["ls"]]) >= interval) & \
-                (nc[7] == I32(ST_RUNNING))
-
-            @pl.when(due)
-            def _():
-                flag[0] = jnp.any(srow(canr, 0) != 0).astype(jnp.int32)
-
-            dirty = due & (flag[0] != 0)
-            clean = due & ~dirty
-
-            @pl.when(dirty)
-            def _():
-                do_restore()
-
-            if mem_hbm:
-                # publish dirty windows before the snapshot so the HBM
-                # plane IS the snapshot's memory state
-                @pl.when(clean & (nc[IDX["wd0"]] != 0))
-                def _():
-                    _wb_way0(nc[IDX["wb0"]])
-
-                @pl.when(clean & (nc[IDX["wd1"]] != 0))
-                def _():
-                    _wb_way1(nc[IDX["wb1"]])
-
-            @pl.when(clean)
-            def _():
-                do_snapshot(nc)
-
-            out = []
-            for i, name in enumerate(_CARRY):
-                v = nc[i]
-                if name == "ls":
-                    v = jnp.where(clean, nc[0], v)
-                elif mem_hbm and name in ("wd0", "wd1"):
-                    v = jnp.where(clean, I32(0), v)
-                out.append(v)
-            rolled = rolled_carry()
-            return tuple(jnp.where(dirty, r, v)
-                         for r, v in zip(rolled, out))
+            # optimistic rollbacks (whose steps were already rewound);
+            # a commit retires nothing
+            uncounted = (nc[7] == I32(ST_DIVERGED)) | \
+                (nc[7] == I32(ST_REGROW)) | (nc[7] == I32(ST_RECHECK))
+            if optimistic:
+                uncounted = uncounted | due
+            return (nc[0] + jnp.where(uncounted, I32(0), I32(1)),) + nc[1:]
 
         init = (I32(0), ctrl_r[blk, _C_PC], ctrl_r[blk, _C_SP],
                 ctrl_r[blk, _C_FP], ctrl_r[blk, _C_OB], ctrl_r[blk, _C_CD],
@@ -3666,6 +3792,9 @@ def _build_kernel(used_hids: tuple, D: int, CD: int, W: int, L: int,
             do_snapshot(init)
         fin = lax.while_loop(cond, body, init)
         if optimistic:
+            # a commit that fell due on the launch's last dispatch
+            fin = lax.cond(commit_due(fin) & (fin[7] == I32(ST_RUNNING)),
+                           commit, lambda c: c, fin)
             # exit validation: every path out of the loop (chunk/fuel
             # exhaustion, DONE, trap, park, diverge) must not publish
             # state built on an unvalidated lane-0 decision
@@ -3930,6 +4059,9 @@ class PallasUniformEngine:
         self.fell_back_to_simt = False
         self.splits = 0  # block-scheduler split count from the last run()
         self.recheck_rounds = 0  # careful-kernel rounds (optimistic mode)
+        # (expected, max) branches a dispatch walks in the kernel's
+        # tree (plan_dispatch_tree), known once a kernel was built
+        self.dispatch_depth = None
         # None = no tpu.aot fused section attached; set by _build when a
         # loaded artifact carries one (True = matched regeneration)
         self.aot_fused_verified = None
@@ -4078,14 +4210,25 @@ class PallasUniformEngine:
                 hid, a_p, b_p, c_p, ilo_p, ihi_p = (
                     attached["hid"], attached["a"], attached["b"],
                     attached["c"], attached["ilo"], attached["ihi"])
-        used = tuple(sorted(set(int(h) for h in hid)))
+        # weight of a handler = the number of slots at which a
+        # converged dispatch can START with its hid: under block fusion
+        # the entry slots (absorbed slots keep their hids for resumes
+        # only and weigh nothing); on the legacy peephole path every
+        # slot.  Dense ids are numbered hot-first (then by flat id, for
+        # determinism) so the contiguous-range tree of
+        # plan_dispatch_tree can put the heavy handlers at the top.
+        live = entry_slots(hid, block_shapes, img) if self.block_fusion \
+            else np.ones(len(hid), bool)
+        count = collections.Counter(int(h) for h in hid[live])
+        used = tuple(sorted(set(int(h) for h in hid),
+                            key=lambda h: (-count[h], h)))
         dense = {h: i for i, h in enumerate(used)}
         hid_dense = np.asarray([dense[int(h)] for h in hid], np.int32)
-        # static frequency of each dense handler id: the dispatch tree
-        # splits on cumulative weight, so hot handlers sit shallow
-        self._hid_weights = tuple(
-            int(c) for c in np.bincount(hid_dense,
-                                        minlength=len(used)))
+        self._hid_weights = tuple(count[h] for h in used)
+        self.dispatch_depth = expected_and_max_depth(
+            self._hid_weights,
+            kernel_dispatch_plan(self._hid_weights, self.optimistic)[1])
+        self.obs.set_dispatch_static(*self.dispatch_depth)
         # host-side view of the fused encoding: the block scheduler's
         # divergence splitter evaluates the stopped instruction from
         # these.  _np_hid_orig is the UNfused plane: a block whose
@@ -4150,8 +4293,10 @@ class PallasUniformEngine:
         import wasmedge_tpu.batch.softfloat as _softfloat_mod
         for _m in (_laneops_mod, _softfloat_mod, _simdops_mod, _image_mod):
             h.update(inspect.getsource(_m).encode())
+        h.update(inspect.getsource(plan_dispatch_tree).encode())
         h.update(repr(self._kargs).encode())
-        h.update(repr((self.optimistic, self.SNAP_STEPS)).encode())
+        h.update(repr((self.optimistic, self.SNAP_STEPS,
+                       self._hid_weights)).encode())
         for k in ("hid", "a", "b", "c", "ilo", "ihi"):
             h.update(np.ascontiguousarray(self._np_fused[k]).tobytes())
         h.update(jax.__version__.encode())
@@ -4541,6 +4686,7 @@ class PallasUniformEngine:
         self.quarantined = sched.quarantined
         self.recheck_rounds = sched.eng.recheck_rounds
         self.aot_fused_verified = sched.eng.aot_fused_verified
+        self.dispatch_depth = sched.eng.dispatch_depth
         return sched.result()
 
     def _serve_hostcalls(self, state, ctrl_np, valid_blocks=None):

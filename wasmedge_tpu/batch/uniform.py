@@ -981,8 +981,21 @@ class UniformBatchEngine:
         )
 
     def run(self, func_name, args_lanes, max_steps: int = 10_000_000):
-        with self.obs.timed("batch/run", cat="engine", lanes=self.lanes):
-            return self._run(func_name, args_lanes, max_steps)
+        tree = self._tree_args()
+        with self.obs.timed("batch/run", cat="engine", lanes=self.lanes,
+                            **tree) as span:
+            res = self._run(func_name, args_lanes, max_steps)
+            if not tree:   # the first run is the one that builds
+                span.set(**self._tree_args())
+            return res
+
+    def _tree_args(self):
+        """What dispatch tree the Pallas kernel was built with, as a
+        span argument; nothing before a kernel exists."""
+        depth = getattr(self.pallas, "dispatch_depth", None)
+        if depth is None:
+            return {}
+        return {"dispatch_depth": f"{depth[0]:.2f}/{depth[1]}"}
 
     def _run(self, func_name, args_lanes, max_steps):
         import numpy as np

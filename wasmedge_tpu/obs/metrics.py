@@ -566,6 +566,17 @@ def render_prometheus(recorder=None, stats=None, hostcall_stats=None,
             w.sample("wasmedge_memfuse_runs",
                      {"verdict": "reverted_sites"},
                      int(mfs.get("unlicensed_sites", 0)))
+        dps = getattr(recorder, "dispatch_static", None)
+        if dps:
+            w.head("wasmedge_dispatch_depth", "gauge",
+                   "Branches one dispatch walks in the newest Pallas "
+                   "kernel's dispatch tree: expected over the static "
+                   "entry-slot weights, and the deepest handler "
+                   "(batch/pallas_engine.py plan_dispatch_tree).")
+            w.sample("wasmedge_dispatch_depth", {"stat": "expected"},
+                     dps["expected"])
+            w.sample("wasmedge_dispatch_depth", {"stat": "max"},
+                     dps["max"])
         if recorder.opcode_counts is not None:
             from wasmedge_tpu.validator.image import lop_name
 

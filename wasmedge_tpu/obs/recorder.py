@@ -197,6 +197,9 @@ class NullRecorder:
     def set_memfuse_static(self, section):
         pass
 
+    def set_dispatch_static(self, expected, deepest):
+        pass
+
     def add_tierup_counts(self, dispatches, retired_comp, retired_total):
         pass
 
@@ -287,6 +290,8 @@ class FlightRecorder:
         # reverted (license-refused) load/store sites + realized runs,
         # set once per plan by BatchEngine._plan_fusion
         self.memfuse_static = None
+        # dispatch-tree depths of the newest Pallas kernel build
+        self.dispatch_static = None
         # compiled-function tier counters folded from the device
         # tu_ctr plane (batch/engine.py _fold_tierup_ctr) + the
         # promotion report set once per plan by _plan_tierup (r20)
@@ -410,6 +415,14 @@ class FlightRecorder:
         plan_fusion report's "memory" section: licensed vs reverted
         sites, realized runs/cells) for the Prometheus export."""
         self.memfuse_static = dict(section)
+
+    def set_dispatch_static(self, expected, deepest):
+        """Record the shape of the dispatch tree the newest Pallas
+        kernel was built with (batch/pallas_engine.py
+        plan_dispatch_tree): expected depth over the static entry
+        weights and the deepest leaf, in branches walked."""
+        self.dispatch_static = {"expected": float(expected),
+                                "max": int(deepest)}
 
     def add_tierup_counts(self, dispatches, retired_comp, retired_total):
         """Fold the device tier-up counters (compiled-function bodies
