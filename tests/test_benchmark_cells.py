@@ -1,0 +1,151 @@
+"""Every cell of BENCHMARK.json finds what benchmark/harness.py looks up
+by name (the cell's workload file, its configuration's file, driver and
+plain reference, each of its per-layer metrics' file and reader), the
+guest a batch configuration names exists, and the instruction counts the
+fib cells pin are the closed form of the guest."""
+
+import json
+import os
+
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+BENCH = os.path.join(ROOT, "benchmark")
+
+
+def _load(*parts):
+    with open(os.path.join(*parts)) as f:
+        return json.load(f)
+
+
+MANIFEST = _load(ROOT, "BENCHMARK.json")
+CELLS = [w["name"] for w in MANIFEST["workloads"]]
+
+
+def _cell(name):
+    cell = next(w for w in MANIFEST["workloads"] if w["name"] == name)
+    conf = next(c for c in MANIFEST["configs"] if c["name"] == cell["config"])
+    return (cell, _load(ROOT, conf["file"]),
+            _load(BENCH, "workloads", name + ".json"))
+
+
+def test_the_manifest_lists_the_batch_cells():
+    assert CELLS == ["batch-fib30-uniform", "batch-mem-uniform",
+                     "batch-fib-divergent"]
+    used = {w["config"] for w in MANIFEST["workloads"]}
+    assert used == {c["name"] for c in MANIFEST["configs"]}
+    # every batch cell reports what the uniform fib cell reports
+    metrics = MANIFEST["end_to_end"] + MANIFEST["per_layer"]
+    for cell in CELLS[1:]:
+        assert [m["name"] for m in metrics
+                if cell in m.get("workloads", ())] == [
+            m["name"] for m in metrics
+            if CELLS[0] in m.get("workloads", ())]
+
+
+@pytest.mark.parametrize("name", CELLS)
+def test_cell_finds_its_files(name):
+    cell, config, workload = _cell(name)
+    assert workload["name"] == name and workload["config"] == cell["config"]
+    assert config["name"] == cell["config"]
+    assert config["chips"] == cell["chips"]
+    for kind, stem in (("drivers", config["driver"]),
+                       ("references", config["reference"])):
+        assert os.path.isfile(os.path.join(BENCH, kind, stem + ".py")), stem
+    reported = 0
+    for group in ("end_to_end", "per_layer"):
+        for m in MANIFEST[group]:
+            if "workloads" in m and name not in m["workloads"]:
+                continue
+            reported += 1
+            if group == "per_layer":
+                spec = _load(BENCH, "layer_metrics", m["name"] + ".json")
+                assert config["driver"] in spec["drivers"], m["name"]
+                assert os.path.isfile(os.path.join(
+                    BENCH, "readers", spec["reader"] + ".py")), m["name"]
+                assert {k: spec[k] for k in ("layer", "unit", "better",
+                                             "source", "moves")} == \
+                    {k: m[k] for k in ("layer", "unit", "better",
+                                       "source", "moves")}, m["name"]
+    assert reported >= 3    # setup_s, one more end to end, one layer
+
+
+@pytest.mark.parametrize("name", [c for c in CELLS if c.startswith("batch-")])
+def test_batch_cell_names_a_guest_the_program_has(name):
+    import wasmedge_tpu.models as models
+    from tests.helpers import load_validate
+
+    _cell_entry, config, workload = _cell(name)
+    mod = load_validate(getattr(models, config["guest"]["builder"])())
+    exports = {e.name for e in mod.exports}
+    assert config["guest"]["export"] in exports
+    assert workload["traffic"]["func"] == config["guest"]["export"]
+    assert set(config["geometry"]) == {
+        "value_stack_depth", "call_stack_depth", "steps_per_launch"}
+    assert len(config["guarantees"]) == 3
+
+
+def _fib(n):
+    a, b = 0, 1
+    for _ in range(n):
+        a, b = b, a + b
+    return a
+
+
+@pytest.mark.parametrize("name,args", [
+    ("batch-fib30-uniform", [30]),
+    ("batch-fib-divergent", list(range(20, 31)))])
+def test_fib_cells_pin_the_guests_instruction_count(name, args):
+    """A leaf call of build_fib retires 7 instructions and an inner call
+    14; fib(n) makes F(n+1) leaf calls and F(n+1) - 1 inner ones."""
+    _cell_entry, _config, workload = _cell(name)
+    counts = workload["expected"]["retired_by_arg"]
+    assert sorted(counts, key=int) == [str(n) for n in args]
+    for n in args:
+        assert counts[str(n)] == 21 * _fib(n + 1) - 14
+    spec = workload["traffic"]["args"]
+    lo, hi = (spec["value"],) * 2 if spec["kind"] == "uniform" \
+        else (spec["lo"], spec["hi"])
+    assert list(range(lo, hi + 1)) == args
+
+
+def test_the_count_the_fib_cells_pin_is_the_scalar_engines():
+    from wasmedge_tpu.common.configure import Configure
+    from wasmedge_tpu.common.statistics import Statistics
+    from wasmedge_tpu.executor import Executor
+    from wasmedge_tpu.loader import Loader
+    from wasmedge_tpu.models import build_fib
+    from wasmedge_tpu.runtime.store import StoreManager
+    from wasmedge_tpu.validator import Validator
+
+    conf = Configure()  # as the cells' retired_by_arg_made_by says
+    conf.statistics.instr_counting = True
+    stat = Statistics(conf)
+    ex = Executor(conf, stat)
+    store = StoreManager()
+    inst = ex.instantiate(store, Validator(conf).validate(
+        Loader(conf).parse_module(build_fib())))
+    ex.invoke_raw(store, inst.find_func("fib"), [15])
+    assert stat.instr_count == 21 * _fib(16) - 14
+
+
+def test_the_memory_cell_pins_an_answer_that_its_reference_gives():
+    """`batch-mem-uniform` records what the scalar engine returned at
+    the timed size beside its instruction count; the plain reference,
+    which the Checker asks, gives the same, it is not the 0 that 64
+    xored passes would give, and the count is the guest's closed form."""
+    import importlib.util
+
+    _cell_entry, config, workload = _cell("batch-mem-uniform")
+    spec = importlib.util.spec_from_file_location(
+        "ref_mem", os.path.join(BENCH, "references",
+                                config["reference"] + ".py"))
+    ref = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(ref)
+    n = workload["traffic"]["args"]["value"]
+    expected = workload["expected"]
+    assert ref.reference("mem_checksum", [n]) == [
+        expected["result_by_arg"][str(n)]]
+    assert expected["result_by_arg"][str(n)] != 0
+    assert expected["retired_by_arg"] == {
+        str(n): ref.PASSES * (36 * n + 19) + 3}

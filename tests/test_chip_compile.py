@@ -150,6 +150,10 @@ _KERNELS = {
                         (128, False)),
     "memory-hbm-window": (_memory_wasm, 128, 64, True, None, False,
                           (4096, True)),
+    # the benchmark's mem-batch-4096 as it runs: mem_hbm left to the
+    # auto rule, the window's DMA counters in the kernel
+    "memory-auto": (_memory_wasm, 128, 64, None, None, False,
+                    (4096, True)),
     "v128": (_simd_wasm, 64, 16, None, None, False, (4096, True)),
 }
 

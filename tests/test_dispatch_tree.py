@@ -232,7 +232,8 @@ def test_dispatch_depth_reaches_metrics_and_the_run_span():
     assert eng.pallas.dispatch_depth == (2.2, 6)
     # the first run builds the kernel and learns the depth at its end;
     # a later one carries it from the start (a profiler trace has it)
-    assert eng._tree_args() == {"dispatch_depth": "2.20/6"}
+    assert eng._kernel_args() == {"dispatch_depth": "2.20/6",
+                                  "mem_mode": "none"}
     eng.run("fib", [np.full(LANES, 5, np.int64)], max_steps=500_000)
     runs = [e for e in eng.obs.events if e["name"] == "batch/run"]
     assert [e["args"]["dispatch_depth"] for e in runs] == ["2.20/6"] * 2

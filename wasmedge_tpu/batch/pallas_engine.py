@@ -248,6 +248,10 @@ _C_FUEL = 9
 # discards — and doubles it back toward SNAP_STEPS on clean launches).
 # 0 means "use the kernel's build-time snap_steps".
 _C_SNAP = 10
+# written by the mem_hbm kernel at exit, per launch (never read by it):
+# window fills and dirty-window write-backs, each one CW-row DMA
+_C_WFILLS = 11
+_C_WWBS = 12
 _SNAP_MIN = 256
 
 
@@ -892,7 +896,7 @@ def _build_kernel(used_hids: tuple, D: int, CD: int, W: int, L: int,
         se3s = next(it_) if simd else None
         glo, ghi = next(it_), next(it_)
         if mem_hbm:
-            mwin0, mwin1 = next(it_), next(it_)
+            mwin0, mwin1, wcnt = next(it_), next(it_), next(it_)
             memr = None
         else:
             memr = next(it_)
@@ -1886,15 +1890,37 @@ def _build_kernel(used_hids: tuple, D: int, CD: int, W: int, L: int,
                 # the (8,128)-tiled HBM memref at a dynamic row
                 return pl.multiple_of(v, 8)
 
+            # wcnt counts the window's DMAs of this launch: [0] fills,
+            # [1] dirty write-backs.  Every one of them already sits in
+            # a branch of its own, so the count costs the converged
+            # path nothing and adds no region (one more nesting level
+            # kills the chip's compiler).
+            wcnt[0] = I32(0)
+            wcnt[1] = I32(0)
+
             def _wb_way0(wb):
                 cp = dma(6, mwin0, lsliceR(mem_out, a8(jnp.clip(wb, 0, W - CW)), CW))
                 cp.start()
                 cp.wait()
+                wcnt[1] = wcnt[1] + 1
 
             def _wb_way1(wb):
                 cp = dma(7, mwin1, lsliceR(mem_out, a8(jnp.clip(wb, 0, W - CW)), CW))
                 cp.start()
                 cp.wait()
+                wcnt[1] = wcnt[1] + 1
+
+            def _fill_way0(nb):
+                cp = dma(6, lsliceR(mem_out, a8(nb), CW), mwin0)
+                cp.start()
+                cp.wait()
+                wcnt[0] = wcnt[0] + 1
+
+            def _fill_way1(nb):
+                cp = dma(7, lsliceR(mem_out, a8(nb), CW), mwin1)
+                cp.start()
+                cp.wait()
+                wcnt[0] = wcnt[0] + 1
 
             def _win_select(wfs, rlo, rhi, en):
                 """Make rows [rlo, rhi] resident in one way; returns
@@ -1927,9 +1953,7 @@ def _build_kernel(used_hids: tuple, D: int, CD: int, W: int, L: int,
 
                 @pl.when(repl0)
                 def _():
-                    cp = dma(6, lsliceR(mem_out, a8(nb), CW), mwin0)
-                    cp.start()
-                    cp.wait()
+                    _fill_way0(nb)
 
                 @pl.when(repl1 & (wd1 != 0))
                 def _():
@@ -1937,9 +1961,7 @@ def _build_kernel(used_hids: tuple, D: int, CD: int, W: int, L: int,
 
                 @pl.when(repl1)
                 def _():
-                    cp = dma(7, lsliceR(mem_out, a8(nb), CW), mwin1)
-                    cp.start()
-                    cp.wait()
+                    _fill_way1(nb)
 
                 wb0n = jnp.where(repl0, nb, jnp.where(ov0, SENT, wb0))
                 wd0n = jnp.where(repl0 | ov0, I32(0), wd0)
@@ -2054,15 +2076,11 @@ def _build_kernel(used_hids: tuple, D: int, CD: int, W: int, L: int,
 
                 @pl.when(okp & repl0)
                 def _():
-                    cp = dma(6, lsliceR(mem_out, a8(nb), CW), mwin0)
-                    cp.start()
-                    cp.wait()
+                    _fill_way0(nb)
 
                 @pl.when(okp & repl1)
                 def _():
-                    cp = dma(7, lsliceR(mem_out, a8(nb), CW), mwin1)
-                    cp.start()
-                    cp.wait()
+                    _fill_way1(nb)
 
                 flushed = needs_wb & okp
                 wb0n = jnp.where(repl0, nb, jnp.where(ov0, SENT, wb0))
@@ -3848,6 +3866,9 @@ def _build_kernel(used_hids: tuple, D: int, CD: int, W: int, L: int,
         ctrl_out[blk, _C_CHUNK] = chunk
         ctrl_out[blk, _C_STEPS] = steps
         ctrl_out[blk, _C_SNAP] = snap_in
+        if mem_hbm:
+            ctrl_out[blk, _C_WFILLS] = wcnt[0]
+            ctrl_out[blk, _C_WWBS] = wcnt[1]
 
         outs = [dma(0, slo, lslice(s_lo_out)),
                 dma(1, shi, lslice(s_hi_out)),
@@ -3909,7 +3930,8 @@ def _build_kernel(used_hids: tuple, D: int, CD: int, W: int, L: int,
             + [vmem_rows(NGp),                          # glo
                vmem_rows(NGp)]                          # ghi
             + ([vmem_rows(CW),                          # mwin0 (way 0)
-                vmem_rows(CW)]                          # mwin1 (way 1)
+                vmem_rows(CW),                          # mwin1 (way 1)
+                pltpu.SMEM((2,), jnp.int32)]            # wcnt (DMA counts)
                if mem_hbm else
                [vmem_rows(W)])                          # memr (resident)
             + [vmem_rows(1),                            # trapr
@@ -4062,6 +4084,15 @@ class PallasUniformEngine:
         # (expected, max) branches a dispatch walks in the kernel's
         # tree (plan_dispatch_tree), known once a kernel was built
         self.dispatch_depth = None
+        # how the newest kernel holds linear memory: {"mem_mode": none |
+        # resident | hbm_window} and, with a memory, "lane_block" and
+        # (hbm_window) "window" = rows x ways
+        self.mem_static = None
+        # the hbm_window kernel's DMA counts over the last run(): window
+        # fills and dirty write-backs, HBM_WINDOW_ROWS rows x the lane
+        # block each (the careful recheck kernel's included)
+        self.window_fills = 0
+        self.window_writebacks = 0
         # None = no tpu.aot fused section attached; set by _build when a
         # loaded artifact carries one (True = matched regeneration)
         self.aot_fused_verified = None
@@ -4247,6 +4278,14 @@ class PallasUniformEngine:
             if img.has_memory else 0
         mem_hbm = self._mem_mode()
         self._geom = (D, CD, W, Lblk)
+        self.mem_static = {"mem_mode": "none"}
+        if img.has_memory:
+            self.mem_static = {
+                "mem_mode": "hbm_window" if mem_hbm else "resident",
+                "lane_block": Lblk}
+            if mem_hbm:
+                self.mem_static["window"] = f"{self.HBM_WINDOW_ROWS}x2"
+        self.obs.set_memory_static(self.mem_static)
         v128_t = np.asarray(img.v128, np.int32)
         self._kargs = (
             used, D, CD, W, self.lanes, Lblk, NG, img.code_len,
@@ -4687,6 +4726,11 @@ class PallasUniformEngine:
         self.recheck_rounds = sched.eng.recheck_rounds
         self.aot_fused_verified = sched.eng.aot_fused_verified
         self.dispatch_depth = sched.eng.dispatch_depth
+        self.mem_static = sched.eng.mem_static
+        self.window_fills = sched.window_fills
+        self.window_writebacks = sched.window_writebacks
+        self.obs.add_window_counts(sched.window_fills,
+                                   sched.window_writebacks)
         return sched.result()
 
     def _serve_hostcalls(self, state, ctrl_np, valid_blocks=None):

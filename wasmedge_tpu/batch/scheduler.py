@@ -75,6 +75,8 @@ from wasmedge_tpu.batch.pallas_engine import (
     _C_SP,
     _C_STATUS,
     _C_STEPS,
+    _C_WFILLS,
+    _C_WWBS,
     _FUEL_OFF,
     _PAGE_WORDS,
     PallasUniformEngine,
@@ -262,6 +264,10 @@ class BlockScheduler:
         self.retired = np.zeros(self.lanes, np.int64)
         self.fell_back_to_simt = False
         self.splits = 0
+        # the hbm_window kernel's DMA counts, summed over blocks and
+        # launches (zero in every other memory mode)
+        self.window_fills = 0
+        self.window_writebacks = 0
         self.quarantined = 0
         self._t_launch = 0.0
         self._plane_idx = _PLANE_IDX_SIMD if outer.img.has_simd \
@@ -534,6 +540,7 @@ class BlockScheduler:
             live = self._live_at_launch
             new_steps = ctrl_np[:, _C_STEPS].astype(np.int64)
             self.block_steps[live] += new_steps[live]
+            self._count_window(ctrl_np, live)
             obs = self.obs
             if obs.enabled:
                 # per-launch span closed at THIS sync point (the ctrl
@@ -574,6 +581,12 @@ class BlockScheduler:
         self._pending = []
         return False
 
+    def _count_window(self, ctrl_np, blocks):
+        """Add what the kernel that just ran counted in `blocks`."""
+        if self.eng.mem_static.get("mem_mode") == "hbm_window":
+            self.window_fills += int(ctrl_np[blocks, _C_WFILLS].sum())
+            self.window_writebacks += int(ctrl_np[blocks, _C_WWBS].sum())
+
     def _run_recheck(self, live) -> np.ndarray:
         """Re-run ST_RECHECK blocks on the careful kernel (synchronous)
         via the engine's shared careful_recheck protocol, then stops
@@ -587,6 +600,7 @@ class BlockScheduler:
         self.state, ctrl = self.eng.careful_recheck(
             self.state, self._ctrl(), recheck)
         self.block_steps += ctrl[:, _C_STEPS].astype(np.int64)
+        self._count_window(ctrl, recheck)
         self._ctrl_cache = ctrl
         self._ctrl_dirty = False
         self._frames_cache = None

@@ -981,21 +981,24 @@ class UniformBatchEngine:
         )
 
     def run(self, func_name, args_lanes, max_steps: int = 10_000_000):
-        tree = self._tree_args()
+        kernel = self._kernel_args()
         with self.obs.timed("batch/run", cat="engine", lanes=self.lanes,
-                            **tree) as span:
+                            **kernel) as span:
             res = self._run(func_name, args_lanes, max_steps)
-            if not tree:   # the first run is the one that builds
-                span.set(**self._tree_args())
+            if not kernel:   # the first run is the one that builds
+                span.set(**self._kernel_args())
             return res
 
-    def _tree_args(self):
-        """What dispatch tree the Pallas kernel was built with, as a
-        span argument; nothing before a kernel exists."""
+    def _kernel_args(self):
+        """What the Pallas kernel was built with, as span arguments:
+        its dispatch tree and how it holds linear memory (`mem_mode`,
+        and with a memory `lane_block` and the window's rows x ways);
+        nothing before a kernel exists."""
         depth = getattr(self.pallas, "dispatch_depth", None)
         if depth is None:
             return {}
-        return {"dispatch_depth": f"{depth[0]:.2f}/{depth[1]}"}
+        return {"dispatch_depth": f"{depth[0]:.2f}/{depth[1]}",
+                **self.pallas.mem_static}
 
     def _run(self, func_name, args_lanes, max_steps):
         import numpy as np

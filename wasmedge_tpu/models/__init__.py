@@ -17,6 +17,9 @@ from wasmedge_tpu.models.programs import (
     build_memory_workload,
     build_simd_memfuse_workload,
 )
+# the guest of the benchmark's mem-batch-4096, which looks its builder
+# up here by name (benchmark/drivers/batch.py); not part of the corpus
+from wasmedge_tpu.models.programs import build_memory_batch  # noqa: F401
 
 __all__ = [
     "build_fib",

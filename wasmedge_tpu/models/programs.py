@@ -54,13 +54,17 @@ def build_loop_sum() -> bytes:
     return b.build()
 
 
-def build_memory_workload(passes: int = 1) -> bytes:
+def build_memory_workload(passes: int = 1, fold: str = "xor") -> bytes:
     """Write-then-checksum over linear memory (config 2 memory traffic).
 
     `passes` repeats the whole write+checksum cycle (same load/store mix,
     more work per invocation) so benchmarks can amortize fixed host-link
     round trips over enough device work to measure the engine rather
-    than the link."""
+    than the link.  `fold` is how a loaded word joins the checksum:
+    "xor", under which an even number of passes cancels to 0 whatever
+    the memory holds, or "add", under which every pass's words count
+    (same instruction count and block shapes)."""
+    fold_op = {"xor": "i32.xor", "add": "i32.add"}[fold]
     b = ModuleBuilder()
     b.add_memory(1, 16)
     # locals: 0=n (param), 1=i, 2=acc, 3=pass counter
@@ -81,14 +85,14 @@ def build_memory_workload(passes: int = 1) -> bytes:
         ("br", 0),
         "end",
         "end",
-        # xor-reduce them back
+        # fold them back into acc
         ("i32.const", 0), ("local.set", 1),
         ("block", None),
         ("loop", None),
         ("local.get", 1), ("local.get", 0), "i32.ge_u", ("br_if", 1),
         ("local.get", 2),
         ("local.get", 1), ("i32.const", 4), "i32.mul", ("i32.load", 2, 0),
-        "i32.xor", ("local.set", 2),
+        fold_op, ("local.set", 2),
         ("local.get", 1), ("i32.const", 1), "i32.add", ("local.set", 1),
         ("br", 0),
         "end",
@@ -101,6 +105,14 @@ def build_memory_workload(passes: int = 1) -> bytes:
         ("local.get", 2),
     ], export="mem_checksum")
     return b.build()
+
+
+def build_memory_batch() -> bytes:
+    """The guest of the benchmark's mem-batch-4096: bench_memory.py's 64
+    passes, summed and not xored, so that the answer depends on every
+    word each pass stored and read back (benchmark/drivers/batch.py
+    takes a builder without arguments)."""
+    return build_memory_workload(passes=64, fold="add")
 
 
 def build_counted_loop(n: int = 64) -> bytes:

@@ -577,6 +577,30 @@ def render_prometheus(recorder=None, stats=None, hostcall_stats=None,
                      dps["expected"])
             w.sample("wasmedge_dispatch_depth", {"stat": "max"},
                      dps["max"])
+        mst = getattr(recorder, "memory_static", None)
+        if mst and "lane_block" in mst:     # a guest with a memory
+            w.head("wasmedge_memory_lane_block", "gauge",
+                   "Lane block of the newest Pallas kernel of a guest "
+                   "with linear memory, labelled with how it holds "
+                   "it: resident in VMEM, or hbm_window (window = "
+                   "rows x ways; batch/pallas_engine.py _mem_mode).")
+            w.sample("wasmedge_memory_lane_block",
+                     {k: mst[k] for k in ("mem_mode", "window")
+                      if k in mst}, mst["lane_block"])
+        wc = getattr(recorder, "window_counts", None)
+        if wc and wc["fills"]:      # stays once a window kernel ran
+            w.head("wasmedge_hbm_window_fills_total", "counter",
+                   "Window fills of the hbm_window kernel: DMAs "
+                   "of window-rows x lane-block words from the "
+                   "memory plane in HBM into a way in VMEM.")
+            w.sample("wasmedge_hbm_window_fills_total", None,
+                     wc["fills"])
+            w.head("wasmedge_hbm_window_writebacks_total",
+                   "counter",
+                   "Dirty ways the hbm_window kernel wrote back "
+                   "to the memory plane, same size as a fill.")
+            w.sample("wasmedge_hbm_window_writebacks_total", None,
+                     wc["writebacks"])
         if recorder.opcode_counts is not None:
             from wasmedge_tpu.validator.image import lop_name
 

@@ -200,6 +200,12 @@ class NullRecorder:
     def set_dispatch_static(self, expected, deepest):
         pass
 
+    def set_memory_static(self, static):
+        pass
+
+    def add_window_counts(self, fills, writebacks):
+        pass
+
     def add_tierup_counts(self, dispatches, retired_comp, retired_total):
         pass
 
@@ -292,6 +298,11 @@ class FlightRecorder:
         self.memfuse_static = None
         # dispatch-tree depths of the newest Pallas kernel build
         self.dispatch_static = None
+        # the newest Pallas kernel's mem_static (how it holds linear
+        # memory), and the hbm_window kernel's DMA counts folded after
+        # each run
+        self.memory_static = None
+        self.window_counts = {"fills": 0, "writebacks": 0}
         # compiled-function tier counters folded from the device
         # tu_ctr plane (batch/engine.py _fold_tierup_ctr) + the
         # promotion report set once per plan by _plan_tierup (r20)
@@ -423,6 +434,19 @@ class FlightRecorder:
         weights and the deepest leaf, in branches walked."""
         self.dispatch_static = {"expected": float(expected),
                                 "max": int(deepest)}
+
+    def set_memory_static(self, static):
+        """Point at the newest Pallas kernel's own record of how it
+        holds linear memory (PallasUniformEngine.mem_static, which
+        says what the keys are)."""
+        self.memory_static = static
+
+    def add_window_counts(self, fills, writebacks):
+        """Fold the hbm_window kernel's DMA counts of one run (window
+        fills and dirty write-backs, summed over blocks and launches by
+        batch/scheduler.py)."""
+        self.window_counts["fills"] += int(fills)
+        self.window_counts["writebacks"] += int(writebacks)
 
     def add_tierup_counts(self, dispatches, retired_comp, retired_total):
         """Fold the device tier-up counters (compiled-function bodies
