@@ -90,6 +90,28 @@ def _memory_wasm():
     return build_memory_workload(passes=64)
 
 
+def _superblock_wasm():
+    """A guest with a memory whose hot block is a superblock of every
+    kind (PR 29): the guard's tail ends in a `call` (of a callee with a
+    local to zero, so the call opens two regions), and the then-arm's
+    `br end` is a jump that runs on into the `return`."""
+    from wasmedge_tpu.utils.builder import ModuleBuilder
+
+    b = ModuleBuilder()
+    b.add_memory(1, 1)
+    leaf = b.add_function(["i32"], ["i32"], ["i32"], [
+        ("local.get", 0), ("i32.const", 1), "i32.add", ("local.tee", 1)])
+    b.add_function(["i32"], ["i32"], ["i32"], [
+        ("local.get", 0), ("i32.load", 2, 0), ("local.tee", 1),
+        ("if", "i32"),
+        ("local.get", 1), ("i32.load", 2, 0),
+        "else",
+        ("local.get", 1), ("call", leaf),
+        "end",
+    ], export="f")
+    return b.build()
+
+
 def _pallas_engine(wasm, depth, call_depth, mem_hbm=None, blk_cap=None):
     """The engine UniformBatchEngine picks for a TPU backend, built at
     4096 lanes (what VM.execute_batch holds; bench.py / bench_memory.py
@@ -155,6 +177,14 @@ _KERNELS = {
     "memory-auto": (_memory_wasm, 128, 64, None, None, False,
                     (4096, True)),
     "v128": (_simd_wasm, 64, 16, None, None, False, (4096, True)),
+    # a superblock with a jump and a tail ending in `call`, behind the
+    # HBM window: the guard for the eleven nested regions Mosaic's
+    # layout inference survives (tails hold no memory op, so there is
+    # no hbm-window tail to lower)
+    "superblock-call-tail": (_superblock_wasm, 128, 64, None, None,
+                             False, (4096, True)),
+    "superblock-call-tail-careful": (_superblock_wasm, 128, 64, None,
+                                     None, True, (4096, True)),
 }
 
 
@@ -164,8 +194,52 @@ def test_pallas_kernel_compiles_for_v5e(case, one_chip):
     eng = _pallas_engine(wasm(), depth, cdepth, mem_hbm=mem_hbm,
                          blk_cap=cap)
     assert (eng._geom[3], eng._mem_mode()) == expect
+    if case.startswith("superblock"):
+        from wasmedge_tpu.batch.pallas_engine import H_CALL, H_RETURN
+
+        (head,) = [s for s in eng._kargs[17] if ("jump", 1) in s]
+        assert head[-1] == ("term", H_RETURN)
+        (tail,) = [op[1] for op in head if op[0] == "guardz"]
+        assert tail[-1] == ("term", H_CALL)
     fn = eng._fn_careful() if careful else eng._fn
     _compile_kernel(eng, fn, one_chip, careful=careful)
+
+
+def _region_depth(jaxpr, depth=0):
+    """The deepest nesting of cond/while/scan regions in a jaxpr: what
+    Mosaic's infer-vector-layout recurses over on its small stack."""
+    deepest = depth
+    for eqn in jaxpr.eqns:
+        inner = depth + (eqn.primitive.name in ("cond", "while", "scan"))
+        for v in eqn.params.values():
+            for x in v if isinstance(v, (list, tuple)) else [v]:
+                x = getattr(x, "jaxpr", x)
+                if hasattr(x, "eqns"):
+                    deepest = max(deepest, _region_depth(x, inner))
+    return deepest
+
+
+# kernel -> regions deep, (optimistic, careful).  Eleven is what the
+# chip's compiler survives (PERF.md section 6, PR 27); the parent of
+# PR 29 read (9, 8) for fib and (11, 11) for the memory guest, and
+# superblocks may deepen no kernel the benchmark's cells build.
+_DEPTHS = {
+    "fib": (_fib_wasm, 256, 256, (9, 7)),
+    "memory-auto": (_memory_wasm, 128, 64, (11, 11)),
+    "superblock-call-tail": (_superblock_wasm, 128, 64, (9, 9)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_DEPTHS))
+def test_kernel_region_depth(case, one_chip):
+    import jax
+
+    wasm, depth, cdepth, expect = _DEPTHS[case]
+    eng = _pallas_engine(wasm(), depth, cdepth)
+    got = tuple(
+        _region_depth(jax.make_jaxpr(fn)(*eng._arg_specs()).jaxpr)
+        for fn in (eng._fn, eng._fn_careful()))
+    assert got == expect and max(got) <= 11
 
 
 def test_exported_kernel_compiles_for_v5e(one_chip):

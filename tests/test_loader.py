@@ -298,3 +298,69 @@ def test_aot_fused_planes_consumed_by_engine():
     assert (res.trap == -1).all()
     assert (np.asarray(res.results[0]) == np.arange(8) * 2).all()
     assert eng.aot_fused_verified is True
+
+
+def test_aot_stale_fused_plane_is_regenerated_not_trusted():
+    """An artifact written before superblocks (PR 29) carries the hid
+    plane of the plain block fuser.  g1 and g2 start with the same
+    plain block (`local.get; brz; const; br`), so that fuser gave both
+    heads shape id 0; their superblocks differ (the `br` runs on into
+    different code), so the ids are 0 and 2 now and every later id
+    shifts.  The stale plane must fail verification and never run: the
+    engine regenerates."""
+    import numpy as np
+
+    from wasmedge_tpu.aot import (
+        compile_module, deserialize_image, extract_precompiled,
+        verify_fused)
+    from wasmedge_tpu.batch.pallas_engine import (
+        H_BLOCK_BASE, PallasUniformEngine)
+    from wasmedge_tpu.common.configure import Configure
+    from wasmedge_tpu.executor import Executor
+    from wasmedge_tpu.loader import Loader
+    from wasmedge_tpu.runtime.store import StoreManager
+    from wasmedge_tpu.utils.builder import ModuleBuilder
+    from wasmedge_tpu.validator import Validator
+
+    b = ModuleBuilder()
+    b.add_function(["i32"], ["i32"], [], [
+        ("local.get", 0), ("if", "i32"), ("i32.const", 1), "else",
+        ("i32.const", 2), "end"], export="g1")
+    b.add_function(["i32"], ["i32"], [], [
+        ("local.get", 0), ("if", "i32"), ("i32.const", 1), "else",
+        ("local.get", 0), "end", ("i32.const", 5), "i32.add"],
+        export="g2")
+    B = H_BLOCK_BASE
+    # what fuse_blocks wrote at commit e867578 (PR 28) for this module
+    parent_hid = np.asarray([B + 0, 10, 1, 9, B + 1, 13,
+                             B + 0, 10, 1, 9, 2, B + 2, 24, 13], np.int32)
+    conf = Configure()
+    conf.batch.steps_per_launch = 10_000
+    twasm = compile_module(b.build(), conf)
+    mod = Loader(conf).parse_module(twasm)
+    payload = extract_precompiled(
+        mod.source_bytes, [(c.name, c.data, c.start) for c in mod.customs])
+    img = deserialize_image(payload)
+    assert verify_fused(img, mod)
+    fresh = img.fused["hid"].copy()
+    assert fresh.tolist() == [B + 0, 10, 1, 9, B + 1, 13,
+                              B + 2, 10, 1, 9, 2, B + 3, 24, 13]
+    img.fused["hid"] = parent_hid
+    assert not verify_fused(img, mod)
+
+    vmod = Validator(conf).validate(Loader(conf).parse_module(twasm))
+    vmod.lowered.fused["hid"] = parent_hid
+    store = StoreManager()
+    inst = Executor(conf).instantiate(store, vmod)
+    eng = PallasUniformEngine(inst, store=store, conf=conf, lanes=8,
+                              interpret=True)
+    xs = np.asarray([0, 1, 0, 0, 0, 0, 0, 0], np.int64)
+    res = eng.run("g2", [xs * 0], max_steps=10_000)
+    assert (res.trap == -1).all()
+    assert (np.asarray(res.results[0]) == 5).all()
+    assert eng.aot_fused_verified is False
+    inner = next(iter(eng.simt._sched_cache.values()))
+    assert inner.aot_fused_verified is False
+    assert np.array_equal(inner._np_fused["hid"], fresh)
+    res = eng.run("g2", [xs + 1], max_steps=10_000)
+    assert np.asarray(res.results[0]).tolist() == [6] * 8

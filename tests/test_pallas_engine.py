@@ -11,6 +11,7 @@ divergence bail-outs, and the SIMT handoff are all exercised by pytest.
 import numpy as np
 import pytest
 
+from wasmedge_tpu.batch import pallas_engine as pe
 from wasmedge_tpu.common.configure import Configure
 from wasmedge_tpu.common.errors import ErrCode, TrapError
 from wasmedge_tpu.models import (
@@ -477,3 +478,222 @@ def test_v128_select_and_global_in_fused_block():
     for arg in (0, 1):
         eng, res = check_parity(wasm, "f", [np.full(LANES, arg, np.int64)])
         assert eng.eligible, eng.ineligible_reason
+
+
+# ---------------------------------------------------------------------------
+# superblocks (PR 29): a fused block runs through forward `br`s (jumps)
+# and taken forward guards (tails) into their targets
+# ---------------------------------------------------------------------------
+def scalar_retired(data, func, args):
+    """(results or TrapError code, instructions retired) of one scalar
+    instance, counted by Statistics.instr_counting."""
+    from wasmedge_tpu.common.statistics import Statistics
+    from wasmedge_tpu.executor import Executor
+    from wasmedge_tpu.loader import Loader
+    from wasmedge_tpu.runtime.store import StoreManager
+    from wasmedge_tpu.validator import Validator
+
+    conf = Configure()
+    conf.statistics.instr_counting = True
+    stat = Statistics(conf)
+    ex = Executor(conf, stat)
+    store = StoreManager()
+    inst = ex.instantiate(
+        store, Validator(conf).validate(Loader(conf).parse_module(data)))
+    try:
+        out = ex.invoke(store, inst.find_func(func), [int(a) for a in args])
+    except TrapError as te:
+        out = int(te.code)
+    return out, stat.instr_count
+
+
+def block_shapes_of(eng):
+    inner = next(iter(eng.simt._sched_cache.values()), eng)
+    return inner._kargs[17]
+
+
+def has_op(shape, kind):
+    """Does `shape` (or a tail in it) hold an op of `kind`?"""
+    return any(op[0] == kind or
+               (op[0] in ("guardz", "guardnz") and has_op(op[1], kind))
+               for op in shape)
+
+
+def tails_of(shapes):
+    return [op[1] for shape in shapes for op in shape
+            if op[0] in ("guardz", "guardnz") and op[1]]
+
+
+def test_fib15_dispatch_count_and_scalar_parity():
+    """fib(15) is 987 leaves and 986 inner calls: one dispatch a leaf
+    and three an inner call, where plain blocks took two and four
+    (5,918)."""
+    eng, res = check_parity(build_fib(), "fib",
+                            [np.full(LANES, 15, np.int64)])
+    assert not eng.fell_back_to_simt and eng.splits == 0
+    assert eng.dispatches == 987 + 3 * 986 == 3945
+    out, retired = scalar_retired(build_fib(), "fib", [15])
+    assert out == [610] and retired == 7 * 987 + 14 * 986
+    assert np.asarray(res.retired).tolist() == [retired] * LANES
+    assert eng.instr_per_dispatch == retired / 3945
+
+
+def if_else_guest(result: bool) -> bytes:
+    """f(x, y): an if/else whose arms leave to `end` by a forward `br`
+    (nkeep 1 with a result, 0 with none), then more code at the `end`
+    that the then-arm's jump runs into."""
+    b = ModuleBuilder()
+    if result:
+        body = [
+            ("local.get", 0),
+            ("if", "i32"),
+            ("local.get", 1), ("i32.const", 3), "i32.mul",
+            "else",
+            ("local.get", 1), ("i32.const", 7), "i32.add",
+            "end",
+            ("i32.const", 1), "i32.add",
+        ]
+    else:
+        body = [
+            ("local.get", 0),
+            ("if", None),
+            ("local.get", 1), ("i32.const", 3), "i32.mul", ("local.set", 2),
+            "else",
+            ("local.get", 1), ("i32.const", 7), "i32.add", ("local.set", 2),
+            "end",
+            ("local.get", 2), ("i32.const", 1), "i32.add",
+        ]
+    b.add_function(["i32", "i32"], ["i32"], ["i32"], body, export="f")
+    return b.build()
+
+
+@pytest.mark.parametrize("result", [True, False],
+                         ids=["nkeep1", "nkeep0"])
+@pytest.mark.parametrize("x", [0, 1], ids=["else-arm", "then-arm"])
+def test_if_else_superblock_parity(result, x):
+    data = if_else_guest(result)
+    ys = np.arange(LANES, dtype=np.int64) + 5
+    eng, res = check_parity(data, "f", [np.full(LANES, x, np.int64), ys])
+    assert not eng.fell_back_to_simt
+    shapes = block_shapes_of(eng)
+    # the then-arm's `br end` is a jump, so its path is one superblock
+    # down to the `return`; the else-arm is the guard's tail and falls
+    # off at the `end`, a join that starts its own block
+    assert ("jump", 1 if result else 0) in shapes[0]
+    assert shapes[0][-1] == ("term", pe.H_RETURN)
+    (tail,) = tails_of(shapes[:1])
+    assert tail[-1][0] != "term" and not has_op(tail, "jump")
+    assert eng.dispatches == (1 if x else 2)
+    _out, retired = scalar_retired(data, "f", [x, 5])
+    assert np.asarray(res.retired).tolist() == [retired] * LANES
+
+
+def test_if_else_superblock_divergent_condition():
+    data = if_else_guest(True)
+    xs = np.array([0, 1, 0, 0, 1, 1, 0, 1], np.int64)
+    ys = np.arange(LANES, dtype=np.int64) + 5
+    eng, _res = check_parity(data, "f", [xs, ys])
+    assert not eng.fell_back_to_simt
+
+
+def test_trap_in_a_tail_call_stack_exhausted():
+    """fib's inner call leaves block 0 through the guard's tail, whose
+    terminal is the `call`: with five frames it traps there, at the
+    call's own slot 9, after what the plain per-op kernel retires."""
+    from wasmedge_tpu.batch.scheduler import BlockScheduler
+
+    rows = {}
+    for fusion in (True, False):
+        conf = Configure()
+        conf.batch.call_stack_depth = 6
+        conf.batch.block_fusion = fusion
+        _ex, _store, _inst, eng = make_engine(build_fib(), conf=conf)
+        sched = BlockScheduler(eng, "fib", [np.full(LANES, 12, np.int64)],
+                               1_000_000)
+        sched.launch()
+        row = sched._ctrl()[0]
+        rows[fusion] = tuple(int(row[c]) for c in (
+            pe._C_STATUS, pe._C_STEPS, pe._C_PC, pe._C_SP, pe._C_CD))
+        res = eng.run("fib", [np.full(LANES, 12, np.int64)],
+                      max_steps=1_000_000)
+        assert (res.trap == int(ErrCode.CallStackExhausted)).all()
+        rows[fusion] += (np.asarray(res.retired).tolist(),)
+    assert rows[True] == rows[False]
+    assert rows[True][:3] == (
+        pe.ST_TRAPPED_BASE + int(ErrCode.CallStackExhausted), 48, 9)
+
+
+def oob_after_jump_guest() -> bytes:
+    """f(x): x != 0 loads from 0x10000 (out of bounds), x == 0 from 4.
+    The then-arm's `br end` is a jump, so the load at the `end` runs in
+    the superblock after it; the loop head after it bounds the block."""
+    b = ModuleBuilder()
+    b.add_memory(1, 1)
+    b.add_function(["i32"], ["i32"], ["i32"], [
+        ("local.get", 0),
+        ("if", "i32"), ("i32.const", 0x10000), "else", ("i32.const", 4),
+        "end",
+        ("i32.load", 2, 0),
+        ("local.set", 1),
+        ("loop", None),
+        ("local.get", 1), ("i32.const", 1), "i32.add", ("local.tee", 1),
+        ("i32.const", 3), "i32.lt_u", ("br_if", 0),
+        "end",
+        ("local.get", 1),
+    ], export="f")
+    return b.build()
+
+
+@pytest.mark.parametrize("xs", [[1] * LANES, [0] * LANES,
+                                [0, 1, 0, 0, 1, 1, 0, 1]],
+                         ids=["all-oob", "none-oob", "some-oob"])
+def test_trap_after_a_jump_out_of_bounds_load(xs):
+    from wasmedge_tpu.batch.scheduler import BlockScheduler
+
+    data = oob_after_jump_guest()
+    eng, res = check_parity(data, "f", [np.asarray(xs, np.int64)])
+    shapes = block_shapes_of(eng)
+    at = shapes[0].index(("jump", 1))
+    assert shapes[0][at + 1][0] == "loadi"
+    for lane, x in enumerate(xs):
+        out, retired = scalar_retired(data, "f", [x])
+        if x:
+            assert out == int(ErrCode.MemoryOutOfBounds) == res.trap[lane]
+        else:
+            assert res.trap[lane] == -1
+            # (a trapped lane's `retired` counts the trapping op or not
+            # by engine; a finished one's is exact)
+            assert res.retired[lane] == retired or len(set(xs)) > 1
+    if len(set(xs)) == 1 and xs[0]:
+        # the kernel stops un-advanced at the load's own slot (5), with
+        # the four ops before it retired
+        _ex, _store, _inst, eng2 = make_engine(data)
+        sched = BlockScheduler(eng2, "f", [np.asarray(xs, np.int64)],
+                               1_000_000)
+        sched.launch()
+        row = sched._ctrl()[0]
+        assert (int(row[pe._C_STATUS]), int(row[pe._C_STEPS]),
+                int(row[pe._C_PC])) == (pe.ST_DIVERGED, 4, 5)
+
+
+def test_fuel_runs_out_inside_a_superblock():
+    """Fuel 1000 on fib(12): the plain per-op kernel stops at step 1000
+    exactly, the superblock kernel at the end of the dispatch that
+    crossed it, less than MAX_BLOCK_LEN later; both kill."""
+    from wasmedge_tpu.batch.scheduler import BlockScheduler
+
+    steps = {}
+    for fusion in (True, False):
+        conf = Configure()
+        conf.batch.fuel_per_launch = 1000
+        conf.batch.block_fusion = fusion
+        _ex, _store, _inst, eng = make_engine(build_fib(), conf=conf)
+        sched = BlockScheduler(eng, "fib", [np.full(LANES, 12, np.int64)],
+                               1_000_000)
+        sched.launch()
+        row = sched._ctrl()[0]
+        assert int(row[pe._C_STATUS]) == \
+            pe.ST_TRAPPED_BASE + int(ErrCode.CostLimitExceeded)
+        steps[fusion] = int(row[pe._C_STEPS])
+    assert steps[False] == 1000
+    assert 1000 <= steps[True] < 1000 + pe.MAX_BLOCK_LEN

@@ -252,6 +252,10 @@ _C_SNAP = 10
 # window fills and dirty-window write-backs, each one CW-row DMA
 _C_WFILLS = 11
 _C_WWBS = 12
+# written by every kernel at exit, per launch (never read by it): the
+# handlers the loop dispatched, those a rollback discarded too (a commit
+# is none: it retires nothing)
+_C_DISPATCHES = 13
 _SNAP_MIN = 256
 
 
@@ -440,6 +444,16 @@ def fuse_image(hid, a, b, c, ilo, ihi, img):
 # interpreter gets from its compiler for free: straight-line runs with
 # values in registers (/root/reference/lib/executor/engine/
 # engine.cpp:68-1641).
+#
+# SUPERBLOCKS (PR 29): a block does not end at a forward edge whose
+# target is static.  It runs through a forward `br` into the target's
+# ops, and the taken side of a forward guard runs the target's block as
+# the guard's tail, so fib pays one dispatch a leaf call and three an
+# inner call where it paid two and four.  The target's ops are
+# DUPLICATED into the block: the target keeps its own head and hid for
+# every other way in, and every op still reads its operands at its own
+# original slot, so a bail, a rollback or a split child lands where it
+# always did.  fuse_blocks holds the rules.
 H_BLOCK_BASE = NUM_HANDLERS
 MAX_BLOCK_SHAPES = 96   # distinct block shapes compiled per kernel
 MAX_BLOCK_LEN = 24      # ops per block (incl. the terminal)
@@ -449,6 +463,40 @@ def _trapping_alu1_subs():
     from wasmedge_tpu.batch import laneops as lo_ops
 
     return set(lo_ops.alu1_trap_fns().keys())
+
+
+# Terminals a superblock may run in code it DUPLICATED (after a followed
+# `br`, or in a guard's tail).  Their *_with cores open one region, the
+# call two where a callee has locals to zero; everything else (memory.*,
+# div/rem, hostcalls, br_table, call_indirect) keeps its own dispatch,
+# where it was on every kernel before superblocks, so duplicated code
+# never holds a deep handler.
+_DUP_TERMS = (H_BR, H_BRZ, H_BRNZ, H_RETURN, H_CALL)
+
+
+def _path_nesting(ops, call_nest, depth=0):
+    """Estimate of the nested regions (scf.if) the optimistic kernel
+    opens to run `ops`, a path of a block shape entered `depth` regions
+    down: every guard and every inline load/store puts what follows it
+    one region deeper, and a terminal opens its own.  (The careful
+    kernel puts a guard's taken side one deeper still, and the hbm
+    window a load's continuation; the estimate is one function for
+    every kernel kind, so the plane stays a function of the image
+    alone.)  `call_nest` is what a `call` terminal opens: 2 where a
+    callee has locals to zero, else 1."""
+    deepest = depth
+    for op in ops:
+        kind = op[0]
+        if kind in ("guardz", "guardnz"):
+            depth += 1
+            deepest = max(deepest, _path_nesting(op[1], call_nest, depth))
+        elif kind in ("loadi", "storei"):
+            depth += 1
+        elif kind == "term":
+            own = {H_BRZ: 0, H_CALL: call_nest}.get(op[1], 1)
+            deepest = max(deepest, depth + own)
+        deepest = max(deepest, depth)
+    return deepest
 
 
 def fuse_blocks(hid, img):
@@ -463,22 +511,43 @@ def fuse_blocks(hid, img):
       ("alu2", sub) ("alu1", sub)            using different locals in
       ("loadi", nbytes, flags)               the same pattern share)
       ("storei", nbytes)
-      ("guardz",) ("guardnz",)
+      ("guardz", tail) ("guardnz", tail)     tail = a shape of its own
+      ("jump", nkeep)
       ("term", flat_hid)
 
     loadi/storei are loads/stores fused INLINE (uniform-address fast
     path; divergence/OOB bails un-advanced at the op's own slot).
     guardz/guardnz are FORWARD branches absorbed mid-block: the block
-    speculates fallthrough and the taken path exits at the branch with
-    everything before it committed — loop back-edges (backward
-    targets) stay terminals so the common taken path pays nothing.
-    guardnz requires nkeep == 0 (no value move on the taken exit).
+    speculates fallthrough — loop back-edges (backward targets) stay
+    terminals so the common taken path pays nothing.  guardnz requires
+    nkeep == 0 (no value move on the taken exit).
+
+    SUPERBLOCKS: a block does not end at a forward edge whose target is
+    static.  ("jump", nkeep) is a forward `br` absorbed mid-block: the
+    ops after it are the TARGET's (tail duplication: the target keeps
+    its own head and hid for every other way in).  A guard's `tail` is
+    what its TAKEN side runs before it leaves the block: the target's
+    block, possibly through jumps, possibly ending in a term; the empty
+    tail leaves at the branch with everything before it committed and
+    pc at the target.  The rules, all read off the image: forward
+    targets only; no path through a shape is longer than MAX_BLOCK_LEN;
+    a continuation is taken whole or not at all (one that room would
+    cut mid-way is left to its own dispatch); duplicated code holds
+    only the terminals of _DUP_TERMS, and a tail no loadi/storei; a
+    tail holds no guard with a tail of its own (one level, so code
+    grows by 2x at most); a guard of a block that ends in a backward
+    branch (a loop's exit) keeps the empty tail; and no path is nested
+    deeper (_path_nesting) than the plain block it grew from, so a tail
+    is never nested deeper than the fall-through side of its guard.  Where MAX_BLOCK_SHAPES is
+    used up a head falls back to its plain shape before it goes
+    unfused.
 
     Immediates/indices are NOT in the shape (handlers read them from
-    the SMEM planes at pc+offset), except local/global ordinals, whose
-    equality structure decides value forwarding, and alu subs, which
-    pick the compute fn.  Deterministic: tpu.aot artifacts verify the
-    persisted hid plane by regeneration (aot/__init__.py)."""
+    the SMEM planes at the op's own original slot), except local/global
+    ordinals, whose equality structure decides value forwarding, and
+    alu subs, which pick the compute fn.  Deterministic: tpu.aot
+    artifacts verify the persisted hid plane by regeneration
+    (aot/__init__.py)."""
     n = img.code_len
     targets = _jump_targets(img)
     # call-return / hostcall-re-arm / trap-partial-resume addresses need
@@ -487,6 +556,7 @@ def fuse_blocks(hid, img):
     # hids, so any resume pc stays independently dispatchable.
 
     trap1 = _trapping_alu1_subs()
+    call_nest = 2 if int(img.max_local_zeros) > 0 else 1
 
     def pure_desc(pc, lmap, gmap):
         """Descriptor if the op at pc is pure (fusible mid-block)."""
@@ -543,69 +613,200 @@ def fuse_blocks(hid, img):
         if cl == CLS_STORE:
             return ("storei", int(img.b[pc]))
         if cl == CLS_BRZ and int(img.a[pc]) > pc:
-            return ("guardz",)
+            return ("guardz", ())
         if cl == CLS_BRNZ and int(img.a[pc]) > pc and int(img.b[pc]) == 0:
-            return ("guardnz",)
+            return ("guardnz", ())
         return None
+
+    def segment(start, room, lmap, gmap, lone_term=False):
+        """The straight run from `start`, at most `room` ops with its
+        terminal: (path, cut), path a list of (op, slot, ordinals), the
+        ordinals a copy of both maps as a guard saw them (a tail
+        numbers on from there), else None.  The run absorbs the
+        non-pure op it stopped at as its terminal (which may itself be
+        a jump target: direct jumps to it dispatch its untouched
+        original hid).  A run that stopped at a pure op stopped at a
+        jump target, which starts its own block, or for room (cut)."""
+        path = []
+        j = start
+        while (j < n and len(path) < room - 1
+               and (j == start or j not in targets)):
+            d = pure_desc(j, lmap, gmap)
+            if d is None:
+                break
+            guard = d[0] in ("guardz", "guardnz")
+            path.append((d, j, (dict(lmap), dict(gmap)) if guard else None))
+            j += 1
+        if (path or lone_term) and j < n and room >= 1 and \
+                pure_desc(j, {}, {}) is None:
+            path.append((("term", int(hid[j])), j, None))
+            return path, False
+        return path, j < n and j not in targets
+
+    def follow(path, lmap, gmap, limit, depth, before):
+        """Run through the forward `br` that `path` ends in, into its
+        target's segment, for as long as the rules hold.  The path is
+        entered `depth` regions down after `before` ops (none: the
+        block's own path; else a tail) and may nest `limit` deep."""
+        while path and path[-1][0][0] == "term":
+            slot = path[-1][1]
+            if int(img.cls[slot]) != CLS_BR or int(img.a[slot]) <= slot \
+                    or int(img.b[slot]) not in (0, 1):
+                break
+            lm, gm = dict(lmap), dict(gmap)
+            more, cut = segment(
+                int(img.a[slot]), MAX_BLOCK_LEN - before - len(path),
+                lm, gm, lone_term=True)
+            new = path[:-1] + [(("jump", int(img.b[slot])), slot, None)] \
+                + more
+            if cut or not more or not duplicable(more, before > 0) or \
+                    nesting(new, depth) > limit:
+                break
+            path = new
+            lmap.update(lm)
+            gmap.update(gm)
+        return path
+
+    def duplicable(path, in_tail):
+        return not any((in_tail and op[0] in ("loadi", "storei"))
+                       or (op[0] == "term" and op[1] not in _DUP_TERMS)
+                       for op, _slot, _ord in path)
+
+    def nesting(path, depth=0):
+        return _path_nesting([p[0] for p in path], call_nest, depth)
 
     hid = hid.copy()
     shapes = []
     shape_ids = {}
     pc = 0
     while pc < n:
-        # scan a candidate block starting at pc
+        # the plain block at pc: one segment, as before superblocks
         lmap, gmap = {}, {}
-        ops = []
-        j = pc
-        while (j < n and len(ops) < MAX_BLOCK_LEN - 1
-               and (j == pc or j not in targets)):
-            d = pure_desc(j, lmap, gmap)
-            if d is None:
+        path, _cut = segment(pc, MAX_BLOCK_LEN, lmap, gmap)
+        nfirst = len(path)
+        if nfirst < 2:
+            pc += 1
+            continue
+        plain = tuple(p[0] for p in path)
+        limit = nesting(path)
+        path = follow(path, lmap, gmap, limit, 0, 0)
+        ops = [p[0] for p in path]
+        # a block that ends in a backward branch is a loop's body, and
+        # a guard in it the loop's exit: taken once a loop where the
+        # fall-through side runs once an iteration, so it keeps the
+        # empty tail (the memory guest's three exits: with tails its
+        # two loops ran 7-9 ns a dispatch slower on a v5e, PR 29)
+        last, end = path[-1][0], path[-1][1]
+        loops = last[0] == "term" and int(img.a[end]) <= end and \
+            int(img.cls[end]) in (CLS_BR, CLS_BRZ, CLS_BRNZ)
+        depth = 0
+        for i, (op, slot, ordinals) in enumerate(path):
+            if op[0] in ("loadi", "storei"):
+                depth += 1
+            if ordinals is None or loops:
+                continue
+            depth += 1
+            lm, gm = dict(ordinals[0]), dict(ordinals[1])
+            tail, cut = segment(int(img.a[slot]), MAX_BLOCK_LEN - (i + 1),
+                                lm, gm, lone_term=True)
+            if cut or not tail or not duplicable(tail, True) or \
+                    nesting(tail, depth) > limit:
+                continue
+            tail = follow(tail, lm, gm, limit, depth, i + 1)
+            ops[i] = (op[0], tuple(p[0] for p in tail))
+        for shape in (tuple(ops), plain):
+            if shape in shape_ids or len(shapes) < MAX_BLOCK_SHAPES:
+                sid = shape_ids.get(shape)
+                if sid is None:
+                    sid = len(shapes)
+                    shape_ids[shape] = sid
+                    shapes.append(shape)
+                hid[pc] = H_BLOCK_BASE + sid
+                pc += nfirst
                 break
-            ops.append(d)
-            j += 1
-        # absorb the stopping op as terminal unless the run stopped at
-        # a pure op (a jump-target boundary: that op starts its own
-        # block).  A non-pure terminal may itself be a jump target —
-        # direct jumps to it dispatch its untouched original hid.
-        term = None
-        if ops and j < n and pure_desc(j, {}, {}) is None:
-            term = ("term", int(hid[j]))
-            j += 1
-        total = len(ops) + (1 if term else 0)
-        shape = tuple(ops) + ((term,) if term else ())
-        if total >= 2 and (shape in shape_ids
-                           or len(shapes) < MAX_BLOCK_SHAPES):
-            sid = shape_ids.get(shape)
-            if sid is None:
-                sid = len(shapes)
-                shape_ids[shape] = sid
-                shapes.append(shape)
-            hid[pc] = H_BLOCK_BASE + sid
-            pc = j
         else:
             pc += 1
     return hid, tuple(shapes)
 
 
+def _successors(img, slot) -> set:
+    """Where the op at `slot`, dispatched alone or as a terminal, can
+    leave pc (but for function entries and br_table targets, which
+    entry_slots seeds)."""
+    cl = int(img.cls[slot])
+    if cl == CLS_BR:
+        return {int(img.a[slot])}
+    if cl in (CLS_BRZ, CLS_BRNZ):
+        return {int(img.a[slot]), slot + 1}
+    if cl in (CLS_RETURN, CLS_TRAP, CLS_BR_TABLE):
+        return set()
+    return {slot + 1}
+
+
+def walk_shape(shape, head, img):
+    """(op, original slot, in_tail) of every op of `shape` dispatched at
+    `head`, tails after their guards; ("end",) marks a path that falls
+    off its last op at that slot."""
+    def walk(ops, slot, in_tail):
+        for op in ops:
+            yield op, slot, in_tail
+            if op[0] == "term":
+                return
+            if op[0] == "jump":
+                slot = int(img.a[slot])
+                continue
+            if op[0] in ("guardz", "guardnz") and op[1]:
+                yield from walk(op[1], int(img.a[slot]), True)
+            slot += 1
+        yield ("end",), slot, in_tail
+
+    return walk(shape, head, False)
+
+
+def superblock_edges(hid, shapes, img) -> dict:
+    """Forward edges the plane's blocks run through, by kind."""
+    edges = {"jump": 0, "guard_tail": 0}
+    for head in np.flatnonzero(hid >= H_BLOCK_BASE):
+        for op, _slot, _in_tail in walk_shape(
+                shapes[int(hid[head]) - H_BLOCK_BASE], int(head), img):
+            if op[0] == "jump":
+                edges["jump"] += 1
+            elif op[0] in ("guardz", "guardnz") and op[1]:
+                edges["guard_tail"] += 1
+    return edges
+
+
 def entry_slots(hid, shapes, img) -> np.ndarray:
     """Mask of the slots at which a dispatch can START while a block's
-    lanes stay converged, for the plane fuse_blocks wrote: block heads,
-    the slots it stepped over unfused, and absorbed slots a jump lands
-    on (a non-pure terminal may itself be a jump target and then
-    dispatches its own untouched hid).  Every other absorbed slot keeps
-    its hid only for a resume (a bail, a split child, a SIMT handoff
-    coming back), so its handler is compiled but cold."""
+    lanes stay converged, for the plane fuse_blocks wrote: function
+    entries, br_table targets, and from there on every slot at which a
+    handler can leave pc: where a block falls off its last op or leaves
+    through a guard with no tail, and the successors of a terminal or
+    of an op dispatched alone.  A slot that every way in runs THROUGH
+    (a forward target whose every in-edge a superblock absorbed: fib's
+    6 and 15) is, like every other absorbed slot, reached only by a
+    resume (a bail, a split child, a SIMT handoff coming back), so its
+    handler is compiled but cold."""
     n = img.code_len
     entry = np.zeros(n, bool)
-    pc = 0
-    while pc < n:
+    todo = [int(x) for x in img.f_entry] + \
+        [int(img.br_table[e, 0]) for e in range(img.br_table.shape[0])]
+    while todo:
+        pc = todo.pop()
+        if not 0 <= pc < n or entry[pc]:
+            continue
         entry[pc] = True
         h = int(hid[pc])
-        pc += len(shapes[h - H_BLOCK_BASE]) if h >= H_BLOCK_BASE else 1
-    for t in _jump_targets(img):
-        if 0 <= t < n:
-            entry[t] = True
+        if h < H_BLOCK_BASE:
+            todo.extend(_successors(img, pc))
+            continue
+        for op, slot, _in_tail in walk_shape(
+                shapes[h - H_BLOCK_BASE], pc, img):
+            if op[0] == "term":
+                todo.extend(_successors(img, slot))
+            elif op[0] == "end" or \
+                    (op[0] in ("guardz", "guardnz") and not op[1]):
+                todo.append(slot if op[0] == "end" else int(img.a[slot]))
     return entry
 
 
@@ -905,6 +1106,7 @@ def _build_kernel(used_hids: tuple, D: int, CD: int, W: int, L: int,
         if optimistic:
             canr, flag, snapf, snapc = (next(it_), next(it_),
                                         next(it_), next(it_))
+        turns = next(it_)
         blk = pl.program_id(0)
         lo = blk * Lblk
         # lane-block slices of the (wrapper-reshaped) HBM planes: in
@@ -2894,63 +3096,98 @@ def _build_kernel(used_hids: tuple, D: int, CD: int, W: int, L: int,
             return h
 
         def mk_block(shape):
-            """Fused basic block: pure ops run with intermediates in
-            vregs (virtual stack resolved at trace time); local/global/
+            """Fused block: pure ops run with intermediates in vregs
+            (virtual stack resolved at trace time); local/global/
             memory writes commit immediately in op order.  Forward
             branches absorbed as GUARDS speculate fallthrough — the
-            taken path exits through a lax.cond branch that flushes the
-            guard-point virtual stack, so nothing after the guard
-            commits.  Inline loads/stores take the uniform-address fast
+            taken path is a lax.cond branch that runs the guard's tail
+            (the target's ops, on the guard-point virtual stack) and
+            leaves, so nothing after the guard commits; with an empty
+            tail it flushes and leaves at once.  A forward `br`
+            absorbed as a JUMP moves no data: the kept cell stays in
+            vregs, the virtual stack is rebased to the target's
+            height, and the ops that follow are the target's.  Every
+            op reads its immediates at its own ORIGINAL slot (`At`: the
+            head's pc, or a followed edge's target, plus a static
+            offset), and counts in `steps` what its own path retired.
+            Inline loads/stores take the uniform-address fast
             path; address divergence (careful kernel) or a lane-0 OOB
             bails un-advanced at the op's own slot with everything
             before it committed, which is exactly the state the
             scheduler's split machinery expects for the op's ORIGINAL
-            opcode.  The terminal (if any) runs via the *_with cores,
+            opcode (a jump's or a tail's first slot is a block head:
+            the splitter reads its original opcode as it does a bail
+            at any head).  A terminal runs via the *_with cores,
             consuming the virtual-stack top directly from vregs."""
-            body_ops = shape[:-1] if shape[-1][0] == "term" else shape
-            term = shape[-1] if shape[-1][0] == "term" else None
-            nops = len(body_ops)
-
             def h(c):
-                pc, sp0, fp = c[1], c[2], c[3]
+                pc, fp = c[1], c[3]
+
+                class At:
+                    """Where a path stands: op `i` of `seq`, whose
+                    original slot is base + off (base a run-time
+                    scalar, off static), after `r` retired ops."""
+                    __slots__ = ("seq", "i", "base", "off", "r")
+
+                    def __init__(self, seq, i, base, off, r):
+                        self.seq, self.i, self.base = seq, i, base
+                        self.off, self.r = off, r
+
+                    def op(self):
+                        return self.seq[self.i]
+
+                    def slot(self):
+                        return self.base + self.off
+
+                    def next(self):
+                        return At(self.seq, self.i + 1, self.base,
+                                  self.off + 1, self.r + 1)
+
+                    def enter(self, seq, i=0):
+                        """The followed edge of this op: `seq` from
+                        `i` on are the ops at its target."""
+                        return At(seq, i, a_r[self.slot()], 0,
+                                  self.r + 1)
 
                 class VS:
-                    """Trace-time virtual stack (immutable snapshots:
-                    guard/bail closures capture the state at their
-                    point)."""
-                    __slots__ = ("items", "nbelow")
+                    """Trace-time virtual stack over the rows from
+                    `base` (immutable snapshots: guard/bail closures
+                    capture the state at their point)."""
+                    __slots__ = ("base", "items", "nbelow")
 
-                    def __init__(self, items=(), nbelow=0):
+                    def __init__(self, base, items=(), nbelow=0):
+                        self.base = base
                         self.items = tuple(items)
                         self.nbelow = nbelow
 
                     def push(self, v):
-                        return VS(self.items + (v,), self.nbelow)
+                        return VS(self.base, self.items + (v,),
+                                  self.nbelow)
 
                     def pop(self):
                         if self.items:
-                            return self.items[-1], VS(self.items[:-1],
-                                                      self.nbelow)
+                            return self.items[-1], VS(
+                                self.base, self.items[:-1], self.nbelow)
                         k = self.nbelow
-                        idx = sp0 - 1 - k
-                        return srow4(idx), VS((), k + 1)
+                        idx = self.base - 1 - k
+                        return srow4(idx), VS(self.base, (), k + 1)
 
                     def drop1(self):
                         if self.items:
-                            return VS(self.items[:-1], self.nbelow)
-                        return VS((), self.nbelow + 1)
+                            return VS(self.base, self.items[:-1],
+                                      self.nbelow)
+                        return VS(self.base, (), self.nbelow + 1)
 
                     def peek(self):
                         if self.items:
                             return self.items[-1]
-                        idx = sp0 - 1 - self.nbelow
+                        idx = self.base - 1 - self.nbelow
                         return srow4(idx)
 
                     def sp(self):
-                        return sp0 + (len(self.items) - self.nbelow)
+                        return self.base + (len(self.items) - self.nbelow)
 
                     def flush(self, skip_top=0):
-                        base = sp0 - self.nbelow
+                        base = self.base - self.nbelow
                         n = len(self.items) - skip_top
                         for i in range(n):
                             wrow4(base + i, self.items[i])
@@ -2964,55 +3201,74 @@ def _build_kernel(used_hids: tuple, D: int, CD: int, W: int, L: int,
                         return (lo_v, hi_v, z, z)
                     return (lo_v, hi_v)
 
-                def bail(cb, j, vs):
-                    """Un-advanced stop at op j: everything before j is
-                    committed; flush the virtual stack so VMEM holds
-                    the exact pre-op state, leave pc at the op's slot
-                    (original hid) for the scheduler/SIMT."""
+                def bail(cb, at, vs):
+                    """Un-advanced stop at the op `at`: everything
+                    before it is committed; flush the virtual stack so
+                    VMEM holds the exact pre-op state, leave pc at the
+                    op's slot (original hid) for the scheduler/SIMT."""
                     vs.flush()
-                    return keep(cb, steps=cb[0] + j, pc=pc + j,
+                    return keep(cb, steps=cb[0] + at.r, pc=at.slot(),
                                 sp=vs.sp(), status=I32(ST_DIVERGED))
 
                 def emit(j, cb, vs, pend_l, pend_g):
-                    if j == nops:
-                        return finish(cb, vs)
-                    pcj = pc + j
-                    op = body_ops[j]
+                    if j.i == len(j.seq):
+                        # the path falls off its last op
+                        vs.flush()
+                        return keep(cb, steps=cb[0] + j.r - 1,
+                                    pc=j.slot(), sp=vs.sp())
+                    pcj = j.slot()
+                    op = j.op()
                     kind = op[0]
+                    if kind == "term":
+                        return finish(j, cb, vs)
+                    if kind == "jump":
+                        # br: the kept cell stays in vregs, the rest of
+                        # the virtual stack goes to its rows (what lies
+                        # above the target's height is dead, what lies
+                        # below it is live), and the stack restarts at
+                        # the target's height
+                        kept = ()
+                        if op[1]:
+                            top, vs = vs.pop()
+                            kept = (top,)
+                        vs.flush()
+                        vs = VS(cb[4] + c_r[pcj], kept)
+                        return emit(j.enter(j.seq, j.i + 1), cb, vs,
+                                    pend_l, pend_g)
                     if kind == "nop":
-                        return emit(j + 1, cb, vs, pend_l, pend_g)
+                        return emit(j.next(), cb, vs, pend_l, pend_g)
                     if kind == "const":
                         vs = vs.push(cell2(full(ilo_r[pcj]),
                                            full(ihi_r[pcj])))
-                        return emit(j + 1, cb, vs, pend_l, pend_g)
+                        return emit(j.next(), cb, vs, pend_l, pend_g)
                     if kind == "lget":
                         v = pend_l.get(op[1])
                         if v is None:
                             v = srow4(fp + a_r[pcj])
-                        return emit(j + 1, cb, vs.push(v), pend_l, pend_g)
+                        return emit(j.next(), cb, vs.push(v), pend_l, pend_g)
                     if kind in ("lset", "ltee"):
                         if kind == "lset":
                             v, vs = vs.pop()
                         else:
                             v = vs.peek()
                         wrow4(fp + a_r[pcj], v)
-                        return emit(j + 1, cb, vs,
+                        return emit(j.next(), cb, vs,
                                     {**pend_l, op[1]: v}, pend_g)
                     if kind == "gget":
                         v = pend_g.get(op[1])
                         if v is None:
                             g = a_r[pcj]
                             v = cell2(srow(glo, g), srow(ghi, g))
-                        return emit(j + 1, cb, vs.push(v), pend_l, pend_g)
+                        return emit(j.next(), cb, vs.push(v), pend_l, pend_g)
                     if kind == "gset":
                         v, vs = vs.pop()
                         g = a_r[pcj]
                         wrow(glo, g, v[0])
                         wrow(ghi, g, v[1])
-                        return emit(j + 1, cb, vs, pend_l,
+                        return emit(j.next(), cb, vs, pend_l,
                                     {**pend_g, op[1]: v})
                     if kind == "drop":
-                        return emit(j + 1, cb, vs.drop1(), pend_l, pend_g)
+                        return emit(j.next(), cb, vs.drop1(), pend_l, pend_g)
                     if kind == "select":
                         cnd, vs = vs.pop()
                         x2, vs = vs.pop()
@@ -3020,69 +3276,69 @@ def _build_kernel(used_hids: tuple, D: int, CD: int, W: int, L: int,
                         z = cnd[0] == 0
                         vs = vs.push(tuple(jnp.where(z, a, b)
                                            for a, b in zip(x2, x1)))
-                        return emit(j + 1, cb, vs, pend_l, pend_g)
+                        return emit(j.next(), cb, vs, pend_l, pend_g)
                     if kind == "memsize":
                         vs = vs.push(cell2(full(cb[6]), full(0)))
-                        return emit(j + 1, cb, vs, pend_l, pend_g)
+                        return emit(j.next(), cb, vs, pend_l, pend_g)
                     if kind == "alu2":
                         y, vs = vs.pop()
                         x, vs = vs.pop()
                         vs = vs.push(cell2(*alu2[op[1]](x[0], x[1],
                                                         y[0], y[1])))
-                        return emit(j + 1, cb, vs, pend_l, pend_g)
+                        return emit(j.next(), cb, vs, pend_l, pend_g)
                     if kind == "alu1":
                         x, vs = vs.pop()
                         vs = vs.push(cell2(*alu1[op[1]](x[0], x[1])))
-                        return emit(j + 1, cb, vs, pend_l, pend_g)
+                        return emit(j.next(), cb, vs, pend_l, pend_g)
                     if kind == "v2":
                         y, vs = vs.pop()
                         x, vs = vs.pop()
                         vs = vs.push(sops.v2_fn(op[1])(x, y))
-                        return emit(j + 1, cb, vs, pend_l, pend_g)
+                        return emit(j.next(), cb, vs, pend_l, pend_g)
                     if kind == "v1":
                         x, vs = vs.pop()
                         vs = vs.push(sops.v1_fn(op[1])(x))
-                        return emit(j + 1, cb, vs, pend_l, pend_g)
+                        return emit(j.next(), cb, vs, pend_l, pend_g)
                     if kind == "vtest":
                         x, vs = vs.pop()
                         vs = vs.push(cell2(sops.vtest_fn(op[1])(x),
                                            full(0)))
-                        return emit(j + 1, cb, vs, pend_l, pend_g)
+                        return emit(j.next(), cb, vs, pend_l, pend_g)
                     if kind == "vshift":
                         cnt, vs = vs.pop()
                         x, vs = vs.pop()
                         vs = vs.push(sops.vshift_fn(op[1])(x, cnt[0]))
-                        return emit(j + 1, cb, vs, pend_l, pend_g)
+                        return emit(j.next(), cb, vs, pend_l, pend_g)
                     if kind == "vsplat":
                         v, vs = vs.pop()
                         vs = vs.push(sops.vsplat_fn(op[1])(v[0], v[1]))
-                        return emit(j + 1, cb, vs, pend_l, pend_g)
+                        return emit(j.next(), cb, vs, pend_l, pend_g)
                     if kind == "vextract":
                         x, vs = vs.pop()
                         rl, rh = sops.vextract_dyn(op[1])(x, a_r[pcj])
                         vs = vs.push(cell2(rl, rh))
-                        return emit(j + 1, cb, vs, pend_l, pend_g)
+                        return emit(j.next(), cb, vs, pend_l, pend_g)
                     if kind == "vreplace":
                         v, vs = vs.pop()
                         x, vs = vs.pop()
                         vs = vs.push(sops.vreplace_dyn(op[1])(
                             x, a_r[pcj], v[0], v[1]))
-                        return emit(j + 1, cb, vs, pend_l, pend_g)
+                        return emit(j.next(), cb, vs, pend_l, pend_g)
                     if kind == "vconst":
                         vs = vs.push(_vconst4(a_r[pcj]))
-                        return emit(j + 1, cb, vs, pend_l, pend_g)
+                        return emit(j.next(), cb, vs, pend_l, pend_g)
                     if kind == "vshuffle":
                         y, vs = vs.pop()
                         x, vs = vs.pop()
                         vs = vs.push(sops.vshuffle_dyn()(
                             x, y, _vconst4(a_r[pcj])))
-                        return emit(j + 1, cb, vs, pend_l, pend_g)
+                        return emit(j.next(), cb, vs, pend_l, pend_g)
                     if kind == "vbitsel":
                         y, vs = vs.pop()
                         x, vs = vs.pop()
                         w_, vs = vs.pop()
                         vs = vs.push(sops.vbitselect()(w_, x, y))
-                        return emit(j + 1, cb, vs, pend_l, pend_g)
+                        return emit(j.next(), cb, vs, pend_l, pend_g)
                     if kind in ("guardz", "guardnz"):
                         return emit_guard(j, cb, vs, pend_l, pend_g)
                     if kind == "loadi":
@@ -3092,31 +3348,36 @@ def _build_kernel(used_hids: tuple, D: int, CD: int, W: int, L: int,
                     raise AssertionError(f"unknown block op {kind}")
 
                 def emit_guard(j, cb, vs, pend_l, pend_g):
-                    pcj = pc + j
-                    nz = body_ops[j][0] == "guardnz"
+                    pcj = j.slot()
+                    nz, tail = j.op()[0] == "guardnz", j.op()[1]
                     vs_pre = vs           # incl. cond (careful bail)
                     cond, vs = vs.pop()
 
                     def exit_taken():
-                        vs.flush()
-                        # brz taken: sp = post-pop; brnz (nkeep==0)
-                        # taken: unwind to ob + pop_to
-                        tsp = (cb[4] + c_r[pcj]) if nz else vs.sp()
-                        return keep(cb, steps=cb[0] + j, pc=a_r[pcj],
-                                    sp=tsp)
+                        # the tail (empty: leave at once, pc at the
+                        # target) runs on the guard-point stack.  brz
+                        # taken: sp = post-pop, the stack stays in
+                        # vregs; brnz (nkeep==0) taken: unwind to
+                        # ob + pop_to
+                        vt = vs
+                        if nz:
+                            vs.flush()
+                            vt = VS(cb[4] + c_r[pcj])
+                        return emit(j.enter(tail), cb, vt, pend_l,
+                                    pend_g)
 
                     if optimistic:
                         t0 = agree_nz(cond[0])
                         taken = (t0 != 0) if nz else (t0 == 0)
                         return lax.cond(
                             taken, exit_taken,
-                            lambda: emit(j + 1, cb, vs, pend_l, pend_g))
+                            lambda: emit(j.next(), cb, vs, pend_l, pend_g))
                     t0 = scal(cond[0])
                     agree = allsame(cond[0], t0)
                     taken = (t0 != 0) if nz else (t0 == 0)
                     return lax.cond(
                         agree & ~taken,
-                        lambda: emit(j + 1, cb, vs, pend_l, pend_g),
+                        lambda: emit(j.next(), cb, vs, pend_l, pend_g),
                         lambda: lax.cond(
                             agree, exit_taken,
                             lambda: bail(cb, j, vs_pre)))
@@ -3152,8 +3413,8 @@ def _build_kernel(used_hids: tuple, D: int, CD: int, W: int, L: int,
                     return ll, lh
 
                 def emit_load(j, cb, vs, pend_l, pend_g):
-                    pcj = pc + j
-                    nbytes, flags = body_ops[j][1], body_ops[j][2]
+                    pcj = j.slot()
+                    nbytes, flags = j.op()[1], j.op()[2]
                     want = 2 if nbytes == 8 else 1
                     vs_pre = vs
                     addr, vs = vs.pop()
@@ -3173,13 +3434,13 @@ def _build_kernel(used_hids: tuple, D: int, CD: int, W: int, L: int,
                             # absorbed slot with the original hid) and
                             # never re-runs the committed prefix.
                             vs_pre.flush()
-                            cb_snap = keep(cb, steps=cb[0] + j,
-                                           pc=pc + j, sp=vs_pre.sp())
+                            cb_snap = keep(cb, steps=cb[0] + j.r,
+                                           pc=pcj, sp=vs_pre.sp())
                             dirty, snapped, way, wfs2 = _opt_window(
                                 cb_snap, u, rhi)
                             cb2 = _keep_win(
                                 cb, wfs2,
-                                ls=jnp.where(snapped, cb[0] + j,
+                                ls=jnp.where(snapped, cb[0] + j.r,
                                              cb[IDX["ls"]]))
                             m0 = win_read_row(way, wfs2, u)
                             m1 = win_read_row(way, wfs2,
@@ -3194,7 +3455,7 @@ def _build_kernel(used_hids: tuple, D: int, CD: int, W: int, L: int,
                                 lambda: lax.cond(
                                     oob0,
                                     lambda: bail(cb2, j, vs_pre),
-                                    lambda: emit(j + 1, cb2, vs2,
+                                    lambda: emit(j.next(), cb2, vs2,
                                                  pend_l, pend_g)))
                         m0 = srow(memr, u)
                         m1 = srow(memr, jnp.minimum(u + 1, W - 1))
@@ -3205,7 +3466,7 @@ def _build_kernel(used_hids: tuple, D: int, CD: int, W: int, L: int,
                         return lax.cond(
                             oob0,
                             lambda: bail(cb, j, vs_pre),
-                            lambda: emit(j + 1, cb, vs2, pend_l, pend_g))
+                            lambda: emit(j.next(), cb, vs2, pend_l, pend_g))
                     # careful kernel: flush and delegate to the original
                     # handler (keeps its divergent-address gather paths
                     # and trap-partial semantics); execution continues
@@ -3224,13 +3485,13 @@ def _build_kernel(used_hids: tuple, D: int, CD: int, W: int, L: int,
 
                 def _delegate_mem(j, cb, vs_pre, flat_hid):
                     vs_pre.flush()
-                    c2 = keep(cb, steps=cb[0] + j, pc=pc + j,
+                    c2 = keep(cb, steps=cb[0] + j.r, pc=j.slot(),
                               sp=vs_pre.sp())
                     return handler_for(flat_hid)(c2)
 
                 def emit_store(j, cb, vs, pend_l, pend_g):
-                    pcj = pc + j
-                    nbytes = body_ops[j][1]
+                    pcj = j.slot()
+                    nbytes = j.op()[1]
                     want = 2 if nbytes == 8 else 1
                     vs_pre = vs
                     val, vs = vs.pop()
@@ -3252,8 +3513,8 @@ def _build_kernel(used_hids: tuple, D: int, CD: int, W: int, L: int,
                             rhi = jnp.minimum(u + want, W - 1)
                             # snapshot-consistency: see emit_load
                             vs_pre.flush()
-                            cb_snap = keep(cb, steps=cb[0] + j,
-                                           pc=pc + j, sp=vs_pre.sp())
+                            cb_snap = keep(cb, steps=cb[0] + j.r,
+                                           pc=pcj, sp=vs_pre.sp())
                             dirty, snapped, way, wfs2 = _opt_window(
                                 cb_snap, u, rhi)
                             okw = ~dirty & ~oob0
@@ -3270,14 +3531,14 @@ def _build_kernel(used_hids: tuple, D: int, CD: int, W: int, L: int,
                             nwd1 = jnp.where(way == 1, I32(1), wfs2[3])
                             cb2 = keep(cb, wb0=wfs2[0], wd0=nwd0,
                                        wb1=wfs2[2], wd1=nwd1, mru=wfs2[4],
-                                       ls=jnp.where(snapped, cb[0] + j,
+                                       ls=jnp.where(snapped, cb[0] + j.r,
                                                     cb[IDX["ls"]]))
                             return lax.cond(
                                 dirty, rolled_carry,
                                 lambda: lax.cond(
                                     oob0,
                                     lambda: bail(cb2, j, vs_pre),
-                                    lambda: emit(j + 1, cb2, vs,
+                                    lambda: emit(j.next(), cb2, vs,
                                                  pend_l, pend_g)))
                         for k, (m, v) in enumerate(masks_vals(shB)):
                             w = jnp.minimum(u + k, W - 1)
@@ -3290,20 +3551,16 @@ def _build_kernel(used_hids: tuple, D: int, CD: int, W: int, L: int,
                         return lax.cond(
                             oob0,
                             lambda: bail(cb, j, vs_pre),
-                            lambda: emit(j + 1, cb, vs, pend_l, pend_g))
+                            lambda: emit(j.next(), cb, vs, pend_l, pend_g))
                     # careful kernel: flush + delegate (see emit_load)
                     return _delegate_mem(
                         j, cb, vs_pre,
                         H_STORE_W if nbytes == 4 else
                         H_STORE_D if nbytes == 8 else H_STORE)
 
-                def finish(cb, vs):
+                def finish(at, cb, vs):
                     sp_t = vs.sp()
-                    if term is None:
-                        vs.flush()
-                        return keep(cb, steps=cb[0] + nops - 1,
-                                    pc=pc + nops, sp=sp_t)
-                    t_hid = term[1]
+                    t_hid = at.op()[1]
                     # Only the cell the terminal POPS (or that dies
                     # with the unwind: return/br kept values) may skip
                     # its flush; a brnz fallthrough keeps sp-2 live, so
@@ -3316,7 +3573,7 @@ def _build_kernel(used_hids: tuple, D: int, CD: int, W: int, L: int,
                     vs.flush(skip_top=nvreg)
                     top1 = vs.items[-1] if len(vs.items) >= 1 else None
                     top2 = vs.items[-2] if len(vs.items) >= 2 else None
-                    c2 = keep(cb, steps=cb[0] + nops, pc=pc + nops,
+                    c2 = keep(cb, steps=cb[0] + at.r, pc=at.slot(),
                               sp=sp_t)
                     if t_hid == H_BRZ:
                         return brz_with(c2, top1, spill=top1 is not None)
@@ -3335,7 +3592,7 @@ def _build_kernel(used_hids: tuple, D: int, CD: int, W: int, L: int,
                                           spill=top1 is not None)
                     return handler_for(t_hid)(c2)
 
-                return emit(0, c, VS(), {}, {})
+                return emit(At(shape, 0, pc, 0, 0), c, VS(c[2]), {}, {})
             return h
 
         # ------------------- v128 handlers ----------------------------
@@ -3722,6 +3979,7 @@ def _build_kernel(used_hids: tuple, D: int, CD: int, W: int, L: int,
             leaves with ST_RECHECK; a clean one records the current
             state as the next rollback point."""
             flag[0] = jnp.any(srow(canr, 0) != 0).astype(jnp.int32)
+            flag[1] = flag[1] + 1
 
             def rolled():
                 do_restore()
@@ -3761,8 +4019,9 @@ def _build_kernel(used_hids: tuple, D: int, CD: int, W: int, L: int,
             section 5; the r4/r5 estimate was ~15 ns), so the plan puts
             the handlers a converged dispatch can start at near the
             root and the resume-only ones, with the commit, in one cold
-            subtree: fib's four dispatched handlers sit at depth 2, 2,
-            2 and 3 where the slot-count weights had them at 4.
+            subtree: fib's three dispatched handlers (superblocks;
+            four before them, at 2, 2, 2 and 3) sit at depth 2 where
+            the slot-count weights had them at 4.
             Bit-exact vs lax.switch; the midpoint tree when no weights
             are known."""
             def tree(node):
@@ -3784,6 +4043,12 @@ def _build_kernel(used_hids: tuple, D: int, CD: int, W: int, L: int,
                 due = commit_due(c)
                 hid = jnp.where(due, I32(H_COMMIT), hid)
             nc = dispatch(hid, c)
+            # the dispatch count: one vreg in VMEM goes up by one every
+            # turn, commits included (the commit leaf counts those in
+            # flag[1]).  No scalar: one more in the carry, which every
+            # region of a dispatch yields, cost the hbm-window kernel
+            # 2 % on a v5e, and an SMEM cell as much (PR 29)
+            turns[...] = turns[...] + 1
             # un-advanced stops rewind the step count (the next engine
             # re-executes the instruction): divergence, regrow, and
             # optimistic rollbacks (whose steps were already rewound);
@@ -3802,13 +4067,16 @@ def _build_kernel(used_hids: tuple, D: int, CD: int, W: int, L: int,
             # SIMT handoffs mutate the HBM plane between launches)
             init = init + (I32(-(1 << 30)), I32(0),
                            I32(-(1 << 30)), I32(0), I32(0))
+        turns[...] = jnp.zeros_like(turns)
         if optimistic:
             init = init + (I32(0),)  # ls: last-snapshot step count
             # entry state was validated at the previous exit: it IS the
             # first rollback point
             wrow(canr, 0, full(0))
             do_snapshot(init)
+            flag[1] = I32(0)         # commits taken as a loop turn
         fin = lax.while_loop(cond, body, init)
+        dispatched = turns[0, 0] - (flag[1] if optimistic else I32(0))
         if optimistic:
             # a commit that fell due on the launch's last dispatch
             fin = lax.cond(commit_due(fin) & (fin[7] == I32(ST_RUNNING)),
@@ -3866,6 +4134,7 @@ def _build_kernel(used_hids: tuple, D: int, CD: int, W: int, L: int,
         ctrl_out[blk, _C_CHUNK] = chunk
         ctrl_out[blk, _C_STEPS] = steps
         ctrl_out[blk, _C_SNAP] = snap_in
+        ctrl_out[blk, _C_DISPATCHES] = dispatched
         if mem_hbm:
             ctrl_out[blk, _C_WFILLS] = wcnt[0]
             ctrl_out[blk, _C_WWBS] = wcnt[1]
@@ -3941,6 +4210,7 @@ def _build_kernel(used_hids: tuple, D: int, CD: int, W: int, L: int,
                 pltpu.SMEM((3, CD), jnp.int32),         # snapf (frames)
                 pltpu.SMEM((16,), jnp.int32)]           # snapc (carry)
                if optimistic else [])
+            + [pltpu.VMEM((8, 128), jnp.int32)]         # turns
         ),
     )
     out_shape = [
@@ -4093,6 +4363,13 @@ class PallasUniformEngine:
         # block each (the careful recheck kernel's included)
         self.window_fills = 0
         self.window_writebacks = 0
+        # handlers the kernels dispatched over the last run(), summed
+        # over blocks and launches, and the block-steps a dispatch
+        # retired (3.5 in fib before superblocks, 5.25 with them)
+        self.dispatches = 0
+        self.instr_per_dispatch = None
+        # forward edges the newest kernel's blocks run through, by kind
+        self.superblock_edges = None
         # None = no tpu.aot fused section attached; set by _build when a
         # loaded artifact carries one (True = matched regeneration)
         self.aot_fused_verified = None
@@ -4251,6 +4528,12 @@ class PallasUniformEngine:
         live = entry_slots(hid, block_shapes, img) if self.block_fusion \
             else np.ones(len(hid), bool)
         count = collections.Counter(int(h) for h in hid[live])
+        if self.block_fusion:
+            # a block whose every way in was absorbed stays among the
+            # hot handlers all the same: it nests as deep as they do,
+            # and the cold subtree is where the tree is deepest
+            for h in set(int(h) for h in hid[hid >= H_BLOCK_BASE]):
+                count[h] = max(count[h], 1)
         used = tuple(sorted(set(int(h) for h in hid),
                             key=lambda h: (-count[h], h)))
         dense = {h: i for i, h in enumerate(used)}
@@ -4260,6 +4543,9 @@ class PallasUniformEngine:
             self._hid_weights,
             kernel_dispatch_plan(self._hid_weights, self.optimistic)[1])
         self.obs.set_dispatch_static(*self.dispatch_depth)
+        self.superblock_edges = superblock_edges(hid, block_shapes, img) \
+            if self.block_fusion else {"jump": 0, "guard_tail": 0}
+        self.obs.set_superblock_static(self.superblock_edges)
         # host-side view of the fused encoding: the block scheduler's
         # divergence splitter evaluates the stopped instruction from
         # these.  _np_hid_orig is the UNfused plane: a block whose
@@ -4731,6 +5017,11 @@ class PallasUniformEngine:
         self.window_writebacks = sched.window_writebacks
         self.obs.add_window_counts(sched.window_fills,
                                    sched.window_writebacks)
+        self.superblock_edges = sched.eng.superblock_edges
+        self.dispatches = sched.dispatches
+        self.instr_per_dispatch = sched.kernel_steps / sched.dispatches \
+            if sched.dispatches else None
+        self.obs.add_dispatch_counts(sched.dispatches)
         return sched.result()
 
     def _serve_hostcalls(self, state, ctrl_np, valid_blocks=None):

@@ -67,6 +67,7 @@ from wasmedge_tpu.batch.pallas_engine import (
     _C_CD,
     _C_SNAP,
     _C_CHUNK,
+    _C_DISPATCHES,
     _C_FP,
     _C_FUEL,
     _C_OB,
@@ -268,6 +269,11 @@ class BlockScheduler:
         # launches (zero in every other memory mode)
         self.window_fills = 0
         self.window_writebacks = 0
+        # handlers the kernels dispatched and the block-steps they
+        # retired doing it, summed over blocks and launches (the
+        # careful recheck's too)
+        self.dispatches = 0
+        self.kernel_steps = 0
         self.quarantined = 0
         self._t_launch = 0.0
         self._plane_idx = _PLANE_IDX_SIMD if outer.img.has_simd \
@@ -540,7 +546,7 @@ class BlockScheduler:
             live = self._live_at_launch
             new_steps = ctrl_np[:, _C_STEPS].astype(np.int64)
             self.block_steps[live] += new_steps[live]
-            self._count_window(ctrl_np, live)
+            self._count_kernel(ctrl_np, live)
             obs = self.obs
             if obs.enabled:
                 # per-launch span closed at THIS sync point (the ctrl
@@ -581,8 +587,10 @@ class BlockScheduler:
         self._pending = []
         return False
 
-    def _count_window(self, ctrl_np, blocks):
+    def _count_kernel(self, ctrl_np, blocks):
         """Add what the kernel that just ran counted in `blocks`."""
+        self.dispatches += int(ctrl_np[blocks, _C_DISPATCHES].sum())
+        self.kernel_steps += int(ctrl_np[blocks, _C_STEPS].sum())
         if self.eng.mem_static.get("mem_mode") == "hbm_window":
             self.window_fills += int(ctrl_np[blocks, _C_WFILLS].sum())
             self.window_writebacks += int(ctrl_np[blocks, _C_WWBS].sum())
@@ -600,7 +608,7 @@ class BlockScheduler:
         self.state, ctrl = self.eng.careful_recheck(
             self.state, self._ctrl(), recheck)
         self.block_steps += ctrl[:, _C_STEPS].astype(np.int64)
-        self._count_window(ctrl, recheck)
+        self._count_kernel(ctrl, recheck)
         self._ctrl_cache = ctrl
         self._ctrl_dirty = False
         self._frames_cache = None

@@ -987,6 +987,9 @@ class UniformBatchEngine:
             res = self._run(func_name, args_lanes, max_steps)
             if not kernel:   # the first run is the one that builds
                 span.set(**self._kernel_args())
+            ipd = getattr(self.pallas, "instr_per_dispatch", None)
+            if ipd is not None:
+                span.set(instr_per_dispatch=round(ipd, 4))
             return res
 
     def _kernel_args(self):

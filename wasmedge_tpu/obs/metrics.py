@@ -577,6 +577,24 @@ def render_prometheus(recorder=None, stats=None, hostcall_stats=None,
                      dps["expected"])
             w.sample("wasmedge_dispatch_depth", {"stat": "max"},
                      dps["max"])
+        sbs = getattr(recorder, "superblock_static", None)
+        if sbs is not None:
+            w.head("wasmedge_superblock_edges", "gauge",
+                   "Forward edges the newest Pallas kernel's fused "
+                   "blocks run through instead of ending at: `br`s "
+                   "absorbed as jumps, and guards whose taken side "
+                   "runs the target's block as a tail "
+                   "(batch/pallas_engine.py fuse_blocks).")
+            for kind in ("jump", "guard_tail"):
+                w.sample("wasmedge_superblock_edges", {"kind": kind},
+                         int(sbs.get(kind, 0)))
+        pdc = getattr(recorder, "pallas_dispatches", 0)
+        if pdc:
+            w.head("wasmedge_pallas_dispatches_total", "counter",
+                   "Handlers the Pallas kernels' loops dispatched, "
+                   "summed over lane blocks and launches (a fused "
+                   "block is one; a commit is none).")
+            w.sample("wasmedge_pallas_dispatches_total", None, pdc)
         mst = getattr(recorder, "memory_static", None)
         if mst and "lane_block" in mst:     # a guest with a memory
             w.head("wasmedge_memory_lane_block", "gauge",

@@ -206,6 +206,12 @@ class NullRecorder:
     def add_window_counts(self, fills, writebacks):
         pass
 
+    def set_superblock_static(self, edges):
+        pass
+
+    def add_dispatch_counts(self, dispatches):
+        pass
+
     def add_tierup_counts(self, dispatches, retired_comp, retired_total):
         pass
 
@@ -303,6 +309,10 @@ class FlightRecorder:
         # each run
         self.memory_static = None
         self.window_counts = {"fills": 0, "writebacks": 0}
+        # forward edges the newest Pallas kernel's blocks run through
+        # ({"jump", "guard_tail"}), and the handlers its loop dispatched
+        self.superblock_static = None
+        self.pallas_dispatches = 0
         # compiled-function tier counters folded from the device
         # tu_ctr plane (batch/engine.py _fold_tierup_ctr) + the
         # promotion report set once per plan by _plan_tierup (r20)
@@ -447,6 +457,18 @@ class FlightRecorder:
         batch/scheduler.py)."""
         self.window_counts["fills"] += int(fills)
         self.window_counts["writebacks"] += int(writebacks)
+
+    def set_superblock_static(self, edges):
+        """Record the forward edges the newest Pallas kernel's blocks
+        run through instead of ending at (batch/pallas_engine.py
+        fuse_blocks): absorbed `br`s and guards with a tail."""
+        self.superblock_static = dict(edges)
+
+    def add_dispatch_counts(self, dispatches):
+        """Fold the handlers the Pallas kernels dispatched in one run
+        (ctrl column 13, summed over blocks and launches by
+        batch/scheduler.py)."""
+        self.pallas_dispatches += int(dispatches)
 
     def add_tierup_counts(self, dispatches, retired_comp, retired_total):
         """Fold the device tier-up counters (compiled-function bodies
