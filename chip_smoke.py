@@ -38,7 +38,7 @@ import time
 HERE = os.path.dirname(os.path.abspath(__file__))
 PLATFORM = "tpu"
 LANES = 4096
-FLAGSHIP_N = 30             # fib(30) in every lane (bench.py's flagship)
+FLAGSHIP_N = 30             # fib(30) in every lane (BASELINE.json configs[0])
 MEMORY_WORDS = 2048         # mem_checksum(n) in every lane
 GATEWAY_REQUESTS = 64
 GATEWAY_TIMEOUT_S = 900     # one HTTP answer, first-launch compile included
@@ -63,8 +63,8 @@ def check(cond, what):
 
 # -- child A: the Pallas batch path ------------------------------------------
 def _bench_conf(depth, call_depth):
-    """The benches' geometry: stacks sized to the workload, one long
-    launch (bench.py:60-82, bench_memory.py:56-58)."""
+    """The benchmark's batch geometry (benchmark/configs/): stacks
+    sized to the workload, one long launch."""
     from wasmedge_tpu.common.configure import Configure
 
     conf = Configure()

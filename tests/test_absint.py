@@ -245,9 +245,9 @@ class TestMemoryFacts:
         assert a.licensed_sites == 0
 
     def test_hostcalls_void_touch_bound(self):
-        import bench_echo
+        from wasmedge_tpu.models import build_echo
 
-        _, a = analyzed(bench_echo.build_module())
+        _, a = analyzed(build_echo())
         assert a.tier0_sites + a.drain_sites > 0
         assert a.mem_pages_touch_bound is None
 

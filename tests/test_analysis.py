@@ -44,7 +44,11 @@ from wasmedge_tpu.analysis import (
 from wasmedge_tpu.common.configure import Configure
 from wasmedge_tpu.common.errors import ErrCode, rejection_info
 from wasmedge_tpu.common.opcodes import NAME_TO_ID
-from wasmedge_tpu.models import build_fib, build_loop_sum
+from wasmedge_tpu.models import (
+    build_counted_loop,
+    build_fib,
+    build_loop_sum,
+)
 from wasmedge_tpu.utils.builder import ModuleBuilder
 from wasmedge_tpu.validator.image import (
     LOP_BR,
@@ -304,9 +308,9 @@ class TestNgrams:
 
 class TestHostcalls:
     def test_echo_fd_write_is_tier0(self):
-        import bench_echo
+        from wasmedge_tpu.models import build_echo
 
-        _, a = analyzed(bench_echo.build_module())
+        _, a = analyzed(build_echo())
         assert a.tier0_sites == 2 and a.drain_sites == 0
         sites = [s for f in a.funcs for s in f.hostcall_sites]
         assert all(s.kind == "fd_write" and s.tier0 for s in sites)
@@ -373,9 +377,9 @@ class TestHostcalls:
 
 class TestFootprint:
     def test_pages_bound_no_grow_is_initial(self):
-        import bench_echo
+        from wasmedge_tpu.models import build_echo
 
-        _, a = analyzed(bench_echo.build_module())
+        _, a = analyzed(build_echo())
         assert a.mem_grow_sites == 0 and a.mem_pages_bound == 1
 
     def test_grow_with_declared_max(self):
@@ -573,9 +577,9 @@ class TestPolicy:
         assert AnalysisPolicy().evaluate(None) == []
 
     def test_memory_and_hostcall_limits(self):
-        import bench_echo
+        from wasmedge_tpu.models import build_echo
 
-        _, echo = analyzed(bench_echo.build_module())
+        _, echo = analyzed(build_echo())
         assert AnalysisPolicy(max_memory_pages=1).evaluate(echo) == []
         assert AnalysisPolicy(max_memory_pages=0).evaluate(echo)
         # echo's fd_write is tier-0-serviceable: tier0-only admits it
@@ -680,13 +684,22 @@ class TestGatewayAdmission:
         assert doc["gateway"]["policy_rejected"] == 1
         assert doc["analysis"]["policy_rejected"] == 1
 
-    def test_bounded_admits_with_summary(self, gw):
+    @pytest.mark.parametrize("guest,cost_bound,trip_bounded_loops", [
+        (build_bounded, 13, 0),
+        # "unbounded" before the abstract interpreter, which the strict
+        # tenant's max_static_cost rejects: admitted on its trip bound
+        (build_counted_loop, 770, 1),
+    ])
+    def test_bounded_admits_with_summary(self, gw, guest, cost_bound,
+                                         trip_bounded_loops):
         st, doc = rpc(gw, "POST", "/v1/modules?name=ok&tenant=strict",
-                      body=build_bounded(),
+                      body=guest(),
                       headers={"Content-Type": "application/wasm"})
         assert st == 201 and doc["ok"]
         assert doc["analysis"]["bounded"] is True
-        assert doc["analysis"]["cost_bound"] == 13
+        assert doc["analysis"]["cost_bound"] == cost_bound
+        assert doc["analysis"].get("trip_bounded_loops", 0) == \
+            trip_bounded_loops
         assert "analysis_warnings" not in doc
 
     def test_flag_mode_registers_with_warnings(self, gw):

@@ -761,13 +761,24 @@ def test_vm_get_import_module_context():
 
 
 def test_capi_parity_table_complete():
-    """Every reference export has a we_* counterpart (CAPI_PARITY.md is
-    generated from this same diff)."""
+    """Every reference export has a we_* counterpart.  The export names
+    come from the reference's header where a machine has it, and
+    otherwise from column 1 of the committed CAPI_PARITY.md, which was
+    generated from that header."""
+    import os
     import re
 
-    hdr = open("/root/reference/include/api/wasmedge/wasmedge.h").read()
-    ref = set("we_" + m[len("WasmEdge_"):] for m in re.findall(
-        r"WasmEdge_[A-Za-z0-9_]+(?= *\()", hdr))
+    header = "/root/reference/include/api/wasmedge/wasmedge.h"
+    if os.path.exists(header):
+        names = re.findall(r"WasmEdge_[A-Za-z0-9_]+(?= *\()",
+                           open(header).read())
+    else:
+        table = os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "CAPI_PARITY.md")
+        names = re.findall(r"^\| (WasmEdge_[A-Za-z0-9_]+) \|",
+                           open(table).read(), re.M)
+        assert len(names) == 236
+    ref = set("we_" + m[len("WasmEdge_"):] for m in names)
     ref = {r for r in ref if not r.endswith("_t")}
     have = set(dir(C))
     missing = sorted(r for r in ref if r not in have)

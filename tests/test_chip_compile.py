@@ -72,10 +72,9 @@ def _on(sharding, tree):
 
 
 def _simd_wasm():
-    import bench_simd
-    from wasmedge_tpu.utils.wat import parse_wat
+    from wasmedge_tpu.models import build_simd_kernel
 
-    return parse_wat(bench_simd._SRC)
+    return build_simd_kernel()
 
 
 def _fib_wasm():
@@ -114,8 +113,8 @@ def _superblock_wasm():
 
 def _pallas_engine(wasm, depth, call_depth, mem_hbm=None, blk_cap=None):
     """The engine UniformBatchEngine picks for a TPU backend, built at
-    4096 lanes (what VM.execute_batch holds; bench.py / bench_memory.py
-    / bench_simd.py geometries)."""
+    4096 lanes (what VM.execute_batch holds; the stack depths are each
+    guest's own in _KERNELS and _DEPTHS)."""
     from wasmedge_tpu.batch.uniform import UniformBatchEngine
     from wasmedge_tpu.common.configure import Configure
 

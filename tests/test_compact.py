@@ -476,7 +476,7 @@ def test_serving_checkpoint_resume_through_permutation():
 
 
 def test_serving_stdout_exactly_once_through_permutation():
-    import bench_echo
+    from wasmedge_tpu.models import build_echo
     from wasmedge_tpu.host.wasi import WasiModule
     from wasmedge_tpu.serve.server import BatchServer
 
@@ -489,7 +489,7 @@ def test_serving_stdout_exactly_once_through_permutation():
         sink = os.open(sink_path, os.O_WRONLY | os.O_CREAT | os.O_TRUNC)
         wasi.env.fds[1].os_fd = sink
         mod = Validator(conf).validate(
-            Loader(conf).parse_module(bench_echo.build_module()))
+            Loader(conf).parse_module(build_echo()))
         store = StoreManager()
         ex = Executor(conf)
         ex.register_import_object(store, wasi)

@@ -56,11 +56,9 @@ def log2_ceil(n):
     return max(n - 1, 0).bit_length()
 
 
-def fib_engine(**batch):
+def fib_engine():
     conf = Configure()
     conf.batch.steps_per_launch = 50_000
-    for k, v in batch.items():
-        setattr(conf.batch, k, v)
     _ex, store, inst = instantiate(build_fib(), conf)
     return PallasUniformEngine(inst, store=store, conf=conf, lanes=LANES,
                                interpret=True)
@@ -178,17 +176,6 @@ def test_fib_entry_slot_weights():
     # the flat plane and the splitter's views are what they were
     assert np.array_equal(eng._np_fused["hid"], hid)
     assert np.array_equal(eng._np_hid_orig, hid_plane(img))
-
-
-def test_legacy_peephole_path_weighs_every_slot():
-    eng = fib_engine(block_fusion=False)
-    eng._build()
-    hid = eng._np_fused["hid"]
-    used = eng._kargs[0]
-    count = {h: int((hid == h).sum()) for h in used}
-    assert eng._hid_weights == tuple(count[h] for h in used)
-    assert min(eng._hid_weights) >= 1
-    assert list(eng._hid_weights) == sorted(eng._hid_weights, reverse=True)
 
 
 def test_memory_workload_blocks_are_hot_and_absorbed_ops_cold():

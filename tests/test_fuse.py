@@ -182,6 +182,26 @@ class TestBitExact:
         assert (trap == TRAP_DONE).all()
         assert (cells[0].view(np.int64) == res[True].results[0]).all()
 
+    def test_echo_stdout_identical_fused_and_unfused(self, tmp_path):
+        """The hostcall path: fd_write's byte stream, results, traps and
+        retired counts are the same with fusion on and off."""
+        from tests.test_serve import _echo_engine
+
+        runs = {}
+        for fuse in (True, False):
+            path = tmp_path / f"out-{fuse}"
+            eng, sink = _echo_engine(
+                make_conf(fuse=fuse, steps_per_launch=100), LANES, path)
+            res = eng.run("echo", [np.full(LANES, 2, np.int64)],
+                          max_steps=1_000_000)
+            os.close(sink)
+            runs[fuse] = (res, path.read_bytes())
+        (on, out_on), (off, out_off) = runs[True], runs[False]
+        assert on.completed.all() and off.completed.all()
+        assert_results_identical(on, off)
+        assert out_on == out_off
+        assert len(out_on) == LANES * 2 * 2 * len(b"hello wasi echo\n")
+
     def test_mid_run_resume_executes_per_op(self):
         """A state whose pcs sit MID-superinstruction (exported at an
         arbitrary step boundary of the unfused build) resumes on the

@@ -219,6 +219,31 @@ def test_register_then_invoke_parity_and_generation_swap(gw_main):
     assert got_dbl == [2 * n + 7 for n in ds]
 
 
+def test_wasi_guest_registers_over_http_and_serves(gw_main):
+    """A guest that imports WASI (the echo guest: two fd_write calls an
+    iteration) registers at runtime as a raw application/wasm body and
+    answers; its stdout goes to the module's own sink."""
+    from wasmedge_tpu.models import build_echo
+
+    gw = gw_main
+    st, doc, _ = rpc(gw, "POST", "/v1/modules?name=echo",
+                     body=build_echo(),
+                     headers={"Content-Type": "application/wasm"})
+    assert st == 201, doc
+    assert doc["exports"] == ["echo"]
+    ids = []
+    for _ in range(3):
+        st, doc, _ = rpc(gw, "POST", "/v1/invoke",
+                         {"module": "echo", "func": "echo", "args": [2],
+                          "async": True})
+        assert st == 202, doc
+        ids.append(doc["request_id"])
+    for rid in ids:
+        st, doc = _poll(gw, rid)
+        # echo returns fd_write's last errno
+        assert st == 200 and doc["ok"] and doc["result"] == [0], doc
+
+
 # ---------------------------------------------------------------------------
 # rejection taxonomy on the wire
 # ---------------------------------------------------------------------------

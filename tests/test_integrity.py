@@ -257,6 +257,29 @@ def _fleet_cfg(peers=(), **kw):
     return FleetConfig(peers=peers, **kw)
 
 
+@pytest.mark.parametrize("both_copies", [False, True])
+def test_swapstore_rot_heals_from_mirror_or_replica(tmp_path, both_copies):
+    """SwapStore rot under the scrubber: a bad memory copy heals from
+    the disk mirror; rot in both copies repairs through the fetch
+    closure (a peer's replica).  Either way one corrupt entry is counted,
+    one repaired, and the payload reads back bit-exact."""
+    from wasmedge_tpu.hv.swapstore import SwapStore
+
+    store = SwapStore(dir=str(tmp_path))
+    payload = np.random.RandomState(5).bytes(4096)
+    key = store.put(payload)
+    store._mem[key] = flip_bit_bytes(store._mem[key], seed=5)
+    if both_copies:
+        flip_file(store._path(key), seed=6)
+    scrub = Scrubber(Configure().integrity,
+                     swap_stores=lambda: [("swap", store, False)],
+                     fetch_blob={key: payload}.get)
+    delta = scrub.scrub_once()
+    assert delta["corrupt"] == 1 and delta["repaired"] == 1
+    assert store.get(key) == payload
+    assert scrub.scrub_once()["corrupt"] == 0
+
+
 def test_corrupt_parked_blob_repaired_from_peer_before_wake():
     from tests.test_fleet import _await_mod, _drain
 

@@ -102,7 +102,7 @@ def test_batch_builder_is_the_64_pass_guest_that_adds():
                                                          fold="add")
     assert build_memory_batch() != build_memory_workload(passes=63,
                                                          fold="add")
-    # one opcode apart from bench_memory.py's build, same length
+    # one opcode apart from the xor-folding build, same length
     xor = build_memory_workload(passes=64)
     assert len(xor) == len(build_memory_batch())
     assert sum(a != b for a, b in zip(xor, build_memory_batch())) == 1

@@ -97,14 +97,17 @@ def fib_ref_result(tmp_path_factory):
 # ---------------------------------------------------------------------------
 # device failure detection: retry-then-recover
 # ---------------------------------------------------------------------------
+@pytest.mark.parametrize("obs", [False, True])
 def test_device_launch_fault_retry_recover_bitmatch(tmp_path,
-                                                    fib_ref_result):
+                                                    fib_ref_result, obs):
     """ISSUE 5 acceptance pin: one injected device failure on an
     8-fake-device run — the supervised drive completes bit-identical to
-    the unfaulted run."""
+    the unfaulted run, and with the flight recorder on the incident is
+    in its event stream."""
     inj = FaultInjector([Fault(point="device_launch", at=0,
                                match={"device": 2})])
     conf = make_conf()
+    conf.obs.enabled = obs
     store, inst = make_inst(build_fib(), conf)
     sup = MeshSupervisor(inst, store=store, conf=conf, devices=devices(8),
                          faults=inj, checkpoint_dir=str(tmp_path))
@@ -113,6 +116,8 @@ def test_device_launch_fault_retry_recover_bitmatch(tmp_path,
     assert_results_identical(res, fib_ref_result)
     assert [f.fault_class for f in sup.failures] == ["device_launch"]
     assert "device 2" in sup.failures[0].error
+    if obs:
+        assert "failure/device_launch" in sup.obs.event_names()
     # retried, never ejected
     assert not sup._bad_devices
 
@@ -428,12 +433,14 @@ def test_mesh_checkpoint_save_fault_never_kills_run(tmp_path,
 # ---------------------------------------------------------------------------
 # r15: shard-drive rung of the degradation ladder
 # ---------------------------------------------------------------------------
-def test_shard_drive_fault_falls_back_to_threaded_rung():
+@pytest.mark.parametrize("obs", [False, True])
+def test_shard_drive_fault_falls_back_to_threaded_rung(obs):
     """An injected shard-drive failure demotes the supervised run to
     the threaded per-device rung: the run completes bit-identical to
     an unfaulted one, with a FailureRecord('shard_drive') attributing
-    the demotion."""
+    the demotion (mirrored into the flight recorder when it is on)."""
     conf = make_conf(checkpoint_every_steps=None)
+    conf.obs.enabled = obs
     store, inst = make_inst(build_fib(), conf)
     ref = MeshSupervisor(inst, store=store, conf=conf,
                          devices=devices(4)).run(
@@ -446,6 +453,8 @@ def test_shard_drive_fault_falls_back_to_threaded_rung():
     res = sup.run("fib", FIB_ARGS, max_steps=200_000)
     assert inj.fired == 1
     assert any(f.fault_class == "shard_drive" for f in sup.failures)
+    if obs:
+        assert "failure/shard_drive" in sup.obs.event_names()
     assert_results_identical(res, ref)
 
 

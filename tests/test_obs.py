@@ -70,7 +70,7 @@ def make_engine(data, conf, lanes=LANES):
 
 def echo_engine(conf, lanes=LANES, iters=2):
     """fd_write echo module, tier 0 off so calls hit the tier-1 drain."""
-    import bench_echo
+    from wasmedge_tpu.models import build_echo
 
     from wasmedge_tpu.executor import Executor
     from wasmedge_tpu.host.wasi import WasiModule
@@ -84,7 +84,7 @@ def echo_engine(conf, lanes=LANES, iters=2):
     sink = os.open(os.devnull, os.O_WRONLY)
     wasi.env.fds[1].os_fd = sink
     mod = Validator(conf).validate(
-        Loader(conf).parse_module(bench_echo.build_module()))
+        Loader(conf).parse_module(build_echo()))
     store = StoreManager()
     ex = Executor(conf)
     ex.register_import_object(store, wasi)

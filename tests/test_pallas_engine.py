@@ -484,7 +484,7 @@ def test_v128_select_and_global_in_fused_block():
 # superblocks (PR 29): a fused block runs through forward `br`s (jumps)
 # and taken forward guards (tails) into their targets
 # ---------------------------------------------------------------------------
-def scalar_retired(data, func, args):
+def scalar_retired(data, func, args, conf=None):
     """(results or TrapError code, instructions retired) of one scalar
     instance, counted by Statistics.instr_counting."""
     from wasmedge_tpu.common.statistics import Statistics
@@ -493,7 +493,7 @@ def scalar_retired(data, func, args):
     from wasmedge_tpu.runtime.store import StoreManager
     from wasmedge_tpu.validator import Validator
 
-    conf = Configure()
+    conf = conf or Configure()
     conf.statistics.instr_counting = True
     stat = Statistics(conf)
     ex = Executor(conf, stat)
@@ -599,28 +599,29 @@ def test_if_else_superblock_divergent_condition():
 def test_trap_in_a_tail_call_stack_exhausted():
     """fib's inner call leaves block 0 through the guard's tail, whose
     terminal is the `call`: with five frames it traps there, at the
-    call's own slot 9, after what the plain per-op kernel retires."""
+    call's own slot 9, after what the scalar engine retires when it is
+    held to the same six frames."""
     from wasmedge_tpu.batch.scheduler import BlockScheduler
 
-    rows = {}
-    for fusion in (True, False):
-        conf = Configure()
-        conf.batch.call_stack_depth = 6
-        conf.batch.block_fusion = fusion
-        _ex, _store, _inst, eng = make_engine(build_fib(), conf=conf)
-        sched = BlockScheduler(eng, "fib", [np.full(LANES, 12, np.int64)],
-                               1_000_000)
-        sched.launch()
-        row = sched._ctrl()[0]
-        rows[fusion] = tuple(int(row[c]) for c in (
-            pe._C_STATUS, pe._C_STEPS, pe._C_PC, pe._C_SP, pe._C_CD))
-        res = eng.run("fib", [np.full(LANES, 12, np.int64)],
-                      max_steps=1_000_000)
-        assert (res.trap == int(ErrCode.CallStackExhausted)).all()
-        rows[fusion] += (np.asarray(res.retired).tolist(),)
-    assert rows[True] == rows[False]
-    assert rows[True][:3] == (
-        pe.ST_TRAPPED_BASE + int(ErrCode.CallStackExhausted), 48, 9)
+    conf = Configure()
+    conf.batch.call_stack_depth = 6
+    _ex, _store, _inst, eng = make_engine(build_fib(), conf=conf)
+    sched = BlockScheduler(eng, "fib", [np.full(LANES, 12, np.int64)],
+                           1_000_000)
+    sched.launch()
+    row = sched._ctrl()[0]
+    res = eng.run("fib", [np.full(LANES, 12, np.int64)],
+                  max_steps=1_000_000)
+    sconf = Configure()
+    sconf.runtime.max_call_depth = 6
+    code, retired = scalar_retired(build_fib(), "fib", [12], conf=sconf)
+    assert code == int(ErrCode.CallStackExhausted)
+    assert (res.trap == code).all()
+    assert np.asarray(res.retired).tolist() == [retired] * LANES
+    assert tuple(int(row[c]) for c in (
+        pe._C_STATUS, pe._C_STEPS, pe._C_PC)) == (
+            pe.ST_TRAPPED_BASE + code, retired, 9)
+    assert retired == 48
 
 
 def oob_after_jump_guest() -> bytes:
@@ -677,23 +678,25 @@ def test_trap_after_a_jump_out_of_bounds_load(xs):
 
 
 def test_fuel_runs_out_inside_a_superblock():
-    """Fuel 1000 on fib(12): the plain per-op kernel stops at step 1000
-    exactly, the superblock kernel at the end of the dispatch that
-    crossed it, less than MAX_BLOCK_LEN later; both kill."""
+    """Fuel 1000 on fib(12): the scalar engine runs 1000 instructions
+    and traps on the next, the superblock kernel at the end of the
+    dispatch that crossed the 1000th, less than MAX_BLOCK_LEN later;
+    both kill."""
     from wasmedge_tpu.batch.scheduler import BlockScheduler
 
-    steps = {}
-    for fusion in (True, False):
-        conf = Configure()
-        conf.batch.fuel_per_launch = 1000
-        conf.batch.block_fusion = fusion
-        _ex, _store, _inst, eng = make_engine(build_fib(), conf=conf)
-        sched = BlockScheduler(eng, "fib", [np.full(LANES, 12, np.int64)],
-                               1_000_000)
-        sched.launch()
-        row = sched._ctrl()[0]
-        assert int(row[pe._C_STATUS]) == \
-            pe.ST_TRAPPED_BASE + int(ErrCode.CostLimitExceeded)
-        steps[fusion] = int(row[pe._C_STEPS])
-    assert steps[False] == 1000
-    assert 1000 <= steps[True] < 1000 + pe.MAX_BLOCK_LEN
+    conf = Configure()
+    conf.batch.fuel_per_launch = 1000
+    _ex, _store, _inst, eng = make_engine(build_fib(), conf=conf)
+    sched = BlockScheduler(eng, "fib", [np.full(LANES, 12, np.int64)],
+                           1_000_000)
+    sched.launch()
+    row = sched._ctrl()[0]
+    sconf = Configure()
+    sconf.statistics.cost_measuring = True
+    sconf.statistics.cost_limit = 1000
+    code, counted = scalar_retired(build_fib(), "fib", [12], conf=sconf)
+    assert code == int(ErrCode.CostLimitExceeded)
+    assert int(row[pe._C_STATUS]) == pe.ST_TRAPPED_BASE + code
+    # the scalar engine counts the instruction that found the meter empty
+    assert counted - 1 == 1000
+    assert 1000 <= int(row[pe._C_STEPS]) < 1000 + pe.MAX_BLOCK_LEN

@@ -340,7 +340,7 @@ def test_cross_process_resume_adopts_in_flight():
 # exactly-once tier-0 stdout across restores
 # ---------------------------------------------------------------------------
 def _echo_engine(conf, lanes, sink_path):
-    import bench_echo
+    from wasmedge_tpu.models import build_echo
     from wasmedge_tpu.batch.engine import BatchEngine
     from wasmedge_tpu.host.wasi import WasiModule
 
@@ -351,7 +351,7 @@ def _echo_engine(conf, lanes, sink_path):
     sink = os.open(sink_path, os.O_WRONLY | os.O_CREAT | os.O_TRUNC)
     wasi.env.fds[1].os_fd = sink
     mod = Validator(conf).validate(
-        Loader(conf).parse_module(bench_echo.build_module()))
+        Loader(conf).parse_module(build_echo()))
     store = StoreManager()
     ex = Executor(conf)
     ex.register_import_object(store, wasi)

@@ -106,7 +106,7 @@ def test_launch_fault_before_first_checkpoint(tmp_path):
 def _echo_setup(conf, lanes, sink_path):
     """fd_write echo module with fd 1 routed to a file; tier 0 disabled
     so every call parks on the tier-1 serve path (the injection seam)."""
-    import bench_echo
+    from wasmedge_tpu.models import build_echo
 
     from wasmedge_tpu.executor import Executor
     from wasmedge_tpu.host.wasi import WasiModule
@@ -115,7 +115,7 @@ def _echo_setup(conf, lanes, sink_path):
     from wasmedge_tpu.validator import Validator
 
     conf.batch.tier0_hostcalls = False
-    data = bench_echo.build_module()
+    data = build_echo()
     wasi = WasiModule()
     wasi.init_wasi(dirs=[], prog_name="echo")
     sink = os.open(sink_path, os.O_WRONLY | os.O_CREAT | os.O_TRUNC)

@@ -19,8 +19,8 @@ Per defined function, over the validated/lowered image (no execution):
     value-stack and frame-depth bounds along the static call graph) for
     ROADMAP #4 resident-lane budgeting
 
-Soundness contract (pinned by tests/test_analysis.py and
-`bench.py --analyze-smoke`): for any terminating run of an exported
+Soundness contract (pinned by tests/test_analysis.py): for any
+terminating run of an exported
 function, cost_bound is None (unbounded verdict) or >= the engine's
 retired-instruction count for that invocation.  Overcounting is fine;
 undercounting is a bug.

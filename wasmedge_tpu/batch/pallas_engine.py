@@ -60,8 +60,6 @@ import numpy as np
 
 from wasmedge_tpu.common.errors import ErrCode
 from wasmedge_tpu.batch.image import (
-    ALU1_SUB,
-    ALU2_F32_BASE,
     ALU2_I32_BASE,
     ALU2_I64_BASE,
     NUM_ALU1,
@@ -106,7 +104,6 @@ from wasmedge_tpu.batch.image import (
     CLS_VTEST,
     DeviceImage,
     TRAP_DONE,
-    _F32_BIN,
     _I32_BIN,
 )
 
@@ -139,37 +136,12 @@ H_MEMFILL = 22
 H_MEMCOPY = 23
 H_ALU2_BASE = 24                      # + ALU2 sub id
 H_ALU1_BASE = H_ALU2_BASE + NUM_ALU2  # + ALU1 sub id
-# superinstructions (pallas-only peephole fusion, see fuse_image):
-#   GCA: local.get a; const imm; alu2 sub   -> one dispatch, pc += 3
-#   GBR: local.get sub; br a,b,c            -> one dispatch
-#   GCB: local.get a; const imm; alu2 sub; brz b -> one dispatch
-#   A2R: alu2 sub; return(1 result)             -> one dispatch
-H_FUSE_GCA_BASE = H_ALU1_BASE + NUM_ALU1      # + ALU2 sub id
-H_FUSE_GCB_BASE = H_FUSE_GCA_BASE + NUM_ALU2  # + ALU2 sub id
-#   GCC: local.get a; const imm; alu2 sub; call b -> one dispatch
-H_FUSE_A2R_BASE = H_FUSE_GCB_BASE + NUM_ALU2  # + ALU2 sub id
-H_FUSE_GCC_BASE = H_FUSE_A2R_BASE + NUM_ALU2  # + ALU2 sub id
-# loop-body families (the hot patterns of counted loops; fields at fuse
-# time:  a/b/c keep the branch or dst operands, ilo/ihi carry local idxs
-# or the immediate):
-#   GCS:   local.get a; const ilo/ihi; alu2; local.set b   -> pc += 4
-#   GGA:   local.get a; local.get c; alu2                  -> pc += 3
-#   GGS:   local.get a; local.get c; alu2; local.set b     -> pc += 4
-#   GGBZ:  local.get ilo; local.get ihi; alu2; brz a       -> pc += 4
-#   GGBNZ: local.get ilo; local.get ihi; alu2; brnz a,b,c  -> pc += 4
-H_FUSE_GCS_BASE = H_FUSE_GCC_BASE + NUM_ALU2
-H_FUSE_GGA_BASE = H_FUSE_GCS_BASE + NUM_ALU2
-H_FUSE_GGS_BASE = H_FUSE_GGA_BASE + NUM_ALU2
-H_FUSE_GGBZ_BASE = H_FUSE_GGS_BASE + NUM_ALU2
-H_FUSE_GGBNZ_BASE = H_FUSE_GGBZ_BASE + NUM_ALU2
-H_FUSE_GBR = H_FUSE_GGBNZ_BASE + NUM_ALU2
-# width-specialized memory ops (appended so earlier ids stay stable):
-# plain 32/64-bit loads/stores skip the sub-word sign/width machinery —
-# the hot shapes in compiled code
-H_LOAD_W = H_FUSE_GBR + 1    # i32.load  (nbytes=4, no extension)
-H_LOAD_D = H_FUSE_GBR + 2    # i64.load  (nbytes=8)
-H_STORE_W = H_FUSE_GBR + 3   # i32.store / f32.store
-H_STORE_D = H_FUSE_GBR + 4   # i64.store / f64.store
+# width-specialized memory ops: plain 32/64-bit loads/stores skip the
+# sub-word sign/width machinery — the hot shapes in compiled code
+H_LOAD_W = H_ALU1_BASE + NUM_ALU1   # i32.load  (nbytes=4, no extension)
+H_LOAD_D = H_LOAD_W + 1             # i64.load  (nbytes=8)
+H_STORE_W = H_LOAD_D + 1            # i32.store / f32.store
+H_STORE_D = H_STORE_W + 1           # i64.store / f64.store
 # v128: cells are 4 int32 planes (lo, hi, e2, e3); op semantics come
 # from batch/simdops.py — the same fns the SIMT engine dispatches
 # (engine.py "v128 (SIMD)" section), here as per-sub handlers.  Dense
@@ -300,127 +272,10 @@ def _jump_targets(img) -> set:
     return targets
 
 
-def fuse_image(hid, a, b, c, ilo, ihi, img):
-    """Peephole superinstruction fusion over the flat-hid planes.
-
-    The dominant dispatch patterns in call-heavy code are
-    `local.get; const; alu2` (operand setup + op) and `local.get; br`
-    (loop/return value shuffles).  Fusing them cuts dispatches and stack
-    row traffic (one read + one write instead of three of each).  Only
-    positions never targeted by a branch/call may be absorbed, and only
-    non-trapping alu2 subs fuse (div/rem keep their own trap handler).
-    Returns rewritten copies; the originals (and every other engine's
-    image) are untouched — this is a pallas-private encoding."""
-    n = img.code_len
-    targets = _jump_targets(img)
-    hid = hid.copy()
-    a = a.copy()
-    b = b.copy()
-    c = c.copy()
-    ilo = ilo.copy()
-    ihi = ihi.copy()
-    pc = 0
-    while pc < n - 1:
-        h0 = int(hid[pc])
-        absorb2 = pc + 1 not in targets
-        absorb3 = absorb2 and pc + 2 not in targets and pc + 2 < n
-        h1 = int(hid[pc + 1]) if absorb2 else -1
-        h2 = int(hid[pc + 2]) if absorb3 else -1
-        if h0 == H_LOCAL_GET and absorb3 and h1 == H_CONST and \
-                H_ALU2_BASE <= h2 < H_ALU2_BASE + NUM_ALU2:
-            sub = h2 - H_ALU2_BASE
-            if sub not in _DIV32_SUBS and sub not in _DIV64_SUBS:
-                ok4 = pc + 3 not in targets and pc + 3 < n
-                h3 = int(hid[pc + 3]) if ok4 else -1
-                if h3 == H_BRZ:
-                    # quad: the compare feeds a brz; no stack writes at all
-                    hid[pc] = H_FUSE_GCB_BASE + sub
-                    ilo[pc] = ilo[pc + 1]
-                    ihi[pc] = ihi[pc + 1]
-                    b[pc] = a[pc + 3]        # brz target
-                    pc += 4
-                    continue
-                if h3 == H_CALL:
-                    # quad: computed value is the callee's argument
-                    hid[pc] = H_FUSE_GCC_BASE + sub
-                    ilo[pc] = ilo[pc + 1]
-                    ihi[pc] = ihi[pc + 1]
-                    b[pc] = a[pc + 3]        # callee index
-                    pc += 4
-                    continue
-                if h3 == H_LOCAL_SET:
-                    # quad: local.set dst of the computed value
-                    hid[pc] = H_FUSE_GCS_BASE + sub
-                    ilo[pc] = ilo[pc + 1]
-                    ihi[pc] = ihi[pc + 1]
-                    b[pc] = a[pc + 3]        # dst local
-                    pc += 4
-                    continue
-                hid[pc] = H_FUSE_GCA_BASE + sub
-                # a keeps the local idx; imm moves up from the const
-                ilo[pc] = ilo[pc + 1]
-                ihi[pc] = ihi[pc + 1]
-                pc += 3
-                continue
-        if h0 == H_LOCAL_GET and absorb3 and h1 == H_LOCAL_GET and \
-                H_ALU2_BASE <= h2 < H_ALU2_BASE + NUM_ALU2:
-            sub = h2 - H_ALU2_BASE
-            if sub not in _DIV32_SUBS and sub not in _DIV64_SUBS:
-                ok4 = pc + 3 not in targets and pc + 3 < n
-                h3 = int(hid[pc + 3]) if ok4 else -1
-                src1, src2 = int(a[pc]), int(a[pc + 1])
-                if h3 == H_BRZ:
-                    hid[pc] = H_FUSE_GGBZ_BASE + sub
-                    a[pc] = a[pc + 3]        # brz target
-                    ilo[pc] = src1
-                    ihi[pc] = src2
-                    pc += 4
-                    continue
-                if h3 == H_BRNZ:
-                    hid[pc] = H_FUSE_GGBNZ_BASE + sub
-                    a[pc] = a[pc + 3]        # brnz target
-                    b[pc] = b[pc + 3]        # nkeep
-                    c[pc] = c[pc + 3]        # pop_to
-                    ilo[pc] = src1
-                    ihi[pc] = src2
-                    pc += 4
-                    continue
-                if h3 == H_LOCAL_SET:
-                    hid[pc] = H_FUSE_GGS_BASE + sub
-                    b[pc] = a[pc + 3]        # dst local
-                    c[pc] = src2
-                    pc += 4
-                    continue
-                hid[pc] = H_FUSE_GGA_BASE + sub
-                c[pc] = src2
-                pc += 3
-                continue
-        if h0 == H_LOCAL_GET and absorb2 and h1 == H_BR:
-            hid[pc] = H_FUSE_GBR
-            b_, c_, a_ = int(b[pc + 1]), int(c[pc + 1]), int(a[pc + 1])
-            # ilo carries the local idx; a/b/c carry the branch
-            c[pc] = c_
-            b[pc] = b_
-            ilo[pc] = a[pc]
-            a[pc] = a_
-            pc += 2
-            continue
-        if H_ALU2_BASE <= h0 < H_ALU2_BASE + NUM_ALU2 and absorb2 and \
-                h1 == H_RETURN and int(b[pc + 1]) == 1:
-            sub = h0 - H_ALU2_BASE
-            if sub not in _DIV32_SUBS and sub not in _DIV64_SUBS:
-                hid[pc] = H_FUSE_A2R_BASE + sub
-                pc += 2
-                continue
-        pc += 1
-    return hid, a, b, c, ilo, ihi
-
-
 # ---------------------------------------------------------------------------
 # Basic-block fusion
 # ---------------------------------------------------------------------------
-# The generalized successor of the peephole superinstructions above:
-# every maximal straight-line run of *pure* stack ops (const, local/
+# Every maximal straight-line run of *pure* stack ops (const, local/
 # global traffic, drop/select, non-trapping alu) fuses into ONE handler
 # that keeps intermediate values in vector registers — dispatch cost
 # (128 ns a dispatch on a v5e before PR 27 and 87 ns after, fib(30):
@@ -1044,8 +899,7 @@ def _build_kernel(used_hids: tuple, D: int, CD: int, W: int, L: int,
 
     # ---- 8-sublane lane remap ------------------------------------------
     # A (1, Lblk) int32 row occupies Lblk/128 vregs at 1/8 sublane
-    # utilization — the measured ~590ns/instr dispatch floor of r04
-    # (MEMORY_r04.json ceiling analysis).  When the lane block splits
+    # utilization.  When the lane block splits
     # into 8 stripes of whole lane tiles (Lpb % 128 == 0), kernel state
     # is laid out [rows, 8, Lpb] instead of [rows, Lblk]: every row op
     # then runs on an (8, Lpb) array = Lblk/1024 fully-packed vregs, an
@@ -2809,187 +2663,6 @@ def _build_kernel(used_hids: tuple, D: int, CD: int, W: int, L: int,
                         lambda: keep(c, pc=pc + 1, sp=sp - 3)),
                     lambda: keep(c, status=I32(ST_DIVERGED)))
 
-        def mk_fuse_gca(sub):
-            fn = alu2[sub]
-
-            def h(c):
-                pc, sp, fp = c[1], c[2], c[3]
-                src = fp + a_r[pc]
-                xl, xh = srow(slo, src), srow(shi, src)
-                yl, yh = full(ilo_r[pc]), full(ihi_r[pc])
-                rl, rh = fn(xl, xh, yl, yh)
-                wrow(slo, sp, rl)
-                wrow(shi, sp, rh)
-                # retires 3 wasm instructions (the dispatch loop adds 1)
-                return keep(c, steps=c[0] + 2, pc=pc + 3, sp=sp + 1)
-            return h
-
-        def mk_fuse_gcb(sub):
-            fn = alu2[sub]
-
-            def h(c):
-                pc, sp, fp = c[1], c[2], c[3]
-                src = fp + a_r[pc]
-                xl, xh = srow(slo, src), srow(shi, src)
-                yl, yh = full(ilo_r[pc]), full(ihi_r[pc])
-                cond, _rh = fn(xl, xh, yl, yh)
-                if optimistic:
-                    t0 = agree_nz(cond)
-                    new_pc = jnp.where(t0 == 0, b_r[pc], pc + 4)
-                    return keep(c, steps=c[0] + 3, pc=new_pc)
-                t0 = scal(cond)
-                agree = allsame(cond, t0)
-                new_pc = jnp.where(t0 == 0, b_r[pc], pc + 4)
-                return lax.cond(
-                    agree,
-                    lambda: keep(c, steps=c[0] + 3, pc=new_pc),
-                    lambda: keep(c, status=I32(ST_DIVERGED)))
-            return h
-
-        def mk_fuse_a2r(sub):
-            fn = alu2[sub]
-
-            def h(c):
-                pc, sp, fp, cd = c[1], c[2], c[3], c[5]
-                xl, xh = srow(slo, sp - 2), srow(shi, sp - 2)
-                yl, yh = srow(slo, sp - 1), srow(shi, sp - 1)
-                rl, rh = fn(xl, xh, yl, yh)
-                wrow(slo, fp, rl)
-                wrow(shi, fp, rh)
-                new_sp = fp + 1
-                rd = jnp.clip(cd - 1, 0, CD - 1)
-                return lax.cond(
-                    cd == 0,
-                    lambda: keep(c, steps=c[0] + 1, sp=new_sp,
-                                 status=I32(ST_DONE)),
-                    lambda: keep(c, steps=c[0] + 1,
-                                 pc=frames_out[blk, 0, rd], sp=new_sp,
-                                 fp=frames_out[blk, 1, rd],
-                                 ob=frames_out[blk, 2, rd], cd=cd - 1))
-            return h
-
-        def mk_fuse_gcs(sub):
-            fn = alu2[sub]
-
-            def h(c):
-                pc, sp, fp = c[1], c[2], c[3]
-                src = fp + a_r[pc]
-                xl, xh = srow(slo, src), srow(shi, src)
-                yl, yh = full(ilo_r[pc]), full(ihi_r[pc])
-                rl, rh = fn(xl, xh, yl, yh)
-                dst = fp + b_r[pc]
-                wrow(slo, dst, rl)
-                wrow(shi, dst, rh)
-                return keep(c, steps=c[0] + 3, pc=pc + 4)
-            return h
-
-        def mk_fuse_gga(sub):
-            fn = alu2[sub]
-
-            def h(c):
-                pc, sp, fp = c[1], c[2], c[3]
-                s1, s2 = fp + a_r[pc], fp + c_r[pc]
-                rl, rh = fn(srow(slo, s1), srow(shi, s1),
-                            srow(slo, s2), srow(shi, s2))
-                wrow(slo, sp, rl)
-                wrow(shi, sp, rh)
-                return keep(c, steps=c[0] + 2, pc=pc + 3, sp=sp + 1)
-            return h
-
-        def mk_fuse_ggs(sub):
-            fn = alu2[sub]
-
-            def h(c):
-                pc, sp, fp = c[1], c[2], c[3]
-                s1, s2 = fp + a_r[pc], fp + c_r[pc]
-                rl, rh = fn(srow(slo, s1), srow(shi, s1),
-                            srow(slo, s2), srow(shi, s2))
-                dst = fp + b_r[pc]
-                wrow(slo, dst, rl)
-                wrow(shi, dst, rh)
-                return keep(c, steps=c[0] + 3, pc=pc + 4)
-            return h
-
-        def mk_fuse_ggbz(sub):
-            fn = alu2[sub]
-
-            def h(c):
-                pc, sp, fp = c[1], c[2], c[3]
-                s1, s2 = fp + ilo_r[pc], fp + ihi_r[pc]
-                cond, _rh = fn(srow(slo, s1), srow(shi, s1),
-                               srow(slo, s2), srow(shi, s2))
-                if optimistic:
-                    t0 = agree_nz(cond)
-                    new_pc = jnp.where(t0 == 0, a_r[pc], pc + 4)
-                    return keep(c, steps=c[0] + 3, pc=new_pc)
-                t0 = scal(cond)
-                agree = allsame(cond, t0)
-                new_pc = jnp.where(t0 == 0, a_r[pc], pc + 4)
-                return lax.cond(
-                    agree,
-                    lambda: keep(c, steps=c[0] + 3, pc=new_pc),
-                    lambda: keep(c, status=I32(ST_DIVERGED)))
-            return h
-
-        def mk_fuse_ggbnz(sub):
-            fn = alu2[sub]
-
-            def h(c):
-                pc, sp, fp, ob = c[1], c[2], c[3], c[4]
-                s1, s2 = fp + ilo_r[pc], fp + ihi_r[pc]
-                cond, _rh = fn(srow(slo, s1), srow(shi, s1),
-                               srow(slo, s2), srow(shi, s2))
-                t0 = agree_nz(cond) if optimistic else scal(cond)
-                agree = True if optimistic else allsame(cond, t0)
-                tgt, nkeep, pop_to = a_r[pc], b_r[pc], c_r[pc]
-                tgt_sp = ob + pop_to
-                taken = t0 != 0
-
-                @pl.when(agree & taken & (nkeep == 1))
-                def _():
-                    # the would-be kept value sits at the pre-fusion top
-                    wrow(slo, tgt_sp, srow(slo, sp - 1))
-                    wrow(shi, tgt_sp, srow(shi, sp - 1))
-
-                return lax.cond(
-                    agree,
-                    lambda: lax.cond(
-                        taken,
-                        lambda: keep(c, steps=c[0] + 3, pc=tgt,
-                                     sp=tgt_sp + nkeep),
-                        lambda: keep(c, steps=c[0] + 3, pc=pc + 4)),
-                    lambda: keep(c, status=I32(ST_DIVERGED)))
-            return h
-
-        def mk_fuse_gcc(sub):
-            fn = alu2[sub]
-
-            def h(c):
-                pc, sp, fp = c[1], c[2], c[3]
-                src = fp + a_r[pc]
-                xl, xh = srow(slo, src), srow(shi, src)
-                yl, yh = full(ilo_r[pc]), full(ihi_r[pc])
-                rl, rh = fn(xl, xh, yl, yh)
-                wrow(slo, sp, rl)
-                wrow(shi, sp, rh)
-                # the fused call returns to pc+4
-                c2 = keep(c, steps=c[0] + 3, pc=pc + 3, sp=sp + 1)
-                return _do_call(c2, b_r[pc], sp + 1)
-            return h
-
-        def h_fuse_gbr(c):
-            pc, sp, fp, ob = c[1], c[2], c[3], c[4]
-            tgt, nkeep, pop_to = a_r[pc], b_r[pc], c_r[pc]
-            tgt_sp = ob + pop_to
-
-            @pl.when(nkeep == 1)
-            def _():
-                src = fp + ilo_r[pc]
-                wrow(slo, tgt_sp, srow(slo, src))
-                wrow(shi, tgt_sp, srow(shi, src))
-
-            return keep(c, steps=c[0] + 1, pc=tgt, sp=tgt_sp + nkeep)
-
         def mk_alu2(sub):
             fn = alu2[sub]
             can_trap = sub in _DIV32_SUBS or sub in _DIV64_SUBS
@@ -3921,26 +3594,6 @@ def _build_kernel(used_hids: tuple, D: int, CD: int, W: int, L: int,
                             H_STORE_W: h_store_w,
                             H_STORE_D: h_store_d}[hid]
                 return h_load if hid in (H_LOAD_W, H_LOAD_D) else h_store
-            if hid == H_FUSE_GBR:
-                return h_fuse_gbr
-            if hid >= H_FUSE_GGBNZ_BASE:
-                return mk_fuse_ggbnz(hid - H_FUSE_GGBNZ_BASE)
-            if hid >= H_FUSE_GGBZ_BASE:
-                return mk_fuse_ggbz(hid - H_FUSE_GGBZ_BASE)
-            if hid >= H_FUSE_GGS_BASE:
-                return mk_fuse_ggs(hid - H_FUSE_GGS_BASE)
-            if hid >= H_FUSE_GGA_BASE:
-                return mk_fuse_gga(hid - H_FUSE_GGA_BASE)
-            if hid >= H_FUSE_GCS_BASE:
-                return mk_fuse_gcs(hid - H_FUSE_GCS_BASE)
-            if hid >= H_FUSE_GCC_BASE:
-                return mk_fuse_gcc(hid - H_FUSE_GCC_BASE)
-            if hid >= H_FUSE_A2R_BASE:
-                return mk_fuse_a2r(hid - H_FUSE_A2R_BASE)
-            if hid >= H_FUSE_GCB_BASE:
-                return mk_fuse_gcb(hid - H_FUSE_GCB_BASE)
-            if hid >= H_FUSE_GCA_BASE:
-                return mk_fuse_gca(hid - H_FUSE_GCA_BASE)
             if hid >= H_ALU1_BASE:
                 return mk_alu1(hid - H_ALU1_BASE)
             if hid >= H_ALU2_BASE:
@@ -4488,21 +4141,11 @@ class PallasUniformEngine:
 
         img = self.img
         interpret = self._interpret()
-        hid = hid_plane(img)
+        hid, block_shapes = fuse_blocks(hid_plane(img), img)
+        # block fusion rewrites block-head hids only: the operand planes
+        # are the image's own
         a_p, b_p, c_p = img.a, img.b, img.c
         ilo_p, ihi_p = img.imm_lo, img.imm_hi
-        bf = getattr(self.cfg, "block_fusion", None)
-        self.block_fusion = True if bf is None else bool(bf)
-        if self.block_fusion:
-            hid, block_shapes = fuse_blocks(hid, img)
-        else:
-            block_shapes = ()
-            if not img.has_simd:
-                # the legacy peephole superinstructions move only the
-                # lo/hi planes of kept values, which would truncate
-                # v128 cells — simd modules run unfused on this path
-                hid, a_p, b_p, c_p, ilo_p, ihi_p = fuse_image(
-                    hid, a_p, b_p, c_p, ilo_p, ihi_p, img)
         # tpu.aot artifacts carry the fused encoding.  Verification IS
         # regeneration (cheap next to XLA compilation); once verified,
         # the attached planes are the ones executed — a stale or
@@ -4519,21 +4162,18 @@ class PallasUniformEngine:
                     attached["hid"], attached["a"], attached["b"],
                     attached["c"], attached["ilo"], attached["ihi"])
         # weight of a handler = the number of slots at which a
-        # converged dispatch can START with its hid: under block fusion
-        # the entry slots (absorbed slots keep their hids for resumes
-        # only and weigh nothing); on the legacy peephole path every
-        # slot.  Dense ids are numbered hot-first (then by flat id, for
-        # determinism) so the contiguous-range tree of
+        # converged dispatch can START with its hid: the entry slots
+        # (absorbed slots keep their hids for resumes only and weigh
+        # nothing).  Dense ids are numbered hot-first (then by flat id,
+        # for determinism) so the contiguous-range tree of
         # plan_dispatch_tree can put the heavy handlers at the top.
-        live = entry_slots(hid, block_shapes, img) if self.block_fusion \
-            else np.ones(len(hid), bool)
+        live = entry_slots(hid, block_shapes, img)
         count = collections.Counter(int(h) for h in hid[live])
-        if self.block_fusion:
-            # a block whose every way in was absorbed stays among the
-            # hot handlers all the same: it nests as deep as they do,
-            # and the cold subtree is where the tree is deepest
-            for h in set(int(h) for h in hid[hid >= H_BLOCK_BASE]):
-                count[h] = max(count[h], 1)
+        # a block whose every way in was absorbed stays among the hot
+        # handlers all the same: it nests as deep as they do, and the
+        # cold subtree is where the tree is deepest
+        for h in set(int(h) for h in hid[hid >= H_BLOCK_BASE]):
+            count[h] = max(count[h], 1)
         used = tuple(sorted(set(int(h) for h in hid),
                             key=lambda h: (-count[h], h)))
         dense = {h: i for i, h in enumerate(used)}
@@ -4543,8 +4183,7 @@ class PallasUniformEngine:
             self._hid_weights,
             kernel_dispatch_plan(self._hid_weights, self.optimistic)[1])
         self.obs.set_dispatch_static(*self.dispatch_depth)
-        self.superblock_edges = superblock_edges(hid, block_shapes, img) \
-            if self.block_fusion else {"jump": 0, "guard_tail": 0}
+        self.superblock_edges = superblock_edges(hid, block_shapes, img)
         self.obs.set_superblock_static(self.superblock_edges)
         # host-side view of the fused encoding: the block scheduler's
         # divergence splitter evaluates the stopped instruction from
@@ -4630,9 +4269,8 @@ class PallasUniformEngine:
 
     def _with_export_cache(self, build):
         """Warm-start path: persist the traced+lowered kernel via
-        jax.export so a fresh process skips Python/Pallas tracing (the
-        ~2s `engine_build` phase in AOT_r04.json); XLA's persistent
-        compilation cache already covers the compile itself, and the
+        jax.export so a fresh process skips Python/Pallas tracing; XLA's
+        persistent compilation cache already covers the compile itself, and the
         exports live in its directory (`kexport/`).  A failure is
         reported on stderr (once per distinct failure) and falls back
         to a plain build — the cache is an optimization, never a

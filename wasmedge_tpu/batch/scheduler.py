@@ -21,9 +21,9 @@ Divergence is handled here, outside the kernel:
   moral equivalent of a GPU warp scheduler's divergence stack, with
   re-packing explicit and amortized.  For fib(n) with mixed n this fires
   once per mixed block; afterwards every block is converged forever.
-- **SIMT residue**: anything the splitter can't express (float-fused
-  branches, per-lane divergent memory addressing, growth beyond the
-  watermark plane) queues its lanes for one final pass on the
+- **SIMT residue**: anything the splitter can't express (per-lane
+  divergent memory addressing, growth beyond the watermark plane)
+  queues its lanes for one final pass on the
   per-lane-pc SIMT engine; everything else keeps running on the kernel.
 
 The reference runs every instance on the same dispatch loop
@@ -40,23 +40,14 @@ from typing import Dict, List
 import numpy as np
 
 from wasmedge_tpu.common.errors import ErrCode
-from wasmedge_tpu.batch.image import (
-    ALU2_I32_BASE,
-    ALU2_I64_BASE,
-    TRAP_DONE,
-    _I32_BIN,
-)
+from wasmedge_tpu.batch.image import TRAP_DONE
 from wasmedge_tpu.batch.pallas_engine import (
     H_BR_TABLE,
     H_BRNZ,
     H_BRZ,
     H_CALL_INDIRECT,
-    H_FUSE_GCB_BASE,
-    H_FUSE_GGBNZ_BASE,
-    H_FUSE_GGBZ_BASE,
     H_BLOCK_BASE,
     H_MEMGROW,
-    NUM_ALU2,
     ST_DIVERGED,
     ST_DONE,
     ST_HOSTCALL,
@@ -95,81 +86,6 @@ _PLANE_IDX_SIMD = dict(_PLANE_IDX, se2=14, se3=15)
 
 def _u32(x):
     return np.asarray(x).astype(np.int64) & 0xFFFFFFFF
-
-
-def _host_alu2(sub: int, xl, xh, yl, yh):
-    """Evaluate one integer ALU2 sub on int32 lo/hi column vectors.
-
-    Only the non-trapping integer families (what superinstruction fusion
-    admits) are supported; returns None for float subs — the caller then
-    routes the block to the SIMT residue.  Semantics mirror
-    batch/laneops.py's device kernels."""
-    names = _I32_BIN
-    if ALU2_I32_BASE <= sub < ALU2_I32_BASE + len(names):
-        name = names[sub - ALU2_I32_BASE]
-        xu, yu = _u32(xl), _u32(yl)
-        xs = xu.astype(np.uint32).view(np.int32).astype(np.int64)
-        ys = yu.astype(np.uint32).view(np.int32).astype(np.int64)
-        sh = yu & 31
-        ops = {
-            "add": lambda: xu + yu, "sub": lambda: xu - yu,
-            "mul": lambda: xu * yu,
-            "and": lambda: xu & yu, "or": lambda: xu | yu,
-            "xor": lambda: xu ^ yu,
-            "shl": lambda: xu << sh,
-            "shr_s": lambda: xs >> sh,
-            "shr_u": lambda: xu >> sh,
-            "rotl": lambda: (xu << sh) | (xu >> ((32 - sh) & 31)),
-            "rotr": lambda: (xu >> sh) | (xu << ((32 - sh) & 31)),
-            "eq": lambda: xu == yu, "ne": lambda: xu != yu,
-            "lt_s": lambda: xs < ys, "lt_u": lambda: xu < yu,
-            "gt_s": lambda: xs > ys, "gt_u": lambda: xu > yu,
-            "le_s": lambda: xs <= ys, "le_u": lambda: xu <= yu,
-            "ge_s": lambda: xs >= ys, "ge_u": lambda: xu >= yu,
-        }.get(name)
-        if ops is None:
-            return None
-        lo = (ops().astype(np.int64) & 0xFFFFFFFF).astype(
-            np.uint32).view(np.int32)
-        return lo, np.zeros_like(lo)
-    if ALU2_I64_BASE <= sub < ALU2_I64_BASE + len(names):
-        name = names[sub - ALU2_I64_BASE]
-        x = (_u32(xl) | (_u32(xh) << 32)).astype(np.uint64)
-        y = (_u32(yl) | (_u32(yh) << 32)).astype(np.uint64)
-        xs, ys = x.view(np.int64), y.view(np.int64)
-        sh = (y & np.uint64(63))
-        with np.errstate(over="ignore"):
-            ops = {
-                "add": lambda: x + y, "sub": lambda: x - y,
-                "mul": lambda: x * y,
-                "and": lambda: x & y, "or": lambda: x | y,
-                "xor": lambda: x ^ y,
-                "shl": lambda: x << sh,
-                "shr_s": lambda: (xs >> sh.astype(np.int64)).view(
-                    np.uint64),
-                "shr_u": lambda: x >> sh,
-                "rotl": lambda: (x << sh) |
-                (x >> ((np.uint64(64) - sh) & np.uint64(63))),
-                "rotr": lambda: (x >> sh) |
-                (x << ((np.uint64(64) - sh) & np.uint64(63))),
-                "eq": lambda: (x == y).astype(np.uint64),
-                "ne": lambda: (x != y).astype(np.uint64),
-                "lt_s": lambda: (xs < ys).astype(np.uint64),
-                "lt_u": lambda: (x < y).astype(np.uint64),
-                "gt_s": lambda: (xs > ys).astype(np.uint64),
-                "gt_u": lambda: (x > y).astype(np.uint64),
-                "le_s": lambda: (xs <= ys).astype(np.uint64),
-                "le_u": lambda: (x <= y).astype(np.uint64),
-                "ge_s": lambda: (xs >= ys).astype(np.uint64),
-                "ge_u": lambda: (x >= y).astype(np.uint64),
-            }.get(name)
-            if ops is None:
-                return None
-            v = ops().astype(np.uint64)
-        lo = (v & np.uint64(0xFFFFFFFF)).astype(np.uint32).view(np.int32)
-        hi = (v >> np.uint64(32)).astype(np.uint32).view(np.int32)
-        return lo, hi
-    return None
 
 
 class _Rows:
@@ -735,7 +651,6 @@ class BlockScheduler:
         case must go to the SIMT residue."""
         fused = self.eng._np_fused
         sp = int(ctrl[_C_SP])
-        fp = int(ctrl[_C_FP])
         ob = int(ctrl[_C_OB])
         a = int(fused["a"][pc])
         b_op = int(fused["b"][pc])
@@ -823,53 +738,6 @@ class BlockScheduler:
                 if nkeep == 1:
                     writes[("stack", tgt_sp)] = (slo[sp - 2, cols],
                                                  shi[sp - 2, cols])
-                children.append((cc, frames.copy(), cols, writes))
-            self._install_children(b, children)
-            return True
-
-        if H_FUSE_GCB_BASE <= hid < H_FUSE_GCB_BASE + NUM_ALU2:
-            sub = hid - H_FUSE_GCB_BASE
-            src = fp + a
-            imm_lo = np.full(Lblk, fused["ilo"][pc], np.int32)
-            imm_hi = np.full(Lblk, fused["ihi"][pc], np.int32)
-            res = _host_alu2(sub, slo[src], shi[src], imm_lo, imm_hi)
-            if res is None:
-                return False
-            cond = _u32(res[0])
-            children = []
-            for key, cols in self._partition([(cond == 0).astype(np.int64)]):
-                cc = ctrl.copy()
-                cc[_C_PC] = b_op if key[0] else pc + 4
-                cc[_C_STATUS] = ST_RUNNING
-                children.append((cc, frames.copy(), cols, {}))
-            self._install_children(b, children)
-            return True
-
-        if H_FUSE_GGBZ_BASE <= hid < H_FUSE_GGBNZ_BASE + NUM_ALU2:
-            nz = hid >= H_FUSE_GGBNZ_BASE
-            sub = hid - (H_FUSE_GGBNZ_BASE if nz else H_FUSE_GGBZ_BASE)
-            s1 = fp + int(fused["ilo"][pc])
-            s2 = fp + int(fused["ihi"][pc])
-            res = _host_alu2(sub, slo[s1], shi[s1], slo[s2], shi[s2])
-            if res is None:
-                return False
-            cond = _u32(res[0])
-            taken_key = (cond != 0) if nz else (cond == 0)
-            tgt_sp = ob + c_op
-            children = []
-            for key, cols in self._partition([taken_key.astype(np.int64)]):
-                cc = ctrl.copy()
-                writes = {}
-                if key[0]:  # taken
-                    cc[_C_PC] = a
-                    if nz:
-                        cc[_C_SP] = tgt_sp + b_op
-                        if b_op == 1:
-                            writes[("stack", tgt_sp)] = (slo[sp - 1, cols],
-                                                         shi[sp - 1, cols])
-                else:
-                    cc[_C_PC] = pc + 4
-                cc[_C_STATUS] = ST_RUNNING
                 children.append((cc, frames.copy(), cols, writes))
             self._install_children(b, children)
             return True

@@ -339,6 +339,18 @@ def _serve_parser() -> ArgumentParser:
     return p
 
 
+def _percentile(sorted_vals, q):
+    """Nearest-rank percentile over an ascending sequence (None when
+    empty): rank ceil(n*q), 1-based."""
+    import math
+
+    if not sorted_vals:
+        return None
+    i = min(max(math.ceil(len(sorted_vals) * q) - 1, 0),
+            len(sorted_vals) - 1)
+    return sorted_vals[i]
+
+
 def serve_command(argv: List[str], out=None, err=None) -> int:
     """`wasmedge-tpu serve app.wasm func [options]`: drive a seeded
     request stream through the continuous-batching BatchServer and
@@ -457,12 +469,9 @@ def serve_command(argv: List[str], out=None, err=None) -> int:
         err.write(f"wasmedge-tpu: serve failed: {e}\n")
         return 1
     wall = _time.monotonic() - t0
-    from wasmedge_tpu.utils.bench_artifact import percentile
-
     lat = sorted(f.t_done - t0 for f in futures if f.t_done is not None)
     c = server.counters
-    # true utilization, same definition bench.py --serve compares with:
-    # retired instructions over device step-lanes
+    # true utilization: retired instructions over device step-lanes
     occupancy = (c["retired_instructions"]
                  / max(server.total * server.lanes, 1))
     summary = {
@@ -478,8 +487,8 @@ def serve_command(argv: List[str], out=None, err=None) -> int:
         "occupancy": round(occupancy, 4),
         "wall_s": round(wall, 3),
         "req_per_s": round(nreq / wall, 1) if wall > 0 else 0.0,
-        "p50_latency_s": round(percentile(lat, 0.5), 4) if lat else None,
-        "p99_latency_s": round(percentile(lat, 0.99), 4) if lat else None,
+        "p50_latency_s": round(_percentile(lat, 0.5), 4) if lat else None,
+        "p99_latency_s": round(_percentile(lat, 0.99), 4) if lat else None,
     }
     hv = server.hv_stats()
     if hv is not None:

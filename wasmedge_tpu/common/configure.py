@@ -107,12 +107,6 @@ class BatchConfigure:
     # commit points instead of per-instruction cross-lane reductions).
     # None = on; False forces the per-step-checked ("careful") kernel.
     optimistic: Optional[bool] = None
-    # Basic-block fusion in the Pallas kernel: straight-line runs of
-    # pure stack ops compile into single handlers that keep
-    # intermediates in vector registers (one dispatch per block instead
-    # of one per instruction).  None = on; False falls back to the
-    # legacy peephole superinstruction fuser.
-    block_fusion: Optional[bool] = None
     # --- SIMT-tier superinstruction fusion (batch/fuse.py) ---
     # Rewrite the analyzer's top straight-line candidates into fused
     # dispatch cells at image-build time: ONE _make_step dispatch

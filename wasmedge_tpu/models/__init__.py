@@ -10,11 +10,13 @@ from wasmedge_tpu.models.programs import (
     build_call_counted_loop,
     build_coremark_kernel,
     build_counted_loop,
+    build_echo,
     build_fac,
     build_fib,
     build_loop_sum,
     build_memfuse_workload,
     build_memory_workload,
+    build_simd_kernel,
     build_simd_memfuse_workload,
 )
 # the guest of the benchmark's mem-batch-4096, which looks its builder
@@ -31,4 +33,6 @@ __all__ = [
     "build_memfuse_workload",
     "build_simd_memfuse_workload",
     "build_coremark_kernel",
+    "build_echo",
+    "build_simd_kernel",
 ]

@@ -3,6 +3,7 @@
 from __future__ import annotations
 
 from wasmedge_tpu.utils.builder import ModuleBuilder
+from wasmedge_tpu.utils.wat import parse_wat
 
 
 def build_fib() -> bytes:
@@ -108,8 +109,8 @@ def build_memory_workload(passes: int = 1, fold: str = "xor") -> bytes:
 
 
 def build_memory_batch() -> bytes:
-    """The guest of the benchmark's mem-batch-4096: bench_memory.py's 64
-    passes, summed and not xored, so that the answer depends on every
+    """The guest of the benchmark's mem-batch-4096: the memory sweep at
+    64 passes, summed and not xored, so that the answer depends on every
     word each pass stored and read back (benchmark/drivers/batch.py
     takes a builder without arguments)."""
     return build_memory_workload(passes=64, fold="add")
@@ -336,3 +337,76 @@ def build_coremark_kernel() -> bytes:
         ("local.get", 4), ("local.get", 2), "i32.xor",
     ], export="coremark")
     return b.build()
+
+
+def build_echo() -> bytes:
+    """The echo guest of BASELINE.json configs[3]: echo(n) writes a
+    16-byte message to stdout through WASI fd_write twice an iteration,
+    n iterations (two host outcalls a turn of the loop), and returns
+    the last errno."""
+    b = ModuleBuilder()
+    b.import_func("wasi_snapshot_preview1", "fd_write",
+                  ["i32", "i32", "i32", "i32"], ["i32"])
+    b.add_memory(1, 1)
+    # iovec at 64 -> "hello wasi echo\n" at 128 (16 bytes)
+    body = [
+        ("i32.const", 64), ("i32.const", 128), ("i32.store", 2, 0),
+        ("i32.const", 68), ("i32.const", 16), ("i32.store", 2, 0),
+    ]
+    msg = b"hello wasi echo\n"
+    for i, ch in enumerate(msg):
+        body += [("i32.const", 128 + i), ("i32.const", ch),
+                 ("i32.store8", 0, 0)]
+    body += [
+        ("block", None), ("loop", None),
+        ("local.get", 1), ("local.get", 0), "i32.ge_u", ("br_if", 1),
+        # write the message
+        ("i32.const", 1), ("i32.const", 64), ("i32.const", 1),
+        ("i32.const", 32), ("call", 0), ("local.set", 2),
+        # write again (second syscall per iteration)
+        ("i32.const", 1), ("i32.const", 64), ("i32.const", 1),
+        ("i32.const", 32), ("call", 0), ("local.set", 2),
+        ("local.get", 1), ("i32.const", 1), "i32.add", ("local.set", 1),
+        ("br", 0), "end", "end",
+        ("local.get", 2),
+    ]
+    b.add_function(["i32"], ["i32"], ["i32", "i32"], body, export="echo")
+    return b.build()
+
+
+# The v128 guest of BASELINE.json configs[2]: i32x4 lane math, a
+# shuffle and unaligned v128 memory traffic in a counted loop.
+_SIMD_KERNEL_WAT = """
+(module
+  (memory 1)
+  (func (export "vloop") (param i32) (result i32)
+    (local $acc v128)
+    (local $mul v128)
+    (local $i i32)
+    (local.set $acc (v128.const i32x4 1 2 3 4))
+    (local.set $mul (v128.const i32x4 3 5 7 11))
+    (block (loop
+      (br_if 1 (i32.ge_u (local.get $i) (local.get 0)))
+      (local.set $acc
+        (i32x4.add
+          (i32x4.mul (local.get $acc) (local.get $mul))
+          (i32x4.splat (local.get $i))))
+      (local.set $acc
+        (v128.xor (local.get $acc)
+                  (i8x16.shuffle 4 5 6 7 0 1 2 3 12 13 14 15 8 9 10 11
+                                 (local.get $acc) (local.get $acc))))
+      (local.set $i (i32.add (local.get $i) (i32.const 1)))
+      (br 0)))
+    (v128.store offset=5 (i32.const 32) (local.get $acc))
+    (local.set $acc (v128.load offset=5 (i32.const 32)))
+    (i32.add
+      (i32x4.extract_lane 0 (local.get $acc))
+      (i32.add (i32x4.extract_lane 1 (local.get $acc))
+               (i32.add (i32x4.extract_lane 2 (local.get $acc))
+                        (i32x4.extract_lane 3 (local.get $acc)))))))
+"""
+
+
+def build_simd_kernel() -> bytes:
+    """vloop(n): n turns of the v128 loop above, then the lanes' sum."""
+    return parse_wat(_SIMD_KERNEL_WAT)

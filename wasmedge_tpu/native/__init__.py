@@ -3,11 +3,8 @@
 This is the `EngineKind.NATIVE` implementation — a C++ interpreter over the
 same lowered SoA image the Python oracle and the TPU engines execute
 (engine.cpp here mirrors /root/reference/lib/executor/engine/
-engine.cpp:68-1641 structurally).  It serves two roles:
-
-1. the fast host-side engine behind `--engine native`, and
-2. the *live-measured* single-core denominator for bench.py's vs_baseline
-   (a real dispatch loop on this machine, not a recorded constant).
+engine.cpp:68-1641 structurally).  It is the fast host-side engine behind
+`--engine native`.
 
 Build-on-demand: the shared library is compiled with g++ on first use and
 cached by source hash under ~/.cache/wasmedge_tpu (no pip, no network).
@@ -125,12 +122,6 @@ def _build_lib():
         ctypes.c_int32, ctypes.c_int64,                 # depth/stack limits
         i32p,                                           # stop flag
         i64p, i32p,                                     # retired, out_pages
-    ]
-    lib.we_native_selfbench.restype = ctypes.c_double
-    lib.we_native_selfbench.argtypes = [
-        i32p, i32p, i32p, i32p, i64p, ctypes.c_int32, i32p,
-        i32p, i32p, i32p, i32p, i32p, i32p, ctypes.c_int32, i32p,
-        i32p, ctypes.c_int32, ctypes.c_int32, ctypes.c_int64,
     ]
     _lib = lib
     return lib
@@ -433,33 +424,3 @@ class NativeModule:
 def module_for(inst, store=None) -> NativeModule:
     return NativeModule(inst, store)
 
-
-def scalar_fib_ops_per_sec(n: int) -> float:
-    """Live single-core baseline: fib(n) on the C++ dispatch loop."""
-    from wasmedge_tpu.common.configure import Configure
-    from wasmedge_tpu.executor import Executor
-    from wasmedge_tpu.loader import Loader
-    from wasmedge_tpu.models import build_fib
-    from wasmedge_tpu.runtime.store import StoreManager
-    from wasmedge_tpu.validator import Validator
-
-    conf = Configure()
-    mod = Validator(conf).validate(Loader(conf).parse_module(build_fib()))
-    store = StoreManager()
-    inst = Executor(conf).instantiate(store, mod)
-    nm = NativeModule(inst, store)
-    if not nm.eligible:
-        raise RuntimeError(f"fib not native-eligible: {nm.reason}")
-    lib = _build_lib()
-    func_idx = inst.exports["fib"][1]
-    # best of three: the baseline is "one dedicated CPU core"; taking
-    # the max keeps the denominator honest when the host is busy (a
-    # slow contended run would otherwise inflate every vs_baseline)
-    i32p = ctypes.POINTER(ctypes.c_int32)
-    tbl = nm.table.ctypes.data_as(i32p)
-    ops = max(lib.we_native_selfbench(*nm._img_args(lib), tbl,
-                                      len(nm.table), func_idx, n)
-              for _ in range(3))
-    if ops <= 0:
-        raise RuntimeError("native selfbench failed")
-    return ops

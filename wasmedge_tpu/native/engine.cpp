@@ -949,40 +949,3 @@ done:
   delete[] frames;
   return trapcode;
 }
-
-// Quick self-contained throughput probe used by bench.py's denominator:
-// returns retired instructions/second for a fib(n) run, measured on this
-// same dispatch loop (the honest single-core baseline).
-#include <chrono>
-
-extern "C" double we_native_selfbench(
-    const int32_t* ops, const int32_t* aa, const int32_t* bb,
-    const int32_t* cc, const int64_t* imm, int32_t code_len,
-    const int32_t* brt, const int32_t* f_entry, const int32_t* f_nparams,
-    const int32_t* f_nlocals, const int32_t* f_nresults,
-    const int32_t* f_ftop, const int32_t* f_typeid, int32_t nf,
-    const int32_t* typeid_of_type, const int32_t* table, int32_t tsize,
-    int32_t func_idx, int64_t arg) {
-  cell args[1] = {(cell)arg};
-  cell results[4];
-  int64_t retired = 0;
-  int32_t out_pages = 0;
-  uint8_t dummy_mem[8] = {0};
-  int32_t tbl_copy[64];
-  int32_t nt = tsize < 64 ? tsize : 64;
-  for (int32_t i = 0; i < nt; i++) tbl_copy[i] = table[i];
-  int32_t ts_io = nt;
-  uint8_t no_drop[1] = {0};
-  auto t0 = std::chrono::steady_clock::now();
-  int32_t rc = we_native_invoke(
-      ops, aa, bb, cc, imm, code_len, brt, f_entry, f_nparams, f_nlocals,
-      f_nresults, f_ftop, f_typeid, nf, typeid_of_type, tbl_copy, &ts_io,
-      nt, nullptr, nullptr, nullptr, 0, no_drop, nullptr, nullptr, nullptr,
-      0, no_drop,
-      nullptr, dummy_mem, 0, 0, func_idx, args, 1, results, 8192, 1 << 20,
-      nullptr, &retired, &out_pages);
-  auto t1 = std::chrono::steady_clock::now();
-  double dt = std::chrono::duration<double>(t1 - t0).count();
-  if (rc != 0 || dt <= 0) return 0.0;
-  return (double)retired / dt;
-}

@@ -8,8 +8,8 @@ thread_name metadata events so the UI labels them; counters ("C"
 events: live-lane occupancy, hostcall queue depth) render as counter
 tracks above the span rows.
 
-`validate_chrome_trace` is the schema check bench.py --trace-smoke and
-the obs test suite run against every emitted artifact: it proves the
+`validate_chrome_trace` is the schema check the obs test suite
+(tests/test_obs.py) runs against every emitted artifact: it proves the
 required keys and types per phase, not merely that json.loads
 succeeds.
 """

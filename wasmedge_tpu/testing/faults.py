@@ -38,8 +38,9 @@ the engines — r13's chaos surface:
                            a slow/flaky network rather than a server
                            exception.
 A gateway process kill/restart is NOT a seam — it is orchestrated by
-the chaos driver (bench.py --chaos: Gateway.kill() then a fresh
-GatewayService(resume=True) over the same state dir), with the seams
+the test that drives it (tests/test_gateway_durability.py:
+Gateway.kill() then a fresh GatewayService(resume=True) over the same
+state dir), with the seams
 above supplying the weather around it.
 
 The lane-virtualization layer (wasmedge_tpu/hv/) adds the swap seams
@@ -79,7 +80,7 @@ The fleet federation layer (wasmedge_tpu/fleet/) adds the peer seams
   `partition_schedule()` composes these into deterministic network
   partitions: directional link cuts between named peers over a window
   of arrivals, healing when the window passes.  A gateway process
-  kill/restart is still driver-orchestrated (bench.py --federation),
+  kill/restart is still driver-orchestrated (tests/test_fleet.py),
   with these seams supplying the weather.
 
 The elastic-fleet layer (r21) adds the churn seams:
@@ -98,7 +99,7 @@ The elastic-fleet layer (r21) adds the churn seams:
                            the OLD mesh with every resident lane
                            intact — the reshard fails closed.
   `churn_schedule()` composes these into the seeded join/leave/reshard
-  weather `bench.py --elastic` arms.
+  weather tests/test_elastic.py arms.
 
 The effects layer (r23, wasmedge_tpu/effects/) adds the suspend/resume
 seams:
@@ -449,7 +450,7 @@ def gateway_chaos_schedule(seed: int,
                            journal_faults: int = 1,
                            edge_faults: int = 2,
                            max_at: int = 6) -> list:
-    """The seeded fault schedule `bench.py --chaos` arms on the gateway:
+    """The seeded fault schedule a chaos run arms on the gateway:
     engine launch/serve faults (the supervisor tier recovers), one-shot
     generation build/swap faults (the registration tier rolls back with
     a retryable 503), durable-journal write faults (the submit is
@@ -524,7 +525,7 @@ def partition_schedule(links, at: int = 0, times: int = 1000000,
 def churn_schedule(seed: int, gossip_drops: int = 2,
                    reshard_faults: int = 0,
                    max_at: int = 6) -> list:
-    """The seeded churn weather `bench.py --elastic` arms: a few
+    """The seeded churn weather an elastic-fleet run arms: a few
     dropped membership-gossip messages (the CRDT view must still
     converge through later exchanges) and, optionally, reshard-install
     faults (the live reshard must roll back onto the old mesh and a
@@ -541,24 +542,6 @@ def churn_schedule(seed: int, gossip_drops: int = 2,
         # arrival 2k faults, its retry (2k+1) goes through — mirrors
         # the gateway_chaos_schedule build/swap pairing
         out.append(Fault(point="reshard_install", at=2 * k))
-    return out
-
-
-def bitflip_campaign(seed: int, n_per_class: int = 2) -> list:
-    """The seeded SDC campaign `bench.py --integrity` drives: for each
-    storage class — resident BatchState plane, SwapStore/parked-session
-    blob, checkpoint shard, WTIC compile-cache entry — derive
-    `n_per_class` flip scenarios.  Every scenario must end DETECTED
-    (audit divergence or scrub/read hash mismatch) or REPAIRED/MASKED
-    with results bit-identical to the uncorrupted reference; a single
-    silent corruption fails the campaign.  Same seed, same flips."""
-    rng = np.random.RandomState(int(seed) & 0x7FFFFFFF)
-    out = []
-    for cls in ("plane", "swap", "checkpoint", "cache"):
-        for k in range(n_per_class):
-            out.append({"cls": cls, "seed": int(rng.randint(1 << 30)),
-                        "at": int(rng.randint(2)) if cls == "plane" else 0,
-                        "index": k})
     return out
 
 
