@@ -204,17 +204,23 @@ def test_pallas_kernel_compiles_for_v5e(case, one_chip):
     _compile_kernel(eng, fn, one_chip, careful=careful)
 
 
+def _inner(eqn):
+    """The jaxprs an equation holds."""
+    for v in eqn.params.values():
+        for x in v if isinstance(v, (list, tuple)) else [v]:
+            x = getattr(x, "jaxpr", x)
+            if hasattr(x, "eqns"):
+                yield x
+
+
 def _region_depth(jaxpr, depth=0):
     """The deepest nesting of cond/while/scan regions in a jaxpr: what
     Mosaic's infer-vector-layout recurses over on its small stack."""
     deepest = depth
     for eqn in jaxpr.eqns:
         inner = depth + (eqn.primitive.name in ("cond", "while", "scan"))
-        for v in eqn.params.values():
-            for x in v if isinstance(v, (list, tuple)) else [v]:
-                x = getattr(x, "jaxpr", x)
-                if hasattr(x, "eqns"):
-                    deepest = max(deepest, _region_depth(x, inner))
+        for x in _inner(eqn):
+            deepest = max(deepest, _region_depth(x, inner))
     return deepest
 
 
@@ -224,8 +230,8 @@ def _region_depth(jaxpr, depth=0):
 # superblocks may deepen no kernel the benchmark's cells build.
 _DEPTHS = {
     "fib": (_fib_wasm, 256, 256, (9, 7)),
-    "memory-auto": (_memory_wasm, 128, 64, (11, 11)),
-    "superblock-call-tail": (_superblock_wasm, 128, 64, (9, 9)),
+    "memory-auto": (_memory_wasm, 128, 64, (11, 10)),
+    "superblock-call-tail": (_superblock_wasm, 128, 64, (10, 9)),
 }
 
 
@@ -239,6 +245,93 @@ def test_kernel_region_depth(case, one_chip):
         _region_depth(jax.make_jaxpr(fn)(*eng._arg_specs()).jaxpr)
         for fn in (eng._fn, eng._fn_careful()))
     assert got == expect and max(got) <= 11
+
+
+def _holds(jaxpr, name):
+    return any(e.primitive.name == name
+               or any(_holds(x, name) for x in _inner(e))
+               for e in jaxpr.eqns)
+
+
+def _conds(jaxpr):
+    return [e for e in jaxpr.eqns if e.primitive.name == "cond"]
+
+
+def _block_leaf(eng, is_block):
+    """(the jaxpr of the dispatch tree's leaf for the one block shape
+    that `is_block` picks, scalars in the loop's carry): down from the
+    kernel's loop along kernel_dispatch_plan, as dispatch() builds it
+    (`hid < mid` is the cond's second branch)."""
+    import jax
+
+    from wasmedge_tpu.batch.pallas_engine import (
+        H_BLOCK_BASE, kernel_dispatch_plan)
+
+    def whiles(jaxpr):
+        for e in jaxpr.eqns:
+            if e.primitive.name == "while":
+                yield e
+            for x in _inner(e):
+                yield from whiles(x)
+
+    loop = max(whiles(jax.make_jaxpr(eng._fn)(*eng._arg_specs()).jaxpr),
+               key=lambda e: len(e.outvars))
+    (shape,) = [i for i, s in enumerate(eng._kargs[17]) if is_block(s)]
+    want = list(eng._kargs[0]).index(H_BLOCK_BASE + shape)
+    node, _depths = kernel_dispatch_plan(eng._hid_weights, True)
+    jaxpr = loop.params["body_jaxpr"].jaxpr
+    while not isinstance(node, int):
+        (cond,) = _conds(jaxpr)
+        mid, left, right = node
+        node = left if want < mid else right
+        jaxpr = cond.params["branches"][int(want < mid)].jaxpr
+    assert node == want
+    return jaxpr, len(loop.outvars)
+
+
+@pytest.mark.parametrize("op", ["loadi", "storei"])
+def test_windowed_access_walks_one_cold_region_and_one_exit(op, one_chip):
+    """The structure of a windowed access in a fused block of the
+    memory guest (its store loop, its load loop): where the access
+    stands, ONE region holds every DMA (the miss: canary, restore,
+    write-backs, snapshot, fill), and its other branch, the hit, is a
+    select; ONE further region yields the carry (the exit), and what
+    follows the access is a branch of that one: it touches state one
+    region below the access, where rollback and bail are two below."""
+    eng = _pallas_engine(_memory_wasm(), 128, 64)
+    leaf, ncarry = _block_leaf(
+        eng, lambda s: sum(o[0] in ("loadi", "storei") for o in s) == 1
+        and any(o[0] == op for o in s))
+    # through the regions that yield the whole carry (the loop's exit
+    # guard) to the level the access stands at
+    while True:
+        dma = [e for e in _conds(leaf) if any(
+            _holds(x, "dma_start") for x in _inner(e))]
+        if len(dma) == 1 and len(dma[0].outvars) == ncarry:
+            (leaf,) = [x for x in _inner(dma[0])
+                       if _holds(x, "dma_start")]
+            continue
+        break
+    (miss,) = dma
+    assert 0 < len(miss.outvars) < ncarry
+    hit = min(_inner(miss), key=lambda x: len(x.eqns))
+    assert len(hit.eqns) <= 1 and not _conds(hit)
+    (leave,) = [e for e in _conds(leaf) if len(e.outvars) == ncarry]
+    assert leave is leaf.eqns[-1]
+
+    def writes_here(jaxpr):
+        """A state write at this level, or under a pl.when of it."""
+        return any(e.primitive.name == "swap"
+                   or (e.primitive.name == "cond" and not e.outvars
+                       and any(_holds(x, "swap") for x in _inner(e)))
+                   for e in jaxpr.eqns)
+
+    rare, go = sorted(_inner(leave), key=writes_here)
+    assert writes_here(go) and not writes_here(rare)
+    # the rare side: rolled back, or lane 0 out of bounds
+    (which,) = _conds(rare)
+    assert len(which.outvars) == ncarry
+    assert not any(_conds(x) for x in _inner(which))
 
 
 def test_exported_kernel_compiles_for_v5e(one_chip):

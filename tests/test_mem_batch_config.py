@@ -148,6 +148,11 @@ def test_pallas_kernel_matches_the_reference(mem_hbm, n, passes):
     # the window's counters count the window's DMAs and nothing else
     want = _window_dmas(n, passes) if mem_hbm else (0, 0)
     assert (eng.pallas.window_fills, eng.pallas.window_writebacks) == want
+    # and one access for every word a sweep stores or loads
+    accesses = 2 * n * passes if mem_hbm else 0
+    assert eng.pallas.window_accesses == accesses
+    assert eng.pallas.window_hit_share == (
+        1 - want[0] / accesses if mem_hbm else None)
 
 
 def test_window_counters_reach_metrics_and_the_run_span():
@@ -170,7 +175,9 @@ def test_window_counters_reach_metrics_and_the_run_span():
             "wasmedge_memory_lane_block": (
                 {"mem_mode": "hbm_window", "window": "128x2"}, LANES),
             "wasmedge_hbm_window_fills_total": ({}, jobs * fills),
-            "wasmedge_hbm_window_writebacks_total": ({}, jobs * wbs)}
+            "wasmedge_hbm_window_writebacks_total": ({}, jobs * wbs),
+            "wasmedge_hbm_window_accesses_total": (
+                {}, jobs * 2 * n * passes)}
         assert (eng.pallas.window_fills,
                 eng.pallas.window_writebacks) == (fills, wbs)
     runs = [e["args"] for e in eng.obs.events if e["name"] == "batch/run"]
@@ -178,6 +185,8 @@ def test_window_counters_reach_metrics_and_the_run_span():
     for a in runs:
         assert (a["mem_mode"], a["lane_block"], a["window"]) == (
             "hbm_window", LANES, "128x2")
+        assert a["window_hit_share"] == round(
+            1 - fills / (2 * n * passes), 6)
 
 
 def test_run_span_of_a_resident_memory_and_of_no_memory():
@@ -219,11 +228,12 @@ def test_window_counters_outlive_the_kernel_that_counted():
               "window": "128x2"}
     rec.set_memory_static(static)
     assert rec.memory_static is static
-    rec.add_window_counts(8832, 4480)
+    rec.add_window_counts(8832, 4480, 1048576)
     rec.set_memory_static({"mem_mode": "none"})
-    rec.add_window_counts(0, 0)
+    rec.add_window_counts(0, 0, 0)
     parsed = parse_prometheus(render_prometheus(recorder=rec))
     assert {name: v for (name, _l), v in parsed.items()
             if "memory" in name or "window" in name} == {
         "wasmedge_hbm_window_fills_total": 8832,
-        "wasmedge_hbm_window_writebacks_total": 4480}
+        "wasmedge_hbm_window_writebacks_total": 4480,
+        "wasmedge_hbm_window_accesses_total": 1048576}

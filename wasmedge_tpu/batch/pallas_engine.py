@@ -228,6 +228,9 @@ _C_WWBS = 12
 # handlers the loop dispatched, those a rollback discarded too (a commit
 # is none: it retires nothing)
 _C_DISPATCHES = 13
+# written by the mem_hbm kernel at exit, per launch (never read by it):
+# the loads and stores it resolved against the window, hits and misses
+_C_WACCESSES = 14
 _SNAP_MIN = 256
 
 
@@ -951,11 +954,10 @@ def _build_kernel(used_hids: tuple, D: int, CD: int, W: int, L: int,
         se3s = next(it_) if simd else None
         glo, ghi = next(it_), next(it_)
         if mem_hbm:
-            mwin0, mwin1, wcnt = next(it_), next(it_), next(it_)
+            mwin, wcnt, wacc = next(it_), next(it_), next(it_)
             memr = None
         else:
             memr = next(it_)
-            mwin0 = mwin1 = None
         trapr, sems = next(it_), next(it_)
         if optimistic:
             canr, flag, snapf, snapc = (next(it_), next(it_),
@@ -1703,13 +1705,16 @@ def _build_kernel(used_hids: tuple, D: int, CD: int, W: int, L: int,
             return keep(c, status=I32(ST_HOSTCALL))
 
         # ---- memory access ------------------------------------------
-        # NOTE predication discipline: `lax.cond` whose branches return
-        # vectors or mutate refs is DISCHARGED by pallas into
-        # execute-both-and-select — a "rare" divergent-gather branch
-        # would then run its whole-memory scan on every access.  All
-        # vector/ref work below therefore sits under `pl.when` (real
-        # Mosaic predicated blocks); only the scalar carry goes through
-        # lax.cond.
+        # NOTE predication discipline: a `lax.cond` whose branches
+        # RETURN vectors is discharged into execute-both-and-select, so
+        # a "rare" divergent-gather branch would run its whole-memory
+        # scan on every access.  Vector results below therefore stay
+        # inside the region that computes them (`pl.when`, or a
+        # `lax.cond` branch that writes them to their rows) and only
+        # the scalar carry comes out of a lax.cond: such a cond is one
+        # scf.if whose untaken side costs nothing, which the dispatch
+        # tree and the window's miss and exit regions (_opt_window,
+        # _opt_leave) rely on.
 
         def _gather_word(widx, row_lo, row_hi):
             """Per-lane word gather from [W, Lblk] by chunked
@@ -1726,7 +1731,9 @@ def _build_kernel(used_hids: tuple, D: int, CD: int, W: int, L: int,
 
             return lax.fori_loop(c_lo, c_hi, chunk, full(0))
 
-        def _load_finish(c, mw0, mw1, mw2, shB, oob, any_oob):
+        def _load_put(c, mw0, mw1, mw2, shB):
+            """The loaded cell, from the three words it may span, into
+            the row of the address it replaces."""
             pc, sp = c[1], c[2]
             nbytes, flags = b_r[pc], c_r[pc]
             inv = (32 - shB) & 31
@@ -1759,6 +1766,9 @@ def _build_kernel(used_hids: tuple, D: int, CD: int, W: int, L: int,
                 full(0))
             wrow(slo, sp - 1, ll)
             wrow(shi, sp - 1, lh)
+
+        def _load_finish(c, mw0, mw1, mw2, shB, oob, any_oob):
+            _load_put(c, mw0, mw1, mw2, shB)
 
             @pl.when(any_oob)
             def _():
@@ -1950,30 +1960,30 @@ def _build_kernel(used_hids: tuple, D: int, CD: int, W: int, L: int,
             # [1] dirty write-backs.  Every one of them already sits in
             # a branch of its own, so the count costs the converged
             # path nothing and adds no region (one more nesting level
-            # kills the chip's compiler).
+            # kills the chip's compiler).  wacc counts the accesses the
+            # launch resolved against the window, hits and misses: one
+            # vreg in VMEM that goes up by one at each, as `turns` does
+            # at each dispatch (a carry scalar or an SMEM cell on the
+            # converged path is not free, PR 29).
             wcnt[0] = I32(0)
             wcnt[1] = I32(0)
+            wacc[...] = jnp.zeros_like(wacc)
 
-            def _wb_way0(wb):
-                cp = dma(6, mwin0, lsliceR(mem_out, a8(jnp.clip(wb, 0, W - CW)), CW))
+            # the two ways are the halves of one scratch: way k holds
+            # rows [k * CW, (k + 1) * CW), so a resident row is one
+            # dynamic row of `mwin` and the DMAs move a half
+            def _way(k):
+                return mwin.at[pl.ds(k * CW, CW)]
+
+            def _wb_way(k, wb):
+                cp = dma(6 + k, _way(k),
+                         lsliceR(mem_out, a8(jnp.clip(wb, 0, W - CW)), CW))
                 cp.start()
                 cp.wait()
                 wcnt[1] = wcnt[1] + 1
 
-            def _wb_way1(wb):
-                cp = dma(7, mwin1, lsliceR(mem_out, a8(jnp.clip(wb, 0, W - CW)), CW))
-                cp.start()
-                cp.wait()
-                wcnt[1] = wcnt[1] + 1
-
-            def _fill_way0(nb):
-                cp = dma(6, lsliceR(mem_out, a8(nb), CW), mwin0)
-                cp.start()
-                cp.wait()
-                wcnt[0] = wcnt[0] + 1
-
-            def _fill_way1(nb):
-                cp = dma(7, lsliceR(mem_out, a8(nb), CW), mwin1)
+            def _fill_way(k, nb):
+                cp = dma(6 + k, lsliceR(mem_out, a8(nb), CW), _way(k))
                 cp.start()
                 cp.wait()
                 wcnt[0] = wcnt[0] + 1
@@ -1981,8 +1991,12 @@ def _build_kernel(used_hids: tuple, D: int, CD: int, W: int, L: int,
             def _win_select(wfs, rlo, rhi, en):
                 """Make rows [rlo, rhi] resident in one way; returns
                 (way, wfs').  All DMAs are predicated on `en`; callers
-                must have checked (rhi - align8(rlo)) < CW."""
+                must have checked (rhi - align8(rlo)) < CW.  INVARIANT
+                SYNC: _opt_window holds the optimistic kernel's copy of
+                these formulas, the hit predicates on its hot path and
+                the rest in its miss()."""
                 wb0, wd0, wb1, wd1, mru = wfs
+                wacc[...] = wacc[...] + jnp.where(en, I32(1), I32(0))
                 hit0 = (rlo >= wb0) & (rhi < wb0 + CW)
                 hit1 = (rlo >= wb1) & (rhi < wb1 + CW)
                 nb = jnp.clip(rlo - lax.rem(rlo, 8), 0, W - CW)
@@ -1997,27 +2011,27 @@ def _build_kernel(used_hids: tuple, D: int, CD: int, W: int, L: int,
 
                 @pl.when(ov0 & (wd0 != 0))
                 def _():
-                    _wb_way0(wb0)
+                    _wb_way(0, wb0)
 
                 @pl.when(ov1 & (wd1 != 0))
                 def _():
-                    _wb_way1(wb1)
+                    _wb_way(1, wb1)
 
                 @pl.when(repl0 & (wd0 != 0))
                 def _():
-                    _wb_way0(wb0)
+                    _wb_way(0, wb0)
 
                 @pl.when(repl0)
                 def _():
-                    _fill_way0(nb)
+                    _fill_way(0, nb)
 
                 @pl.when(repl1 & (wd1 != 0))
                 def _():
-                    _wb_way1(wb1)
+                    _wb_way(1, wb1)
 
                 @pl.when(repl1)
                 def _():
-                    _fill_way1(nb)
+                    _fill_way(1, nb)
 
                 wb0n = jnp.where(repl0, nb, jnp.where(ov0, SENT, wb0))
                 wd0n = jnp.where(repl0 | ov0, I32(0), wd0)
@@ -2036,36 +2050,56 @@ def _build_kernel(used_hids: tuple, D: int, CD: int, W: int, L: int,
 
                 @pl.when(wd0 != 0)
                 def _():
-                    _wb_way0(wb0)
+                    _wb_way(0, wb0)
 
                 @pl.when(wd1 != 0)
                 def _():
-                    _wb_way1(wb1)
+                    _wb_way(1, wb1)
 
                 return (SENT, I32(0), SENT, I32(0), I32(0))
 
-            def win_read_row(way, wfs, r):
-                i0 = jnp.clip(r - wfs[0], 0, CW - 1)
-                i1 = jnp.clip(r - wfs[2], 0, CW - 1)
-                return jnp.where(way == 0, srow(mwin0, i0), srow(mwin1, i1))
+            def win_at(way, wfs):
+                """Where the resident way lies: (its first row of `mwin`,
+                the plane row that one holds)."""
+                return way * CW, jnp.where(way == 0, wfs[0], wfs[2])
 
-            def win_write_row(way, wfs, r, v):
-                @pl.when(way == 0)
-                def _():
-                    wrow(mwin0, jnp.clip(r - wfs[0], 0, CW - 1), v)
+            def _win_row(win, r):
+                return win[0] + jnp.clip(r - win[1], 0, CW - 1)
 
-                @pl.when(way == 1)
-                def _():
-                    wrow(mwin1, jnp.clip(r - wfs[2], 0, CW - 1), v)
+            def win_read_row(win, r):
+                return srow(mwin, _win_row(win, r))
 
-            def _win_gather(way, wfs, wk):
+            def win_write_row(win, r, v):
+                wrow(mwin, _win_row(win, r), v)
+
+            def _win_gather(win, wk):
                 """Per-lane word gather from the selected resident way."""
-                base = jnp.where(way == 0, wfs[0], wfs[2])
-                rel = wk - base
+                rel = wk - win[1]
                 wi = riota(CW)
-                rows = jnp.where(way == 0, srows(mwin0, 0, CW),
-                                 srows(mwin1, 0, CW))
-                return rsum(jnp.where(wi == rel, rows, 0))
+                return rsum(jnp.where(wi == rel,
+                                      srows(mwin, a8(win[0]), CW), 0))
+
+            def win_store_words(win, u, shB, triples, nbytes):
+                """Merge a store's words into the resident way.  Which
+                of its up-to-three words a store of `nbytes` touches
+                follows from its width but for one: the first (two of
+                an i64) always, the next where the byte shift spills
+                into it, the rest never, and a word's mask is zero
+                exactly where it is not touched.  So only that one
+                word's write needs a region of its own."""
+                n_whole = 2 if nbytes == 8 else 1
+                fits_upto = 32 * n_whole - 8 * nbytes   # shB that fits
+                for k, (m, v) in enumerate(triples):
+                    w = jnp.minimum(u + k, W - 1)
+
+                    def put(m=m, v=v, w=w):
+                        cur = win_read_row(win, w)
+                        win_write_row(win, w, (cur & ~m) | (v & m))
+
+                    if k < n_whole:
+                        put()
+                    elif k == n_whole and fits_upto < 24:
+                        pl.when(shB > fits_upto)(put)
 
             def _wfs_of(c):
                 return (c[8], c[9], c[10], c[11], c[12])
@@ -2074,80 +2108,112 @@ def _build_kernel(used_hids: tuple, D: int, CD: int, W: int, L: int,
                 return keep(c, wb0=wfs[0], wd0=wfs[1], wb1=wfs[2],
                             wd1=wfs[3], mru=wfs[4], **kw)
 
+            def _win_dirtied(way, wfs, en=True):
+                """wfs after a store into `way` (where `en`)."""
+                return (wfs[0],
+                        jnp.where(en & (way == 0), I32(1), wfs[1]),
+                        wfs[2],
+                        jnp.where(en & (way == 1), I32(1), wfs[3]),
+                        wfs[4])
+
             def _opt_window(c, u, rhi):
                 """Optimistic scalar window select: resolve [u, rhi] to
-                a resident way with all decisions scalar.  A dirty
-                eviction is a commit point — validate the canary first,
-                roll back on a pending stale decision, snapshot
-                otherwise.  Returns (dirty, way, wfs') where wfs' has
-                the new window fields with mru=way; callers must gate
-                every ref mutation on ~dirty and return rolled_carry()
-                when dirty.
+                a resident way with all decisions scalar.  An access
+                that hits computes hit0, hit1 and the way, and walks one
+                region: everything a miss needs lies in that region's
+                cold branch.  A dirty eviction there is a commit point:
+                validate the canary first, roll back on a pending stale
+                decision, snapshot otherwise (`c` is the carry that
+                snapshot pairs with the planes: positioned at THIS
+                access).  Returns (dirty, way, wfs', ls') where wfs' has
+                the new window fields with mru=way and ls' is c[0] after
+                a snapshot; callers touch no ref and return
+                rolled_carry() when dirty (_opt_leave).
 
-                INVARIANT SYNC: the hit predicates, victim choice,
-                overlap eviction (single-resident-copy rule) and
-                wb/wd/mru update formulas here MUST match _win_select
-                above — the careful kernel runs that one against the
-                same window state this one leaves behind."""
+                INVARIANT SYNC: the hit predicates here, and in miss()
+                the victim choice, the overlap eviction (single-
+                resident-copy rule) and the wb/wd/mru update formulas,
+                MUST match _win_select above: the careful kernel runs
+                that one against the same window state this one leaves
+                behind."""
                 wb0, wd0, wb1, wd1, mru = _wfs_of(c)
                 hit0 = (u >= wb0) & (rhi < wb0 + CW)
                 hit1 = (u >= wb1) & (rhi < wb1 + CW)
-                miss = ~(hit0 | hit1)
-                vic1 = mru == 0
-                nb = jnp.clip(u - lax.rem(u, 8), 0, W - CW)
-                ov0 = miss & vic1 & (wb0 < nb + CW) & (nb < wb0 + CW)
-                ov1 = miss & ~vic1 & (wb1 < nb + CW) & (nb < wb1 + CW)
-                repl0 = miss & ~vic1
-                repl1 = miss & vic1
-                needs_wb = (repl0 & (wd0 != 0)) | (repl1 & (wd1 != 0)) | \
-                    (ov0 & (wd0 != 0)) | (ov1 & (wd1 != 0))
+                wacc[...] = wacc[...] + 1
 
-                @pl.when(needs_wb)
-                def _():
-                    flag[0] = jnp.any(srow(canr, 0) != 0).astype(jnp.int32)
+                def hit():
+                    return (I32(0), wb0, wd0, wb1, wd1,
+                            jnp.where(hit0, I32(0), I32(1)),
+                            c[IDX["ls"]])
 
-                dirty = needs_wb & (flag[0] != 0)
-                okp = ~dirty
+                def miss():
+                    vic1 = mru == 0
+                    repl0, repl1 = ~vic1, vic1
+                    nb = jnp.clip(u - lax.rem(u, 8), 0, W - CW)
+                    ov0 = repl1 & (wb0 < nb + CW) & (nb < wb0 + CW)
+                    ov1 = repl0 & (wb1 < nb + CW) & (nb < wb1 + CW)
+                    needs_wb = ((repl0 | ov0) & (wd0 != 0)) | \
+                        ((repl1 | ov1) & (wd1 != 0))
 
-                @pl.when(dirty)
-                def _():
-                    do_restore()
+                    @pl.when(needs_wb)
+                    def _():
+                        flag[0] = jnp.any(
+                            srow(canr, 0) != 0).astype(jnp.int32)
 
-                # publish BOTH dirty ways before the snapshot so the HBM
-                # plane IS the snapshot's memory state — otherwise a
-                # later rollback would discard the non-victim way's
-                # validated stores (same discipline as the periodic
-                # commit in body())
-                @pl.when(needs_wb & okp & (wd0 != 0))
-                def _():
-                    _wb_way0(wb0)
+                    dirty = needs_wb & (flag[0] != 0)
+                    flushed = needs_wb & ~dirty
 
-                @pl.when(needs_wb & okp & (wd1 != 0))
-                def _():
-                    _wb_way1(wb1)
+                    @pl.when(dirty)
+                    def _():
+                        do_restore()
 
-                @pl.when(needs_wb & okp)
-                def _():
-                    do_snapshot(c)
+                    # publish BOTH dirty ways before the snapshot so the
+                    # HBM plane IS the snapshot's memory state: otherwise
+                    # a later rollback would discard the non-victim way's
+                    # validated stores (same discipline as the periodic
+                    # commit in body())
+                    @pl.when(flushed & (wd0 != 0))
+                    def _():
+                        _wb_way(0, wb0)
 
-                @pl.when(okp & repl0)
-                def _():
-                    _fill_way0(nb)
+                    @pl.when(flushed & (wd1 != 0))
+                    def _():
+                        _wb_way(1, wb1)
 
-                @pl.when(okp & repl1)
-                def _():
-                    _fill_way1(nb)
+                    @pl.when(flushed)
+                    def _():
+                        do_snapshot(c)
 
-                flushed = needs_wb & okp
-                wb0n = jnp.where(repl0, nb, jnp.where(ov0, SENT, wb0))
-                wd0n = jnp.where(flushed | repl0 | ov0, I32(0), wd0)
-                wb1n = jnp.where(repl1, nb, jnp.where(ov1, SENT, wb1))
-                wd1n = jnp.where(flushed | repl1 | ov1, I32(0), wd1)
-                way = jnp.where(hit0, I32(0),
-                                jnp.where(hit1, I32(1),
-                                          jnp.where(vic1, I32(1), I32(0))))
-                return dirty, flushed, way, \
-                    (wb0n, wd0n, wb1n, wd1n, way)
+                    @pl.when(~dirty & repl0)
+                    def _():
+                        _fill_way(0, nb)
+
+                    @pl.when(~dirty & repl1)
+                    def _():
+                        _fill_way(1, nb)
+
+                    return (
+                        jnp.where(dirty, I32(1), I32(0)),
+                        jnp.where(repl0, nb, jnp.where(ov0, SENT, wb0)),
+                        jnp.where(flushed | repl0 | ov0, I32(0), wd0),
+                        jnp.where(repl1, nb, jnp.where(ov1, SENT, wb1)),
+                        jnp.where(flushed | repl1 | ov1, I32(0), wd1),
+                        jnp.where(vic1, I32(1), I32(0)),
+                        jnp.where(flushed, c[0], c[IDX["ls"]]))
+
+                dirty, wb0n, wd0n, wb1n, wd1n, way, lsn = lax.cond(
+                    hit0 | hit1, hit, miss)
+                return dirty != 0, way, (wb0n, wd0n, wb1n, wd1n, way), lsn
+
+            def _opt_leave(dirty, oob0, on_oob, go):
+                """The one exit of a windowed access: `go` is what
+                follows it, one region below it, and the two rare
+                outcomes share the other branch: the rollback
+                _opt_window made (dirty) and lane 0 out of bounds."""
+                return lax.cond(
+                    dirty | oob0,
+                    lambda: lax.cond(dirty, rolled_carry, on_oob),
+                    go)
 
             def _opt_ls_prolog(c, addr_row, nb_extra):
                 """Shared optimistic load/store address computation."""
@@ -2176,18 +2242,16 @@ def _build_kernel(used_hids: tuple, D: int, CD: int, W: int, L: int,
                 rhi = jnp.minimum(u + want_rows, W - 1)
                 return ea, oob0, u, shB0, rhi
 
-            def _opt_trap_oob(c, ea, nbytes, oob0):
+            def _trap_oob_lanes(c, ea, nbytes):
                 """Per-lane OOB trap plane write, only materialized on
                 the (rare) lane-0-oob path."""
-                @pl.when(oob0)
-                def _():
-                    pages = c[6]
-                    addr = ea - a_r[c[1]]
-                    carry_ = u_lt(ea, addr) | u_lt(ea, full(a_r[c[1]]))
-                    end = ea + nbytes
-                    oob = carry_ | u_lt(end, ea) | \
-                        u_lt(full(pages * I32(65536)), end)
-                    trap_where(oob, I32(int(ErrCode.MemoryOutOfBounds)))
+                pages = c[6]
+                addr = ea - a_r[c[1]]
+                carry_ = u_lt(ea, addr) | u_lt(ea, full(a_r[c[1]]))
+                end = ea + nbytes
+                oob = carry_ | u_lt(end, ea) | \
+                    u_lt(full(pages * I32(65536)), end)
+                trap_where(oob, I32(int(ErrCode.MemoryOutOfBounds)))
 
             def _mk_load_wd(is64):
                 nbytes = 8 if is64 else 4
@@ -2197,38 +2261,38 @@ def _build_kernel(used_hids: tuple, D: int, CD: int, W: int, L: int,
                     pc, sp = c[1], c[2]
                     ea, oob0, u, shB0, rhi = _opt_ls_scalar(
                         c, srow(slo, sp - 1), nbytes, want)
-                    dirty, snapped, way, wfs2 = _opt_window(c, u, rhi)
-                    inv = (32 - shB0) & 31
-                    hi_or = jnp.where(shB0 == 0, 0, -1)
+                    dirty, way, wfs2, ls2 = _opt_window(c, u, rhi)
+                    c2 = _keep_win(c, wfs2, ls=ls2)
 
-                    @pl.when(~dirty)
-                    def _():
-                        m0 = win_read_row(way, wfs2, u)
-                        m1 = win_read_row(way, wfs2,
-                                          jnp.minimum(u + 1, W - 1))
+                    def load():
+                        win = win_at(way, wfs2)
+                        inv = (32 - shB0) & 31
+                        hi_or = jnp.where(shB0 == 0, 0, -1)
+                        m0 = win_read_row(win, u)
+                        m1 = win_read_row(win, jnp.minimum(u + 1, W - 1))
                         ll = lax.shift_right_logical(m0, shB0) | \
                             (lax.shift_left(m1, inv) & hi_or)
                         wrow(slo, sp - 1, ll)
                         if is64:
-                            m2 = win_read_row(way, wfs2,
+                            m2 = win_read_row(win,
                                               jnp.minimum(u + 2, W - 1))
                             lh = lax.shift_right_logical(m1, shB0) | \
                                 (lax.shift_left(m2, inv) & hi_or)
                             wrow(shi, sp - 1, lh)
                         else:
                             wrow(shi, sp - 1, full(0))
-                        _opt_trap_oob(c, ea, nbytes, oob0)
 
-                    c2 = _keep_win(
-                        c, wfs2,
-                        ls=jnp.where(snapped, c[0], c[IDX["ls"]]))
-                    return lax.cond(
-                        dirty, rolled_carry,
-                        lambda: lax.cond(
-                            oob0,
-                            lambda: keep(c2, pc=pc + 1,
-                                         status=I32(ST_DIVERGED)),
-                            lambda: keep(c2, pc=pc + 1)))
+                    def go():
+                        load()
+                        return keep(c2, pc=pc + 1)
+
+                    def oob():
+                        load()
+                        _trap_oob_lanes(c, ea, nbytes)
+                        return keep(c2, pc=pc + 1,
+                                    status=I32(ST_DIVERGED))
+
+                    return _opt_leave(dirty, oob0, oob, go)
                 return h
 
             def _mk_store_wd(is64):
@@ -2240,39 +2304,27 @@ def _build_kernel(used_hids: tuple, D: int, CD: int, W: int, L: int,
                     vl, vh = srow(slo, sp - 1), srow(shi, sp - 1)
                     ea, oob0, u, shB0, rhi = _opt_ls_scalar(
                         c, srow(slo, sp - 2), nbytes, want)
-                    dirty, snapped, way, wfs2 = _opt_window(c, u, rhi)
+                    dirty, way, wfs2, ls2 = _opt_window(c, u, rhi)
                     m_lo = I32(-1)
                     m_hi = I32(-1) if is64 else I32(0)
                     triples = shifted_store_triples(m_lo, m_hi, vl, vh,
                                                     shB0)
+                    c2 = _keep_win(c, _win_dirtied(way, wfs2), ls=ls2)
 
-                    @pl.when(~dirty & ~oob0)
-                    def _():
+                    def go():
                         # common path: no lane traps assumed — write
                         # unmasked (a lane disagreeing on the address is
                         # already canary-marked and will roll back)
-                        for k, (m, v) in enumerate(triples):
-                            w = jnp.minimum(u + k, W - 1)
+                        win_store_words(win_at(way, wfs2), u, shB0,
+                                        triples, nbytes)
+                        return keep(c2, pc=pc + 1, sp=sp - 2)
 
-                            @pl.when(m != 0)
-                            def _(m=m, v=v, w=w):
-                                cur = win_read_row(way, wfs2, w)
-                                win_write_row(way, wfs2, w,
-                                              (cur & ~m) | (v & m))
+                    def oob():
+                        _trap_oob_lanes(c, ea, nbytes)
+                        return keep(c2, pc=pc + 1, sp=sp - 2,
+                                    status=I32(ST_DIVERGED))
 
-                    _opt_trap_oob(c, ea, nbytes, oob0 & ~dirty)
-                    nwd0 = jnp.where(way == 0, I32(1), wfs2[1])
-                    nwd1 = jnp.where(way == 1, I32(1), wfs2[3])
-                    c2 = keep(c, wb0=wfs2[0], wd0=nwd0, wb1=wfs2[2],
-                              wd1=nwd1, mru=wfs2[4],
-                              ls=jnp.where(snapped, c[0], c[IDX["ls"]]))
-                    return lax.cond(
-                        dirty, rolled_carry,
-                        lambda: lax.cond(
-                            oob0,
-                            lambda: keep(c2, pc=pc + 1, sp=sp - 2,
-                                         status=I32(ST_DIVERGED)),
-                            lambda: keep(c2, pc=pc + 1, sp=sp - 2)))
+                    return _opt_leave(dirty, oob0, oob, go)
                 return h
 
             h_load_w = _mk_load_wd(False)
@@ -2285,28 +2337,28 @@ def _build_kernel(used_hids: tuple, D: int, CD: int, W: int, L: int,
                     pc, sp = c[1], c[2]
                     oob, oob0, u, shB0, rhi, _nb = _opt_ls_prolog(
                         c, srow(slo, sp - 1), 2)
-                    dirty, snapped, way, wfs2 = _opt_window(c, u, rhi)
+                    dirty, way, wfs2, ls2 = _opt_window(c, u, rhi)
+                    c2 = _keep_win(c, wfs2, ls=ls2)
 
-                    @pl.when(~dirty)
-                    def _():
-                        _load_finish(
-                            c, win_read_row(way, wfs2, u),
-                            win_read_row(way, wfs2,
-                                         jnp.minimum(u + 1, W - 1)),
-                            win_read_row(way, wfs2,
-                                         jnp.minimum(u + 2, W - 1)),
-                            shB0, oob, oob0)
+                    def load():
+                        win = win_at(way, wfs2)
+                        _load_put(
+                            c, win_read_row(win, u),
+                            win_read_row(win, jnp.minimum(u + 1, W - 1)),
+                            win_read_row(win, jnp.minimum(u + 2, W - 1)),
+                            shB0)
 
-                    c2 = _keep_win(
-                        c, wfs2,
-                        ls=jnp.where(snapped, c[0], c[IDX["ls"]]))
-                    return lax.cond(
-                        dirty, rolled_carry,
-                        lambda: lax.cond(
-                            oob0,
-                            lambda: keep(c2, pc=pc + 1,
-                                         status=I32(ST_DIVERGED)),
-                            lambda: keep(c2, pc=pc + 1)))
+                    def go():
+                        load()
+                        return keep(c2, pc=pc + 1)
+
+                    def oob_():
+                        load()
+                        trap_where(oob, I32(int(ErrCode.MemoryOutOfBounds)))
+                        return keep(c2, pc=pc + 1,
+                                    status=I32(ST_DIVERGED))
+
+                    return _opt_leave(dirty, oob0, oob_, go)
                 pc, sp, pages = c[1], c[2], c[6]
                 off, nbytes = a_r[pc], b_r[pc]
                 addr = srow(slo, sp - 1)
@@ -2325,21 +2377,23 @@ def _build_kernel(used_hids: tuple, D: int, CD: int, W: int, L: int,
                 u0 = scal(widx)
                 uni = allsame(widx, u0) & allsame(shB, scal(shB))
 
+                win = win_at(way, wfs)
+
                 @pl.when(fits & uni)
                 def _():
                     _load_finish(
-                        c, win_read_row(way, wfs, u0),
-                        win_read_row(way, wfs, jnp.minimum(u0 + 1, W - 1)),
-                        win_read_row(way, wfs, jnp.minimum(u0 + 2, W - 1)),
+                        c, win_read_row(win, u0),
+                        win_read_row(win, jnp.minimum(u0 + 1, W - 1)),
+                        win_read_row(win, jnp.minimum(u0 + 2, W - 1)),
                         shB, oob, any_oob)
 
                 @pl.when(fits & ~uni)
                 def _():
                     w1 = jnp.clip(widx + 1, 0, W - 1)
                     w2 = jnp.clip(widx + 2, 0, W - 1)
-                    _load_finish(c, _win_gather(way, wfs, widx),
-                                 _win_gather(way, wfs, w1),
-                                 _win_gather(way, wfs, w2),
+                    _load_finish(c, _win_gather(win, widx),
+                                 _win_gather(win, w1),
+                                 _win_gather(win, w2),
                                  shB, oob, any_oob)
 
                 c = _keep_win(c, wfs)
@@ -2358,40 +2412,40 @@ def _build_kernel(used_hids: tuple, D: int, CD: int, W: int, L: int,
                     oob, oob0, u, shB0, rhi, nbytes = _opt_ls_prolog(
                         c, srow(slo, sp - 2), 2)
                     ok = ~oob
-                    dirty, snapped, way, wfs2 = _opt_window(c, u, rhi)
+                    dirty, way, wfs2, ls2 = _opt_window(c, u, rhi)
                     b1 = nbytes == 1
                     b2_ = nbytes == 2
                     m_lo = jnp.where(b1, I32(0xFF),
                                      jnp.where(b2_, I32(0xFFFF), I32(-1)))
                     m_hi = jnp.where(nbytes == 8, I32(-1), I32(0))
-                    for k, (m, v) in enumerate(
-                            shifted_store_triples(m_lo, m_hi, vl, vh,
-                                                  shB0)):
-                        w = jnp.minimum(u + k, W - 1)
+                    triples = shifted_store_triples(m_lo, m_hi, vl, vh,
+                                                    shB0)
+                    c2 = _keep_win(c, _win_dirtied(way, wfs2), ls=ls2)
 
-                        @pl.when(~dirty & (m != 0))
-                        def _(m=m, v=v, w=w):
-                            cur = win_read_row(way, wfs2, w)
-                            win_write_row(
-                                way, wfs2, w,
-                                jnp.where(ok, (cur & ~m) | (v & m), cur))
+                    def store():
+                        win = win_at(way, wfs2)
+                        for k, (m, v) in enumerate(triples):
+                            w = jnp.minimum(u + k, W - 1)
 
-                    @pl.when(~dirty & oob0)
-                    def _():
+                            @pl.when(m != 0)
+                            def _(m=m, v=v, w=w):
+                                cur = win_read_row(win, w)
+                                win_write_row(
+                                    win, w,
+                                    jnp.where(ok, (cur & ~m) | (v & m),
+                                              cur))
+
+                    def go():
+                        store()
+                        return keep(c2, pc=pc + 1, sp=sp - 2)
+
+                    def oob_():
+                        store()
                         trap_where(oob, I32(int(ErrCode.MemoryOutOfBounds)))
+                        return keep(c2, pc=pc + 1, sp=sp - 2,
+                                    status=I32(ST_DIVERGED))
 
-                    nwd0 = jnp.where(way == 0, I32(1), wfs2[1])
-                    nwd1 = jnp.where(way == 1, I32(1), wfs2[3])
-                    c2 = keep(c, wb0=wfs2[0], wd0=nwd0, wb1=wfs2[2],
-                              wd1=nwd1, mru=wfs2[4],
-                              ls=jnp.where(snapped, c[0], c[IDX["ls"]]))
-                    return lax.cond(
-                        dirty, rolled_carry,
-                        lambda: lax.cond(
-                            oob0,
-                            lambda: keep(c2, pc=pc + 1, sp=sp - 2,
-                                         status=I32(ST_DIVERGED)),
-                            lambda: keep(c2, pc=pc + 1, sp=sp - 2)))
+                    return _opt_leave(dirty, oob0, oob_, go)
                 pc, sp, pages = c[1], c[2], c[6]
                 off, nbytes = a_r[pc], b_r[pc]
                 vl, vh = srow(slo, sp - 1), srow(shi, sp - 1)
@@ -2421,6 +2475,8 @@ def _build_kernel(used_hids: tuple, D: int, CD: int, W: int, L: int,
                 u0 = scal(widx)
                 uni = allsame(widx, u0) & allsame(shB, scal(shB))
 
+                win = win_at(way, wfs)
+
                 @pl.when(fits & uni)
                 def _():
                     for k, (m, v) in enumerate(((sm0, sv0), (sm1, sv1),
@@ -2429,37 +2485,24 @@ def _build_kernel(used_hids: tuple, D: int, CD: int, W: int, L: int,
 
                         @pl.when(jnp.any(m != 0))
                         def _(m=m, v=v, w=w):
-                            cur = win_read_row(way, wfs, w)
+                            cur = win_read_row(win, w)
                             win_write_row(
-                                way, wfs, w,
+                                win, w,
                                 jnp.where(ok & (m != 0),
                                           (cur & ~m) | (v & m), cur))
 
                 @pl.when(fits & ~uni)
                 def _():
-                    base = jnp.where(way == 0, wfs[0], wfs[2])
-                    wi = riota(CW) + base
+                    wi = riota(CW) + win[1]
                     for k, (m, v) in enumerate(((sm0, sv0), (sm1, sv1),
                                                 (sm2, sv2))):
                         wk = jnp.clip(widx + k, 0, W - 1)
                         hit = (wi == wk) & (ok & (m != 0))
+                        cur = srows(mwin, a8(win[0]), CW)
+                        wrows(mwin, a8(win[0]), CW, jnp.where(
+                            hit, (cur & ~m) | (v & m), cur))
 
-                        @pl.when(way == 0)
-                        def _(hit=hit, m=m, v=v):
-                            cur = srows(mwin0, 0, CW)
-                            wrows(mwin0, 0, CW, jnp.where(
-                                hit, (cur & ~m) | (v & m), cur))
-
-                        @pl.when(way == 1)
-                        def _(hit=hit, m=m, v=v):
-                            cur = srows(mwin1, 0, CW)
-                            wrows(mwin1, 0, CW, jnp.where(
-                                hit, (cur & ~m) | (v & m), cur))
-
-                nwd0 = jnp.where(fits & (way == 0), I32(1), wfs[1])
-                nwd1 = jnp.where(fits & (way == 1), I32(1), wfs[3])
-                c = keep(c, wb0=wfs[0], wd0=nwd0, wb1=wfs[2], wd1=nwd1,
-                         mru=wfs[4])
+                c = _keep_win(c, _win_dirtied(way, wfs, fits))
 
                 @pl.when(fits & any_oob)
                 def _():
@@ -2502,10 +2545,10 @@ def _build_kernel(used_hids: tuple, D: int, CD: int, W: int, L: int,
                 def chunk(i, _):
                     base = a8(i * GR)
                     cin = dma(6, lsliceR(mem_out, base, GR),
-                              mwin0.at[pl.ds(0, GR)])
+                              mwin.at[pl.ds(0, GR)])
                     cin.start()
                     cin.wait()
-                    rows = srows(mwin0, 0, GR)
+                    rows = srows(mwin, 0, GR)
                     wi = base + riota(GR)
                     byte0 = wi * 4
                     mask = jnp.zeros_like(rows)
@@ -2515,9 +2558,9 @@ def _build_kernel(used_hids: tuple, D: int, CD: int, W: int, L: int,
                         mask = mask | jnp.where(
                             inr, jnp.int32(lo_ops.BYTE_MASKS[bpos]), 0)
                     write = (mask != 0) & go
-                    wrows(mwin0, 0, GR, jnp.where(
+                    wrows(mwin, 0, GR, jnp.where(
                         write, (rows & ~mask) | (fill_word & mask), rows))
-                    cout = dma(6, mwin0.at[pl.ds(0, GR)],
+                    cout = dma(6, mwin.at[pl.ds(0, GR)],
                                lsliceR(mem_out, base, GR))
                     cout.start()
                     cout.wait()
@@ -2596,28 +2639,25 @@ def _build_kernel(used_hids: tuple, D: int, CD: int, W: int, L: int,
                 useA = agree & one_win & (nrows > 0)
                 wayA, wfsA = _win_select(_wfs_of(c), lo_all, hi_all, useA)
 
+                winA = win_at(wayA, wfsA)
+
                 def bodyA(i, _):
                     r = jnp.where(fwd, row_lo + i, row_hi - 1 - i)
                     rc = jnp.clip(r, 0, W - 1)
-                    m0 = win_read_row(wayA, wfsA,
-                                      jnp.clip(r + qv, 0, W - 1))
-                    m1 = win_read_row(wayA, wfsA,
+                    m0 = win_read_row(winA, jnp.clip(r + qv, 0, W - 1))
+                    m1 = win_read_row(winA,
                                       jnp.clip(r + qv + 1, 0, W - 1))
                     val = shift_val(m0, m1)
                     mask = row_mask(r)
-                    old = win_read_row(wayA, wfsA, rc)
+                    old = win_read_row(winA, rc)
                     win_write_row(
-                        wayA, wfsA, rc,
+                        winA, rc,
                         jnp.where(mask != 0, (old & ~mask) | (val & mask),
                                   old))
                     return 0
 
                 lax.fori_loop(0, jnp.where(useA, nrows, 0), bodyA, 0)
-                wfsA = (wfsA[0],
-                        jnp.where(useA & (wayA == 0), I32(1), wfsA[1]),
-                        wfsA[2],
-                        jnp.where(useA & (wayA == 1), I32(1), wfsA[3]),
-                        wfsA[4])
+                wfsA = _win_dirtied(wayA, wfsA, useA)
 
                 useB = agree & ~one_win & disjoint & (nrows > 0)
 
@@ -2628,22 +2668,20 @@ def _build_kernel(used_hids: tuple, D: int, CD: int, W: int, L: int,
                     ws, wfs = _win_select(wfs, jnp.minimum(rs0, rs1),
                                           jnp.maximum(rs0, rs1),
                                           jnp.bool_(True))
-                    m0 = win_read_row(ws, wfs, rs0)
-                    m1 = win_read_row(ws, wfs, rs1)
+                    src = win_at(ws, wfs)
+                    m0 = win_read_row(src, rs0)
+                    m1 = win_read_row(src, rs1)
                     val = shift_val(m0, m1)
                     rc = jnp.clip(r, 0, W - 1)
                     wd_, wfs = _win_select(wfs, rc, rc, jnp.bool_(True))
+                    dst_ = win_at(wd_, wfs)
                     mask = row_mask(r)
-                    old = win_read_row(wd_, wfs, rc)
+                    old = win_read_row(dst_, rc)
                     win_write_row(
-                        wd_, wfs, rc,
+                        dst_, rc,
                         jnp.where(mask != 0, (old & ~mask) | (val & mask),
                                   old))
-                    return (wfs[0],
-                            jnp.where(wd_ == 0, I32(1), wfs[1]),
-                            wfs[2],
-                            jnp.where(wd_ == 1, I32(1), wfs[3]),
-                            wfs[4])
+                    return _win_dirtied(wd_, wfs)
 
                 wfsB = lax.fori_loop(0, jnp.where(useB, nrows, 0), bodyB,
                                      wfsA)
@@ -3109,27 +3147,26 @@ def _build_kernel(used_hids: tuple, D: int, CD: int, W: int, L: int,
                             vs_pre.flush()
                             cb_snap = keep(cb, steps=cb[0] + j.r,
                                            pc=pcj, sp=vs_pre.sp())
-                            dirty, snapped, way, wfs2 = _opt_window(
+                            dirty, way, wfs2, ls2 = _opt_window(
                                 cb_snap, u, rhi)
-                            cb2 = _keep_win(
-                                cb, wfs2,
-                                ls=jnp.where(snapped, cb[0] + j.r,
-                                             cb[IDX["ls"]]))
-                            m0 = win_read_row(way, wfs2, u)
-                            m1 = win_read_row(way, wfs2,
-                                              jnp.minimum(u + 1, W - 1))
-                            m2 = win_read_row(way, wfs2,
-                                              jnp.minimum(u + 2, W - 1)) \
-                                if nbytes == 8 else None
-                            vs2 = vs.push(cell2(*_load_val(
-                                m0, m1, m2, shB, nbytes, flags)))
-                            return lax.cond(
-                                dirty, rolled_carry,
-                                lambda: lax.cond(
-                                    oob0,
-                                    lambda: bail(cb2, j, vs_pre),
-                                    lambda: emit(j.next(), cb2, vs2,
-                                                 pend_l, pend_g)))
+                            cb2 = _keep_win(cb, wfs2, ls=ls2)
+
+                            def go():
+                                win = win_at(way, wfs2)
+                                m0 = win_read_row(win, u)
+                                m1 = win_read_row(
+                                    win, jnp.minimum(u + 1, W - 1))
+                                m2 = win_read_row(
+                                    win, jnp.minimum(u + 2, W - 1)) \
+                                    if nbytes == 8 else None
+                                vs2 = vs.push(cell2(*_load_val(
+                                    m0, m1, m2, shB, nbytes, flags)))
+                                return emit(j.next(), cb2, vs2,
+                                            pend_l, pend_g)
+
+                            return _opt_leave(
+                                dirty, oob0,
+                                lambda: bail(cb2, j, vs_pre), go)
                         m0 = srow(memr, u)
                         m1 = srow(memr, jnp.minimum(u + 1, W - 1))
                         m2 = srow(memr, jnp.minimum(u + 2, W - 1)) \
@@ -3188,31 +3225,21 @@ def _build_kernel(used_hids: tuple, D: int, CD: int, W: int, L: int,
                             vs_pre.flush()
                             cb_snap = keep(cb, steps=cb[0] + j.r,
                                            pc=pcj, sp=vs_pre.sp())
-                            dirty, snapped, way, wfs2 = _opt_window(
+                            dirty, way, wfs2, ls2 = _opt_window(
                                 cb_snap, u, rhi)
-                            okw = ~dirty & ~oob0
-                            for k, (m, v) in enumerate(masks_vals(shB)):
-                                w = jnp.minimum(u + k, W - 1)
+                            cb2 = _keep_win(
+                                cb, _win_dirtied(way, wfs2), ls=ls2)
 
-                                @pl.when(okw & (m != 0))
-                                def _(m=m, v=v, w=w):
-                                    cur = win_read_row(way, wfs2, w)
-                                    win_write_row(way, wfs2, w,
-                                                  (cur & ~m) | (v & m))
+                            def go():
+                                win_store_words(
+                                    win_at(way, wfs2), u, shB,
+                                    masks_vals(shB), nbytes)
+                                return emit(j.next(), cb2, vs,
+                                            pend_l, pend_g)
 
-                            nwd0 = jnp.where(way == 0, I32(1), wfs2[1])
-                            nwd1 = jnp.where(way == 1, I32(1), wfs2[3])
-                            cb2 = keep(cb, wb0=wfs2[0], wd0=nwd0,
-                                       wb1=wfs2[2], wd1=nwd1, mru=wfs2[4],
-                                       ls=jnp.where(snapped, cb[0] + j.r,
-                                                    cb[IDX["ls"]]))
-                            return lax.cond(
-                                dirty, rolled_carry,
-                                lambda: lax.cond(
-                                    oob0,
-                                    lambda: bail(cb2, j, vs_pre),
-                                    lambda: emit(j.next(), cb2, vs,
-                                                 pend_l, pend_g)))
+                            return _opt_leave(
+                                dirty, oob0,
+                                lambda: bail(cb2, j, vs_pre), go)
                         for k, (m, v) in enumerate(masks_vals(shB)):
                             w = jnp.minimum(u + k, W - 1)
 
@@ -3371,13 +3398,12 @@ def _build_kernel(used_hids: tuple, D: int, CD: int, W: int, L: int,
                 wrow4(sp - 3, r)
                 return keep(c, pc=pc + 1, sp=sp - 2)
 
-            def _vmem_rows(cb, u, n_rows, wfs_sel):
+            def _vmem_rows(u, n_rows, win):
                 """Read n_rows consecutive memory words starting at
-                scalar row u (resident rows or window rows)."""
+                scalar row u (resident rows, or rows of the resident
+                way `win`)."""
                 if mem_hbm:
-                    way, wfs2 = wfs_sel
-                    return [win_read_row(way, wfs2,
-                                         jnp.minimum(u + k, W - 1))
+                    return [win_read_row(win, jnp.minimum(u + k, W - 1))
                             for k in range(n_rows)]
                 return [srow(memr, jnp.minimum(u + k, W - 1))
                         for k in range(n_rows)]
@@ -3402,25 +3428,19 @@ def _build_kernel(used_hids: tuple, D: int, CD: int, W: int, L: int,
                         ea, off, 16, c[6])
                     if mem_hbm:
                         rhi = jnp.minimum(u + 4, W - 1)
-                        dirty, snapped, way, wfs2 = _opt_window(
-                            c, u, rhi)
-                        m = _vmem_rows(c, u, 5, (way, wfs2))
+                        dirty, way, wfs2, ls2 = _opt_window(c, u, rhi)
+                        c2 = _keep_win(c, wfs2, ls=ls2)
 
-                        @pl.when(~dirty & ~oob0)
-                        def _():
+                        def go():
+                            m = _vmem_rows(u, 5, win_at(way, wfs2))
                             wrow4(sp - 1, _v128_from_words(m, shB))
+                            return keep(c2, pc=pc + 1)
 
-                        c2 = _keep_win(
-                            c, wfs2,
-                            ls=jnp.where(snapped, c[0], c[IDX["ls"]]))
-                        return lax.cond(
-                            dirty, rolled_carry,
-                            lambda: lax.cond(
-                                oob0,
-                                lambda: keep(c2,
-                                             status=I32(ST_DIVERGED)),
-                                lambda: keep(c2, pc=pc + 1)))
-                    m = _vmem_rows(c, u, 5, None)
+                        return _opt_leave(
+                            dirty, oob0,
+                            lambda: keep(c2, status=I32(ST_DIVERGED)),
+                            go)
+                    m = _vmem_rows(u, 5, None)
 
                     @pl.when(~oob0)
                     def _():
@@ -3447,10 +3467,10 @@ def _build_kernel(used_hids: tuple, D: int, CD: int, W: int, L: int,
                     rhi = jnp.minimum(u0 + 4, W - 1)
                     way, wfs = _win_select(_wfs_of(c), u0, rhi, ok)
                     c2 = _keep_win(c, wfs)
-                    m = _vmem_rows(c2, u0, 5, (way, wfs))
+                    m = _vmem_rows(u0, 5, win_at(way, wfs))
                 else:
                     c2 = c
-                    m = _vmem_rows(c2, u0, 5, None)
+                    m = _vmem_rows(u0, 5, None)
 
                 @pl.when(ok)
                 def _():
@@ -3489,11 +3509,9 @@ def _build_kernel(used_hids: tuple, D: int, CD: int, W: int, L: int,
                         @pl.when(okp & (mmask != 0))
                         def _(v=v, mmask=mmask, w=w):
                             if mem_hbm:
-                                way, wfs2 = win
-                                cur = win_read_row(way, wfs2, w)
+                                cur = win_read_row(win, w)
                                 win_write_row(
-                                    way, wfs2, w,
-                                    (cur & ~mmask) | (v & mmask))
+                                    win, w, (cur & ~mmask) | (v & mmask))
                             else:
                                 cur = srow(memr, w)
                                 wrow(memr, w,
@@ -3504,23 +3522,18 @@ def _build_kernel(used_hids: tuple, D: int, CD: int, W: int, L: int,
                         ea, off, 16, c[6])
                     if mem_hbm:
                         rhi = jnp.minimum(u + 4, W - 1)
-                        dirty, snapped, way, wfs2 = _opt_window(
-                            c, u, rhi)
-                        commit(u, shB, ~dirty & ~oob0, (way, wfs2))
-                        nwd0 = jnp.where(way == 0, I32(1), wfs2[1])
-                        nwd1 = jnp.where(way == 1, I32(1), wfs2[3])
-                        c2 = keep(c, wb0=wfs2[0], wd0=nwd0,
-                                  wb1=wfs2[2], wd1=nwd1, mru=wfs2[4],
-                                  ls=jnp.where(snapped, c[0],
-                                               c[IDX["ls"]]))
-                        return lax.cond(
-                            dirty, rolled_carry,
-                            lambda: lax.cond(
-                                oob0,
-                                lambda: keep(c2,
-                                             status=I32(ST_DIVERGED)),
-                                lambda: keep(c2, pc=pc + 1,
-                                             sp=sp - 2)))
+                        dirty, way, wfs2, ls2 = _opt_window(c, u, rhi)
+                        c2 = _keep_win(c, _win_dirtied(way, wfs2),
+                                       ls=ls2)
+
+                        def go():
+                            commit(u, shB, True, win_at(way, wfs2))
+                            return keep(c2, pc=pc + 1, sp=sp - 2)
+
+                        return _opt_leave(
+                            dirty, oob0,
+                            lambda: keep(c2, status=I32(ST_DIVERGED)),
+                            go)
                     commit(u, shB, ~oob0, None)
                     return lax.cond(
                         oob0,
@@ -3540,11 +3553,8 @@ def _build_kernel(used_hids: tuple, D: int, CD: int, W: int, L: int,
                 if mem_hbm:
                     rhi = jnp.minimum(u0 + 4, W - 1)
                     way, wfs = _win_select(_wfs_of(c), u0, rhi, ok)
-                    commit(u0, shB, ok, (way, wfs))
-                    nwd0 = jnp.where(ok & (way == 0), I32(1), wfs[1])
-                    nwd1 = jnp.where(ok & (way == 1), I32(1), wfs[3])
-                    c2 = keep(c, wb0=wfs[0], wd0=nwd0, wb1=wfs[2],
-                              wd1=nwd1, mru=wfs[4])
+                    commit(u0, shB, ok, win_at(way, wfs))
+                    c2 = _keep_win(c, _win_dirtied(way, wfs, ok))
                 else:
                     commit(u0, shB, ok, None)
                     c2 = c
@@ -3645,11 +3655,11 @@ def _build_kernel(used_hids: tuple, D: int, CD: int, W: int, L: int,
                     # HBM plane IS the snapshot's memory state
                     @pl.when(c[IDX["wd0"]] != 0)
                     def _():
-                        _wb_way0(c[IDX["wb0"]])
+                        _wb_way(0, c[IDX["wb0"]])
 
                     @pl.when(c[IDX["wd1"]] != 0)
                     def _():
-                        _wb_way1(c[IDX["wb1"]])
+                        _wb_way(1, c[IDX["wb1"]])
 
                     kw.update(wd0=I32(0), wd1=I32(0))
                 do_snapshot(c)
@@ -3755,11 +3765,11 @@ def _build_kernel(used_hids: tuple, D: int, CD: int, W: int, L: int,
 
             @pl.when(wd0f != 0)
             def _():
-                _wb_way0(wb0f)
+                _wb_way(0, wb0f)
 
             @pl.when(wd1f != 0)
             def _():
-                _wb_way1(wb1f)
+                _wb_way(1, wb1f)
         exhausted = (status == I32(ST_RUNNING)) & (steps >= fuel_in)
         status = jnp.where(
             exhausted,
@@ -3791,6 +3801,7 @@ def _build_kernel(used_hids: tuple, D: int, CD: int, W: int, L: int,
         if mem_hbm:
             ctrl_out[blk, _C_WFILLS] = wcnt[0]
             ctrl_out[blk, _C_WWBS] = wcnt[1]
+            ctrl_out[blk, _C_WACCESSES] = wacc[0, 0]
 
         outs = [dma(0, slo, lslice(s_lo_out)),
                 dma(1, shi, lslice(s_hi_out)),
@@ -3851,9 +3862,9 @@ def _build_kernel(used_hids: tuple, D: int, CD: int, W: int, L: int,
                if simd else [])
             + [vmem_rows(NGp),                          # glo
                vmem_rows(NGp)]                          # ghi
-            + ([vmem_rows(CW),                          # mwin0 (way 0)
-                vmem_rows(CW),                          # mwin1 (way 1)
-                pltpu.SMEM((2,), jnp.int32)]            # wcnt (DMA counts)
+            + ([vmem_rows(2 * CW),                      # mwin (two ways)
+                pltpu.SMEM((2,), jnp.int32),            # wcnt (DMA counts)
+                pltpu.VMEM((8, 128), jnp.int32)]        # wacc (accesses)
                if mem_hbm else
                [vmem_rows(W)])                          # memr (resident)
             + [vmem_rows(1),                            # trapr
@@ -4013,9 +4024,13 @@ class PallasUniformEngine:
         self.mem_static = None
         # the hbm_window kernel's DMA counts over the last run(): window
         # fills and dirty write-backs, HBM_WINDOW_ROWS rows x the lane
-        # block each (the careful recheck kernel's included)
+        # block each (the careful recheck kernel's included); the loads
+        # and stores it resolved against the window, and the share of
+        # them that found their rows resident (1 - fills / accesses)
         self.window_fills = 0
         self.window_writebacks = 0
+        self.window_accesses = 0
+        self.window_hit_share = None
         # handlers the kernels dispatched over the last run(), summed
         # over blocks and launches, and the block-steps a dispatch
         # retired (3.5 in fib before superblocks, 5.25 with them)
@@ -4653,8 +4668,13 @@ class PallasUniformEngine:
         self.mem_static = sched.eng.mem_static
         self.window_fills = sched.window_fills
         self.window_writebacks = sched.window_writebacks
+        self.window_accesses = sched.window_accesses
+        self.window_hit_share = \
+            1 - sched.window_fills / sched.window_accesses \
+            if sched.window_accesses else None
         self.obs.add_window_counts(sched.window_fills,
-                                   sched.window_writebacks)
+                                   sched.window_writebacks,
+                                   sched.window_accesses)
         self.superblock_edges = sched.eng.superblock_edges
         self.dispatches = sched.dispatches
         self.instr_per_dispatch = sched.kernel_steps / sched.dispatches \

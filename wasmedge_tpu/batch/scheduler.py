@@ -67,6 +67,7 @@ from wasmedge_tpu.batch.pallas_engine import (
     _C_SP,
     _C_STATUS,
     _C_STEPS,
+    _C_WACCESSES,
     _C_WFILLS,
     _C_WWBS,
     _FUEL_OFF,
@@ -181,10 +182,12 @@ class BlockScheduler:
         self.retired = np.zeros(self.lanes, np.int64)
         self.fell_back_to_simt = False
         self.splits = 0
-        # the hbm_window kernel's DMA counts, summed over blocks and
-        # launches (zero in every other memory mode)
+        # the hbm_window kernel's DMA counts and the accesses it
+        # resolved against the window, summed over blocks and launches
+        # (zero in every other memory mode)
         self.window_fills = 0
         self.window_writebacks = 0
+        self.window_accesses = 0
         # handlers the kernels dispatched and the block-steps they
         # retired doing it, summed over blocks and launches (the
         # careful recheck's too)
@@ -510,6 +513,8 @@ class BlockScheduler:
         if self.eng.mem_static.get("mem_mode") == "hbm_window":
             self.window_fills += int(ctrl_np[blocks, _C_WFILLS].sum())
             self.window_writebacks += int(ctrl_np[blocks, _C_WWBS].sum())
+            self.window_accesses += int(
+                ctrl_np[blocks, _C_WACCESSES].sum())
 
     def _run_recheck(self, live) -> np.ndarray:
         """Re-run ST_RECHECK blocks on the careful kernel (synchronous)

@@ -990,6 +990,9 @@ class UniformBatchEngine:
             ipd = getattr(self.pallas, "instr_per_dispatch", None)
             if ipd is not None:
                 span.set(instr_per_dispatch=round(ipd, 4))
+            whs = getattr(self.pallas, "window_hit_share", None)
+            if whs is not None:
+                span.set(window_hit_share=round(whs, 6))
             return res
 
     def _kernel_args(self):

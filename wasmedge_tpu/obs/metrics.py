@@ -619,6 +619,12 @@ def render_prometheus(recorder=None, stats=None, hostcall_stats=None,
                    "to the memory plane, same size as a fill.")
             w.sample("wasmedge_hbm_window_writebacks_total", None,
                      wc["writebacks"])
+            w.head("wasmedge_hbm_window_accesses_total", "counter",
+                   "Loads and stores the hbm_window kernel resolved "
+                   "against the window, a lane block at a time; "
+                   "all but the fills found their rows resident.")
+            w.sample("wasmedge_hbm_window_accesses_total", None,
+                     wc["accesses"])
         if recorder.opcode_counts is not None:
             from wasmedge_tpu.validator.image import lop_name
 

@@ -203,7 +203,7 @@ class NullRecorder:
     def set_memory_static(self, static):
         pass
 
-    def add_window_counts(self, fills, writebacks):
+    def add_window_counts(self, fills, writebacks, accesses):
         pass
 
     def set_superblock_static(self, edges):
@@ -308,7 +308,7 @@ class FlightRecorder:
         # memory), and the hbm_window kernel's DMA counts folded after
         # each run
         self.memory_static = None
-        self.window_counts = {"fills": 0, "writebacks": 0}
+        self.window_counts = {"fills": 0, "writebacks": 0, "accesses": 0}
         # forward edges the newest Pallas kernel's blocks run through
         # ({"jump", "guard_tail"}), and the handlers its loop dispatched
         self.superblock_static = None
@@ -451,12 +451,14 @@ class FlightRecorder:
         says what the keys are)."""
         self.memory_static = static
 
-    def add_window_counts(self, fills, writebacks):
-        """Fold the hbm_window kernel's DMA counts of one run (window
-        fills and dirty write-backs, summed over blocks and launches by
+    def add_window_counts(self, fills, writebacks, accesses):
+        """Fold the hbm_window kernel's counts of one run (window fills
+        and dirty write-backs, and the loads and stores it resolved
+        against the window, summed over blocks and launches by
         batch/scheduler.py)."""
         self.window_counts["fills"] += int(fills)
         self.window_counts["writebacks"] += int(writebacks)
+        self.window_counts["accesses"] += int(accesses)
 
     def set_superblock_static(self, edges):
         """Record the forward edges the newest Pallas kernel's blocks
