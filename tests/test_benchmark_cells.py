@@ -32,18 +32,39 @@ def _cell(name):
             _load(BENCH, "workloads", name + ".json"))
 
 
+# what the cell that splits in flight reports for itself (PR 32): the two
+# kernels apart, the phases of a split, and the counts a job
+SPLIT_CELL_METRICS = [
+    "kernel_optimistic_ms.batch", "kernel_careful_ms.batch",
+    "recheck_ms.batch", "split_ms.batch", "install_ms.batch",
+    "launches_per_job.batch", "careful_steps_per_job.batch"]
+
+
 def test_the_manifest_lists_the_batch_cells():
     assert CELLS == ["batch-fib30-uniform", "batch-mem-uniform",
-                     "batch-fib-divergent"]
+                     "batch-fib-divergent", "batch-fib-split"]
     used = {w["config"] for w in MANIFEST["workloads"]}
     assert used == {c["name"] for c in MANIFEST["configs"]}
     # every batch cell reports what the uniform fib cell reports
     metrics = MANIFEST["end_to_end"] + MANIFEST["per_layer"]
-    for cell in CELLS[1:]:
-        assert [m["name"] for m in metrics
-                if cell in m.get("workloads", ())] == [
-            m["name"] for m in metrics
-            if CELLS[0] in m.get("workloads", ())]
+
+    def reported(cell):
+        return [m["name"] for m in metrics
+                if cell in m.get("workloads", ())]
+
+    for cell in CELLS[1:3]:
+        assert reported(cell) == reported(CELLS[0])
+    # but for one difference in the cell that splits: ` custom-call$`
+    # would add the careful kernel's time to the optimistic one's and
+    # `trace_steps` is the longest block's, so `kernel_ns_per_step.batch`
+    # gives way to the two kernels' milliseconds a job and five more
+    assert reported("batch-fib-split") == [
+        m for m in reported(CELLS[0]) if m != "kernel_ns_per_step.batch"
+    ] + SPLIT_CELL_METRICS
+    for m in MANIFEST["per_layer"]:
+        if m["name"] in SPLIT_CELL_METRICS:
+            assert m["workloads"] == ["batch-fib-split"]
+            assert m["moves"] == "batch_ginstr_per_s"
 
 
 @pytest.mark.parametrize("name", CELLS)
@@ -63,7 +84,10 @@ def test_cell_finds_its_files(name):
             reported += 1
             if group == "per_layer":
                 spec = _load(BENCH, "layer_metrics", m["name"] + ".json")
-                assert config["driver"] in spec["drivers"], m["name"]
+                # a metric's file names the driver families it fits; a
+                # driver built on another names that one as its `family`
+                assert {config["driver"], config.get("family")} \
+                    & set(spec["drivers"]), m["name"]
                 assert os.path.isfile(os.path.join(
                     BENCH, "readers", spec["reader"] + ".py")), m["name"]
                 assert {k: spec[k] for k in ("layer", "unit", "better",
@@ -84,7 +108,9 @@ def test_batch_cell_names_a_guest_the_program_has(name):
     assert workload["traffic"]["func"] == config["guest"]["export"]
     assert set(config["geometry"]) == {
         "value_stack_depth", "call_stack_depth", "steps_per_launch"}
-    assert len(config["guarantees"]) == 3
+    # exact results, completion, the scalar engine's count; the cell
+    # that splits adds that nothing falls back to the per-step engine
+    assert len(config["guarantees"]) == (4 if "split" in name else 3)
 
 
 # (length, sha256) of the two guests that moved out of the root's
@@ -124,7 +150,8 @@ def _fib(n):
 
 @pytest.mark.parametrize("name,args", [
     ("batch-fib30-uniform", [30]),
-    ("batch-fib-divergent", list(range(20, 31)))])
+    ("batch-fib-divergent", list(range(20, 31))),
+    ("batch-fib-split", list(range(20, 31)))])
 def test_fib_cells_pin_the_guests_instruction_count(name, args):
     """A leaf call of build_fib retires 7 instructions and an inner call
     14; fib(n) makes F(n+1) leaf calls and F(n+1) - 1 inner ones."""
@@ -157,6 +184,12 @@ def test_the_count_the_fib_cells_pin_is_the_scalar_engines():
         Loader(conf).parse_module(build_fib())))
     ex.invoke_raw(store, inst.find_func("fib"), [15])
     assert stat.instr_count == 21 * _fib(16) - 14
+    # a leaf call, whatever n < 2 it is given: `batch-fib-split` pins it
+    split = _cell("batch-fib-split")[2]["expected"]
+    for n in (-1024, -1, 0, 1):
+        before = stat.instr_count
+        ex.invoke_raw(store, inst.find_func("fib"), [n])
+        assert stat.instr_count - before == split["retired_below_2"] == 7
 
 
 def test_the_memory_cell_pins_an_answer_that_its_reference_gives():

@@ -200,8 +200,9 @@ def test_resume_on_an_absorbed_slot_is_bit_exact(monkeypatch):
     weight zero; the taken children start at slot 6, which since PR 29
     only a resume reaches (block 0 runs its ops as the guard's tail):
     its block id is hot through slot 10.  Slot 15's bare `return` is
-    cold.  Results and retired counts are the parent commit's (retired
-    is low by one per split there too)."""
+    cold.  Results are the parent commit's; retired is the scalar
+    engine's 21 F(n+1) - 14, the `brz` the host resolved at each of a
+    lane's splits counted (PR 32)."""
     from wasmedge_tpu.batch.scheduler import BlockScheduler
 
     eng = fib_engine()
@@ -220,7 +221,7 @@ def test_resume_on_an_absorbed_slot_is_bit_exact(monkeypatch):
     assert np.asarray(res.results[0]).tolist() == \
         [2, 5, 21, 1, 34, 3, 13, 8]
     assert np.asarray(res.retired).tolist() == \
-        [47, 150, 693, 27, 1134, 88, 421, 254]
+        [49, 154, 700, 28, 1141, 91, 427, 259]
     assert not eng.fell_back_to_simt and eng.splits == 7
 
     inner = next(iter(eng.simt._sched_cache.values()))

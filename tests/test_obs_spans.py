@@ -40,6 +40,8 @@ BATCH_SPANS = {"batch/run", "batch/plan", "batch/launch", "batch/sync",
 SERVE_SPANS = {"serve/round", "serve/lock_wait", "serve/admit",
                "serve/install", "serve/launch", "serve/enforce",
                "serve/harvest", "serve/park", "simt/chunk"}
+# opened only where a block splits in flight: tests/test_split_batch_config.py
+SPLIT_SPANS = {"batch/recheck", "batch/split", "batch/install"}
 # child -> the span it must lie inside
 PARENTS = {"batch/plan": "batch/run", "batch/launch": "batch/run",
            "batch/sync": "batch/run", "batch/statuses": "batch/run",
@@ -286,7 +288,8 @@ def test_span_metrics_name_spans_the_program_opens():
     lists, so a renamed span cannot leave its metric silent unseen."""
     import json
 
-    known = {SPAN_PREFIX + n for n in BATCH_SPANS | SERVE_SPANS}
+    known = {SPAN_PREFIX + n
+             for n in BATCH_SPANS | SERVE_SPANS | SPLIT_SPANS}
     seen = 0
     for path in glob.glob(os.path.join(BENCH, "layer_metrics", "*.json")):
         with open(path) as f:
@@ -295,4 +298,4 @@ def test_span_metrics_name_spans_the_program_opens():
             assert spec["reader"] == "trace_program_span"
             assert spec["args"]["span"] in known, spec["name"]
             seen += 1
-    assert seen == 12
+    assert seen == 15

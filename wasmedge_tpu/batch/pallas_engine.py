@@ -4015,6 +4015,11 @@ class PallasUniformEngine:
         self.fell_back_to_simt = False
         self.splits = 0  # block-scheduler split count from the last run()
         self.recheck_rounds = 0  # careful-kernel rounds (optimistic mode)
+        # the last run()'s launches of the optimistic kernel, rounds of
+        # the careful one and the block-steps those rounds retired
+        self.launches = 0
+        self.rechecks = 0
+        self.careful_steps = 0
         # (expected, max) branches a dispatch walks in the kernel's
         # tree (plan_dispatch_tree), known once a kernel was built
         self.dispatch_depth = None
@@ -4649,7 +4654,10 @@ class PallasUniformEngine:
         """Run through the block scheduler (batch/scheduler.py): entry
         grouping packs same-args lanes into the same blocks, data
         divergence splits blocks instead of abandoning the kernel, and
-        only the genuinely per-lane residue finishes on SIMT."""
+        only the genuinely per-lane residue finishes on SIMT.
+        `splits`, `launches`, `rechecks` (`recheck_rounds` is the same
+        number) and `careful_steps` are this run's; the cached
+        per-geometry engines keep their own growing `recheck_rounds`."""
         ex = self.inst.exports.get(func_name)
         if ex is None or ex[0] != 0:
             raise KeyError(f"no exported function {func_name}")
@@ -4662,7 +4670,11 @@ class PallasUniformEngine:
         self.fell_back_to_simt = sched.fell_back_to_simt
         self.splits = sched.splits
         self.quarantined = sched.quarantined
-        self.recheck_rounds = sched.eng.recheck_rounds
+        self.launches = sched.launches
+        self.recheck_rounds = self.rechecks = sched.rechecks
+        self.careful_steps = sched.careful_steps
+        self.obs.add_split_counts(sched.splits, sched.launches,
+                                  sched.rechecks, sched.careful_steps)
         self.aot_fused_verified = sched.eng.aot_fused_verified
         self.dispatch_depth = sched.eng.dispatch_depth
         self.mem_static = sched.eng.mem_static

@@ -182,6 +182,14 @@ class BlockScheduler:
         self.retired = np.zeros(self.lanes, np.int64)
         self.fell_back_to_simt = False
         self.splits = 0
+        # this run's launches of the optimistic kernel, rounds of the
+        # careful one, and the block-steps the careful rounds retired
+        self.launches = 0
+        self.rechecks = 0
+        self.careful_steps = 0
+        # blocks a hostcall serve re-armed as DIVERGED: the kernel had
+        # counted their call when it parked them
+        self._served_stops = set()
         # the hbm_window kernel's DMA counts and the accesses it
         # resolved against the window, summed over blocks and launches
         # (zero in every other memory mode)
@@ -418,6 +426,7 @@ class BlockScheduler:
                 self.state[1] = jnp.asarray(self._frames_cache)
                 self._frames_dirty = False
             if self._launched:
+                self.launches += 1
                 self._live_at_launch = live
                 self._t_launch = self.obs.now()
                 out = self.eng._fn(*self.eng._tables, self.state[0],
@@ -458,6 +467,8 @@ class BlockScheduler:
             # (the kernel passed the parked blocks' ctrl rows through)
             for b, row in self._serve_rearms.items():
                 ctrl_np[b] = row
+                if row[_C_STATUS] == ST_DIVERGED:
+                    self._served_stops.add(b)
             self._serve_rearms = None
             self._ctrl_dirty = True
             served = True
@@ -523,11 +534,14 @@ class BlockScheduler:
         import jax.numpy as jnp
 
         recheck = live & (self._ctrl()[:, _C_STATUS] == ST_RECHECK)
-        if self._frames_dirty:
-            self.state[1] = jnp.asarray(self._frames_cache)
-            self._frames_dirty = False
-        self.state, ctrl = self.eng.careful_recheck(
-            self.state, self._ctrl(), recheck)
+        with self._phase("batch/recheck", blocks=int(recheck.sum())):
+            if self._frames_dirty:
+                self.state[1] = jnp.asarray(self._frames_cache)
+                self._frames_dirty = False
+            self.state, ctrl = self.eng.careful_recheck(
+                self.state, self._ctrl(), recheck)
+        self.rechecks += 1
+        self.careful_steps += int(ctrl[recheck, _C_STEPS].sum())
         self.block_steps += ctrl[:, _C_STEPS].astype(np.int64)
         self._count_kernel(ctrl, recheck)
         self._ctrl_cache = ctrl
@@ -571,7 +585,9 @@ class BlockScheduler:
             self._harvest(b, ctrl_np, running=running)
             progress = True
         for b, status in splits:
-            self._split(b, ctrl_np, status)
+            with self._phase("batch/split",
+                             pc=int(ctrl_np[b, _C_PC])) as span:
+                span.set(children=self._split(b, ctrl_np, status))
             progress = True
         if hostcall_blocks:
             # tier-2 overlap: capture the serve's device reads now (the
@@ -627,9 +643,10 @@ class BlockScheduler:
                          block=b)
 
     # -- split machinery ---------------------------------------------------
-    def _split(self, b: int, ctrl_np, status: int):
+    def _split(self, b: int, ctrl_np, status: int) -> int:
         """Resolve a stopped block: evaluate the divergent instruction
-        per lane, partition lanes by outcome, install uniform children."""
+        per lane, partition lanes by outcome, install uniform children.
+        Returns the number of children queued for a block slot."""
         eng = self.eng
         ctrl = ctrl_np[b].copy()
         frames = self._frames()[b]
@@ -638,9 +655,20 @@ class BlockScheduler:
         self.obs.instant("split", cat="scheduler", track=self._track,
                          block=b, pc=int(ctrl[_C_PC]), status=status,
                          splits=self.splits)
+        queued = len(self._pending)
+        # The kernel counts no step that ends DIVERGED, whether or not
+        # it advanced control.  Where it did advance (a trap-partial
+        # site: the trap plane holds the codes) the instruction is
+        # retired here; a serve's re-arm sits past a call the kernel
+        # had counted when it parked the block.
+        lo = b * self.Lblk
+        advanced = int(status == ST_DIVERGED
+                       and b not in self._served_stops
+                       and self._trap_full[lo:lo + self.Lblk].any())
+        self._served_stops.discard(b)
         if status == ST_REGROW or self.splits > self.split_budget:
-            self._to_simt(b, ctrl, frames, pages_over)
-            return
+            self._to_simt(b, ctrl, frames, pages_over, advanced)
+            return 0
         pc = int(ctrl[_C_PC])
         hid = int(eng._np_fused["hid"][pc])
         if hid >= H_BLOCK_BASE:
@@ -648,12 +676,17 @@ class BlockScheduler:
             # operand fields are the original op's, so resolve via the
             # original opcode instead of demoting the lanes to SIMT
             hid = int(eng._np_hid_orig[pc])
-        if not self._try_resolve(b, ctrl, frames, hid, pc, pages_over):
-            self._to_simt(b, ctrl, frames, pages_over)
+        if not self._try_resolve(b, ctrl, frames, hid, pc, pages_over,
+                                 advanced):
+            self._to_simt(b, ctrl, frames, pages_over, advanced)
+        return len(self._pending) - queued
 
-    def _try_resolve(self, b, ctrl, frames, hid, pc, pages_over) -> bool:
+    def _try_resolve(self, b, ctrl, frames, hid, pc, pages_over,
+                     advanced=0) -> bool:
         """Dispatch on the stopped instruction.  Returns False when the
-        case must go to the SIMT residue."""
+        case must go to the SIMT residue.  An instruction evaluated
+        here retires with the children (`resolved=1`); `advanced` is 1
+        where the kernel advanced past one it left uncounted."""
         fused = self.eng._np_fused
         sp = int(ctrl[_C_SP])
         ob = int(ctrl[_C_OB])
@@ -689,7 +722,7 @@ class BlockScheduler:
                 if pages_over is not None:
                     cc[_C_PAGES] = int(key[1])
                 children.append((cc, frames.copy(), cols, {}))
-            self._install_children(b, children)
+            self._install_children(b, children, resolved=advanced)
             return True
 
         if hid == H_BRZ:
@@ -701,7 +734,7 @@ class BlockScheduler:
                 cc[_C_SP] = sp - 1
                 cc[_C_STATUS] = ST_RUNNING
                 children.append((cc, frames.copy(), cols, {}))
-            self._install_children(b, children)
+            self._install_children(b, children, resolved=1)
             return True
 
         if hid == H_BRNZ:
@@ -722,7 +755,7 @@ class BlockScheduler:
                     cc[_C_SP] = sp - 1
                 cc[_C_STATUS] = ST_RUNNING
                 children.append((cc, frames.copy(), cols, writes))
-            self._install_children(b, children)
+            self._install_children(b, children, resolved=1)
             return True
 
         if hid == H_BR_TABLE:
@@ -744,7 +777,7 @@ class BlockScheduler:
                     writes[("stack", tgt_sp)] = (slo[sp - 2, cols],
                                                  shi[sp - 2, cols])
                 children.append((cc, frames.copy(), cols, writes))
-            self._install_children(b, children)
+            self._install_children(b, children, resolved=1)
             return True
 
         if hid == H_CALL_INDIRECT:
@@ -770,7 +803,7 @@ class BlockScheduler:
                 cc[_C_SP] = sp - 1
                 trip = self._host_call(cc, frames.copy(), h - 1, sp - 1, pc)
                 children.append((trip[0], trip[1], cols, trip[2]))
-            self._install_children(b, children)
+            self._install_children(b, children, resolved=1)
             return True
 
         if hid == H_MEMGROW:
@@ -794,7 +827,7 @@ class BlockScheduler:
                     np.full(len(cols), pages if legal else -1, np.int32),
                     np.zeros(len(cols), np.int32))}
                 children.append((cc, frames.copy(), cols, writes))
-            self._install_children(b, children)
+            self._install_children(b, children, resolved=1)
             return True
 
         # data-divergent loads/stores/copies (no trap codes, control not
@@ -846,10 +879,15 @@ class BlockScheduler:
                 out.append((key, [col]))
         return [(k, np.asarray(c, np.int64)) for k, c in out]
 
-    def _install_children(self, b: int, children):
-        """Queue child groups; immediately-trapped ones harvest in place."""
+    def _install_children(self, b: int, children, resolved: int = 0):
+        """Queue child groups; immediately-trapped ones harvest in place.
+        `resolved` is 1 where the host evaluated the stopped instruction
+        for the children (`_try_resolve`'s five opcodes): it retires
+        with them, as it does on every other engine, and `max_steps`
+        sees it; or where the kernel advanced past a trap-partial site
+        without counting it (`_split`)."""
         ids = self.block_lanes[b]
-        steps0 = int(self.block_steps[b])
+        steps0 = int(self.block_steps[b]) + resolved
         for (cc, fr, cols, writes) in children:
             lane_ids = ids[cols]
             sel = lane_ids >= 0
@@ -905,6 +943,12 @@ class BlockScheduler:
                 if self.block_state[b] == _B_FREE]
         if not free:
             return False
+        with self._phase("batch/install",
+                         blocks=min(len(free), len(self._pending))):
+            self._install(free)
+        return True
+
+    def _install(self, free):
         import jax.numpy as jnp
 
         ctrl = self._ctrl()
@@ -930,11 +974,12 @@ class BlockScheduler:
             self.block_steps[b] = p.steps0
             self._ctrl_dirty = True
             self._frames_dirty = True
-        return True
 
     # -- SIMT residue ------------------------------------------------------
-    def _to_simt(self, b: int, ctrl, frames, pages_over=None):
-        """Queue a block's valid lanes for the final SIMT pass."""
+    def _to_simt(self, b: int, ctrl, frames, pages_over=None, advanced=0):
+        """Queue a block's valid lanes for the final SIMT pass, which
+        runs the stopped instruction again unless the kernel `advanced`
+        past it."""
         ids = self.block_lanes[b]
         vcols = np.nonzero(ids >= 0)[0]
         self.obs.instant("simt_residue_queue", cat="scheduler",
@@ -943,7 +988,7 @@ class BlockScheduler:
         self._simt_queue.append(_Pending(
             ctrl=ctrl.copy(), frames=frames.copy(), cols=cols,
             lane_ids=ids[vcols].astype(np.int64),
-            steps0=int(self.block_steps[b]),
+            steps0=int(self.block_steps[b]) + advanced,
             pages=pages_over[vcols].astype(np.int32)
             if pages_over is not None else None))
         self._free_block(b)

@@ -993,6 +993,10 @@ class UniformBatchEngine:
             whs = getattr(self.pallas, "window_hit_share", None)
             if whs is not None:
                 span.set(window_hit_share=round(whs, 6))
+            if getattr(self.pallas, "splits", 0):
+                span.set(splits=self.pallas.splits,
+                         launches=self.pallas.launches,
+                         rechecks=self.pallas.rechecks)
             return res
 
     def _kernel_args(self):

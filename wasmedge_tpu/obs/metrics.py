@@ -595,6 +595,26 @@ def render_prometheus(recorder=None, stats=None, hostcall_stats=None,
                    "summed over lane blocks and launches (a fused "
                    "block is one; a commit is none).")
             w.sample("wasmedge_pallas_dispatches_total", None, pdc)
+        sc = getattr(recorder, "split_counts", None)
+        if sc and sc["launches"]:   # stays once the scheduler ran
+            for key, name, text in (
+                    ("launches", "wasmedge_kernel_launches_total",
+                     "Launches of the optimistic Pallas kernel by the "
+                     "block scheduler (batch/scheduler.py), one for "
+                     "every round with a runnable block."),
+                    ("splits", "wasmedge_block_splits_total",
+                     "Lane blocks the scheduler split at an instruction "
+                     "whose lanes disagreed, or handed to the per-step "
+                     "engine there."),
+                    ("rechecks", "wasmedge_careful_rechecks_total",
+                     "Rounds of the careful kernel: after a rollback it "
+                     "runs a block from its snapshot to the instruction "
+                     "that stops it."),
+                    ("careful_steps", "wasmedge_careful_steps_total",
+                     "Block-steps the careful kernel retired in those "
+                     "rounds.")):
+                w.head(name, "counter", text)
+                w.sample(name, None, sc[key])
         mst = getattr(recorder, "memory_static", None)
         if mst and "lane_block" in mst:     # a guest with a memory
             w.head("wasmedge_memory_lane_block", "gauge",

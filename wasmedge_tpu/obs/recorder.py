@@ -212,6 +212,9 @@ class NullRecorder:
     def add_dispatch_counts(self, dispatches):
         pass
 
+    def add_split_counts(self, splits, launches, rechecks, careful_steps):
+        pass
+
     def add_tierup_counts(self, dispatches, retired_comp, retired_total):
         pass
 
@@ -313,6 +316,11 @@ class FlightRecorder:
         # ({"jump", "guard_tail"}), and the handlers its loop dispatched
         self.superblock_static = None
         self.pallas_dispatches = 0
+        # what the block scheduler did, folded after each run: blocks
+        # split, launches of the optimistic kernel, rounds of the
+        # careful one and the block-steps those rounds retired
+        self.split_counts = {"splits": 0, "launches": 0, "rechecks": 0,
+                             "careful_steps": 0}
         # compiled-function tier counters folded from the device
         # tu_ctr plane (batch/engine.py _fold_tierup_ctr) + the
         # promotion report set once per plan by _plan_tierup (r20)
@@ -471,6 +479,16 @@ class FlightRecorder:
         (ctrl column 13, summed over blocks and launches by
         batch/scheduler.py)."""
         self.pallas_dispatches += int(dispatches)
+
+    def add_split_counts(self, splits, launches, rechecks, careful_steps):
+        """Fold what the block scheduler did in one run
+        (batch/scheduler.py): blocks it split, launches of the
+        optimistic kernel, rounds of the careful kernel after a
+        rollback, and the block-steps those rounds retired."""
+        self.split_counts["splits"] += int(splits)
+        self.split_counts["launches"] += int(launches)
+        self.split_counts["rechecks"] += int(rechecks)
+        self.split_counts["careful_steps"] += int(careful_steps)
 
     def add_tierup_counts(self, dispatches, retired_comp, retired_total):
         """Fold the device tier-up counters (compiled-function bodies
