@@ -80,7 +80,7 @@ def pytest_addoption(parser):
 _SLOW_FILES = {
     "test_spec.py", "test_batch_parity.py", "test_batch_simd.py",
     "test_pallas_engine.py", "test_pallas_hbm.py", "test_optimistic.py",
-    "test_mesh.py", "test_scheduler.py", "test_simd.py",
+    "test_mesh.py", "test_simd.py",
 }
 
 

@@ -419,3 +419,47 @@ def test_shard_drive_chunk_compiles_for_four_v5e(topo, one_chip):
     compiled = eng._run_chunk.lower(state, tt).compile()
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= mem.argument_size_in_bytes - 1024
+
+
+# guest -> (wasm, value stack, call stack): the planes one 4096-lane
+# block holds, which the block scheduler's two surgery programs read
+# and write (batch/scheduler.py _surgery_fns; PR 33)
+_SURGERY = {
+    "fib": (_fib_wasm, 256, 256),
+    "memory-auto": (_memory_wasm, 128, 64),
+    "v128": (_simd_wasm, 64, 16),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_SURGERY))
+def test_block_surgery_compiles_for_v5e(case, one_chip):
+    """A child of 280 lanes (512 columns wide) gathered out of the
+    planes and set into the block's columns: both programs compile, and
+    the install writes the donated planes in place."""
+    import jax
+    import jax.numpy as jnp
+
+    from wasmedge_tpu.batch.scheduler import (
+        _PLANE_IDX, _PLANE_IDX_SIMD, _pad_width, _surgery_fns)
+
+    wasm, depth, cdepth = _SURGERY[case]
+    eng = _pallas_engine(wasm(), depth, cdepth)
+    lblk = eng._geom[3]
+    state = eng._arg_specs()[len(eng._tables):]   # ctrl, frames, planes
+    idx = _PLANE_IDX_SIMD if eng.img.has_simd else _PLANE_IDX
+    planes = tuple(state[i] for i in idx.values())
+    width = _pad_width(280, lblk)
+    assert (lblk, width) == (LANES, 512)
+    extract, install = _surgery_fns()
+    col_idx = jax.ShapeDtypeStruct((width,), jnp.int32)
+    cols = jax.eval_shape(extract, planes, col_idx)
+    assert [c.shape for c in cols] == [(p.shape[0], width) for p in planes]
+    extract.lower(*_on(one_chip, (planes, col_idx))).compile()
+    compiled = install.lower(*_on(one_chip, (
+        planes, cols, jax.ShapeDtypeStruct((lblk,), jnp.int32),
+        jax.ShapeDtypeStruct((), jnp.int32)))).compile()
+    mem = compiled.memory_analysis()
+    plane_bytes = [4 * p.shape[0] * p.shape[1] for p in planes]
+    assert mem.alias_size_in_bytes == sum(plane_bytes)
+    # at most the widened child of the largest plane beside them
+    assert mem.temp_size_in_bytes <= max(plane_bytes) + (1 << 20)

@@ -211,3 +211,137 @@ def test_partial_trap_followed_by_branch_keeps_codes():
     assert (res.trap[ys != 0] == -1).all()
     assert (np.asarray(res.results[0])[ys == 5] == 111).all()
     assert (np.asarray(res.results[0])[ys == 200] == 222).all()
+
+
+# -- block surgery: two compiled programs a child ---------------------------
+
+SURGERY_LANES = 64
+# group sizes no entry grouping takes (median under MIN_GROUP_LANES), and
+# every split peels one group off: children of 1..7 and 63, 61, ... 36
+_GROUP_SIZES = (1, 2, 3, 4, 5, 6, 7, 36)
+
+
+def _cascade_fib():
+    ns = np.repeat(np.arange(4, 4 + len(_GROUP_SIZES)), _GROUP_SIZES)
+    return build_fib(), "fib", ns, None
+
+
+def _cascade_memory():
+    """A loop of n turns that stores its counter at 4 * counter, then
+    answers the first turn's word plus the counter: the memory plane
+    rides through every split."""
+    b = ModuleBuilder()
+    b.add_memory(1, 1)
+    b.add_function(["i32"], ["i32"], ["i32"], [
+        ("loop", None),
+        ("local.get", 1), ("i32.const", 1), "i32.add", ("local.set", 1),
+        ("local.get", 1), ("i32.const", 2), "i32.shl",
+        ("local.get", 1), ("i32.store", 2, 0),
+        ("local.get", 0), ("i32.const", 1), "i32.sub", ("local.tee", 0),
+        ("br_if", 0),
+        "end",
+        ("i32.const", 4), ("i32.load", 2, 0), ("local.get", 1), "i32.add",
+    ], export="f")
+    ns = np.repeat(np.arange(1, 1 + len(_GROUP_SIZES)), _GROUP_SIZES)
+    return b.build(), "f", ns, ns + 1
+
+
+@pytest.fixture(scope="module", params=[_cascade_fib, _cascade_memory],
+                ids=lambda g: g.__name__.strip("_"))
+def cascade(request):
+    """A split cascade run twice on one engine -> (engine, every child
+    extracted as (lanes, {plane: shape}), the compiled variants the two
+    surgery programs held after each run)."""
+    from wasmedge_tpu.batch.scheduler import BlockScheduler
+
+    data, func, ns, want = request.param()
+    ns = np.random.default_rng(3).permutation(ns).astype(np.int64)
+    _ex, _store, _inst, eng = make_engine(data, lanes=SURGERY_LANES)
+    children = []
+    extract = BlockScheduler._extract_cols
+
+    def spy(self, b, cols, writes, sel=None):
+        out = extract(self, b, cols, writes, sel)
+        children.append((len(cols), {k: v.shape for k, v in out.items()}))
+        return out
+
+    variants = []
+    BlockScheduler._extract_cols = spy
+    try:
+        for _ in range(2):
+            res = eng.run(func, [ns], max_steps=2_000_000)
+            (inner,) = eng.simt._sched_cache.values()
+            variants.append(tuple(f._cache_size() for f in inner._surgery))
+    finally:
+        BlockScheduler._extract_cols = extract
+    assert not eng.fell_back_to_simt and (res.trap == -1).all()
+    if want is not None:
+        assert (np.asarray(res.results[0])[np.argsort(ns, kind="stable")]
+                == np.sort(want)).all()
+    return eng, children, variants
+
+
+def test_surgery_compiles_a_variant_a_width_not_a_lane_count(cascade):
+    eng, children, variants = cascade
+    (inner,) = eng.simt._sched_cache.values()
+    lblk = inner._geom[3]
+    assert lblk == SURGERY_LANES
+    first = children[:len(children) // 2]
+    assert len({n for n, _shapes in first}) >= 6
+    assert eng.splits == len(_GROUP_SIZES) - 1
+    # an extract and an install for every child, this run's own
+    assert eng.surgery_programs == 2 * len(first) == 4 * eng.splits
+    most = int(np.log2(lblk)) + 1
+    assert all(0 < v <= most for v in variants[0])
+    assert variants[1] == variants[0]      # the second run compiled none
+
+
+def test_a_pending_childs_columns_are_a_power_of_two_wide(cascade):
+    eng, children, _variants = cascade
+    (inner,) = eng.simt._sched_cache.values()
+    D, _CD, W, lblk = inner._geom
+    for n, shapes in children:
+        widths = {shape[1] for shape in shapes.values()}
+        (w,) = widths
+        assert w & (w - 1) == 0 and n <= w <= lblk
+        assert w < 2 * n or w == 1
+        assert shapes["mem"][0] == W and shapes["slo"][0] == D
+    if inner.img.has_memory:
+        assert W > 1
+
+
+def _partition_by_loop(keys):
+    """`BlockScheduler._partition` as it was before it was vectorised."""
+    out = []
+    seen = {}
+    for col in range(len(keys[0])):
+        key = tuple(int(k[col]) for k in keys)
+        if key in seen:
+            out[seen[key]][1].append(col)
+        else:
+            seen[key] = len(out)
+            out.append((key, [col]))
+    return [(k, np.asarray(c, np.int64)) for k, c in out]
+
+
+@pytest.mark.parametrize("nkeys,distinct,seed", [
+    (1, 2, 0), (1, 7, 1), (1, 200, 2), (2, 3, 3), (2, 40, 4), (1, 1, 5)])
+def test_partition_matches_the_loop_it_replaces(nkeys, distinct, seed):
+    from wasmedge_tpu.batch.scheduler import BlockScheduler
+
+    rng = np.random.default_rng(seed)
+    n, real = 256, 180
+    # negative and over-32-bit keys (memory.grow's delta, a u32 index);
+    # the pads repeat column 0's keys, as clones of its data would
+    values = rng.integers(-2 ** 33, 2 ** 33, size=(nkeys, distinct))
+    pick = rng.integers(0, distinct, size=(nkeys, n))
+    keys = [values[k][pick[k]] for k in range(nkeys)]
+    for k in keys:
+        k[real:] = k[0]
+    got = BlockScheduler._partition(keys)
+    want = _partition_by_loop(keys)
+    assert [key for key, _cols in got] == [key for key, _cols in want]
+    for (_key, a), (_key2, b) in zip(got, want):
+        assert a.dtype == b.dtype and (a == b).all()
+    # the pads follow their clone source
+    assert set(range(real, n)) <= set(got[0][1].tolist())

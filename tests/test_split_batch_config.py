@@ -112,7 +112,7 @@ def split_job():
         BlockScheduler._install_children = install
     first = {k: getattr(eng.pallas, k) for k in
              ("splits", "launches", "rechecks", "recheck_rounds",
-              "careful_steps")}
+              "careful_steps", "surgery_programs")}
     again = eng.run("fib", [args], max_steps=1_000_000)
     return args, passed, eng, res, first, again
 
@@ -175,14 +175,18 @@ def test_every_lane_is_exact_and_nothing_fell_back(split_job):
 def test_the_runs_counters_are_the_runs_own(split_job):
     """One split peels the lanes below 2 off and seven peel 5..11 off the
     rest; each costs a launch that rolls back and a careful round, each
-    child but the last its own launch.  A second run reads the same,
-    though the cached engine's total has doubled, and `/metrics` sums
-    both."""
+    child but the last its own launch, and every child two compiled
+    programs of block surgery (its extract, its install).  A second run
+    reads the same, though the cached engine's total has doubled, and
+    `/metrics` sums both."""
     _args, _passed, eng, _res, first, _again = split_job
     assert first["splits"] == 8 and first["rechecks"] == 8
     assert first["launches"] == 2 * 8 + 1
     assert first["recheck_rounds"] == first["rechecks"]
     assert 0 < first["careful_steps"] < 8 * 64
+    installs = [e for e in eng.obs.events if e["name"] == "batch/install"]
+    assert sum(e["args"]["blocks"] for e in installs) == 2 * 16
+    assert first["surgery_programs"] == 2 * 16
     second = {k: getattr(eng.pallas, k) for k in first}
     assert second == first
     inner = next(iter(eng.pallas.simt._sched_cache.values()))
@@ -197,9 +201,11 @@ def test_the_runs_counters_are_the_runs_own(split_job):
     assert value("wasmedge_careful_rechecks_total") == 16
     assert value("wasmedge_careful_steps_total") == \
         2 * first["careful_steps"]
+    assert value("wasmedge_block_surgery_programs_total") == 64
     runs = [e for e in eng.obs.events if e["name"] == "batch/run"]
     assert [(e["args"]["splits"], e["args"]["launches"],
-             e["args"]["rechecks"]) for e in runs] == [(8, 17, 8)] * 2
+             e["args"]["rechecks"], e["args"]["surgery_programs"])
+            for e in runs] == [(8, 17, 8, 32)] * 2
     splits = [e for e in eng.obs.events if e["name"] == "batch/split"]
     assert len(splits) == 16
     assert {e["args"]["parent"] for e in splits} == {"batch/statuses"}
@@ -215,28 +221,38 @@ def test_a_uniform_run_reports_no_split_counts():
     eng = UniformBatchEngine(inst, store=store, conf=conf, lanes=8)
     eng.run("fib", [np.full(8, 9, np.int64)], max_steps=100_000)
     assert (eng.pallas.splits, eng.pallas.rechecks,
-            eng.pallas.careful_steps) == (0, 0, 0)
+            eng.pallas.careful_steps, eng.pallas.surgery_programs) == \
+        (0, 0, 0, 0)
     assert eng.pallas.launches == 1
     (run,) = [e for e in eng.obs.events if e["name"] == "batch/run"]
-    assert not {"splits", "launches", "rechecks"} & set(run["args"])
-    names = {n for n, _labels in
-             parse_prometheus(render_prometheus(recorder=eng.obs))}
+    assert not {"splits", "launches", "rechecks", "surgery_programs"} \
+        & set(run["args"])
+    samples = parse_prometheus(render_prometheus(recorder=eng.obs))
+    names = {n for n, _labels in samples}
     assert "wasmedge_kernel_launches_total" in names
+    assert [v for (n, _labels), v in samples.items()
+            if n == "wasmedge_block_surgery_programs_total"] == [0]
 
 
 def test_profiler_trace_holds_the_split_spans_with_obs_off(tmp_path):
     def work():
         eng = _split_engine()
         assert eng.obs is NULL_RECORDER
-        return eng.run("fib", [_split_args()], max_steps=1_000_000)
+        res = eng.run("fib", [_split_args()], max_steps=1_000_000)
+        return res, eng.pallas.surgery_programs
 
-    res, lines = _profiled(tmp_path, work)
+    (res, surgery_programs), lines = _profiled(tmp_path, work)
     (events,) = lines.values()      # all on the calling thread
     names = [name for name, _a, _b in events]
     assert SPLIT_SPANS <= set(names)
     assert names.count("batch/split") == names.count("batch/recheck") == 8
     # every child is installed: two a split
     assert names.count("batch/install") == 16
+    # what `wasm/batch/run` carries as `surgery_programs` where a ring
+    # records it (an annotation takes its args when entered, so the end
+    # args reach the ring alone): counted with obs off too, an extract
+    # and an install for every install span
+    assert surgery_programs == 2 * names.count("batch/install")
     for name, a, b in events:
         if name in SPLIT_SPANS:
             assert any(p == "batch/statuses" and pa <= a and b <= pb

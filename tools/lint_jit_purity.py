@@ -2,7 +2,8 @@
 """Static lint: no host-side nondeterminism inside jitted chunk bodies.
 
 The jitted regions (the SIMT/uniform step builders and chunk loops, the
-recycler's column-install) trace ONCE and replay: a `time.time()`,
+recycler's column-install, the block scheduler's surgery programs)
+trace ONCE and replay: a `time.time()`,
 `np.random.*`, or `print()` inside them either burns into the trace as
 a compile-time constant (silent nondeterminism between compiles — the
 bit-identical-output contracts would break run-to-run) or fires on
@@ -53,6 +54,9 @@ TARGETS = {
     # builder; the narrowed chunk variant traces inside the engine's
     # _build_narrow_chunk, covered alongside the main builders
     "wasmedge_tpu/batch/compact.py": ("make_permute",),
+    # block surgery (batch/scheduler.py): the column gather out of the
+    # planes and the donated column set into a free slot
+    "wasmedge_tpu/batch/scheduler.py": ("_surgery_fns",),
 }
 
 # Dotted-call prefixes that are host-side nondeterminism (or host

@@ -4016,10 +4016,13 @@ class PallasUniformEngine:
         self.splits = 0  # block-scheduler split count from the last run()
         self.recheck_rounds = 0  # careful-kernel rounds (optimistic mode)
         # the last run()'s launches of the optimistic kernel, rounds of
-        # the careful one and the block-steps those rounds retired
+        # the careful one, the block-steps those rounds retired, and the
+        # compiled programs of block surgery (an extract and an install
+        # for every child of a split)
         self.launches = 0
         self.rechecks = 0
         self.careful_steps = 0
+        self.surgery_programs = 0
         # (expected, max) branches a dispatch walks in the kernel's
         # tree (plan_dispatch_tree), known once a kernel was built
         self.dispatch_depth = None
@@ -4656,8 +4659,9 @@ class PallasUniformEngine:
         divergence splits blocks instead of abandoning the kernel, and
         only the genuinely per-lane residue finishes on SIMT.
         `splits`, `launches`, `rechecks` (`recheck_rounds` is the same
-        number) and `careful_steps` are this run's; the cached
-        per-geometry engines keep their own growing `recheck_rounds`."""
+        number), `careful_steps` and `surgery_programs` are this run's;
+        the cached per-geometry engines keep their own growing
+        `recheck_rounds`."""
         ex = self.inst.exports.get(func_name)
         if ex is None or ex[0] != 0:
             raise KeyError(f"no exported function {func_name}")
@@ -4673,8 +4677,10 @@ class PallasUniformEngine:
         self.launches = sched.launches
         self.recheck_rounds = self.rechecks = sched.rechecks
         self.careful_steps = sched.careful_steps
+        self.surgery_programs = sched.surgery_programs
         self.obs.add_split_counts(sched.splits, sched.launches,
-                                  sched.rechecks, sched.careful_steps)
+                                  sched.rechecks, sched.careful_steps,
+                                  sched.surgery_programs)
         self.aot_fused_verified = sched.eng.aot_fused_verified
         self.dispatch_depth = sched.eng.dispatch_depth
         self.mem_static = sched.eng.mem_static

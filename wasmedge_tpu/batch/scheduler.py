@@ -21,6 +21,9 @@ Divergence is handled here, outside the kernel:
   moral equivalent of a GPU warp scheduler's divergence stack, with
   re-packing explicit and amortized.  For fib(n) with mixed n this fires
   once per mixed block; afterwards every block is converged forever.
+  The surgery is two compiled programs a child (`_surgery_fns`): one
+  gathers its columns out of every plane at a power-of-two width, one
+  sets them into a free slot's columns of the donated planes.
 - **SIMT residue**: anything the splitter can't express (per-lane
   divergent memory addressing, growth beyond the watermark plane)
   queues its lanes for one final pass on the
@@ -29,7 +32,7 @@ Divergence is handled here, outside the kernel:
 The reference runs every instance on the same dispatch loop
 (/root/reference/lib/executor/engine/engine.cpp:68-1641) one thread at a
 time; here 'threads' are lane blocks and 'context switches' are block
-installs.
+installs: one compiled, donated column-block set over all planes.
 """
 
 from __future__ import annotations
@@ -89,6 +92,50 @@ def _u32(x):
     return np.asarray(x).astype(np.int64) & 0xFFFFFFFF
 
 
+def _pad_width(n: int, lblk: int) -> int:
+    """Columns a child of `n` lanes is carried at: the next power of
+    two, so under 2 n, and never over the block."""
+    return min(1 << max(n - 1, 0).bit_length(), lblk)
+
+
+def _clone_pad(n: int, width: int) -> np.ndarray:
+    """int32[width]: 0..n-1, then repeats of 0 (pads clone the first
+    column: they compute redundantly and are dropped at harvest)."""
+    idx = np.zeros(width, np.int32)
+    idx[:n] = np.arange(n, dtype=np.int32)
+    return idx
+
+
+def _surgery_fns():
+    """-> (extract, install), the two compiled programs of block
+    surgery.  Both take the planes of `_plane_idx` as one tuple, so one
+    pair serves every guest (with a memory, with v128) and jit keys its
+    variants by the planes' shapes and the child's width alone: at most
+    log2(Lblk) + 1 each a geometry.
+
+    extract(planes, idx[w]) -> the columns `idx` of every plane.
+    install(planes, cols, sel[Lblk], lo) -> the planes with columns
+    lo..lo+Lblk set to `cols[:, sel]`, in place: the planes are donated
+    (the caller rebinds them), with the same cpu + persistent-cache
+    carve-out as `serve/recycle.py:_install_fn` (a deserialized
+    executable can lose input/output aliasing there)."""
+    import jax
+    from jax import lax
+
+    def extract(planes, idx):
+        return tuple(p[:, idx] for p in planes)
+
+    def install(planes, cols, sel, lo):
+        return tuple(lax.dynamic_update_slice(p, c[:, sel], (0, lo))
+                     for p, c in zip(planes, cols))
+
+    donate = (0,)
+    if jax.default_backend() == "cpu" and \
+            getattr(jax.config, "jax_compilation_cache_dir", None):
+        donate = ()
+    return jax.jit(extract), jax.jit(install, donate_argnums=donate)
+
+
 class _Rows:
     """Lazy row-sliced view of a [rows, L] device plane: downloads one
     row's block columns at a time, cached."""
@@ -113,7 +160,8 @@ class _Pending:
 
     ctrl: np.ndarray              # [16] int32
     frames: np.ndarray            # [3, CD] int32
-    cols: Dict[str, np.ndarray]   # plane name -> [rows, n] columns
+    cols: Dict[str, np.ndarray]   # plane name -> [rows, w] device columns,
+    #                               w = _pad_width(n): the first n real
     lane_ids: np.ndarray          # [n] original lane ids (no pads)
     steps0: int = 0               # instructions already retired
     pages: np.ndarray = None      # [n] per-lane page counts when a host
@@ -187,6 +235,8 @@ class BlockScheduler:
         self.launches = 0
         self.rechecks = 0
         self.careful_steps = 0
+        # compiled surgery calls: one extract and one install a child
+        self.surgery_programs = 0
         # blocks a hostcall serve re-armed as DIVERGED: the kernel had
         # counted their call when it parked them
         self._served_stops = set()
@@ -271,7 +321,8 @@ class BlockScheduler:
         self.split_budget = 4 * self.nblk + 16
         # internal engine at the scheduler's geometry, cached on the
         # long-lived SIMT engine per (L, Lblk) so repeated run() calls
-        # reuse the image, the fused tables, and the jitted kernel
+        # reuse the image, the fused tables, the jitted kernel and the
+        # two surgery programs
         cache = getattr(outer.simt, "_sched_cache", None)
         if cache is None:
             cache = outer.simt._sched_cache = {}
@@ -292,6 +343,7 @@ class BlockScheduler:
                     f"{eng.ineligible_reason}")
             eng._build()
             assert eng._geom[3] == lblk, (eng._geom, lblk)
+            eng._surgery = _surgery_fns()
             cache[(L, lblk)] = eng
         self.eng = eng
         self.block_lanes = np.stack(blocks)  # [nblk, lblk]
@@ -868,16 +920,16 @@ class BlockScheduler:
         """Partition columns by key tuples, first-seen order.  Pads carry
         their clone source's data, so they follow its side and stay
         harmless clones there."""
-        out = []
-        seen = {}
-        for col in range(len(keys[0])):
-            key = tuple(int(k[col]) for k in keys)
-            if key in seen:
-                out[seen[key]][1].append(col)
-            else:
-                seen[key] = len(out)
-                out.append((key, [col]))
-        return [(k, np.asarray(c, np.int64)) for k, c in out]
+        rows = np.stack([np.asarray(k, np.int64) for k in keys], axis=1)
+        uniq, first, inverse = np.unique(
+            rows, axis=0, return_index=True, return_inverse=True)
+        inverse = inverse.reshape(-1)
+        # a group's columns in ascending order (stable sort), the groups
+        # by their first column
+        cols = np.split(np.argsort(inverse, kind="stable"),
+                        np.cumsum(np.bincount(inverse))[:-1])
+        return [(tuple(int(v) for v in uniq[g]), cols[g])
+                for g in np.argsort(first, kind="stable")]
 
     def _install_children(self, b: int, children, resolved: int = 0):
         """Queue child groups; immediately-trapped ones harvest in place.
@@ -908,35 +960,36 @@ class BlockScheduler:
         self._free_block(b)
 
     def _extract_cols(self, b: int, cols, writes, sel=None):
-        """Snapshot a child's valid columns as DEVICE arrays (gathers —
-        no host transfer), applying the side's writes.
+        """Snapshot a child's valid columns as DEVICE arrays: one
+        compiled gather over every plane (no host transfer), at the
+        child's lane count padded to a power of two (`_pad_width`; the
+        pads repeat its first column), then the side's writes.
 
         `writes` values are either (lo, hi) scalars or (lo, hi) arrays
-        indexed like the PRE-selection column list; `sel` maps them down
-        to the valid columns."""
+        indexed like the PRE-selection column list; `sel`, which comes
+        with them, maps them down to the valid columns.  They are rare (a value carried under
+        brnz/br_table, zeroed locals under call_indirect, memory.grow's
+        result) and stay eager row sets on the padded child."""
         import jax.numpy as jnp
 
-        Lblk = self.Lblk
-        lo = b * Lblk
-        idx = jnp.asarray(lo + np.asarray(cols, np.int64))
-        out = {}
-        for name, i in self._plane_idx.items():
-            out[name] = self.state[i][:, idx]
+        n = len(cols)
+        pad = _clone_pad(n, _pad_width(n, self.Lblk))
+        idx = (b * self.Lblk + np.asarray(cols)[pad]).astype(np.int32)
+        self.surgery_programs += 1
+        out = dict(zip(self._plane_idx, self.eng._surgery[0](
+            tuple(self.state[i] for i in self._plane_idx.values()), idx)))
         for key, val in writes.items():
             row = key[1]
-            vlo, vhi = val
-            if np.ndim(vlo):
-                vlo = np.asarray(vlo)[sel] if sel is not None else vlo
-            if np.ndim(vhi):
-                vhi = np.asarray(vhi)[sel] if sel is not None else vhi
+            vlo, vhi = (np.asarray(v)[sel][pad] if np.ndim(v) else v
+                        for v in val)
             out["slo"] = out["slo"].at[row].set(jnp.asarray(vlo))
             out["shi"] = out["shi"].at[row].set(jnp.asarray(vhi))
         return out
 
     def _install_pending(self) -> bool:
-        """Move queued children into free block slots.  Plane writes are
-        device-side column-block sets (the snapshots are device arrays),
-        so no state crosses the host link."""
+        """Move queued children into free block slots.  A child is one
+        compiled program over all planes (the snapshots are device
+        arrays), so no state crosses the host link."""
         if not self._pending:
             return False
         free = [b for b in range(self.nblk)
@@ -949,22 +1002,25 @@ class BlockScheduler:
         return True
 
     def _install(self, free):
-        import jax.numpy as jnp
-
+        """Set each child's columns, clone-padded to the block width,
+        into a free slot's columns of every plane, in place (the planes
+        are donated to the program); `ctrl`, `frames` and the block
+        tables are host mirrors; the rollback shadows are not written."""
         ctrl = self._ctrl()
         frames = self._frames()
         Lblk = self.Lblk
+        planes = list(self._plane_idx.values())
         while self._pending and free:
             p = self._pending.pop(0)
             b = free.pop(0)
-            lo = b * Lblk
             n = len(p.lane_ids)
-            # pad by cloning the first column
-            sel = jnp.asarray(np.concatenate(
-                [np.arange(n), np.zeros(max(Lblk - n, 0), np.int64)]))
-            for name, i in self._plane_idx.items():
-                self.state[i] = self.state[i].at[:, lo:lo + Lblk].set(
-                    p.cols[name][:, sel])
+            self.surgery_programs += 1
+            out = self.eng._surgery[1](
+                tuple(self.state[i] for i in planes),
+                tuple(p.cols[name] for name in self._plane_idx),
+                _clone_pad(n, Lblk), np.int32(b * Lblk))
+            for i, plane in zip(planes, out):
+                self.state[i] = plane
             ctrl[b] = p.ctrl
             frames[b] = p.frames
             ids = np.full(Lblk, -1, np.int64)

@@ -996,7 +996,8 @@ class UniformBatchEngine:
             if getattr(self.pallas, "splits", 0):
                 span.set(splits=self.pallas.splits,
                          launches=self.pallas.launches,
-                         rechecks=self.pallas.rechecks)
+                         rechecks=self.pallas.rechecks,
+                         surgery_programs=self.pallas.surgery_programs)
             return res
 
     def _kernel_args(self):

@@ -612,7 +612,12 @@ def render_prometheus(recorder=None, stats=None, hostcall_stats=None,
                      "that stops it."),
                     ("careful_steps", "wasmedge_careful_steps_total",
                      "Block-steps the careful kernel retired in those "
-                     "rounds.")):
+                     "rounds."),
+                    ("surgery_programs",
+                     "wasmedge_block_surgery_programs_total",
+                     "Compiled programs of block surgery: one that "
+                     "gathers a split's child out of the planes, one "
+                     "that sets it into a free slot.")):
                 w.head(name, "counter", text)
                 w.sample(name, None, sc[key])
         mst = getattr(recorder, "memory_static", None)

@@ -212,7 +212,8 @@ class NullRecorder:
     def add_dispatch_counts(self, dispatches):
         pass
 
-    def add_split_counts(self, splits, launches, rechecks, careful_steps):
+    def add_split_counts(self, splits, launches, rechecks, careful_steps,
+                         surgery_programs):
         pass
 
     def add_tierup_counts(self, dispatches, retired_comp, retired_total):
@@ -318,9 +319,10 @@ class FlightRecorder:
         self.pallas_dispatches = 0
         # what the block scheduler did, folded after each run: blocks
         # split, launches of the optimistic kernel, rounds of the
-        # careful one and the block-steps those rounds retired
+        # careful one, the block-steps those rounds retired, and the
+        # compiled programs of block surgery
         self.split_counts = {"splits": 0, "launches": 0, "rechecks": 0,
-                             "careful_steps": 0}
+                             "careful_steps": 0, "surgery_programs": 0}
         # compiled-function tier counters folded from the device
         # tu_ctr plane (batch/engine.py _fold_tierup_ctr) + the
         # promotion report set once per plan by _plan_tierup (r20)
@@ -480,15 +482,19 @@ class FlightRecorder:
         batch/scheduler.py)."""
         self.pallas_dispatches += int(dispatches)
 
-    def add_split_counts(self, splits, launches, rechecks, careful_steps):
+    def add_split_counts(self, splits, launches, rechecks, careful_steps,
+                         surgery_programs):
         """Fold what the block scheduler did in one run
         (batch/scheduler.py): blocks it split, launches of the
         optimistic kernel, rounds of the careful kernel after a
-        rollback, and the block-steps those rounds retired."""
+        rollback, the block-steps those rounds retired, and the
+        compiled programs of block surgery (one that gathers a child's
+        columns, one that sets them into a free slot)."""
         self.split_counts["splits"] += int(splits)
         self.split_counts["launches"] += int(launches)
         self.split_counts["rechecks"] += int(rechecks)
         self.split_counts["careful_steps"] += int(careful_steps)
+        self.split_counts["surgery_programs"] += int(surgery_programs)
 
     def add_tierup_counts(self, dispatches, retired_comp, retired_total):
         """Fold the device tier-up counters (compiled-function bodies
