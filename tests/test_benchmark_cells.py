@@ -38,11 +38,19 @@ SPLIT_CELL_METRICS = [
     "kernel_optimistic_ms.batch", "kernel_careful_ms.batch",
     "recheck_ms.batch", "split_ms.batch", "install_ms.batch",
     "launches_per_job.batch", "careful_steps_per_job.batch"]
+# and the cell of the compiled numeric guest (PR 34): the HBM window's
+# traffic, a dispatch's length and the softfloat routines, off counters
+# the driver sums job by job
+GEMM_CELL_METRICS = [
+    "window_fills_per_job.batch", "window_writebacks_per_job.batch",
+    "window_miss_share.batch", "instr_per_dispatch.batch",
+    "softfloat_ops_per_job.batch", "window_hbm_share.batch"]
 
 
 def test_the_manifest_lists_the_batch_cells():
     assert CELLS == ["batch-fib30-uniform", "batch-mem-uniform",
-                     "batch-fib-divergent", "batch-fib-split"]
+                     "batch-fib-divergent", "batch-fib-split",
+                     "batch-gemm-small"]
     used = {w["config"] for w in MANIFEST["workloads"]}
     assert used == {c["name"] for c in MANIFEST["configs"]}
     # every batch cell reports what the uniform fib cell reports
@@ -61,10 +69,15 @@ def test_the_manifest_lists_the_batch_cells():
     assert reported("batch-fib-split") == [
         m for m in reported(CELLS[0]) if m != "kernel_ns_per_step.batch"
     ] + SPLIT_CELL_METRICS
+    # the gemm cell reports all the uniform fib cell does, and six more
+    assert reported("batch-gemm-small") == reported(CELLS[0]) \
+        + GEMM_CELL_METRICS
     for m in MANIFEST["per_layer"]:
-        if m["name"] in SPLIT_CELL_METRICS:
-            assert m["workloads"] == ["batch-fib-split"]
-            assert m["moves"] == "batch_ginstr_per_s"
+        for own, cell in ((SPLIT_CELL_METRICS, "batch-fib-split"),
+                          (GEMM_CELL_METRICS, "batch-gemm-small")):
+            if m["name"] in own:
+                assert m["workloads"] == [cell]
+                assert m["moves"] == "batch_ginstr_per_s"
 
 
 @pytest.mark.parametrize("name", CELLS)
@@ -102,15 +115,18 @@ def test_batch_cell_names_a_guest_the_program_has(name):
     from tests.helpers import load_validate
 
     _cell_entry, config, workload = _cell(name)
-    mod = load_validate(getattr(models, config["guest"]["builder"])())
+    mod = load_validate(getattr(models, config["guest"]["builder"])(
+        **config["guest"].get("args", {})))
     exports = {e.name for e in mod.exports}
     assert config["guest"]["export"] in exports
     assert workload["traffic"]["func"] == config["guest"]["export"]
     assert set(config["geometry"]) == {
         "value_stack_depth", "call_stack_depth", "steps_per_launch"}
     # exact results, completion, the scalar engine's count; the cell
-    # that splits adds that nothing falls back to the per-step engine
-    assert len(config["guarantees"]) == (4 if "split" in name else 3)
+    # that splits and the one with 4096 arguments add that nothing falls
+    # back to the per-step engine
+    assert len(config["guarantees"]) == (
+        4 if name in ("batch-fib-split", "batch-gemm-small") else 3)
 
 
 # (length, sha256) of the two guests that moved out of the root's

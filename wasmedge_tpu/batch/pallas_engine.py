@@ -60,6 +60,8 @@ import numpy as np
 
 from wasmedge_tpu.common.errors import ErrCode
 from wasmedge_tpu.batch.image import (
+    ALU1_SUB,
+    ALU2_F64_BASE,
     ALU2_I32_BASE,
     ALU2_I64_BASE,
     NUM_ALU1,
@@ -231,6 +233,10 @@ _C_DISPATCHES = 13
 # written by the mem_hbm kernel at exit, per launch (never read by it):
 # the loads and stores it resolved against the window, hits and misses
 _C_WACCESSES = 14
+# written at exit, per launch (never read), by a kernel whose image holds
+# a binary64 ALU op: the softfloat routines its handlers ran, counted
+# along the path taken as _C_STEPS is, those a rollback discarded too
+_C_SOFTFLOAT = 15
 _SNAP_MIN = 256
 
 
@@ -806,6 +812,20 @@ _DIV64_SUBS = {ALU2_I64_BASE + _I32_BIN.index(n) for n in
 _DIVS_SUBS = {ALU2_I32_BASE + _I32_BIN.index("div_s"),
               ALU2_I64_BASE + _I32_BIN.index("div_s")}
 # trapping ALU1 subs come from the shared table (laneops.alu1_trap_fns)
+# ALU subs that run a binary64 routine of batch/softfloat.py (a
+# reinterpret moves bits and runs none)
+_F64_ALU2_SUBS = frozenset(range(ALU2_F64_BASE, NUM_ALU2))
+_F64_ALU1_SUBS = frozenset(i for n, i in ALU1_SUB.items()
+                           if "f64" in n and "reinterpret" not in n)
+
+
+def holds_softfloat(img) -> bool:
+    """Whether the image holds a binary64 ALU op: its kernel then
+    counts the softfloat routines it runs (ctrl column _C_SOFTFLOAT)."""
+    cls, sub = np.asarray(img.cls), np.asarray(img.sub)
+    return bool(
+        np.any((cls == CLS_ALU2) & np.isin(sub, list(_F64_ALU2_SUBS)))
+        or np.any((cls == CLS_ALU1) & np.isin(sub, list(_F64_ALU1_SUBS))))
 
 
 @functools.lru_cache(maxsize=64)
@@ -817,7 +837,8 @@ def _build_kernel(used_hids: tuple, D: int, CD: int, W: int, L: int,
                   block_shapes: tuple = (),
                   simd: bool = False, NV: int = 1,
                   optimistic: bool = False, snap_steps: int = 8192,
-                  shadow_full: bool = None, hid_weights: tuple = ()):
+                  shadow_full: bool = None, hid_weights: tuple = (),
+                  softfloat: bool = False):
     """Compile the chunk-runner for one kernel geometry.
 
     Returns a jitted callable over
@@ -963,6 +984,7 @@ def _build_kernel(used_hids: tuple, D: int, CD: int, W: int, L: int,
             canr, flag, snapf, snapc = (next(it_), next(it_),
                                         next(it_), next(it_))
         turns = next(it_)
+        sfc = next(it_) if softfloat else None
         blk = pl.program_id(0)
         lo = blk * Lblk
         # lane-block slices of the (wrapper-reshaped) HBM planes: in
@@ -2701,12 +2723,20 @@ def _build_kernel(used_hids: tuple, D: int, CD: int, W: int, L: int,
                         lambda: keep(c, pc=pc + 1, sp=sp - 3)),
                     lambda: keep(c, status=I32(ST_DIVERGED)))
 
+        def count_softfloat(subs, sub):
+            """One more binary64 routine run, where the kernel counts
+            them: a vreg in VMEM that goes up by one, as `wacc` does
+            at a windowed access."""
+            if softfloat and sub in subs:
+                sfc[...] = sfc[...] + 1
+
         def mk_alu2(sub):
             fn = alu2[sub]
             can_trap = sub in _DIV32_SUBS or sub in _DIV64_SUBS
 
             def h(c):
                 pc, sp = c[1], c[2]
+                count_softfloat(_F64_ALU2_SUBS, sub)
                 xl, xh = srow(slo, sp - 2), srow(shi, sp - 2)
                 yl, yh = srow(slo, sp - 1), srow(shi, sp - 1)
                 rl, rh = fn(xl, xh, yl, yh)
@@ -2769,6 +2799,7 @@ def _build_kernel(used_hids: tuple, D: int, CD: int, W: int, L: int,
 
             def h(c):
                 pc, sp = c[1], c[2]
+                count_softfloat(_F64_ALU1_SUBS, sub)
                 wl, wh = srow(slo, sp - 1), srow(shi, sp - 1)
                 rl, rh = fn(wl, wh)
                 wrow(slo, sp - 1, rl)
@@ -2992,12 +3023,14 @@ def _build_kernel(used_hids: tuple, D: int, CD: int, W: int, L: int,
                         vs = vs.push(cell2(full(cb[6]), full(0)))
                         return emit(j.next(), cb, vs, pend_l, pend_g)
                     if kind == "alu2":
+                        count_softfloat(_F64_ALU2_SUBS, op[1])
                         y, vs = vs.pop()
                         x, vs = vs.pop()
                         vs = vs.push(cell2(*alu2[op[1]](x[0], x[1],
                                                         y[0], y[1])))
                         return emit(j.next(), cb, vs, pend_l, pend_g)
                     if kind == "alu1":
+                        count_softfloat(_F64_ALU1_SUBS, op[1])
                         x, vs = vs.pop()
                         vs = vs.push(cell2(*alu1[op[1]](x[0], x[1])))
                         return emit(j.next(), cb, vs, pend_l, pend_g)
@@ -3731,6 +3764,8 @@ def _build_kernel(used_hids: tuple, D: int, CD: int, W: int, L: int,
             init = init + (I32(-(1 << 30)), I32(0),
                            I32(-(1 << 30)), I32(0), I32(0))
         turns[...] = jnp.zeros_like(turns)
+        if softfloat:
+            sfc[...] = jnp.zeros_like(sfc)
         if optimistic:
             init = init + (I32(0),)  # ls: last-snapshot step count
             # entry state was validated at the previous exit: it IS the
@@ -3802,6 +3837,8 @@ def _build_kernel(used_hids: tuple, D: int, CD: int, W: int, L: int,
             ctrl_out[blk, _C_WFILLS] = wcnt[0]
             ctrl_out[blk, _C_WWBS] = wcnt[1]
             ctrl_out[blk, _C_WACCESSES] = wacc[0, 0]
+        if softfloat:
+            ctrl_out[blk, _C_SOFTFLOAT] = sfc[0, 0]
 
         outs = [dma(0, slo, lslice(s_lo_out)),
                 dma(1, shi, lslice(s_hi_out)),
@@ -3875,6 +3912,8 @@ def _build_kernel(used_hids: tuple, D: int, CD: int, W: int, L: int,
                 pltpu.SMEM((16,), jnp.int32)]           # snapc (carry)
                if optimistic else [])
             + [pltpu.VMEM((8, 128), jnp.int32)]         # turns
+            + ([pltpu.VMEM((8, 128), jnp.int32)]        # sfc (softfloat)
+               if softfloat else [])
         ),
     )
     out_shape = [
@@ -4044,6 +4083,12 @@ class PallasUniformEngine:
         # retired (3.5 in fib before superblocks, 5.25 with them)
         self.dispatches = 0
         self.instr_per_dispatch = None
+        # the binary64 routines of batch/softfloat.py the kernels ran
+        # over the last run(), a lane-block step each, and their share
+        # of the block-steps retired; None for an image without one
+        self.counts_softfloat = holds_softfloat(self.img)
+        self.softfloat_ops = None
+        self.softfloat_share = None
         # forward edges the newest kernel's blocks run through, by kind
         self.superblock_edges = None
         # None = no tpu.aot fused section attached; set by _build when a
@@ -4252,7 +4297,8 @@ class PallasUniformEngine:
                                   optimistic=self.optimistic,
                                   snap_steps=self.SNAP_STEPS,
                                   shadow_full=self.optimistic,
-                                  hid_weights=self._hid_weights))
+                                  hid_weights=self._hid_weights,
+                                  softfloat=self.counts_softfloat))
         self._fn_careful_cache = None if self.optimistic else self._fn
 
     def _export_cache_key(self):
@@ -4369,7 +4415,8 @@ class PallasUniformEngine:
             self._fn_careful_cache = _build_kernel(
                 *self._kargs, optimistic=False,
                 snap_steps=self.SNAP_STEPS, shadow_full=self.optimistic,
-                hid_weights=self._hid_weights)
+                hid_weights=self._hid_weights,
+                softfloat=self.counts_softfloat)
         return self._fn_careful_cache
 
     def shadow_planes(self):
@@ -4698,6 +4745,12 @@ class PallasUniformEngine:
         self.instr_per_dispatch = sched.kernel_steps / sched.dispatches \
             if sched.dispatches else None
         self.obs.add_dispatch_counts(sched.dispatches)
+        if self.counts_softfloat:
+            self.softfloat_ops = sched.softfloat_ops
+            self.softfloat_share = \
+                sched.softfloat_ops / sched.kernel_steps \
+                if sched.kernel_steps else None
+            self.obs.add_softfloat_counts(sched.softfloat_ops)
         return sched.result()
 
     def _serve_hostcalls(self, state, ctrl_np, valid_blocks=None):

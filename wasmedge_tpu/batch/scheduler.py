@@ -62,6 +62,7 @@ from wasmedge_tpu.batch.pallas_engine import (
     _C_SNAP,
     _C_CHUNK,
     _C_DISPATCHES,
+    _C_SOFTFLOAT,
     _C_FP,
     _C_FUEL,
     _C_OB,
@@ -251,6 +252,9 @@ class BlockScheduler:
         # careful recheck's too)
         self.dispatches = 0
         self.kernel_steps = 0
+        # the softfloat routines they ran (a kernel whose image holds a
+        # binary64 ALU op counts them; zero for any other)
+        self.softfloat_ops = 0
         self.quarantined = 0
         self._t_launch = 0.0
         self._plane_idx = _PLANE_IDX_SIMD if outer.img.has_simd \
@@ -578,6 +582,8 @@ class BlockScheduler:
             self.window_writebacks += int(ctrl_np[blocks, _C_WWBS].sum())
             self.window_accesses += int(
                 ctrl_np[blocks, _C_WACCESSES].sum())
+        if self.eng.counts_softfloat:
+            self.softfloat_ops += int(ctrl_np[blocks, _C_SOFTFLOAT].sum())
 
     def _run_recheck(self, live) -> np.ndarray:
         """Re-run ST_RECHECK blocks on the careful kernel (synchronous)

@@ -993,6 +993,9 @@ class UniformBatchEngine:
             whs = getattr(self.pallas, "window_hit_share", None)
             if whs is not None:
                 span.set(window_hit_share=round(whs, 6))
+            sfs = getattr(self.pallas, "softfloat_share", None)
+            if sfs is not None:
+                span.set(softfloat_share=round(sfs, 6))
             if getattr(self.pallas, "splits", 0):
                 span.set(splits=self.pallas.splits,
                          launches=self.pallas.launches,

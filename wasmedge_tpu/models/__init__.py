@@ -22,6 +22,8 @@ from wasmedge_tpu.models.programs import (
 # the guest of the benchmark's mem-batch-4096, which looks its builder
 # up here by name (benchmark/drivers/batch.py); not part of the corpus
 from wasmedge_tpu.models.programs import build_memory_batch  # noqa: F401
+# likewise the guest of polybench-gemm-4096 (benchmark/drivers/batch_seeded.py)
+from wasmedge_tpu.models.programs import build_polybench_gemm  # noqa: F401
 
 __all__ = [
     "build_fib",

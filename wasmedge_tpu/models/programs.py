@@ -116,6 +116,107 @@ def build_memory_batch() -> bytes:
     return build_memory_workload(passes=64, fold="add")
 
 
+def build_polybench_gemm(ni: int = 60, nj: int = 70, nk: int = 80) -> bytes:
+    """PolyBench/C 4.2.1 `linear-algebra/blas/gemm`, DATA_TYPE double,
+    alpha 1.5, beta 1.2, hand-lowered loop by loop (the defaults are
+    SMALL_DATASET).  Export `gemm(seed: i32) -> i64`: `init_array`,
+    `kernel_gemm` and, in place of `print_array`, a fold of every bit
+    of C, in that order.  C, A and B are contiguous row-major f64
+    arrays from address 0.
+
+        C[i][j] = (double)((i*j+1+seed) % ni) / ni
+        A[i][k] = (double)((i*(k+1)+seed) % nk) / nk
+        B[k][j] = (double)((k*(j+2)+seed) % nj) / nj
+        for i: for j: C[i][j] *= beta
+               for k: for j: C[i][j] += alpha * A[i][k] * B[k][j]
+        for i: for j: acc = rotl(acc, 1) ^ bits(C[i][j])
+
+    The seed (unsigned, as the remainders are) changes data and never
+    control flow.  Lowered as scalar -O2 code: one i32 pointer a stream
+    bumped by 8, `alpha * A[i][k]` hoisted into an f64 local, constant
+    bounds, bottom-tested loops; nothing unrolled, vectorised or
+    reassociated."""
+    c_base, a_base = 0, ni * nj * 8
+    b_base = a_base + ni * nk * 8
+    pages = -(-(b_base + nk * nj * 8) // 65536)
+    SEED, I, J, K, PC, PA, PB, ROWC, AIK, ACC = range(10)
+
+    def loop(var, bound, body):
+        """do { body } while (++var != bound), var from 0."""
+        return [("i32.const", 0), ("local.set", var), ("loop", None),
+                *body,
+                ("local.get", var), ("i32.const", 1), "i32.add",
+                ("local.tee", var), ("i32.const", bound), "i32.ne",
+                ("br_if", 0), "end"]
+
+    def bump(ptr):
+        return [("local.get", ptr), ("i32.const", 8), "i32.add",
+                ("local.set", ptr)]
+
+    def init(ptr, base, rows, cols, row, col, col_add, add, mod):
+        """ptr[row][col] = (double)((row * (col + col_add) + add + seed)
+        % mod) / mod over one array, the pointer running through it."""
+        return [("i32.const", base), ("local.set", ptr)] + loop(
+            row, rows, loop(col, cols, [
+                ("local.get", ptr),
+                ("local.get", row), ("local.get", col),
+                *([("i32.const", col_add), "i32.add"] if col_add else []),
+                "i32.mul",
+                *([("i32.const", add), "i32.add"] if add else []),
+                ("local.get", SEED), "i32.add",
+                ("i32.const", mod), "i32.rem_u",
+                "f64.convert_i32_u", ("f64.const", float(mod)), "f64.div",
+                ("f64.store", 3, 0),
+                *bump(ptr)]))
+
+    body = [
+        # init_array
+        *init(PC, c_base, ni, nj, I, J, 0, 1, ni),
+        *init(PA, a_base, ni, nk, I, K, 1, 0, nk),
+        *init(PB, b_base, nk, nj, K, J, 2, 0, nj),
+        # kernel_gemm
+        ("i32.const", c_base), ("local.set", ROWC),
+        ("i32.const", a_base), ("local.set", PA),
+        *loop(I, ni, [
+            ("local.get", ROWC), ("local.set", PC),
+            *loop(J, nj, [
+                ("local.get", PC),
+                ("local.get", PC), ("f64.load", 3, 0),
+                ("f64.const", 1.2), "f64.mul",
+                ("f64.store", 3, 0),
+                *bump(PC)]),
+            ("i32.const", b_base), ("local.set", PB),
+            *loop(K, nk, [
+                ("f64.const", 1.5), ("local.get", PA), ("f64.load", 3, 0),
+                "f64.mul", ("local.set", AIK),
+                *bump(PA),
+                ("local.get", ROWC), ("local.set", PC),
+                *loop(J, nj, [
+                    ("local.get", PC),
+                    ("local.get", PC), ("f64.load", 3, 0),
+                    ("local.get", AIK), ("local.get", PB),
+                    ("f64.load", 3, 0), "f64.mul",
+                    "f64.add",
+                    ("f64.store", 3, 0),
+                    *bump(PC), *bump(PB)])]),
+            ("local.get", ROWC), ("i32.const", nj * 8), "i32.add",
+            ("local.set", ROWC)]),
+        # the fold that stands for print_array
+        ("i32.const", c_base), ("local.set", PC),
+        *loop(I, ni, loop(J, nj, [
+            ("local.get", ACC), ("i64.const", 1), "i64.rotl",
+            ("local.get", PC), ("i64.load", 3, 0), "i64.xor",
+            ("local.set", ACC),
+            *bump(PC)])),
+        ("local.get", ACC),
+    ]
+    b = ModuleBuilder()
+    b.add_memory(pages, pages)
+    b.add_function(["i32"], ["i64"], ["i32"] * 7 + ["f64", "i64"], body,
+                   export="gemm")
+    return b.build()
+
+
 def build_counted_loop(n: int = 64) -> bytes:
     """Latch-tested counted loop with a CONSTANT limit — the canonical
     shape the absint trip analysis (analysis/absint.py) bounds

@@ -595,6 +595,14 @@ def render_prometheus(recorder=None, stats=None, hostcall_stats=None,
                    "summed over lane blocks and launches (a fused "
                    "block is one; a commit is none).")
             w.sample("wasmedge_pallas_dispatches_total", None, pdc)
+        sfo = getattr(recorder, "softfloat_ops", 0)
+        if sfo:
+            w.head("wasmedge_softfloat_ops_total", "counter",
+                   "Binary64 routines of batch/softfloat.py the Pallas "
+                   "kernels ran (f64 arithmetic, comparisons and "
+                   "conversions; a reinterpret is none), a lane-block "
+                   "step each, summed over lane blocks and launches.")
+            w.sample("wasmedge_softfloat_ops_total", None, sfo)
         sc = getattr(recorder, "split_counts", None)
         if sc and sc["launches"]:   # stays once the scheduler ran
             for key, name, text in (

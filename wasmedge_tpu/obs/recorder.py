@@ -212,6 +212,9 @@ class NullRecorder:
     def add_dispatch_counts(self, dispatches):
         pass
 
+    def add_softfloat_counts(self, ops):
+        pass
+
     def add_split_counts(self, splits, launches, rechecks, careful_steps,
                          surgery_programs):
         pass
@@ -317,6 +320,8 @@ class FlightRecorder:
         # ({"jump", "guard_tail"}), and the handlers its loop dispatched
         self.superblock_static = None
         self.pallas_dispatches = 0
+        # the binary64 routines those kernels ran (softfloat.py)
+        self.softfloat_ops = 0
         # what the block scheduler did, folded after each run: blocks
         # split, launches of the optimistic kernel, rounds of the
         # careful one, the block-steps those rounds retired, and the
@@ -481,6 +486,13 @@ class FlightRecorder:
         (ctrl column 13, summed over blocks and launches by
         batch/scheduler.py)."""
         self.pallas_dispatches += int(dispatches)
+
+    def add_softfloat_counts(self, ops):
+        """Fold the binary64 routines of batch/softfloat.py the Pallas
+        kernels ran in one run, a lane-block step each (ctrl column
+        15, which only a kernel whose image holds a binary64 ALU op
+        writes; summed by batch/scheduler.py)."""
+        self.softfloat_ops += int(ops)
 
     def add_split_counts(self, splits, launches, rechecks, careful_steps,
                          surgery_programs):
