@@ -17,6 +17,13 @@ import sys
 import numpy as np
 import pytest
 
+from wasmedge_tpu.batch.pallas_engine import (
+    ST_RECHECK,
+    _C_SNAP,
+    _C_STATUS,
+    _C_STEPS,
+    PallasUniformEngine,
+)
 from wasmedge_tpu.batch.scheduler import BlockScheduler
 from wasmedge_tpu.batch.uniform import UniformBatchEngine
 from wasmedge_tpu.common.configure import Configure
@@ -112,7 +119,8 @@ def split_job():
         BlockScheduler._install_children = install
     first = {k: getattr(eng.pallas, k) for k in
              ("splits", "launches", "rechecks", "recheck_rounds",
-              "careful_steps", "surgery_programs")}
+              "careful_steps", "surgery_programs", "snap_restored",
+              "snap_commits")}
     again = eng.run("fib", [args], max_steps=1_000_000)
     return args, passed, eng, res, first, again
 
@@ -202,7 +210,14 @@ def test_the_runs_counters_are_the_runs_own(split_job):
     assert value("wasmedge_careful_steps_total") == \
         2 * first["careful_steps"]
     assert value("wasmedge_block_surgery_programs_total") == 64
+    # every child's parent has just rolled back, so each of the 2 x 8 is
+    # given the full interval back; the second run was held to the same
+    assert first["snap_restored"] == 2 * 8
+    assert value("wasmedge_snapshot_intervals_restored_total") == 32
+    assert value("wasmedge_snapshot_commits_total") == \
+        2 * first["snap_commits"]
     runs = [e for e in eng.obs.events if e["name"] == "batch/run"]
+    assert [e["args"]["snap_restored"] for e in runs] == [16, 16]
     assert [(e["args"]["splits"], e["args"]["launches"],
              e["args"]["rechecks"], e["args"]["surgery_programs"])
             for e in runs] == [(8, 17, 8, 32)] * 2
@@ -224,14 +239,118 @@ def test_a_uniform_run_reports_no_split_counts():
             eng.pallas.careful_steps, eng.pallas.surgery_programs) == \
         (0, 0, 0, 0)
     assert eng.pallas.launches == 1
+    # fib(9) is 1141 steps in one launch: the short first interval's
+    # commit, and no child to give an interval back to
+    assert (eng.pallas.snap_restored, eng.pallas.snap_commits) == (0, 1)
     (run,) = [e for e in eng.obs.events if e["name"] == "batch/run"]
-    assert not {"splits", "launches", "rechecks", "surgery_programs"} \
-        & set(run["args"])
+    assert not {"splits", "launches", "rechecks", "surgery_programs",
+                "snap_restored"} & set(run["args"])
     samples = parse_prometheus(render_prometheus(recorder=eng.obs))
     names = {n for n, _labels in samples}
     assert "wasmedge_kernel_launches_total" in names
     assert [v for (n, _labels), v in samples.items()
             if n == "wasmedge_block_surgery_programs_total"] == [0]
+    assert [v for (n, _labels), v in samples.items()
+            if n == "wasmedge_snapshot_intervals_restored_total"] == [0]
+    assert [v for (n, _labels), v in samples.items()
+            if n == "wasmedge_snapshot_commits_total"] == [1]
+
+
+@pytest.mark.parametrize("inherited", [0, 256, 4096, "full"])
+def test_a_child_is_queued_with_the_full_interval(monkeypatch, inherited):
+    """Whatever `_C_SNAP` the parent's row hands a child (`ctrl.copy()`
+    in `_try_resolve`), the `_Pending` carries the engine's full
+    interval; only a halved one counts as given back."""
+    full = PallasUniformEngine.SNAP_STEPS
+    handed = full if inherited == "full" else inherited
+    install = BlockScheduler._install_children
+    queued = []
+
+    def spy(self, b, children, resolved=0):
+        for cc, *_rest in children:
+            cc[_C_SNAP] = handed
+        before = len(self._pending)
+        install(self, b, children, resolved)
+        queued.extend(int(p.ctrl[_C_SNAP]) for p in self._pending[before:])
+
+    monkeypatch.setattr(BlockScheduler, "_install_children", spy)
+    _ex, _store, _inst, eng = make_engine(build_fib(), lanes=8)
+    res = eng.run("fib", [np.arange(3, 11, dtype=np.int64)],
+                  max_steps=500_000)
+    assert np.asarray(res.results[0]).tolist() == \
+        [2, 3, 5, 8, 13, 21, 34, 55]
+    assert eng.splits == 7 and queued == [full] * 14
+    assert eng.snap_restored == (14 if 0 < handed < full else 0)
+
+
+def _two_blocks(monkeypatch, chunk=2_000, full=1024):
+    """fib(16) and fib(15) in two blocks of eight lanes that entry
+    grouping made, with a build-time interval of `full` steps, so that
+    a launch of `chunk` crosses commits and a job takes many launches."""
+    monkeypatch.setattr(PallasUniformEngine, "SNAP_STEPS", full)
+    _ex, _store, _inst, eng = make_engine(build_fib(), lanes=16,
+                                          chunk=chunk)
+    args = np.repeat(np.array([16, 15], np.int64), 8)
+    sched = BlockScheduler(eng, "fib", [args], 10_000_000)
+    assert sched.nblk == 2 and sched.eng.optimistic
+    assert sched.eng.SNAP_STEPS == full
+    return sched
+
+
+def _implied_commits(steps, snap, full):
+    snap = snap or full
+    first = min(512, full, snap)
+    return 0 if steps < first else 1 + (steps - first) // snap
+
+
+def test_a_block_that_rolls_back_keeps_its_own_halving(monkeypatch):
+    """No split, so no child: `careful_recheck` halves the interval of
+    the block that rolled back (down to 256) and of no other, each clean
+    launch after it doubles it back to the full one, and the commits
+    counted are those each launch's steps and interval imply."""
+    full = 1024
+    sched = _two_blocks(monkeypatch, full=full)
+    live = np.ones(2, bool)
+    sched.launch()
+    assert sched.process()
+    assert sched._ctrl()[:, _C_SNAP].tolist() == [0, 0]
+    # a fused block may carry a launch a few steps past its 2,000
+    want = sum(_implied_commits(int(s), 0, full)
+               for s in sched._ctrl()[:, _C_STEPS])
+    assert sched.snap_commits == want == 4
+    for halved in (512, 256, 256):
+        # what a dirty commit leaves: the block at its last snapshot
+        # with ST_RECHECK, which the careful kernel then walks
+        sched._ctrl()[0, _C_STATUS] = ST_RECHECK
+        ctrl = sched._run_recheck(live)
+        assert ctrl[:, _C_SNAP].tolist() == [halved, full]
+    assert sched.snap_commits == want     # the careful kernel takes none
+    for doubled in (512, 1024, 1024):
+        snap = [int(v) for v in sched._ctrl()[:, _C_SNAP]]
+        sched.launch()
+        assert sched.process()
+        want += sum(_implied_commits(int(s), v, full)
+                    for s, v in zip(sched._ctrl()[:, _C_STEPS], snap))
+        assert sched._ctrl()[:, _C_SNAP].tolist() == [doubled, full]
+        assert sched.snap_commits == want
+    sched.run()      # the rest of the job
+    assert sched.snap_restored == 0 and sched.splits == 0
+    res = sched.result()
+    assert np.asarray(res.results[0]).tolist() == [987] * 8 + [610] * 8
+    assert np.asarray(res.retired).tolist() == \
+        [21 * 1597 - 14] * 8 + [21 * 987 - 14] * 8
+
+
+def test_commits_of_one_launch_follow_the_formula(monkeypatch):
+    """fib(16) is 33,523 steps; in one launch at an interval of 1024 the
+    short first interval's commit and 32 whole ones (33 of fib(15)'s
+    20,713: 1 + 19)."""
+    sched = _two_blocks(monkeypatch, chunk=50_000)
+    sched.run()
+    assert sched.launches == 1
+    assert sched.snap_commits == (1 + 32) + (1 + 19)
+    assert sched.outer.snap_commits == sched.snap_commits
+    assert sched.outer.snap_restored == 0
 
 
 def test_profiler_trace_holds_the_split_spans_with_obs_off(tmp_path):

@@ -238,6 +238,12 @@ class BlockScheduler:
         self.careful_steps = 0
         # compiled surgery calls: one extract and one install a child
         self.surgery_programs = 0
+        # children given the full snapshot interval back in place of
+        # their parent's halved one, and the periodic commits the
+        # launches' intervals imply (computed from steps and interval at
+        # each sync, not counted by the kernel)
+        self.snap_restored = 0
+        self.snap_commits = 0
         # blocks a hostcall serve re-armed as DIVERGED: the kernel had
         # counted their call when it parked them
         self._served_stops = set()
@@ -448,6 +454,12 @@ class BlockScheduler:
             if not self.process():
                 break
         self._run_simt_residue()
+        # PallasUniformEngine.run folds the other counts of a run; these
+        # two leave from here (as `eng.pallas.<name>` and in /metrics)
+        self.outer.snap_restored = self.snap_restored
+        self.outer.snap_commits = self.snap_commits
+        self.obs.add_split_counts(snap_restored=self.snap_restored,
+                                  snap_commits=self.snap_commits)
 
     def _finish_pending_serve(self):
         """Phase 2 of a deferred hostcall serve: host-side WASI work
@@ -533,6 +545,7 @@ class BlockScheduler:
             new_steps = ctrl_np[:, _C_STEPS].astype(np.int64)
             self.block_steps[live] += new_steps[live]
             self._count_kernel(ctrl_np, live)
+            self._count_commits(ctrl_np, live)
             obs = self.obs
             if obs.enabled:
                 # per-launch span closed at THIS sync point (the ctrl
@@ -550,8 +563,10 @@ class BlockScheduler:
                 with self._phase("batch/statuses", splits=self.splits):
                     ctrl_np = self._run_recheck(live)
             else:
-                # adaptive-window growth (careful_recheck halves):
-                # clean launches double a shrunken snapshot interval
+                # who moves a block's snapshot interval: careful_recheck
+                # halves it when the block rolls back, a clean launch
+                # doubles it here, and a split's child starts at the
+                # full one (_install_children), never at its parent's
                 snap = ctrl_np[:, _C_SNAP]
                 grow = live & (snap > 0) & (snap < self.eng.SNAP_STEPS)
                 if grow.any():
@@ -584,6 +599,25 @@ class BlockScheduler:
                 ctrl_np[blocks, _C_WACCESSES].sum())
         if self.eng.counts_softfloat:
             self.softfloat_ops += int(ctrl_np[blocks, _C_SOFTFLOAT].sum())
+
+    def _count_commits(self, ctrl_np, blocks):
+        """Add the periodic commits the launch that just ran implies in
+        `blocks`, by `commit_due`'s rule (batch/pallas_engine.py): the
+        first falls due after min(512, interval) steps, the others an
+        interval apart.  Computed from each block's `_C_STEPS` (a
+        rollback rewound it to the last commit) and the `_C_SNAP` it
+        ran under, which the kernel hands back as it got it; a fused
+        block may overshoot a boundary by its length, so a long run can
+        take a commit fewer than this says."""
+        if not self.eng.optimistic:
+            return
+        full = self.eng.SNAP_STEPS
+        steps = ctrl_np[blocks, _C_STEPS].astype(np.int64)
+        snap = ctrl_np[blocks, _C_SNAP].astype(np.int64)
+        snap = np.where(snap > 0, snap, full)
+        first = np.minimum(min(512, full), snap)
+        self.snap_commits += int(np.where(
+            steps < first, 0, 1 + (steps - first) // snap).sum())
 
     def _run_recheck(self, live) -> np.ndarray:
         """Re-run ST_RECHECK blocks on the careful kernel (synchronous)
@@ -946,6 +980,7 @@ class BlockScheduler:
         without counting it (`_split`)."""
         ids = self.block_lanes[b]
         steps0 = int(self.block_steps[b]) + resolved
+        full = self.eng.SNAP_STEPS
         for (cc, fr, cols, writes) in children:
             lane_ids = ids[cols]
             sel = lane_ids >= 0
@@ -960,6 +995,13 @@ class BlockScheduler:
             vcols = cols[sel]
             child_cols = self._extract_cols(b, vcols, writes, sel)
             cc[_C_CHUNK] = self.cfg.steps_per_launch
+            # a child starts fresh, as a block planned at entry does:
+            # the engine's full interval (the value itself, not the 0
+            # the kernel reads as it), whatever halvings its parent's
+            # row carries.  The kernel's short first interval bounds
+            # the run-up if the child diverges again at once.
+            self.snap_restored += int(0 < cc[_C_SNAP] < full)
+            cc[_C_SNAP] = full
             self._pending.append(_Pending(
                 ctrl=cc, frames=fr, cols=child_cols,
                 lane_ids=lane_ids[sel].astype(np.int64), steps0=steps0))

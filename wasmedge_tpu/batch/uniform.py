@@ -1000,7 +1000,8 @@ class UniformBatchEngine:
                 span.set(splits=self.pallas.splits,
                          launches=self.pallas.launches,
                          rechecks=self.pallas.rechecks,
-                         surgery_programs=self.pallas.surgery_programs)
+                         surgery_programs=self.pallas.surgery_programs,
+                         snap_restored=self.pallas.snap_restored)
             return res
 
     def _kernel_args(self):

@@ -625,7 +625,17 @@ def render_prometheus(recorder=None, stats=None, hostcall_stats=None,
                      "wasmedge_block_surgery_programs_total",
                      "Compiled programs of block surgery: one that "
                      "gathers a split's child out of the planes, one "
-                     "that sets it into a free slot.")):
+                     "that sets it into a free slot."),
+                    ("snap_restored",
+                     "wasmedge_snapshot_intervals_restored_total",
+                     "Children of a split installed with the full "
+                     "snapshot interval in place of the halved one "
+                     "their parent's row carried."),
+                    ("snap_commits", "wasmedge_snapshot_commits_total",
+                     "Periodic commits of the optimistic kernel, as "
+                     "each launch's steps and snapshot interval imply "
+                     "them (computed at the sync, not counted by the "
+                     "kernel).")):
                 w.head(name, "counter", text)
                 w.sample(name, None, sc[key])
         mst = getattr(recorder, "memory_static", None)

@@ -215,8 +215,9 @@ class NullRecorder:
     def add_softfloat_counts(self, ops):
         pass
 
-    def add_split_counts(self, splits, launches, rechecks, careful_steps,
-                         surgery_programs):
+    def add_split_counts(self, splits=0, launches=0, rechecks=0,
+                         careful_steps=0, surgery_programs=0,
+                         snap_restored=0, snap_commits=0):
         pass
 
     def add_tierup_counts(self, dispatches, retired_comp, retired_total):
@@ -325,9 +326,12 @@ class FlightRecorder:
         # what the block scheduler did, folded after each run: blocks
         # split, launches of the optimistic kernel, rounds of the
         # careful one, the block-steps those rounds retired, and the
-        # compiled programs of block surgery
+        # compiled programs of block surgery, the children given the
+        # full snapshot interval back, and the periodic commits the
+        # launches' intervals imply
         self.split_counts = {"splits": 0, "launches": 0, "rechecks": 0,
-                             "careful_steps": 0, "surgery_programs": 0}
+                             "careful_steps": 0, "surgery_programs": 0,
+                             "snap_restored": 0, "snap_commits": 0}
         # compiled-function tier counters folded from the device
         # tu_ctr plane (batch/engine.py _fold_tierup_ctr) + the
         # promotion report set once per plan by _plan_tierup (r20)
@@ -494,19 +498,25 @@ class FlightRecorder:
         writes; summed by batch/scheduler.py)."""
         self.softfloat_ops += int(ops)
 
-    def add_split_counts(self, splits, launches, rechecks, careful_steps,
-                         surgery_programs):
+    def add_split_counts(self, splits=0, launches=0, rechecks=0,
+                         careful_steps=0, surgery_programs=0,
+                         snap_restored=0, snap_commits=0):
         """Fold what the block scheduler did in one run
         (batch/scheduler.py): blocks it split, launches of the
         optimistic kernel, rounds of the careful kernel after a
-        rollback, the block-steps those rounds retired, and the
-        compiled programs of block surgery (one that gathers a child's
-        columns, one that sets them into a free slot)."""
-        self.split_counts["splits"] += int(splits)
-        self.split_counts["launches"] += int(launches)
-        self.split_counts["rechecks"] += int(rechecks)
-        self.split_counts["careful_steps"] += int(careful_steps)
-        self.split_counts["surgery_programs"] += int(surgery_programs)
+        rollback, the block-steps those rounds retired, the compiled
+        programs of block surgery (one that gathers a child's columns,
+        one that sets them into a free slot), the children installed
+        with the full snapshot interval in place of a halved one, and
+        the periodic commits the launches' intervals imply.  The engine
+        folds the first five, the scheduler the last two."""
+        for key, n in (("splits", splits), ("launches", launches),
+                       ("rechecks", rechecks),
+                       ("careful_steps", careful_steps),
+                       ("surgery_programs", surgery_programs),
+                       ("snap_restored", snap_restored),
+                       ("snap_commits", snap_commits)):
+            self.split_counts[key] += int(n)
 
     def add_tierup_counts(self, dispatches, retired_comp, retired_total):
         """Fold the device tier-up counters (compiled-function bodies
