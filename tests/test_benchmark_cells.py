@@ -47,6 +47,14 @@ GEMM_CELL_METRICS = [
     "softfloat_ops_per_job.batch", "window_hbm_share.batch"]
 
 
+# the self times and counts of the scheduler's transfers and enqueues
+# (PR 36), read by `readers/trace_span_self.py` in every batch cell
+HOST_LINK_METRICS = [
+    "host_d2h_ms.batch", "host_h2d_ms.batch", "host_enqueue_ms.batch",
+    "host_initial_state_ms.batch", "host_unspanned_ms.batch",
+    "d2h_per_job.batch", "h2d_per_job.batch", "enqueues_per_job.batch"]
+
+
 def test_the_manifest_lists_the_batch_cells():
     assert CELLS == ["batch-fib30-uniform", "batch-mem-uniform",
                      "batch-fib-divergent", "batch-fib-split",
@@ -66,12 +74,16 @@ def test_the_manifest_lists_the_batch_cells():
     # would add the careful kernel's time to the optimistic one's and
     # `trace_steps` is the longest block's, so `kernel_ns_per_step.batch`
     # gives way to the two kernels' milliseconds a job and five more
-    assert reported("batch-fib-split") == [
+    # (sorted: the manifest lists its metrics in the order PRs appended
+    # them, and a metric of every cell may come after a cell's own)
+    assert sorted(reported("batch-fib-split")) == sorted([
         m for m in reported(CELLS[0]) if m != "kernel_ns_per_step.batch"
-    ] + SPLIT_CELL_METRICS
+    ] + SPLIT_CELL_METRICS)
     # the gemm cell reports all the uniform fib cell does, and six more
-    assert reported("batch-gemm-small") == reported(CELLS[0]) \
-        + GEMM_CELL_METRICS
+    assert sorted(reported("batch-gemm-small")) == sorted(
+        reported(CELLS[0]) + GEMM_CELL_METRICS)
+    # the host's account (PR 36) is every batch cell's
+    assert set(HOST_LINK_METRICS) <= set(reported(CELLS[0]))
     for m in MANIFEST["per_layer"]:
         for own, cell in ((SPLIT_CELL_METRICS, "batch-fib-split"),
                           (GEMM_CELL_METRICS, "batch-gemm-small")):

@@ -1,6 +1,6 @@
 """The program's phase spans (`obs.timed`): on the profiler's clock with
 the recorder off, on the ring with their parent when it is on, and read
-by the benchmark's `trace_program_span` reader.
+by the benchmark's `trace_program_span` and `trace_span_self` readers.
 
 Everything here runs on the CPU (Pallas in interpret mode) at tiny sizes
 and under `jax.profiler` with the Python tracer off, as the benchmark's
@@ -35,9 +35,15 @@ BENCH = os.path.join(os.path.dirname(os.path.dirname(
 LANES = 16
 ROUNDS = 3
 
-BATCH_SPANS = {"batch/run", "batch/plan", "batch/launch", "batch/sync",
-               "batch/statuses", "batch/result"}
-SERVE_SPANS = {"serve/round", "serve/lock_wait", "serve/admit",
+# what crosses the host link (batch/pallas_engine.py HostLink): leaves,
+# each inside whichever phase made the crossing
+LINK_SPANS = {"batch/d2h", "batch/h2d", "batch/enqueue"}
+BATCH_SPANS = {"batch/run", "batch/plan", "batch/group",
+               "batch/initial_state", "batch/launch", "batch/sync",
+               "batch/statuses", "batch/harvest", "batch/result"} \
+    | LINK_SPANS
+SERVE_SPANS = {"serve/step_enter", "serve/step_exit", "serve/round",
+               "serve/lock_wait", "serve/admit",
                "serve/install", "serve/launch", "serve/enforce",
                "serve/harvest", "serve/park", "simt/chunk"}
 # opened only where a block splits in flight: tests/test_split_batch_config.py
@@ -46,6 +52,10 @@ SPLIT_SPANS = {"batch/recheck", "batch/split", "batch/install"}
 PARENTS = {"batch/plan": "batch/run", "batch/launch": "batch/run",
            "batch/sync": "batch/run", "batch/statuses": "batch/run",
            "batch/result": "batch/run",
+           "batch/group": "batch/plan", "batch/initial_state": "batch/plan",
+           "batch/harvest": "batch/statuses",
+           "batch/d2h": "batch/run", "batch/h2d": "batch/run",
+           "batch/enqueue": "batch/run",
            "serve/lock_wait": "serve/round", "serve/admit": "serve/round",
            "serve/launch": "serve/round", "serve/enforce": "serve/round",
            "serve/harvest": "serve/round",
@@ -133,15 +143,27 @@ def test_profiler_trace_holds_the_spans_with_obs_off(tmp_path):
         eng, res = _batch_job()
         srv, _futs = _serve_rounds()
         assert eng.obs is NULL_RECORDER and srv.obs is NULL_RECORDER
-        return res
+        return res, eng.pallas
 
-    res, lines = _profiled(tmp_path, work)
+    (res, pallas), lines = _profiled(tmp_path, work)
     (events,) = lines.values()      # all on the calling thread
     names = [name for name, _a, _b in events]
     assert BATCH_SPANS | SERVE_SPANS <= set(names)
     _assert_nested(events)
     assert names.count("serve/round") == ROUNDS
+    assert names.count("serve/step_enter") == ROUNDS
+    assert names.count("serve/step_exit") == ROUNDS
     assert names.count("batch/run") == names.count("batch/plan") == 1
+    assert names.count("batch/group") == 1
+    assert names.count("batch/initial_state") == 1
+    # every crossing of the link is a span, counted with obs off too:
+    # the first launch's, the sync's and the harvest's three downloads;
+    # the argument rows, the globals and ctrl at entry, ctrl after the
+    # harvest; one launch of the optimistic kernel
+    assert (names.count("batch/d2h"), names.count("batch/h2d"),
+            names.count("batch/enqueue")) == \
+        (pallas.d2h_transfers, pallas.h2d_transfers,
+         pallas.programs_enqueued) == (5, 6, 1)
     # both locked sections of every round wait for the lock in a span
     assert names.count("serve/lock_wait") == 2 * ROUNDS
     # opened only where the subsystem is configured
@@ -153,6 +175,66 @@ def test_profiler_trace_holds_the_spans_with_obs_off(tmp_path):
         assert (got == want).all()
     assert (res.trap == plain.trap).all()
     assert (res.retired == plain.retired).all()
+
+
+def test_a_scheduler_dies_with_its_run_and_not_at_the_next_gc():
+    """Nothing the link or a span holds points back at the scheduler: a
+    cycle would keep a finished job's planes on the device until the
+    collector next runs (on the chip that was gigabytes: a job's planes
+    several times over)."""
+    import gc
+    import weakref
+
+    from wasmedge_tpu.batch.scheduler import BlockScheduler
+    from tests.test_scheduler import make_engine
+
+    _ex, _store, _inst, eng = make_engine(build_fib(), lanes=8)
+    gc.collect()
+    gc.disable()
+    try:
+        sched = BlockScheduler(eng, "fib", [np.arange(3, 11, dtype=np.int64)],
+                               500_000)
+        sched.run()                 # seven splits: every kind of crossing
+        assert sched.link.d2h_transfers and sched.link.programs_enqueued
+        gone = weakref.ref(sched)
+        del sched
+        assert gone() is None
+    finally:
+        gc.enable()
+
+
+def test_drive_thread_spends_no_time_between_rounds_outside_a_span(
+        tmp_path):
+    """The background driver (`BatchServer.start`): between one
+    `serve/round` and the next its thread is in `serve/step_exit`, then
+    `serve/drive_wait` (one span however long it waits), then
+    `serve/step_enter`, so the gap between rounds has names."""
+    def work():
+        conf = _conf()
+        _ex, store, inst = instantiate(build_fib(), conf)
+        srv = BatchServer(inst, store=store, conf=conf, lanes=4)
+        srv.start()
+        try:
+            assert srv.submit("fib", [12]).result(120)[0] == 144
+        finally:
+            srv.shutdown(timeout_s=60)
+        return srv.counters["rounds"]
+
+    rounds, lines = _profiled(tmp_path, work)
+    (events,) = [evs for evs in lines.values()
+                 if any(name == "serve/round" for name, _a, _b in evs)]
+    top = [name for name, _a, _b in sorted(events, key=lambda e: e[1])
+           if name in ("serve/round", "serve/step_enter",
+                       "serve/step_exit", "serve/drive_wait")]
+    assert rounds > 1 and top.count("serve/round") == rounds
+    assert top[:3] == ["serve/drive_wait", "serve/step_enter",
+                       "serve/round"]
+    between = ["serve/step_exit", "serve/drive_wait", "serve/step_enter"]
+    at = [i for i, name in enumerate(top) if name == "serve/round"]
+    for a, b in zip(at, at[1:]):
+        assert top[a + 1:b] == between
+    # the last wait ends when shutdown() stops the thread
+    assert top[at[-1] + 1:] == ["serve/step_exit", "serve/drive_wait"]
 
 
 def test_ring_holds_the_spans_with_their_parents():
@@ -170,7 +252,27 @@ def test_ring_holds_the_spans_with_their_parents():
         want = PARENTS.get(name)
         if name == "simt/chunk":    # also the batch engines' own loop
             continue
+        if name in LINK_SPANS:      # a leaf of whichever phase crossed
+            continue
         assert {e["args"]["parent"] for e in evs} == {want}, name
+    assert {(e["args"]["what"], e["args"]["parent"])
+            for e in by_name["batch/d2h"]} == {
+        ("ctrl", "batch/run"), ("ctrl", "batch/sync"),
+        ("trap", "batch/harvest"), ("res_lo", "batch/harvest"),
+        ("res_hi", "batch/harvest")}
+    assert {(e["args"]["what"], e["args"]["parent"])
+            for e in by_name["batch/h2d"]} == {
+        ("args_lo", "batch/initial_state"),
+        ("args_hi", "batch/initial_state"),
+        ("globals_lo", "batch/initial_state"),
+        ("globals_hi", "batch/initial_state"),
+        ("ctrl", "batch/initial_state"), ("ctrl", "batch/launch")}
+    assert [(e["args"]["program"], e["args"]["parent"])
+            for e in by_name["batch/enqueue"]] == \
+        [("optimistic", "batch/launch")]
+    # a download's size is known when it has come: it rides the ring
+    assert all(e["args"]["bytes"] > 0 for n in ("batch/d2h", "batch/h2d")
+               for e in by_name[n])
     # a child lands on its parent's track, so the Chrome export nests it
     assert {e["track"] for n in SERVE_SPANS - {"simt/chunk"}
             for e in by_name[n]} == {"serve/phases"}
@@ -283,19 +385,120 @@ def test_trace_program_span_reader_without_a_trace(hand_built_obs):
         reader.read(hand_built_obs, span="wasm/batch/run", stat="mean")
 
 
+@pytest.fixture(scope="module")
+def hand_built_nest():
+    """A slice of 10 s; the device busy 1..2 s and 5..7 s.  A first
+    `wasm/batch/run` 0.5..4 s holds a plan that starts with it, and a
+    `statuses` that holds a `d2h` under a JAX event of the same length;
+    a second one runs across the slice's end with a `d2h` across it too
+    and an `h2d` beyond it; one `d2h` lies between the two runs."""
+    reduce_trace = _bench_module("reduce_trace")
+    spans = [(0.5, 4.0, "wasm/batch/run"), (0.5, 0.7, "wasm/batch/plan"),
+             (1.5, 3.5, "wasm/batch/statuses"),
+             (1.8, 2.4, "wasm/batch/d2h"),
+             (1.8, 2.4, "np.asarray(jax.Array)"),
+             (4.5, 4.8, "wasm/batch/d2h"),
+             (8.0, 12.0, "wasm/batch/run"), (9.5, 10.5, "wasm/batch/d2h"),
+             (10.2, 10.4, "wasm/batch/h2d")]
+    host = (np.asarray([s[0] for s in spans]),
+            np.asarray([s[1] for s in spans]), [s[2] for s in spans])
+    trace = reduce_trace.Trace((0.0, 10.0), [[[1.0, 2.0], [5.0, 7.0]]],
+                               {}, {}, {}, host)
+    return {"trace": trace, "samples": {},
+            "counters": {"trace_jobs": 2, "none": 0}}
+
+
+# every `wasm/` name of the nest with its idle self time over both runs:
+# together the 2.5 s and the 2 s in which the device idles inside them
+NEST_SELF_IDLE = {"wasm/batch/run": 0.8 + 1.5, "wasm/batch/plan": 0.2,
+                  "wasm/batch/statuses": 1.1, "wasm/batch/d2h": 0.4 + 0.5}
+
+
+@pytest.mark.parametrize("args, want", [
+    # a leaf inside a phase inside a root, the device busy over the
+    # leaf's first 0.2 s; the part of the second leaf inside the slice
+    (dict(span="wasm/batch/d2h", stat="self_idle"), 0.45),
+    (dict(span="wasm/batch/d2h", stat="self_idle", scale=1000.0), 450.0),
+    # a phase less its leaf, its first 0.3 s under a busy device
+    (dict(span="wasm/batch/statuses", stat="self_idle"), 0.55),
+    # of two spans that start together the shorter is the inner one
+    (dict(span="wasm/batch/plan", stat="self_idle"), 0.1),
+    # the roots' own remainder: what no child span names
+    (dict(span="wasm/batch/run", stat="self_idle"), 1.15),
+    # the leaf between the runs and the one beyond the slice count
+    # nowhere; the one across the slice's end starts inside it
+    (dict(span="wasm/batch/d2h", stat="count"), 1.0),
+    (dict(span="wasm/batch/h2d", stat="count"), None),
+    (dict(span="wasm/batch/h2d", stat="self_idle"), None),
+    (dict(span="wasm/batch/result", stat="self_idle"), None),
+    # another root: only what lies inside it
+    (dict(span="wasm/batch/d2h", stat="self_idle",
+          root="wasm/batch/statuses"), 0.2),
+    (dict(span="wasm/batch/statuses", stat="self_idle",
+          root="wasm/batch/statuses"), 0.55),
+    (dict(span="wasm/batch/d2h", stat="count",
+          root="wasm/batch/statuses"), 0.5),
+    (dict(span="wasm/batch/d2h", stat="self_idle",
+          root="wasm/batch/result"), None),
+    # no such counter, or nothing counted
+    (dict(span="wasm/batch/d2h", stat="count", per="absent"), None),
+    (dict(span="wasm/batch/d2h", stat="count", per="none"), None),
+])
+def test_trace_span_self_reader(hand_built_nest, args, want):
+    reader = _bench_module("readers", "trace_span_self")
+    got = reader.read(hand_built_nest, **{"per": "trace_jobs", **args})
+    assert got == (want if want is None else pytest.approx(want))
+
+
+def test_trace_span_self_sums_to_the_roots_idle_time(hand_built_nest):
+    """What makes the table of self times an account: over all names it
+    is the idle time inside the roots, to the last gap."""
+    reader = _bench_module("readers", "trace_span_self")
+    trace = hand_built_nest["trace"]
+    jobs = hand_built_nest["counters"]["trace_jobs"]
+    got = {name: reader.read(hand_built_nest, span=name, stat="self_idle",
+                             per="trace_jobs") for name in NEST_SELF_IDLE}
+    assert got == {name: pytest.approx(s / jobs)
+                   for name, s in NEST_SELF_IDLE.items()}
+    roots_idle = sum((b - a) - trace.busy_in(a, b)
+                     for a, b in ((0.5, 4.0), (8.0, 10.0)))
+    assert sum(got.values()) * jobs == pytest.approx(roots_idle) \
+        == pytest.approx(4.5)
+
+
+def test_trace_span_self_reader_refuses_what_does_not_nest(
+        hand_built_nest):
+    reader = _bench_module("readers", "trace_span_self")
+    trace = hand_built_nest["trace"]
+    starts, ends, names = trace._host
+    overlapping = type(trace)(
+        trace.window, trace.busy, {}, {}, {},
+        (np.append(starts, 3.0), np.append(ends, 6.0),
+         names + ["wasm/batch/run"]))
+    args = dict(span="wasm/batch/d2h", stat="self_idle", per="trace_jobs")
+    assert reader.read(dict(hand_built_nest, trace=overlapping),
+                       **args) is None
+    assert reader.read(dict(hand_built_nest, trace=None), **args) is None
+    with pytest.raises(ValueError):
+        reader.read(hand_built_nest, **dict(args, stat="self"))
+
+
 def test_span_metrics_name_spans_the_program_opens():
     """Every `program_span` layer metric reads a span of this file's
     lists, so a renamed span cannot leave its metric silent unseen."""
     import json
 
     known = {SPAN_PREFIX + n
-             for n in BATCH_SPANS | SERVE_SPANS | SPLIT_SPANS}
-    seen = 0
+             for n in BATCH_SPANS | SERVE_SPANS | SPLIT_SPANS
+             | {"serve/drive_wait"}}
+    seen = {"trace_program_span": 0, "trace_span_self": 0}
     for path in glob.glob(os.path.join(BENCH, "layer_metrics", "*.json")):
         with open(path) as f:
             spec = json.load(f)
         if spec["source"] == "program_span":
-            assert spec["reader"] == "trace_program_span"
             assert spec["args"]["span"] in known, spec["name"]
-            seen += 1
-    assert seen == 15
+            assert spec["args"].get("root", "wasm/batch/run") in known
+            seen[spec["reader"]] += 1
+    # PR 26's fifteen and the three of the serve loop's gap; the host's
+    # account of a batch job: five self times and three counts
+    assert seen == {"trace_program_span": 18, "trace_span_self": 8}

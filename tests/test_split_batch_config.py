@@ -120,7 +120,8 @@ def split_job():
     first = {k: getattr(eng.pallas, k) for k in
              ("splits", "launches", "rechecks", "recheck_rounds",
               "careful_steps", "surgery_programs", "snap_restored",
-              "snap_commits")}
+              "snap_commits", "d2h_transfers", "h2d_transfers",
+              "programs_enqueued")}
     again = eng.run("fib", [args], max_steps=1_000_000)
     return args, passed, eng, res, first, again
 
@@ -225,6 +226,58 @@ def test_the_runs_counters_are_the_runs_own(split_job):
     assert len(splits) == 16
     assert {e["args"]["parent"] for e in splits} == {"batch/statuses"}
     assert {e["args"]["children"] for e in splits} == {2}
+
+
+def test_the_runs_crossings_of_the_host_link_are_pinned(split_job):
+    """What a job of this mix moves between host and device, as counts
+    that repeat: with S = 8 splits and L = 2 S + 1 = 17 launches,
+    downloads: `ctrl` 1 before the first launch + L at the syncs + S in
+    the careful rounds, `trap` L (every pass that harvests or splits),
+    `res_lo` and `res_hi` S + 1 each (the passes that harvest), `frames`
+    S in the splits + S in the installs, S stack rows a `brz` is
+    resolved from; uploads: 5 at entry (two argument rows, two globals,
+    `ctrl`), `ctrl` L at the launches + 2 S in the careful rounds,
+    `frames` 2 S; programs: L + S kernels and 4 S of block surgery."""
+    _args, _passed, eng, _res, first, _again = split_job
+    S, L = 8, 17
+    assert first["d2h_transfers"] == 85 == \
+        (1 + L + S) + L + 2 * (S + 1) + 2 * S + S
+    assert first["h2d_transfers"] == 54 == 5 + (L + 2 * S) + 2 * S
+    assert first["programs_enqueued"] == 57 == \
+        first["launches"] + first["rechecks"] + first["surgery_programs"]
+    assert {k: getattr(eng.pallas, k) for k in first} == first
+    samples = parse_prometheus(render_prometheus(recorder=eng.obs))
+    assert samples[("wasmedge_batch_transfers_total",
+                    frozenset({("dir", "d2h")}))] == 2 * 85
+    assert samples[("wasmedge_batch_transfers_total",
+                    frozenset({("dir", "h2d")}))] == 2 * 54
+    assert samples[("wasmedge_batch_programs_enqueued_total",
+                    frozenset())] == 2 * 57
+    # each is a span of the ring under the phase that made it
+    by_parent = {}
+    for e in eng.obs.events:
+        if e["name"] in ("batch/d2h", "batch/h2d", "batch/enqueue"):
+            key = (e["name"][6:], e["args"].get("what")
+                   or e["args"]["program"], e["args"]["parent"][6:])
+            by_parent[key] = by_parent.get(key, 0) + 1
+    assert by_parent == {k: 2 * n for k, n in {
+        ("d2h", "ctrl", "run"): 1, ("d2h", "ctrl", "sync"): L,
+        ("d2h", "ctrl", "recheck"): S, ("d2h", "trap", "harvest"): L,
+        ("d2h", "res_lo", "harvest"): S + 1,
+        ("d2h", "res_hi", "harvest"): S + 1,
+        ("d2h", "frames", "split"): S, ("d2h", "frames", "install"): S,
+        ("d2h", "rows", "split"): S,
+        ("h2d", "args_lo", "initial_state"): 1,
+        ("h2d", "args_hi", "initial_state"): 1,
+        ("h2d", "globals_lo", "initial_state"): 1,
+        ("h2d", "globals_hi", "initial_state"): 1,
+        ("h2d", "ctrl", "initial_state"): 1, ("h2d", "ctrl", "launch"): L,
+        ("h2d", "ctrl", "recheck"): 2 * S,
+        ("h2d", "frames", "launch"): 2 * S,
+        ("enqueue", "optimistic", "launch"): L,
+        ("enqueue", "careful", "recheck"): S,
+        ("enqueue", "extract", "split"): 2 * S,
+        ("enqueue", "install", "install"): 2 * S}.items()}
 
 
 def test_a_uniform_run_reports_no_split_counts():
@@ -341,6 +394,37 @@ def test_a_block_that_rolls_back_keeps_its_own_halving(monkeypatch):
         [21 * 1597 - 14] * 8 + [21 * 987 - 14] * 8
 
 
+def test_careful_recheck_crosses_a_link_from_the_engines_own_drive_too(
+        monkeypatch):
+    """`careful_recheck` has two callers; from `engine._drive`
+    (`PallasUniformEngine._run_recheck`, the multi-tenant path) its two
+    uploads, its enqueue and its download lie under the same three leaf
+    spans, opened on the engine's own recorder."""
+    import contextlib
+
+    sched = _two_blocks(monkeypatch)
+    sched.launch()
+    assert sched.process()
+    inner = sched.eng
+    opened = []
+
+    class Spy:
+        def timed(self, name, cat="", track=None, **args):
+            opened.append((name, args.get("what") or args.get("program")))
+            return contextlib.nullcontext(self)
+
+        def set(self, **args):
+            pass
+
+    monkeypatch.setattr(inner, "obs", Spy())
+    ctrl_np = sched._ctrl().copy()
+    ctrl_np[0, _C_STATUS] = ST_RECHECK
+    _state, ctrl = inner._run_recheck(list(sched.state), ctrl_np)
+    assert opened == [("batch/h2d", "ctrl"), ("batch/enqueue", "careful"),
+                      ("batch/d2h", "ctrl"), ("batch/h2d", "ctrl")]
+    assert ctrl[0, _C_STATUS] != ST_RECHECK
+
+
 def test_commits_of_one_launch_follow_the_formula(monkeypatch):
     """fib(16) is 33,523 steps; in one launch at an interval of 1024 the
     short first interval's commit and 32 whole ones (33 of fib(15)'s
@@ -358,12 +442,18 @@ def test_profiler_trace_holds_the_split_spans_with_obs_off(tmp_path):
         eng = _split_engine()
         assert eng.obs is NULL_RECORDER
         res = eng.run("fib", [_split_args()], max_steps=1_000_000)
-        return res, eng.pallas.surgery_programs
+        return res, eng.pallas
 
-    (res, surgery_programs), lines = _profiled(tmp_path, work)
+    (res, pallas), lines = _profiled(tmp_path, work)
+    surgery_programs = pallas.surgery_programs
     (events,) = lines.values()      # all on the calling thread
     names = [name for name, _a, _b in events]
     assert SPLIT_SPANS <= set(names)
+    # the trace holds every crossing of the host link the run counted
+    assert (names.count("batch/d2h"), names.count("batch/h2d"),
+            names.count("batch/enqueue")) == \
+        (pallas.d2h_transfers, pallas.h2d_transfers,
+         pallas.programs_enqueued) == (85, 54, 57)
     assert names.count("batch/split") == names.count("batch/recheck") == 8
     # every child is installed: two a split
     assert names.count("batch/install") == 16

@@ -3996,6 +3996,53 @@ def pallas_enabled(cfg) -> bool:
     return bool(use or cfg.interpret)
 
 
+class HostLink:
+    """What crosses between the host and the device on the block
+    scheduler's path, each crossing under a leaf span and counted: a
+    download (`batch/d2h`), an upload (`batch/h2d`), a call of a compiled
+    program (`batch/enqueue`).  A profiler trace then says for every
+    millisecond the device idles whether the host was moving data,
+    enqueueing, or doing its own work in the phase span around them.
+
+    `timed` opens the spans (the recorder's `obs.timed` with its
+    category and track bound, and no object that owns device planes: the
+    link must not keep them alive); a link lives as long as the run it
+    counts.  A numpy argument handed straight to a compiled program
+    rides its enqueue."""
+
+    def __init__(self, timed):
+        self._timed = timed
+        self.d2h_transfers = 0
+        self.h2d_transfers = 0
+        self.programs_enqueued = 0
+
+    def d2h(self, what: str, arr, index=None) -> np.ndarray:
+        """The device array `arr` (its `index`, cut on the device inside
+        the span) on the host: blocks until the device has produced it.
+        Read-only where the backend hands out its own buffer."""
+        self.d2h_transfers += 1
+        with self._timed("batch/d2h", what=what) as span:
+            out = np.asarray(arr if index is None else arr[index])
+            span.set(bytes=out.nbytes)
+        return out
+
+    def h2d(self, what: str, arr):
+        """The host value `arr` as a device array."""
+        import jax.numpy as jnp
+
+        self.h2d_transfers += 1
+        with self._timed("batch/h2d", what=what,
+                         bytes=int(np.asarray(arr).nbytes)):
+            return jnp.asarray(arr)
+
+    def enqueue(self, program: str, fn, *args):
+        """`fn(*args)` for a compiled `fn`: returns once the program is
+        enqueued, not when it has run."""
+        self.programs_enqueued += 1
+        with self._timed("batch/enqueue", program=program):
+            return fn(*args)
+
+
 class PallasUniformEngine:
     """Block-converged engine running the dispatch loop on-device.
 
@@ -4062,6 +4109,11 @@ class PallasUniformEngine:
         self.rechecks = 0
         self.careful_steps = 0
         self.surgery_programs = 0
+        # the last run()'s crossings of the host link (HostLink):
+        # blocking downloads, uploads, calls of a compiled program
+        self.d2h_transfers = 0
+        self.h2d_transfers = 0
+        self.programs_enqueued = 0
         # (expected, max) branches a dispatch walks in the kernel's
         # tree (plan_dispatch_tree), known once a kernel was built
         self.dispatch_depth = None
@@ -4580,7 +4632,7 @@ class PallasUniformEngine:
                 continue
             return state, steps_per_block, statuses
 
-    def careful_recheck(self, state, ctrl_np, recheck_mask):
+    def careful_recheck(self, state, ctrl_np, recheck_mask, link):
         """ONE recheck protocol for both drive paths (engine._drive and
         BlockScheduler): re-run ST_RECHECK blocks on the careful kernel
         for one short chunk.  An optimistic rollback rewound them to
@@ -4590,9 +4642,8 @@ class PallasUniformEngine:
         proceeds.  Non-recheck blocks get chunk=0 (zero steps, state
         untouched).  Returns (state, ctrl_np) with saved chunk restored
         and non-recheck step counts zeroed so callers' accounting is
-        exact."""
-        import jax.numpy as jnp
-
+        exact.  Its two uploads, its download and its enqueue go
+        through the caller's `link` (HostLink)."""
         self.recheck_rounds += 1
         ctrl = ctrl_np.copy()
         saved_chunk = ctrl[:, _C_CHUNK].copy()
@@ -4607,21 +4658,23 @@ class PallasUniformEngine:
         ctrl[:, _C_CHUNK] = np.where(recheck_mask, snap + 64, 0)
         ctrl[:, _C_STATUS] = np.where(recheck_mask, ST_RUNNING,
                                       ctrl[:, _C_STATUS])
-        state[0] = jnp.asarray(ctrl)
-        out = self._fn_careful()(*self._tables, state[0], state[1],
-                                 *state[2:])
+        state[0] = link.h2d("ctrl", ctrl)
+        out = link.enqueue("careful", self._fn_careful(), *self._tables,
+                           state[0], state[1], *state[2:])
         state = list(out)
-        ctrl = np.asarray(state[0]).copy()
+        ctrl = link.d2h("ctrl", state[0]).copy()
         ctrl[:, _C_CHUNK] = saved_chunk
         # blocks that ran clean past the divergence window resume
         # optimistic on the next launch
         ctrl[:, _C_STEPS] = np.where(recheck_mask, ctrl[:, _C_STEPS], 0)
-        state[0] = jnp.asarray(ctrl)
+        state[0] = link.h2d("ctrl", ctrl)
         return state, ctrl
 
     def _run_recheck(self, state, ctrl_np):
         recheck = ctrl_np[:, _C_STATUS] == ST_RECHECK
-        return self.careful_recheck(state, ctrl_np, recheck)
+        return self.careful_recheck(
+            state, ctrl_np, recheck,
+            HostLink(functools.partial(self.obs.timed, cat="scheduler")))
 
     def _to_simt_state(self, state, steps_per_block):
         """Expand per-block scalars to the SIMT engine's per-lane layout."""
@@ -4706,8 +4759,9 @@ class PallasUniformEngine:
         divergence splits blocks instead of abandoning the kernel, and
         only the genuinely per-lane residue finishes on SIMT.
         `splits`, `launches`, `rechecks` (`recheck_rounds` is the same
-        number), `careful_steps` and `surgery_programs` are this run's;
-        the cached per-geometry engines keep their own growing
+        number), `careful_steps`, `surgery_programs`, `d2h_transfers`,
+        `h2d_transfers` and `programs_enqueued` are this run's; the
+        cached per-geometry engines keep their own growing
         `recheck_rounds`."""
         ex = self.inst.exports.get(func_name)
         if ex is None or ex[0] != 0:
@@ -4725,9 +4779,16 @@ class PallasUniformEngine:
         self.recheck_rounds = self.rechecks = sched.rechecks
         self.careful_steps = sched.careful_steps
         self.surgery_programs = sched.surgery_programs
+        link = sched.link
+        self.d2h_transfers = link.d2h_transfers
+        self.h2d_transfers = link.h2d_transfers
+        self.programs_enqueued = link.programs_enqueued
         self.obs.add_split_counts(sched.splits, sched.launches,
                                   sched.rechecks, sched.careful_steps,
-                                  sched.surgery_programs)
+                                  sched.surgery_programs,
+                                  d2h_transfers=link.d2h_transfers,
+                                  h2d_transfers=link.h2d_transfers,
+                                  programs_enqueued=link.programs_enqueued)
         self.aot_fused_verified = sched.eng.aot_fused_verified
         self.dispatch_depth = sched.eng.dispatch_depth
         self.mem_static = sched.eng.mem_static

@@ -38,6 +38,7 @@ installs: one compiled, donated column-block set over all planes.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Dict, List
 
 import numpy as np
@@ -76,6 +77,7 @@ from wasmedge_tpu.batch.pallas_engine import (
     _C_WWBS,
     _FUEL_OFF,
     _PAGE_WORDS,
+    HostLink,
     PallasUniformEngine,
 )
 
@@ -139,10 +141,10 @@ def _surgery_fns():
 
 class _Rows:
     """Lazy row-sliced view of a [rows, L] device plane: downloads one
-    row's block columns at a time, cached."""
+    row's block columns at a time through `d2h` (HostLink.d2h), cached."""
 
-    def __init__(self, arr, lo: int, n: int):
-        self._arr, self._lo, self._n = arr, lo, n
+    def __init__(self, d2h, arr, lo: int, n: int):
+        self._d2h, self._arr, self._lo, self._n = d2h, arr, lo, n
         self._c = {}
 
     def __getitem__(self, key):
@@ -151,7 +153,8 @@ class _Rows:
             return self[row][cols]
         r = int(key)
         if r not in self._c:
-            self._c[r] = np.asarray(self._arr[r, self._lo:self._lo + self._n])
+            self._c[r] = self._d2h(
+                "rows", self._arr, np.s_[r, self._lo:self._lo + self._n])
         return self._c[r]
 
 
@@ -192,14 +195,18 @@ class BlockScheduler:
             else self._track
         # phase spans (obs.timed): under a caller's open span they land
         # on its track; a device thread of a mesh drive has none open
-        self._track_phases = None if self._track == "pallas" \
-            else self._track + "/phases"
+        self._phase = functools.partial(
+            self.obs.timed, cat="scheduler",
+            track=None if self._track == "pallas"
+            else self._track + "/phases")
+        # every transfer and every call of a compiled program below goes
+        # through the link, so each lies in a leaf span and is counted:
+        # no np.asarray / jnp.asarray of a plane or a mirror beside it.
+        # (It holds the recorder's `timed`, not this scheduler: a cycle
+        # would keep a job's planes on the device until the next gc.)
+        self.link = HostLink(self._phase)
         with self._phase("batch/plan"):
             self._setup(func_name, args_lanes, max_steps)
-
-    def _phase(self, name, **args):
-        return self.obs.timed(name, cat="scheduler",
-                              track=self._track_phases, **args)
 
     def _setup(self, func_name, args_lanes, max_steps):
         """Entry grouping and the initial planes on the device."""
@@ -265,11 +272,15 @@ class BlockScheduler:
         self._t_launch = 0.0
         self._plane_idx = _PLANE_IDX_SIMD if outer.img.has_simd \
             else _PLANE_IDX
-        self._plan()
+        with self._phase("batch/group"):
+            self._plan()
+        with self._phase("batch/initial_state"):
+            self._build_initial_state()
 
     # -- entry packing -----------------------------------------------------
     def _plan(self):
-        """Choose (L_sched, Lblk), build the engine and the packed state."""
+        """Choose (L_sched, Lblk), pack the lanes into blocks and find
+        (first build) the engine of that geometry: host work only."""
         outer = self.outer
         if self.args:
             order = np.lexsort(tuple(self.args))
@@ -367,7 +378,6 @@ class BlockScheduler:
         self._ctrl_dirty = False
         self._frames_cache = None
         self._frames_dirty = False
-        self._build_initial_state()
 
     def _build_initial_state(self):
         """Construct the packed state ON DEVICE.  Host->device bandwidth
@@ -382,6 +392,7 @@ class BlockScheduler:
         img = eng.img
         D, CD, W, Lblk = eng._geom
         L = eng.lanes
+        h2d = self.link.h2d
         meta = self.inst.lowered.funcs[self.func_idx]
         # packed column -> original lane (pads clone their block's first
         # valid lane so they run the same program)
@@ -397,25 +408,22 @@ class BlockScheduler:
             lo = (arg_m & 0xFFFFFFFF).astype(np.uint32).view(np.int32)
             hi = ((arg_m >> 32) & 0xFFFFFFFF).astype(np.uint32).view(
                 np.int32)
-            stack_lo = stack_lo.at[:len(self.args)].set(jnp.asarray(lo))
-            stack_hi = stack_hi.at[:len(self.args)].set(jnp.asarray(hi))
+            stack_lo = stack_lo.at[:len(self.args)].set(h2d("args_lo", lo))
+            stack_hi = stack_hi.at[:len(self.args)].set(h2d("args_hi", hi))
         NGp = max(img.globals_lo.shape[0], 1)
         glo = jnp.zeros((NGp, L), jnp.int32)
         ghi = jnp.zeros((NGp, L), jnp.int32)
         ng = img.globals_lo.shape[0]
         if ng:
-            glo = glo.at[:ng].set(
-                jnp.broadcast_to(jnp.asarray(img.globals_lo)[:, None],
-                                 (ng, L)))
-            ghi = ghi.at[:ng].set(
-                jnp.broadcast_to(jnp.asarray(img.globals_hi)[:, None],
-                                 (ng, L)))
+            glo = glo.at[:ng].set(jnp.broadcast_to(
+                h2d("globals_lo", img.globals_lo)[:, None], (ng, L)))
+            ghi = ghi.at[:ng].set(jnp.broadcast_to(
+                h2d("globals_hi", img.globals_hi)[:, None], (ng, L)))
         mem = jnp.zeros((W, L), jnp.int32)
         if img.mem_init.shape[0] > 1 or img.mem_pages_init:
             n = min(img.mem_init.shape[0], W)
-            mem = mem.at[:n].set(
-                jnp.broadcast_to(jnp.asarray(img.mem_init[:n])[:, None],
-                                 (n, L)))
+            mem = mem.at[:n].set(jnp.broadcast_to(
+                h2d("mem_init", img.mem_init[:n])[:, None], (n, L)))
         ctrl = np.zeros((self.nblk, 16), np.int32)
         ctrl[:, _C_PC] = meta.entry_pc
         ctrl[:, _C_SP] = meta.nlocals
@@ -424,7 +432,7 @@ class BlockScheduler:
         ctrl[:, _C_CHUNK] = self.cfg.steps_per_launch
         fuel = self.cfg.fuel_per_launch
         ctrl[:, _C_FUEL] = _FUEL_OFF if fuel is None else fuel
-        self.state = [jnp.asarray(ctrl),
+        self.state = [h2d("ctrl", ctrl),
                       jnp.zeros((self.nblk, 3, CD), jnp.int32),
                       stack_lo, stack_hi, glo, ghi, mem,
                       jnp.zeros((1, L), jnp.int32)] + eng.shadow_planes()
@@ -478,8 +486,6 @@ class BlockScheduler:
         dispatch is asynchronous (JAX): multiple schedulers' launches
         pipeline on the device while hosts process results — the
         latency-hiding seam the multi-tenant driver uses."""
-        import jax.numpy as jnp
-
         ctrl_np = self._ctrl()
         live = self.block_state == _B_LIVE
         runnable = live & (ctrl_np[:, _C_STATUS] == ST_RUNNING) & \
@@ -488,17 +494,16 @@ class BlockScheduler:
         self._launch_blocks = int(runnable.sum())
         with self._phase("batch/launch", blocks=self._launch_blocks):
             if self._ctrl_dirty:
-                self.state[0] = jnp.asarray(ctrl_np)
+                self.state[0] = self.link.h2d("ctrl", ctrl_np)
                 self._ctrl_dirty = False
-            if self._frames_dirty:
-                self.state[1] = jnp.asarray(self._frames_cache)
-                self._frames_dirty = False
+            self._upload_frames()
             if self._launched:
                 self.launches += 1
                 self._live_at_launch = live
                 self._t_launch = self.obs.now()
-                out = self.eng._fn(*self.eng._tables, self.state[0],
-                                   self.state[1], *self.state[2:])
+                out = self.link.enqueue(
+                    "optimistic", self.eng._fn, *self.eng._tables,
+                    self.state[0], self.state[1], *self.state[2:])
                 self.state = list(out)
                 self._ctrl_cache = None   # kernel wrote fresh ctrl/frames
                 self._frames_cache = None
@@ -508,16 +513,24 @@ class BlockScheduler:
         Every per-block interaction below reads/writes this mirror (tiny
         transfers each pay the host link's full round-trip latency)."""
         if self._ctrl_cache is None:
-            self._ctrl_cache = np.array(self.state[0])
+            self._ctrl_cache = self.link.d2h("ctrl", self.state[0]).copy()
             self._ctrl_dirty = False
         return self._ctrl_cache
 
     def _frames(self) -> np.ndarray:
         """Host mirror of the frames plane (same discipline as _ctrl)."""
         if self._frames_cache is None:
-            self._frames_cache = np.array(self.state[1])
+            self._frames_cache = \
+                self.link.d2h("frames", self.state[1]).copy()
             self._frames_dirty = False
         return self._frames_cache
+
+    def _upload_frames(self):
+        """The frames mirror back onto the device, if a child's install
+        wrote it."""
+        if self._frames_dirty:
+            self.state[1] = self.link.h2d("frames", self._frames_cache)
+            self._frames_dirty = False
 
     def process(self) -> bool:
         """Sync on the launch (if any) and handle block statuses.
@@ -623,15 +636,11 @@ class BlockScheduler:
         """Re-run ST_RECHECK blocks on the careful kernel (synchronous)
         via the engine's shared careful_recheck protocol, then stops
         with the precise status which _handle_statuses splits/serves."""
-        import jax.numpy as jnp
-
         recheck = live & (self._ctrl()[:, _C_STATUS] == ST_RECHECK)
         with self._phase("batch/recheck", blocks=int(recheck.sum())):
-            if self._frames_dirty:
-                self.state[1] = jnp.asarray(self._frames_cache)
-                self._frames_dirty = False
+            self._upload_frames()
             self.state, ctrl = self.eng.careful_recheck(
-                self.state, self._ctrl(), recheck)
+                self.state, self._ctrl(), recheck, self.link)
         self.rechecks += 1
         self.careful_steps += int(ctrl[recheck, _C_STEPS].sum())
         self.block_steps += ctrl[:, _C_STEPS].astype(np.int64)
@@ -669,13 +678,18 @@ class BlockScheduler:
             elif status in (ST_DIVERGED, ST_REGROW):
                 splits.append((b, status))
         if harvests or splits:
-            self._trap_full = np.asarray(self.state[7][0])
-            if self.nres and harvests:
-                self._res_lo_full = np.asarray(self.state[2][:self.nres])
-                self._res_hi_full = np.asarray(self.state[3][:self.nres])
-        for b, running in harvests:
-            self._harvest(b, ctrl_np, running=running)
-            progress = True
+            # one download a plane for every block that needs it (the
+            # trap plane for the splits too)
+            with self._phase("batch/harvest", blocks=len(harvests)):
+                d2h = self.link.d2h
+                self._trap_full = d2h("trap", self.state[7], 0)
+                if self.nres and harvests:
+                    rows = np.s_[:self.nres]
+                    self._res_lo_full = d2h("res_lo", self.state[2], rows)
+                    self._res_hi_full = d2h("res_hi", self.state[3], rows)
+                for b, running in harvests:
+                    self._harvest(b, ctrl_np, running=running)
+                    progress = True
         for b, status in splits:
             with self._phase("batch/split",
                              pc=int(ctrl_np[b, _C_PC])) as span:
@@ -789,8 +803,8 @@ class BlockScheduler:
         lo = b * Lblk
         # lazy per-row download: the resolver inspects only a handful of
         # stack rows; whole-plane transfers would ride the slow host link
-        slo = _Rows(self.state[2], lo, Lblk)
-        shi = _Rows(self.state[3], lo, Lblk)
+        slo = _Rows(self.link.d2h, self.state[2], lo, Lblk)
+        shi = _Rows(self.link.d2h, self.state[3], lo, Lblk)
         trap_row = self._trap_full[lo:lo + Lblk]
 
         # Advanced-with-per-lane-outcomes stops come FIRST, regardless of
@@ -1018,20 +1032,19 @@ class BlockScheduler:
         with them, maps them down to the valid columns.  They are rare (a value carried under
         brnz/br_table, zeroed locals under call_indirect, memory.grow's
         result) and stay eager row sets on the padded child."""
-        import jax.numpy as jnp
-
         n = len(cols)
         pad = _clone_pad(n, _pad_width(n, self.Lblk))
         idx = (b * self.Lblk + np.asarray(cols)[pad]).astype(np.int32)
         self.surgery_programs += 1
-        out = dict(zip(self._plane_idx, self.eng._surgery[0](
+        out = dict(zip(self._plane_idx, self.link.enqueue(
+            "extract", self.eng._surgery[0],
             tuple(self.state[i] for i in self._plane_idx.values()), idx)))
         for key, val in writes.items():
             row = key[1]
             vlo, vhi = (np.asarray(v)[sel][pad] if np.ndim(v) else v
                         for v in val)
-            out["slo"] = out["slo"].at[row].set(jnp.asarray(vlo))
-            out["shi"] = out["shi"].at[row].set(jnp.asarray(vhi))
+            out["slo"] = out["slo"].at[row].set(self.link.h2d("rows", vlo))
+            out["shi"] = out["shi"].at[row].set(self.link.h2d("rows", vhi))
         return out
 
     def _install_pending(self) -> bool:
@@ -1063,7 +1076,8 @@ class BlockScheduler:
             b = free.pop(0)
             n = len(p.lane_ids)
             self.surgery_programs += 1
-            out = self.eng._surgery[1](
+            out = self.link.enqueue(
+                "install", self.eng._surgery[1],
                 tuple(self.state[i] for i in planes),
                 tuple(p.cols[name] for name in self._plane_idx),
                 _clone_pad(n, Lblk), np.int32(b * Lblk))
@@ -1104,8 +1118,6 @@ class BlockScheduler:
             self._simt_residue()
 
     def _simt_residue(self):
-        import jax.numpy as jnp
-
         from wasmedge_tpu.batch.engine import BatchState
 
         self.fell_back_to_simt = True
@@ -1139,6 +1151,7 @@ class BlockScheduler:
         s_e2 = np.zeros((D_s, L), np.int32) if simd else None
         s_e3 = np.zeros((D_s, L), np.int32) if simd else None
         members = []
+        d2h, h2d = self.link.d2h, self.link.h2d
         for p in self._simt_queue:
             n = len(p.lane_ids)
             li = p.lane_ids
@@ -1151,19 +1164,19 @@ class BlockScheduler:
             pages[li] = p.ctrl[_C_PAGES] if p.pages is None else p.pages
             if cfg.fuel_per_launch is not None:
                 fuel[li] = max(int(p.ctrl[_C_FUEL]), 0)
-            trap[li] = p.cols["trap"][0][:n]
+            trap[li] = d2h("trap", p.cols["trap"], np.s_[0, :n])
             retired0[li] = p.steps0
             d = min(p.cols["slo"].shape[0], D_s)
-            s_lo[:d, li] = p.cols["slo"][:d, :n]
-            s_hi[:d, li] = p.cols["shi"][:d, :n]
+            s_lo[:d, li] = d2h("slo", p.cols["slo"], np.s_[:d, :n])
+            s_hi[:d, li] = d2h("shi", p.cols["shi"], np.s_[:d, :n])
             if simd:
-                s_e2[:d, li] = p.cols["se2"][:d, :n]
-                s_e3[:d, li] = p.cols["se3"][:d, :n]
+                s_e2[:d, li] = d2h("se2", p.cols["se2"], np.s_[:d, :n])
+                s_e3[:d, li] = d2h("se3", p.cols["se3"], np.s_[:d, :n])
             g = min(p.cols["glo"].shape[0], NG)
-            g_lo[:g, li] = p.cols["glo"][:g, :n]
-            g_hi[:g, li] = p.cols["ghi"][:g, :n]
+            g_lo[:g, li] = d2h("glo", p.cols["glo"], np.s_[:g, :n])
+            g_hi[:g, li] = d2h("ghi", p.cols["ghi"], np.s_[:g, :n])
             m = min(p.cols["mem"].shape[0], simt_w)
-            mem[:m, li] = p.cols["mem"][:m, :n]
+            mem[:m, li] = d2h("mem", p.cols["mem"], np.s_[:m, :n])
             ncd = min(p.frames.shape[1], CD_s)
             frp[:ncd, li] = p.frames[0, :ncd, None]
             frf[:ncd, li] = p.frames[1, :ncd, None]
@@ -1171,18 +1184,13 @@ class BlockScheduler:
         from wasmedge_tpu.batch.engine import t0_state_planes
 
         state = BatchState(
-            pc=jnp.asarray(pc), sp=jnp.asarray(sp), fp=jnp.asarray(fp),
-            opbase=jnp.asarray(ob), call_depth=jnp.asarray(cd),
-            trap=jnp.asarray(trap),
-            retired=jnp.asarray(np.zeros(L, np.int32)),
-            fuel=jnp.asarray(fuel), mem_pages=jnp.asarray(pages),
-            stack_lo=jnp.asarray(s_lo), stack_hi=jnp.asarray(s_hi),
-            fr_ret_pc=jnp.asarray(frp), fr_fp=jnp.asarray(frf),
-            fr_opbase=jnp.asarray(fro),
-            glob_lo=jnp.asarray(g_lo), glob_hi=jnp.asarray(g_hi),
-            mem=jnp.asarray(mem),
-            stack_e2=jnp.asarray(s_e2) if simd else None,
-            stack_e3=jnp.asarray(s_e3) if simd else None,
+            **{name: h2d(name, plane) for name, plane in dict(
+                pc=pc, sp=sp, fp=fp, opbase=ob, call_depth=cd, trap=trap,
+                retired=np.zeros(L, np.int32), fuel=fuel, mem_pages=pages,
+                stack_lo=s_lo, stack_hi=s_hi, fr_ret_pc=frp, fr_fp=frf,
+                fr_opbase=fro, glob_lo=g_lo, glob_hi=g_hi, mem=mem).items()},
+            stack_e2=h2d("stack_e2", s_e2) if simd else None,
+            stack_e3=h2d("stack_e3", s_e3) if simd else None,
             **t0_state_planes(img, cfg, L,
                               getattr(simt, "_t0kinds", None)))
         # account for work already done on the kernel so the caller's
@@ -1202,13 +1210,13 @@ class BlockScheduler:
         state, total = simt.run_from_state(state, total0, max_steps_eff)
         self._residue_steps = int(total)
         all_m = np.concatenate(members)
-        trap_f = np.asarray(state.trap)
-        ret_f = np.asarray(state.retired).astype(np.int64)
+        trap_f = d2h("trap", state.trap)
+        ret_f = d2h("retired", state.retired).astype(np.int64)
         self.trap[all_m] = trap_f[all_m]
         self.retired[all_m] = retired0[all_m] + ret_f[all_m]
         if self.nres:
-            s_lo_f = np.asarray(state.stack_lo[:self.nres])
-            s_hi_f = np.asarray(state.stack_hi[:self.nres])
+            s_lo_f = d2h("res_lo", state.stack_lo, np.s_[:self.nres])
+            s_hi_f = d2h("res_hi", state.stack_hi, np.s_[:self.nres])
             self.res_lo[:, all_m] = s_lo_f[:, all_m]
             self.res_hi[:, all_m] = s_hi_f[:, all_m]
         self.obs.span("simt_residue", t_residue, cat="scheduler",

@@ -638,6 +638,20 @@ def render_prometheus(recorder=None, stats=None, hostcall_stats=None,
                      "kernel).")):
                 w.head(name, "counter", text)
                 w.sample(name, None, sc[key])
+            w.head("wasmedge_batch_transfers_total", "counter",
+                   "Transfers between host and device on the block "
+                   "scheduler's path (batch/pallas_engine.py HostLink): "
+                   "d2h blocks until the device has produced the array, "
+                   "h2d uploads a host mirror or argument rows.")
+            for way in ("d2h", "h2d"):
+                w.sample("wasmedge_batch_transfers_total", {"dir": way},
+                         sc[way + "_transfers"])
+            w.head("wasmedge_batch_programs_enqueued_total", "counter",
+                   "Calls of a compiled program by the block scheduler: "
+                   "the optimistic and the careful kernel and the two "
+                   "programs of block surgery.")
+            w.sample("wasmedge_batch_programs_enqueued_total", None,
+                     sc["programs_enqueued"])
         mst = getattr(recorder, "memory_static", None)
         if mst and "lane_block" in mst:     # a guest with a memory
             w.head("wasmedge_memory_lane_block", "gauge",

@@ -217,7 +217,9 @@ class NullRecorder:
 
     def add_split_counts(self, splits=0, launches=0, rechecks=0,
                          careful_steps=0, surgery_programs=0,
-                         snap_restored=0, snap_commits=0):
+                         snap_restored=0, snap_commits=0,
+                         d2h_transfers=0, h2d_transfers=0,
+                         programs_enqueued=0):
         pass
 
     def add_tierup_counts(self, dispatches, retired_comp, retired_total):
@@ -328,10 +330,14 @@ class FlightRecorder:
         # careful one, the block-steps those rounds retired, and the
         # compiled programs of block surgery, the children given the
         # full snapshot interval back, and the periodic commits the
-        # launches' intervals imply
+        # launches' intervals imply; and its crossings of the host link
+        # (batch/pallas_engine.py HostLink): blocking downloads, uploads,
+        # calls of a compiled program
         self.split_counts = {"splits": 0, "launches": 0, "rechecks": 0,
                              "careful_steps": 0, "surgery_programs": 0,
-                             "snap_restored": 0, "snap_commits": 0}
+                             "snap_restored": 0, "snap_commits": 0,
+                             "d2h_transfers": 0, "h2d_transfers": 0,
+                             "programs_enqueued": 0}
         # compiled-function tier counters folded from the device
         # tu_ctr plane (batch/engine.py _fold_tierup_ctr) + the
         # promotion report set once per plan by _plan_tierup (r20)
@@ -500,22 +506,29 @@ class FlightRecorder:
 
     def add_split_counts(self, splits=0, launches=0, rechecks=0,
                          careful_steps=0, surgery_programs=0,
-                         snap_restored=0, snap_commits=0):
+                         snap_restored=0, snap_commits=0,
+                         d2h_transfers=0, h2d_transfers=0,
+                         programs_enqueued=0):
         """Fold what the block scheduler did in one run
         (batch/scheduler.py): blocks it split, launches of the
         optimistic kernel, rounds of the careful kernel after a
         rollback, the block-steps those rounds retired, the compiled
         programs of block surgery (one that gathers a child's columns,
         one that sets them into a free slot), the children installed
-        with the full snapshot interval in place of a halved one, and
-        the periodic commits the launches' intervals imply.  The engine
-        folds the first five, the scheduler the last two."""
+        with the full snapshot interval in place of a halved one, the
+        periodic commits the launches' intervals imply, and what its
+        HostLink counted: blocking downloads, uploads, calls of a
+        compiled program.  The scheduler folds the two snapshot counts,
+        the engine the rest."""
         for key, n in (("splits", splits), ("launches", launches),
                        ("rechecks", rechecks),
                        ("careful_steps", careful_steps),
                        ("surgery_programs", surgery_programs),
                        ("snap_restored", snap_restored),
-                       ("snap_commits", snap_commits)):
+                       ("snap_commits", snap_commits),
+                       ("d2h_transfers", d2h_transfers),
+                       ("h2d_transfers", h2d_transfers),
+                       ("programs_enqueued", programs_enqueued)):
             self.split_counts[key] += int(n)
 
     def add_tierup_counts(self, dispatches, retired_comp, retired_total):

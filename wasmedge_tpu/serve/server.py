@@ -568,7 +568,11 @@ class BatchServer:
         """One serving round: expire, admit, run one launch slice,
         enforce deadlines/budgets, harvest, checkpoint, autotune.
         Returns True while queued or in-flight work remains."""
-        with self._lock:
+        # `serve/step_enter`, `serve/step_exit` and `_drive`'s
+        # `serve/drive_wait` leave no time of the drive thread between
+        # two `serve/round` spans outside a span
+        with self.obs.timed("serve/step_enter", cat="serve",
+                            track="serve/phases"), self._lock:
             if self.failed is not None:
                 return False
             if self._stepping:
@@ -590,13 +594,16 @@ class BatchServer:
             # neither steal the nap nor zero it.  The sleep itself
             # stays OUTSIDE the lock: submit()/shutdown() from other
             # threads must not block on it.
-            with self._lock:
-                self._stepping = False
-                self._inflight = False   # safety: never strand a waiter
-                self._wake.notify_all()
-                nap, self._pending_backoff = self._pending_backoff, 0.0
-            if nap > 0:
-                time.sleep(nap)
+            with self.obs.timed("serve/step_exit", cat="serve",
+                                track="serve/phases"):
+                with self._lock:
+                    self._stepping = False
+                    self._inflight = False  # safety: never strand a waiter
+                    self._wake.notify_all()
+                    nap, self._pending_backoff = \
+                        self._pending_backoff, 0.0
+                if nap > 0:
+                    time.sleep(nap)
 
     def _step_body(self) -> bool:
         with self.obs.timed("serve/round", cat="serve",
@@ -783,23 +790,35 @@ class BatchServer:
             self._thread.start()
         return self
 
-    def _drive(self):
+    def _wait_for_work(self) -> bool:
+        """Block the drive thread until a round would advance
+        something; False once the server is stopping."""
         while True:
             with self._lock:
                 if self._stop:
+                    return False
+                if self._runnable_work():
+                    return True
+                # nothing a round would advance (possibly parked
+                # sessions waiting on an external wake): sleep on
+                # the condvar — submit()/wake() notify it, and the
+                # 50ms cap bounds timer-wake latency
+                self._wake.wait(timeout=0.05)
+                if self._stop:
+                    return False
+                # still nothing after the wait: don't burn an idle
+                # round (rounds counter, no-op checkpoint checks)
+                if self._runnable_work():
+                    return True
+
+    def _drive(self):
+        while True:
+            # one span from the end of a round to the start of the
+            # next, however many waits an idle server makes in it
+            with self.obs.timed("serve/drive_wait", cat="serve",
+                                track="serve/phases"):
+                if not self._wait_for_work():
                     return
-                if not self._runnable_work():
-                    # nothing a round would advance (possibly parked
-                    # sessions waiting on an external wake): sleep on
-                    # the condvar — submit()/wake() notify it, and the
-                    # 50ms cap bounds timer-wake latency
-                    self._wake.wait(timeout=0.05)
-                    if self._stop:
-                        return
-                    # still nothing after the wait: don't burn an idle
-                    # round (rounds counter, no-op checkpoint checks)
-                    if not self._runnable_work():
-                        continue
             try:
                 self.step()
             except (KeyboardInterrupt, SystemExit):
