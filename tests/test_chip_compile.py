@@ -483,3 +483,52 @@ def test_block_surgery_compiles_for_v5e(case, one_chip):
     assert mem.alias_size_in_bytes == sum(plane_bytes)
     # at most the widened child of the largest plane beside them
     assert mem.temp_size_in_bytes <= max(plane_bytes) + (1 << 20)
+
+
+# cell -> (wasm, value stack, call stack, blocks, lane block): the
+# geometry of the block scheduler's inner engine in each benchmark
+# cell, whose pass record one compiled program packs behind the kernel
+# (batch/pallas_engine.py _pass_record_fn; PR 37)
+_RECORDS = {
+    "fib-1x4096": (_fib_wasm, 256, 256, 1, LANES),
+    "fib-11x512": (_fib_wasm, 256, 256, 11, 512),
+    "memory-1x4096": (_memory_wasm, 128, 64, 1, LANES),
+    "gemm-1x4096": (_gemm_wasm, 128, 64, 1, LANES),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_RECORDS))
+def test_pass_record_compiles_for_v5e(case, one_chip):
+    """ctrl, frames, the trap row and one result row of each stack laid
+    end to end: the pack compiles for the chip at each cell's geometry,
+    and its output is a buffer of its own that aliases no plane."""
+    import jax
+    import jax.numpy as jnp
+
+    from wasmedge_tpu.batch.pallas_engine import _pass_record_fn
+
+    wasm, depth, cdepth, nblk, lblk = _RECORDS[case]
+    eng = _pallas_engine(wasm(), depth, cdepth,
+                         blk_cap=None if lblk == LANES else lblk)
+    D, CD, _W, Lblk = eng._geom
+    assert (D, CD, Lblk) == (depth, cdepth, lblk)
+    lanes = nblk * lblk
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32)
+
+    specs = (i32(nblk, 16), i32(nblk, 3, CD), i32(1, lanes),
+             i32(D, lanes), i32(D, lanes))
+    if lanes == LANES:
+        # what the kernel hands on: ctrl, frames, stacks, the trap plane
+        state = eng._arg_specs()[len(eng._tables):]
+        assert [s.shape for s in specs] == \
+            [state[i].shape for i in (0, 1, 7, 2, 3)]
+    pack = _pass_record_fn()
+    words = nblk * (16 + 3 * CD) + 3 * lanes
+    assert jax.eval_shape(pack, *specs, 1).shape == (words,)
+    compiled = pack.lower(*_on(one_chip, specs), 1).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == 0
+    assert 4 * words <= mem.output_size_in_bytes <= 4 * words + 4096
+    assert mem.temp_size_in_bytes <= 1 << 20

@@ -214,11 +214,11 @@ def test_wake_before_park_delivers_without_parking():
 
 
 def test_timer_park_and_timer_wake():
-    srv = _server(_sleep_mod(60_000_000), wasi=True, lanes=2)  # 60ms
+    srv = _server(_sleep_mod(400_000_000), wasi=True, lanes=2)  # 0.4 s
     fut = srv.submit("nap", [10])
     srv.run_until_idle()
     assert srv.effects.in_flight() == 1 and not srv._bindings
-    time.sleep(0.08)
+    time.sleep(0.45)
     srv.run_until_idle()
     assert fut.result(0)[0] == 11    # n + the single clock event
     st = srv.session_stats()

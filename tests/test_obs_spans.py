@@ -157,13 +157,13 @@ def test_profiler_trace_holds_the_spans_with_obs_off(tmp_path):
     assert names.count("batch/group") == 1
     assert names.count("batch/initial_state") == 1
     # every crossing of the link is a span, counted with obs off too:
-    # the first launch's, the sync's and the harvest's three downloads;
-    # the argument rows, the globals and ctrl at entry, ctrl after the
-    # harvest; one launch of the optimistic kernel
+    # the one pass record at the sync; the argument rows, the globals
+    # and ctrl at entry, ctrl after the harvest; one launch of the
+    # optimistic kernel and the pack of its record behind it
     assert (names.count("batch/d2h"), names.count("batch/h2d"),
             names.count("batch/enqueue")) == \
         (pallas.d2h_transfers, pallas.h2d_transfers,
-         pallas.programs_enqueued) == (5, 6, 1)
+         pallas.programs_enqueued) == (1, 6, 2)
     # both locked sections of every round wait for the lock in a span
     assert names.count("serve/lock_wait") == 2 * ROUNDS
     # opened only where the subsystem is configured
@@ -256,10 +256,7 @@ def test_ring_holds_the_spans_with_their_parents():
             continue
         assert {e["args"]["parent"] for e in evs} == {want}, name
     assert {(e["args"]["what"], e["args"]["parent"])
-            for e in by_name["batch/d2h"]} == {
-        ("ctrl", "batch/run"), ("ctrl", "batch/sync"),
-        ("trap", "batch/harvest"), ("res_lo", "batch/harvest"),
-        ("res_hi", "batch/harvest")}
+            for e in by_name["batch/d2h"]} == {("pass", "batch/sync")}
     assert {(e["args"]["what"], e["args"]["parent"])
             for e in by_name["batch/h2d"]} == {
         ("args_lo", "batch/initial_state"),
@@ -269,7 +266,7 @@ def test_ring_holds_the_spans_with_their_parents():
         ("ctrl", "batch/initial_state"), ("ctrl", "batch/launch")}
     assert [(e["args"]["program"], e["args"]["parent"])
             for e in by_name["batch/enqueue"]] == \
-        [("optimistic", "batch/launch")]
+        [("optimistic", "batch/launch"), ("pack", "batch/launch")]
     # a download's size is known when it has come: it rides the ring
     assert all(e["args"]["bytes"] > 0 for n in ("batch/d2h", "batch/h2d")
                for e in by_name[n])

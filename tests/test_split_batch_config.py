@@ -231,28 +231,29 @@ def test_the_runs_counters_are_the_runs_own(split_job):
 def test_the_runs_crossings_of_the_host_link_are_pinned(split_job):
     """What a job of this mix moves between host and device, as counts
     that repeat: with S = 8 splits and L = 2 S + 1 = 17 launches,
-    downloads: `ctrl` 1 before the first launch + L at the syncs + S in
-    the careful rounds, `trap` L (every pass that harvests or splits),
-    `res_lo` and `res_hi` S + 1 each (the passes that harvest), `frames`
-    S in the splits + S in the installs, S stack rows a `brz` is
-    resolved from; uploads: 5 at entry (two argument rows, two globals,
+    downloads: one pass record (`ctrl`, `frames`, the `trap` row and the
+    result rows in one array) at each of the L syncs and after each of
+    the S careful rounds, and S stack rows a `brz` is resolved from:
+    nothing under `harvest`, `install` or `run`, and no plane on its own
+    (a `what` of `trap`, `res_lo`, `frames` would be a mirror that
+    missed); uploads: 5 at entry (two argument rows, two globals,
     `ctrl`), `ctrl` L at the launches + 2 S in the careful rounds,
-    `frames` 2 S; programs: L + S kernels and 4 S of block surgery."""
+    `frames` 2 S; programs: L + S kernels, a pack behind each, and 4 S
+    of block surgery."""
     _args, _passed, eng, _res, first, _again = split_job
     S, L = 8, 17
-    assert first["d2h_transfers"] == 85 == \
-        (1 + L + S) + L + 2 * (S + 1) + 2 * S + S
+    assert first["d2h_transfers"] == 33 == L + 2 * S
     assert first["h2d_transfers"] == 54 == 5 + (L + 2 * S) + 2 * S
-    assert first["programs_enqueued"] == 57 == \
-        first["launches"] + first["rechecks"] + first["surgery_programs"]
+    assert first["programs_enqueued"] == 82 == 2 * (
+        first["launches"] + first["rechecks"]) + first["surgery_programs"]
     assert {k: getattr(eng.pallas, k) for k in first} == first
     samples = parse_prometheus(render_prometheus(recorder=eng.obs))
     assert samples[("wasmedge_batch_transfers_total",
-                    frozenset({("dir", "d2h")}))] == 2 * 85
+                    frozenset({("dir", "d2h")}))] == 2 * 33
     assert samples[("wasmedge_batch_transfers_total",
                     frozenset({("dir", "h2d")}))] == 2 * 54
     assert samples[("wasmedge_batch_programs_enqueued_total",
-                    frozenset())] == 2 * 57
+                    frozenset())] == 2 * 82
     # each is a span of the ring under the phase that made it
     by_parent = {}
     for e in eng.obs.events:
@@ -261,11 +262,7 @@ def test_the_runs_crossings_of_the_host_link_are_pinned(split_job):
                    or e["args"]["program"], e["args"]["parent"][6:])
             by_parent[key] = by_parent.get(key, 0) + 1
     assert by_parent == {k: 2 * n for k, n in {
-        ("d2h", "ctrl", "run"): 1, ("d2h", "ctrl", "sync"): L,
-        ("d2h", "ctrl", "recheck"): S, ("d2h", "trap", "harvest"): L,
-        ("d2h", "res_lo", "harvest"): S + 1,
-        ("d2h", "res_hi", "harvest"): S + 1,
-        ("d2h", "frames", "split"): S, ("d2h", "frames", "install"): S,
+        ("d2h", "pass", "sync"): L, ("d2h", "pass", "recheck"): S,
         ("d2h", "rows", "split"): S,
         ("h2d", "args_lo", "initial_state"): 1,
         ("h2d", "args_hi", "initial_state"): 1,
@@ -275,7 +272,9 @@ def test_the_runs_crossings_of_the_host_link_are_pinned(split_job):
         ("h2d", "ctrl", "recheck"): 2 * S,
         ("h2d", "frames", "launch"): 2 * S,
         ("enqueue", "optimistic", "launch"): L,
+        ("enqueue", "pack", "launch"): L,
         ("enqueue", "careful", "recheck"): S,
+        ("enqueue", "pack", "recheck"): S,
         ("enqueue", "extract", "split"): 2 * S,
         ("enqueue", "install", "install"): 2 * S}.items()}
 
@@ -398,8 +397,9 @@ def test_careful_recheck_crosses_a_link_from_the_engines_own_drive_too(
         monkeypatch):
     """`careful_recheck` has two callers; from `engine._drive`
     (`PallasUniformEngine._run_recheck`, the multi-tenant path) its two
-    uploads, its enqueue and its download lie under the same three leaf
-    spans, opened on the engine's own recorder."""
+    uploads, its two enqueues (the careful kernel, then the pack of its
+    pass record behind it) and its one download lie under the same three
+    leaf spans, opened on the engine's own recorder."""
     import contextlib
 
     sched = _two_blocks(monkeypatch)
@@ -421,7 +421,8 @@ def test_careful_recheck_crosses_a_link_from_the_engines_own_drive_too(
     ctrl_np[0, _C_STATUS] = ST_RECHECK
     _state, ctrl = inner._run_recheck(list(sched.state), ctrl_np)
     assert opened == [("batch/h2d", "ctrl"), ("batch/enqueue", "careful"),
-                      ("batch/d2h", "ctrl"), ("batch/h2d", "ctrl")]
+                      ("batch/enqueue", "pack"), ("batch/d2h", "pass"),
+                      ("batch/h2d", "ctrl")]
     assert ctrl[0, _C_STATUS] != ST_RECHECK
 
 
@@ -453,7 +454,7 @@ def test_profiler_trace_holds_the_split_spans_with_obs_off(tmp_path):
     assert (names.count("batch/d2h"), names.count("batch/h2d"),
             names.count("batch/enqueue")) == \
         (pallas.d2h_transfers, pallas.h2d_transfers,
-         pallas.programs_enqueued) == (85, 54, 57)
+         pallas.programs_enqueued) == (33, 54, 82)
     assert names.count("batch/split") == names.count("batch/recheck") == 8
     # every child is installed: two a split
     assert names.count("batch/install") == 16

@@ -54,7 +54,7 @@ from __future__ import annotations
 
 import collections
 import functools
-from typing import List, Optional
+from typing import List, NamedTuple, Optional
 
 import numpy as np
 
@@ -4043,6 +4043,51 @@ class HostLink:
             return fn(*args)
 
 
+class PassRecord(NamedTuple):
+    """What the host reads to decide a pass, as it left the device in
+    one download (`_pass_record_fn`).  `ctrl` and `frames` are the
+    host's own copies (the scheduler writes its mirrors); the rest are
+    read-only views of the downloaded buffer."""
+
+    ctrl: np.ndarray      # [nblk, 16]
+    frames: np.ndarray    # [nblk, 3, CD]
+    trap: np.ndarray      # [L]: the trap plane's one row
+    res_lo: np.ndarray    # [nres, L]: rows [:nres] of stack_lo
+    res_hi: np.ndarray    # [nres, L]: and of stack_hi
+
+
+def _pass_record_fn():
+    """-> pack(ctrl, frames, trap, stack_lo, stack_hi, nres), the
+    compiled program that lays `PassRecord`'s five parts end to end in
+    one flat int32 array (`nres` static).  Enqueued behind the kernel
+    whose outputs it reads, it turns the two to five blocking downloads
+    of a pass into one: a download costs its round trip, not its bytes.
+    Its output is a buffer of its own; it holds no plane alive."""
+    import jax
+    import jax.numpy as jnp
+
+    def pack(ctrl, frames, trap, stack_lo, stack_hi, nres):
+        return jnp.concatenate([
+            ctrl.ravel(), frames.ravel(), trap.ravel(),
+            stack_lo[:nres].ravel(), stack_hi[:nres].ravel()])
+
+    return jax.jit(pack, static_argnums=5)
+
+
+def _split_pass_record(flat: np.ndarray, nblk: int, cd: int, lanes: int,
+                       nres: int) -> PassRecord:
+    """`pack`'s layout read back: it follows from the shapes alone."""
+    sizes = (nblk * 16, nblk * 3 * cd, lanes, nres * lanes, nres * lanes)
+    if flat.shape != (sum(sizes),):
+        raise ValueError(f"pass record of {flat.shape}, not {sum(sizes)}")
+    ctrl, frames, trap, res_lo, res_hi = np.split(
+        flat, np.cumsum(sizes)[:-1])
+    return PassRecord(ctrl.reshape(nblk, 16).copy(),
+                      frames.reshape(nblk, 3, cd).copy(), trap,
+                      res_lo.reshape(nres, lanes),
+                      res_hi.reshape(nres, lanes))
+
+
 class PallasUniformEngine:
     """Block-converged engine running the dispatch loop on-device.
 
@@ -4096,6 +4141,7 @@ class PallasUniformEngine:
         self.optimistic = True if opt is None else bool(opt)
         self._fn = None
         self._fn_careful_cache = None
+        self._pack_cache = None
         self._tables = None
         self._blk_cap = None  # lane-block ceiling (multi-tenant alignment)
         self.fell_back_to_simt = False
@@ -4471,6 +4517,23 @@ class PallasUniformEngine:
                 softfloat=self.counts_softfloat)
         return self._fn_careful_cache
 
+    def enqueue_pass_record(self, state, nres, link):
+        """Enqueue the pack of `state`'s pass record behind whatever
+        program produced `state`; -> the record, still on the device.
+        The program is one an engine (so one a geometry and `nres`: it
+        compiles in a warm-up job, like the surgery pair)."""
+        if self._pack_cache is None:
+            self._pack_cache = _pass_record_fn()
+        return link.enqueue("pack", self._pack_cache, state[0], state[1],
+                            state[7], state[2], state[3], nres)
+
+    def read_pass_record(self, record, nres, link) -> PassRecord:
+        """The one download of a pass: blocks until the program the
+        record was enqueued behind has ended."""
+        return _split_pass_record(
+            link.d2h("pass", record), self.lanes // self._geom[3],
+            self._geom[1], self.lanes, nres)
+
     def shadow_planes(self):
         """Fresh rollback-shadow planes matching this geometry (appended
         to the kernel state list; contents only matter intra-launch)."""
@@ -4632,7 +4695,7 @@ class PallasUniformEngine:
                 continue
             return state, steps_per_block, statuses
 
-    def careful_recheck(self, state, ctrl_np, recheck_mask, link):
+    def careful_recheck(self, state, ctrl_np, recheck_mask, link, nres=0):
         """ONE recheck protocol for both drive paths (engine._drive and
         BlockScheduler): re-run ST_RECHECK blocks on the careful kernel
         for one short chunk.  An optimistic rollback rewound them to
@@ -4640,10 +4703,14 @@ class PallasUniformEngine:
         the divergent instruction and stops there with the precise
         status (DIVERGED/trap/...), after which normal handling
         proceeds.  Non-recheck blocks get chunk=0 (zero steps, state
-        untouched).  Returns (state, ctrl_np) with saved chunk restored
-        and non-recheck step counts zeroed so callers' accounting is
-        exact.  Its two uploads, its download and its enqueue go
-        through the caller's `link` (HostLink)."""
+        untouched).  Returns (state, record): the round's pass record
+        (`PassRecord`, with rows [:nres] of the stacks), packed behind
+        the careful kernel and downloaded once, its `ctrl` with the
+        saved chunk restored and non-recheck step counts zeroed so
+        callers' accounting is exact; that `ctrl` is uploaded again,
+        the record's other parts are the planes as they stand.  Its two
+        uploads, its download and its two enqueues go through the
+        caller's `link` (HostLink)."""
         self.recheck_rounds += 1
         ctrl = ctrl_np.copy()
         saved_chunk = ctrl[:, _C_CHUNK].copy()
@@ -4662,19 +4729,22 @@ class PallasUniformEngine:
         out = link.enqueue("careful", self._fn_careful(), *self._tables,
                            state[0], state[1], *state[2:])
         state = list(out)
-        ctrl = link.d2h("ctrl", state[0]).copy()
+        rec = self.read_pass_record(
+            self.enqueue_pass_record(state, nres, link), nres, link)
+        ctrl = rec.ctrl
         ctrl[:, _C_CHUNK] = saved_chunk
         # blocks that ran clean past the divergence window resume
         # optimistic on the next launch
         ctrl[:, _C_STEPS] = np.where(recheck_mask, ctrl[:, _C_STEPS], 0)
         state[0] = link.h2d("ctrl", ctrl)
-        return state, ctrl
+        return state, rec
 
     def _run_recheck(self, state, ctrl_np):
         recheck = ctrl_np[:, _C_STATUS] == ST_RECHECK
-        return self.careful_recheck(
+        state, rec = self.careful_recheck(
             state, ctrl_np, recheck,
             HostLink(functools.partial(self.obs.timed, cat="scheduler")))
+        return state, rec.ctrl
 
     def _to_simt_state(self, state, steps_per_block):
         """Expand per-block scalars to the SIMT engine's per-lane layout."""

@@ -374,10 +374,19 @@ class BlockScheduler:
         self._simt_queue: List[_Pending] = []
         self._pending_serve = None   # tier-2 deferred hostcall serve
         self._serve_rearms = None
+        # Host mirrors of what decides a pass, filled together from the
+        # pass record (one download a pass).  THE RULE: a mirror is
+        # valid from the record that filled it until the next program
+        # that writes its plane (a kernel, the careful kernel, an
+        # install, a hostcall serve's finish); that write drops it, and
+        # a read that misses downloads the plane itself.
         self._ctrl_cache = None
         self._ctrl_dirty = False
         self._frames_cache = None
         self._frames_dirty = False
+        self._drop_plane_mirrors()
+        # the record enqueued behind the last launch, until it is read
+        self._record = None
 
     def _build_initial_state(self):
         """Construct the packed state ON DEVICE.  Host->device bandwidth
@@ -432,6 +441,10 @@ class BlockScheduler:
         ctrl[:, _C_CHUNK] = self.cfg.steps_per_launch
         fuel = self.cfg.fuel_per_launch
         ctrl[:, _C_FUEL] = _FUEL_OFF if fuel is None else fuel
+        # the host built both, so it holds them: no download before the
+        # first launch (the mirror is the host's own copy)
+        self._ctrl_cache = ctrl.copy()
+        self._frames_cache = np.zeros((self.nblk, 3, CD), np.int32)
         self.state = [h2d("ctrl", ctrl),
                       jnp.zeros((self.nblk, 3, CD), jnp.int32),
                       stack_lo, stack_hi, glo, ghi, mem,
@@ -469,17 +482,20 @@ class BlockScheduler:
         self.obs.add_split_counts(snap_restored=self.snap_restored,
                                   snap_commits=self.snap_commits)
 
-    def _finish_pending_serve(self):
+    def _finish_pending_serve(self) -> bool:
         """Phase 2 of a deferred hostcall serve: host-side WASI work
         overlapping the in-flight kernel; re-armed ctrl rows are folded
-        into the mirror by process() after it syncs on the launch."""
+        into the mirror by process() after it syncs on the launch.
+        True if it ran: it writes result rows and trap columns into the
+        live planes."""
         p = self._pending_serve
         if p is None:
-            return
+            return False
         self._pending_serve = None
         self.state, rearms = self.eng._serve_hostcalls_finish(
             self.state, p)
         self._serve_rearms = rearms
+        return True
 
     def launch(self):
         """Dispatch one kernel round if any block is runnable.  The
@@ -505,13 +521,39 @@ class BlockScheduler:
                     "optimistic", self.eng._fn, *self.eng._tables,
                     self.state[0], self.state[1], *self.state[2:])
                 self.state = list(out)
-                self._ctrl_cache = None   # kernel wrote fresh ctrl/frames
-                self._frames_cache = None
+                # the kernel writes every plane: no mirror outlives it.
+                # Its pass record is enqueued here, behind it, so the
+                # host's part of the call runs while the kernel does
+                self._ctrl_cache = self._frames_cache = None
+                self._drop_plane_mirrors()
+                self._record = self.eng.enqueue_pass_record(
+                    self.state, self.nres, self.link)
+
+    def _drop_plane_mirrors(self):
+        """A program wrote the trap plane and the stacks."""
+        self._trap_full = self._res_lo_full = self._res_hi_full = None
+
+    def _fill_mirrors(self, rec):
+        self._ctrl_cache, self._frames_cache = rec.ctrl, rec.frames
+        self._ctrl_dirty = self._frames_dirty = False
+        self._trap_full = rec.trap
+        self._res_lo_full, self._res_hi_full = rec.res_lo, rec.res_hi
+
+    def _take_record(self):
+        """Read the last launch's pass record, if it is still out (every
+        mirror is empty then): the ONE transfer of a kernel round, which
+        waits for the kernel."""
+        if self._record is not None:
+            rec = self.eng.read_pass_record(self._record, self.nres,
+                                            self.link)
+            self._record = None
+            self._fill_mirrors(rec)
 
     def _ctrl(self) -> np.ndarray:
-        """Host mirror of the ctrl plane: ONE transfer per kernel round.
-        Every per-block interaction below reads/writes this mirror (tiny
-        transfers each pay the host link's full round-trip latency)."""
+        """Host mirror of the ctrl plane.  Every per-block interaction
+        below reads/writes this mirror (tiny transfers each pay the host
+        link's full round-trip latency)."""
+        self._take_record()
         if self._ctrl_cache is None:
             self._ctrl_cache = self.link.d2h("ctrl", self.state[0]).copy()
             self._ctrl_dirty = False
@@ -519,11 +561,28 @@ class BlockScheduler:
 
     def _frames(self) -> np.ndarray:
         """Host mirror of the frames plane (same discipline as _ctrl)."""
+        self._take_record()
         if self._frames_cache is None:
             self._frames_cache = \
                 self.link.d2h("frames", self.state[1]).copy()
             self._frames_dirty = False
         return self._frames_cache
+
+    def _trap(self) -> np.ndarray:
+        """Host mirror of the trap plane's row, read-only."""
+        self._take_record()
+        if self._trap_full is None:
+            self._trap_full = self.link.d2h("trap", self.state[7], 0)
+        return self._trap_full
+
+    def _res(self):
+        """Host mirrors of the result rows: ([nres, L] lo, hi)."""
+        self._take_record()
+        if self._res_lo_full is None:
+            rows = np.s_[:self.nres]
+            self._res_lo_full = self.link.d2h("res_lo", self.state[2], rows)
+            self._res_hi_full = self.link.d2h("res_hi", self.state[3], rows)
+        return self._res_lo_full, self._res_hi_full
 
     def _upload_frames(self):
         """The frames mirror back onto the device, if a child's install
@@ -539,9 +598,13 @@ class BlockScheduler:
         # phase 2 of a serve captured by the PREVIOUS process(): the
         # host-side WASI work runs now, before we sync on the launch
         # dispatched in between — CPU drain overlapping device compute
-        self._finish_pending_serve()
+        served_now = self._finish_pending_serve()
         with self._phase("batch/sync"):
-            ctrl_np = self._ctrl()   # waits for the launched kernel
+            ctrl_np = self._ctrl()   # the pass record: waits for the
+            #                          launched kernel
+        if served_now:
+            # the serve's finish wrote after that record was packed
+            self._drop_plane_mirrors()
         served = False
         if self._serve_rearms:
             # fold the overlapped serve's re-arms into the fresh mirror
@@ -639,15 +702,16 @@ class BlockScheduler:
         recheck = live & (self._ctrl()[:, _C_STATUS] == ST_RECHECK)
         with self._phase("batch/recheck", blocks=int(recheck.sum())):
             self._upload_frames()
-            self.state, ctrl = self.eng.careful_recheck(
-                self.state, self._ctrl(), recheck, self.link)
+            self.state, rec = self.eng.careful_recheck(
+                self.state, self._ctrl(), recheck, self.link, self.nres)
+        ctrl = rec.ctrl
         self.rechecks += 1
         self.careful_steps += int(ctrl[recheck, _C_STEPS].sum())
         self.block_steps += ctrl[:, _C_STEPS].astype(np.int64)
         self._count_kernel(ctrl, recheck)
-        self._ctrl_cache = ctrl
-        self._ctrl_dirty = False
-        self._frames_cache = None
+        # the careful kernel wrote every plane and the record was packed
+        # behind it: every mirror is fresh (`ctrl` as uploaded again)
+        self._fill_mirrors(rec)
         return ctrl
 
     def _handle_statuses(self, ctrl_np) -> bool:
@@ -659,8 +723,7 @@ class BlockScheduler:
     def _statuses(self, ctrl_np) -> bool:
         progress = False
         hostcall_blocks = []
-        # classify first so the downloads below batch into single
-        # transfers covering every block that needs them
+        # classify first: every harvest, then every split
         harvests = []
         splits = []
         for b in range(self.nblk):
@@ -677,16 +740,9 @@ class BlockScheduler:
                 hostcall_blocks.append(b)
             elif status in (ST_DIVERGED, ST_REGROW):
                 splits.append((b, status))
-        if harvests or splits:
-            # one download a plane for every block that needs it (the
-            # trap plane for the splits too)
+        if harvests:
+            # out of the mirrors the pass record filled
             with self._phase("batch/harvest", blocks=len(harvests)):
-                d2h = self.link.d2h
-                self._trap_full = d2h("trap", self.state[7], 0)
-                if self.nres and harvests:
-                    rows = np.s_[:self.nres]
-                    self._res_lo_full = d2h("res_lo", self.state[2], rows)
-                    self._res_hi_full = d2h("res_hi", self.state[3], rows)
                 for b, running in harvests:
                     self._harvest(b, ctrl_np, running=running)
                     progress = True
@@ -722,18 +778,19 @@ class BlockScheduler:
         valid = ids >= 0
         vids = ids[valid].astype(np.int64)
         status = int(ctrl_np[b, _C_STATUS])
-        trap_row = self._trap_full[lo:lo + Lblk]
         if running:
-            codes = trap_row.copy()  # 0 = still running
+            codes = self._trap()[lo:lo + Lblk].copy()  # 0 = still running
         elif status == ST_DONE:
             codes = np.full(Lblk, TRAP_DONE, np.int32)
             if self.nres:
+                res_lo, res_hi = self._res()
                 self.res_lo[:self.nres, vids] = \
-                    self._res_lo_full[:, lo:lo + Lblk][:, valid]
+                    res_lo[:, lo:lo + Lblk][:, valid]
                 self.res_hi[:self.nres, vids] = \
-                    self._res_hi_full[:, lo:lo + Lblk][:, valid]
+                    res_hi[:, lo:lo + Lblk][:, valid]
         else:
             code = status - ST_TRAPPED_BASE
+            trap_row = self._trap()[lo:lo + Lblk]
             codes = np.where(trap_row != 0, trap_row, code).astype(np.int32)
         self.trap[vids] = codes[valid]
         self.retired[vids] = self.block_steps[b]
@@ -770,7 +827,7 @@ class BlockScheduler:
         lo = b * self.Lblk
         advanced = int(status == ST_DIVERGED
                        and b not in self._served_stops
-                       and self._trap_full[lo:lo + self.Lblk].any())
+                       and self._trap()[lo:lo + self.Lblk].any())
         self._served_stops.discard(b)
         if status == ST_REGROW or self.splits > self.split_budget:
             self._to_simt(b, ctrl, frames, pages_over, advanced)
@@ -805,7 +862,7 @@ class BlockScheduler:
         # stack rows; whole-plane transfers would ride the slow host link
         slo = _Rows(self.link.d2h, self.state[2], lo, Lblk)
         shi = _Rows(self.link.d2h, self.state[3], lo, Lblk)
-        trap_row = self._trap_full[lo:lo + Lblk]
+        trap_row = self._trap()[lo:lo + Lblk]
 
         # Advanced-with-per-lane-outcomes stops come FIRST, regardless of
         # what instruction ctrl now points at: trap-partial sites (div/rem
@@ -1083,6 +1140,7 @@ class BlockScheduler:
                 _clone_pad(n, Lblk), np.int32(b * Lblk))
             for i, plane in zip(planes, out):
                 self.state[i] = plane
+            self._drop_plane_mirrors()   # it wrote the slot's columns
             ctrl[b] = p.ctrl
             frames[b] = p.frames
             ids = np.full(Lblk, -1, np.int64)
