@@ -45,6 +45,11 @@ GEMM_CELL_METRICS = [
     "window_fills_per_job.batch", "window_writebacks_per_job.batch",
     "window_miss_share.batch", "instr_per_dispatch.batch",
     "softfloat_ops_per_job.batch", "window_hbm_share.batch"]
+# and the cell of the v128 guest (PR 38): gemm's but the softfloat
+# routines, and the v128 instructions a job in their place
+CHACHA_CELL_METRICS = [
+    m for m in GEMM_CELL_METRICS if m != "softfloat_ops_per_job.batch"] \
+    + ["simd_ops_per_job.batch"]
 
 
 # the self times and counts of the scheduler's transfers and enqueues
@@ -58,7 +63,7 @@ HOST_LINK_METRICS = [
 def test_the_manifest_lists_the_batch_cells():
     assert CELLS == ["batch-fib30-uniform", "batch-mem-uniform",
                      "batch-fib-divergent", "batch-fib-split",
-                     "batch-gemm-small"]
+                     "batch-gemm-small", "batch-chacha20-192k"]
     used = {w["config"] for w in MANIFEST["workloads"]}
     assert used == {c["name"] for c in MANIFEST["configs"]}
     # every batch cell reports what the uniform fib cell reports
@@ -82,13 +87,20 @@ def test_the_manifest_lists_the_batch_cells():
     # the gemm cell reports all the uniform fib cell does, and six more
     assert sorted(reported("batch-gemm-small")) == sorted(
         reported(CELLS[0]) + GEMM_CELL_METRICS)
+    # the ChaCha20 cell the same but softfloat's for its own one
+    assert sorted(reported("batch-chacha20-192k")) == sorted(
+        reported(CELLS[0]) + CHACHA_CELL_METRICS)
     # the host's account (PR 36) is every batch cell's
     assert set(HOST_LINK_METRICS) <= set(reported(CELLS[0]))
     for m in MANIFEST["per_layer"]:
-        for own, cell in ((SPLIT_CELL_METRICS, "batch-fib-split"),
-                          (GEMM_CELL_METRICS, "batch-gemm-small")):
+        for own, cells in (
+                (SPLIT_CELL_METRICS, ["batch-fib-split"]),
+                (["softfloat_ops_per_job.batch"], ["batch-gemm-small"]),
+                (set(GEMM_CELL_METRICS) & set(CHACHA_CELL_METRICS),
+                 ["batch-gemm-small", "batch-chacha20-192k"]),
+                (["simd_ops_per_job.batch"], ["batch-chacha20-192k"])):
             if m["name"] in own:
-                assert m["workloads"] == [cell]
+                assert m["workloads"] == cells
                 assert m["moves"] == "batch_ginstr_per_s"
 
 
@@ -138,7 +150,8 @@ def test_batch_cell_names_a_guest_the_program_has(name):
     # that splits and the one with 4096 arguments add that nothing falls
     # back to the per-step engine
     assert len(config["guarantees"]) == (
-        4 if name in ("batch-fib-split", "batch-gemm-small") else 3)
+        4 if name in ("batch-fib-split", "batch-gemm-small",
+                      "batch-chacha20-192k") else 3)
 
 
 # (length, sha256) of the two guests that moved out of the root's
@@ -150,6 +163,35 @@ _MOVED_GUESTS = {
     "build_simd_kernel": (193, "c016b6adf02c00cc0ec4fedc630a452d"
                                "2c39189908a85d9a89f3f5bac45c3ee6"),
 }
+
+
+# (length, sha256) of the guests the benchmark's configurations build by
+# name with their `guest.args` (not in `models.__all__`): the bytes a
+# cell ran are the bytes its numbers belong to
+_CELL_GUESTS = {
+    "build_memory_batch": ({}, (
+        165, "d10af4ba8559e4ebc5f46539df878cc9"
+             "be1309399ce46f4ac7addb106bef258d")),
+    "build_polybench_gemm": ({"ni": 60, "nj": 70, "nk": 80}, (
+        557, "adff256bdd086d57115061d1a608a75a"
+             "ddc42fed1aab130f4b2fc8d9e6a23cbb")),
+    "build_chacha20": ({"blocks": 3072}, (
+        1341, "d66ea7d01a6c52b2c26ad42759e19780"
+              "31ad524be70bd25dcf60daf91575caf7")),
+}
+
+
+@pytest.mark.parametrize("builder", sorted(_CELL_GUESTS))
+def test_the_cells_guests_are_the_bytes_that_were_measured(builder):
+    import hashlib
+
+    kwargs, pinned = _CELL_GUESTS[builder]
+    data = getattr(models, builder)(**kwargs)
+    assert (len(data), hashlib.sha256(data).hexdigest()) == pinned
+    assert data == getattr(models, builder)()     # the defaults are these
+    configs = [_cell(name)[1] for name in CELLS]
+    assert any(c["guest"]["builder"] == builder
+               and c["guest"].get("args", {}) == kwargs for c in configs)
 
 
 @pytest.mark.parametrize("builder", models.__all__)

@@ -98,6 +98,16 @@ def _gemm_wasm():
     return build_polybench_gemm()
 
 
+def _chacha20_wasm():
+    """The benchmark's chacha20-simd-4096 at 3,072 blocks: ten v128
+    locals, blocks of 24 ops that hold v128 arithmetic and shuffles,
+    16-byte loads and stores behind the HBM window over four pages a
+    lane, the v128 instruction count in a 17th ctrl column."""
+    from wasmedge_tpu.models.programs import build_chacha20
+
+    return build_chacha20()
+
+
 def _superblock_wasm():
     """A guest with a memory whose hot block is a superblock of every
     kind (PR 29): the guard's tail ends in a `call` (of a callee with a
@@ -189,6 +199,12 @@ _KERNELS = {
     "gemm-auto": (_gemm_wasm, 128, 64, None, None, False, (4096, True)),
     "gemm-auto-careful": (_gemm_wasm, 128, 64, None, None, True,
                           (4096, True)),
+    # four pages a lane (1 GiB of plane), four stack planes, the v128
+    # counter and the wider ctrl row in the kernel (PR 38)
+    "chacha20-auto": (_chacha20_wasm, 64, 16, None, None, False,
+                      (4096, True)),
+    "chacha20-auto-careful": (_chacha20_wasm, 64, 16, None, None, True,
+                              (4096, True)),
     # a superblock with a jump and a tail ending in `call`, behind the
     # HBM window: the guard for the eleven nested regions Mosaic's
     # layout inference survives (tails hold no memory op, so there is
@@ -245,26 +261,84 @@ def _region_depth(jaxpr, depth=0):
 # build.  The gemm kernel of PR 34 is twelve deep (fused blocks with
 # three windowed accesses and softfloat between them), compiles here
 # and ran on the chip: what the recursion's stack holds is not a count
-# of levels alone, so twelve is pinned for that kernel only and its
-# compile above is the guard.
+# of levels alone, so twelve is pinned for that kernel and for PR 38's
+# ChaCha20 kernel only, and their compiles above are the guard.
 _DEPTHS = {
     "fib": (_fib_wasm, 256, 256, (9, 7)),
     "memory-auto": (_memory_wasm, 128, 64, (11, 10)),
     "superblock-call-tail": (_superblock_wasm, 128, 64, (10, 9)),
     "gemm-auto": (_gemm_wasm, 128, 64, (12, 10)),
+    # PR 38: twelve too (16-byte accesses behind the window at the end
+    # of blocks of v128 ops); its compile above is the guard, as gemm's
+    "chacha20-auto": (_chacha20_wasm, 64, 16, (12, 11)),
 }
+_JAXPRS = {}
+
+
+def _kernel_jaxprs(case):
+    """(engine, the optimistic kernel's jaxpr, the careful kernel's) of
+    a `_DEPTHS` guest at 4096 lanes, traced once a process."""
+    import jax
+
+    if case not in _JAXPRS:
+        wasm, depth, cdepth, _expect = _DEPTHS[case]
+        eng = _pallas_engine(wasm(), depth, cdepth)
+        _JAXPRS[case] = (eng,) + tuple(
+            jax.make_jaxpr(fn)(*eng._arg_specs())
+            for fn in (eng._fn, eng._fn_careful()))
+    return _JAXPRS[case]
 
 
 @pytest.mark.parametrize("case", sorted(_DEPTHS))
 def test_kernel_region_depth(case, one_chip):
+    expect = _DEPTHS[case][3]
+    got = tuple(_region_depth(j.jaxpr) for j in _kernel_jaxprs(case)[1:])
+    assert got == expect and max(got) <= (
+        12 if case in ("gemm-auto", "chacha20-auto") else 11)
+
+
+# kernel -> sha256 of its jaxprs' text (optimistic, careful) as PR 37
+# left them: what the benchmark's fib, memory and gemm cells run.  PR 38
+# put a counter and a wider ctrl row into the kernel of an image with
+# v128 and into no other, and this holds it: the same jaxpr is the same
+# Mosaic kernel.  A PR that changes one of these kernels on purpose pins
+# its new text here (PR 30 to PR 37 compared the same hashes by hand).
+_JAXPR_SHA256 = {
+    "fib": ("8db265711534ef9316ee8e8ef5d9966d96e5dcb5467ecbcab13df9071280523e",
+            "2c052f7365d0a1b29447cc696f01da20f1ddff45dd881ea8a6900174d416a930"),
+    "memory-auto": (
+        "b9709bbfabbf42ad2bcbaaea636bb9059999a1437343e0096980fe0002f598ad",
+        "edfb28e251ac75af6e39b566578a7b5bf8f0475ae7eeccd0500f6dd9bb3a0da2"),
+    "gemm-auto": (
+        "f6aa6f7107511f5fdcea7c97f3ec6ea4428e0d56b5164c9e5476f773b6f41368",
+        "7962a15a5eeb87eaa37d0d41a95331914c2de69de698c2b67822716724abcdef"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_JAXPR_SHA256))
+def test_kernels_without_v128_are_the_parents(case, one_chip):
+    import hashlib
+
     import jax
 
-    wasm, depth, cdepth, expect = _DEPTHS[case]
-    eng = _pallas_engine(wasm(), depth, cdepth)
-    got = tuple(
-        _region_depth(jax.make_jaxpr(fn)(*eng._arg_specs()).jaxpr)
-        for fn in (eng._fn, eng._fn_careful()))
-    assert got == expect and max(got) <= (12 if case == "gemm-auto" else 11)
+    eng, *jaxprs = _kernel_jaxprs(case)
+    assert not eng.img.has_simd and eng.ctrl_width == 16
+    assert tuple(hashlib.sha256(str(j).encode()).hexdigest()
+                 for j in jaxprs) == _JAXPR_SHA256[case]
+    # the ctrl row the kernel hands back is sixteen columns wide
+    nblk = LANES // eng._geom[3]
+    assert jax.eval_shape(eng._fn, *eng._arg_specs())[0].shape == (nblk, 16)
+
+
+def test_a_v128_kernel_counts_in_a_seventeenth_column(one_chip):
+    import jax
+
+    eng = _kernel_jaxprs("chacha20-auto")[0]
+    assert eng.img.has_simd and eng.ctrl_width == 17
+    specs = eng._arg_specs()
+    assert specs[len(eng._tables)].shape == (1, 17)
+    for fn in (eng._fn, eng._fn_careful()):
+        assert jax.eval_shape(fn, *specs)[0].shape == (1, 17)
 
 
 def _holds(jaxpr, name):
@@ -494,6 +568,7 @@ _RECORDS = {
     "fib-11x512": (_fib_wasm, 256, 256, 11, 512),
     "memory-1x4096": (_memory_wasm, 128, 64, 1, LANES),
     "gemm-1x4096": (_gemm_wasm, 128, 64, 1, LANES),
+    "chacha20-1x4096": (_chacha20_wasm, 64, 16, 1, LANES),
 }
 
 
@@ -517,7 +592,9 @@ def test_pass_record_compiles_for_v5e(case, one_chip):
     def i32(*shape):
         return jax.ShapeDtypeStruct(shape, jnp.int32)
 
-    specs = (i32(nblk, 16), i32(nblk, 3, CD), i32(1, lanes),
+    ctrl_w = eng.ctrl_width     # 16; 17 for an image with v128
+    assert ctrl_w == (17 if case.startswith("chacha20") else 16)
+    specs = (i32(nblk, ctrl_w), i32(nblk, 3, CD), i32(1, lanes),
              i32(D, lanes), i32(D, lanes))
     if lanes == LANES:
         # what the kernel hands on: ctrl, frames, stacks, the trap plane
@@ -525,7 +602,7 @@ def test_pass_record_compiles_for_v5e(case, one_chip):
         assert [s.shape for s in specs] == \
             [state[i].shape for i in (0, 1, 7, 2, 3)]
     pack = _pass_record_fn()
-    words = nblk * (16 + 3 * CD) + 3 * lanes
+    words = nblk * (ctrl_w + 3 * CD) + 3 * lanes
     assert jax.eval_shape(pack, *specs, 1).shape == (words,)
     compiled = pack.lower(*_on(one_chip, specs), 1).compile()
     mem = compiled.memory_analysis()

@@ -19,7 +19,7 @@ from wasmedge_tpu.common.errors import ErrCode, TrapError
 from wasmedge_tpu.common.statistics import Statistics
 from wasmedge_tpu.executor import Executor
 from wasmedge_tpu.loader import Loader
-from wasmedge_tpu.models import build_fib
+from wasmedge_tpu.models import build_fib, build_simd_kernel
 from wasmedge_tpu.runtime.store import StoreManager
 from wasmedge_tpu.utils.builder import ModuleBuilder
 from wasmedge_tpu.validator import Validator
@@ -131,17 +131,28 @@ def _fib_shattered():
     return build_fib(), "fib", [(np.arange(LANES, dtype=np.int64) % 7) + 4]
 
 
+def _v128_through_splits():
+    """A `has_simd` image: the ctrl row is one column wider (the v128
+    instruction count), a cell is four stack planes, and the loop's
+    trip count, from five values no grouping takes, splits the block
+    with two v128 locals live."""
+    return build_simd_kernel(), "vloop", [
+        (np.arange(LANES, dtype=np.int64) % 5) + 2]
+
+
 @pytest.mark.parametrize("guest,blk_cap,splits", [
     (_div_then_if, 8, True), (_div_then_if, None, True),
     (_no_result, None, True), (_i64_result, None, False),
     (_i64_through_splits, None, True), (_fib_shattered, None, True),
-    (_fib_shattered, 16, True)],
+    (_fib_shattered, 16, True), (_v128_through_splits, None, True)],
     ids=["traps-4-blocks", "traps-1-block", "no-result", "i64-result",
-         "i64-through-splits", "frames-1-slot", "frames-2-slots"])
+         "i64-through-splits", "frames-1-slot", "frames-2-slots",
+         "v128-wider-ctrl"])
 def test_every_lane_is_exact_out_of_the_record(downloads, guest, blk_cap,
                                                splits):
     data, func, per_lane = guest()
     _ex, _store, _inst, eng = make_engine(data, lanes=LANES)
+    assert eng.ctrl_width == (17 if eng.img.has_simd else 16)
     eng._blk_cap = blk_cap
     res = eng.run(func, per_lane, max_steps=2_000_000)
     assert not eng.fell_back_to_simt
@@ -346,20 +357,32 @@ def test_a_mirror_that_misses_falls_back_to_the_plane(downloads):
     assert np.asarray(sched.result().results[0]).tolist() == [34] * LANES
 
 
-@pytest.mark.parametrize("nblk,cd,lanes,nres", [
-    (1, 256, 4096, 1), (11, 256, 5632, 1), (4, 16, 32, 0), (2, 8, 16, 2)])
-def test_the_records_layout_follows_from_the_shapes(nblk, cd, lanes, nres):
+@pytest.mark.parametrize("nblk,cd,lanes,nres,ctrl_w", [
+    (1, 256, 4096, 1, 16), (11, 256, 5632, 1, 16), (4, 16, 32, 0, 16),
+    (2, 8, 16, 2, 16), (1, 16, 4096, 1, 17), (3, 16, 24, 2, 17)])
+def test_the_records_layout_follows_from_the_shapes(nblk, cd, lanes, nres,
+                                                    ctrl_w):
+    """At both widths of a ctrl row: 16 columns, and the 17 of an image
+    with v128."""
     rng = np.random.default_rng(nblk * 1000 + nres)
 
     def plane(*shape):
         return rng.integers(-2 ** 31, 2 ** 31, shape).astype(np.int32)
 
-    ctrl, frames, trap = plane(nblk, 16), plane(nblk, 3, cd), plane(1, lanes)
+    ctrl, frames, trap = (plane(nblk, ctrl_w), plane(nblk, 3, cd),
+                          plane(1, lanes))
     slo, shi = plane(5, lanes), plane(5, lanes)
     flat = np.asarray(_pass_record_fn()(ctrl, frames, trap, slo, shi, nres))
     assert flat.dtype == np.int32
-    assert flat.shape == (nblk * (16 + 3 * cd) + lanes * (1 + 2 * nres),)
-    rec = _split_pass_record(flat, nblk, cd, lanes, nres)
+    assert flat.shape == (
+        nblk * (ctrl_w + 3 * cd) + lanes * (1 + 2 * nres),)
+    if ctrl_w == 16:    # the width of every image without v128
+        assert _split_pass_record(flat, nblk, cd, lanes, nres).ctrl.shape \
+            == (nblk, 16)
+    else:
+        with pytest.raises(ValueError):
+            _split_pass_record(flat, nblk, cd, lanes, nres)
+    rec = _split_pass_record(flat, nblk, cd, lanes, nres, ctrl_w)
     assert isinstance(rec, PassRecord)
     for got, want in zip(rec, (ctrl, frames, trap[0], slo[:nres],
                                shi[:nres])):
@@ -368,4 +391,4 @@ def test_the_records_layout_follows_from_the_shapes(nblk, cd, lanes, nres):
     assert rec.ctrl.flags.writeable and rec.frames.flags.writeable
     rec.ctrl[0, _C_STATUS] += 1
     with pytest.raises(ValueError):
-        _split_pass_record(flat[:-1], nblk, cd, lanes, nres)
+        _split_pass_record(flat[:-1], nblk, cd, lanes, nres, ctrl_w)

@@ -213,7 +213,8 @@ ST_TRAPPED_BASE = 16
 _PAGE_WORDS = 65536 // 4
 _FUEL_OFF = 0x7FFFFFFF  # fuel column value when gas metering is disabled
 
-# ctrl row layout (SMEM, int32[nblk, 16])
+# ctrl row layout (SMEM, int32[nblk, ctrl_width(simd)]: 16 columns, 17
+# for an image with v128)
 _C_PC, _C_SP, _C_FP, _C_OB, _C_CD, _C_STATUS, _C_PAGES, _C_CHUNK = range(8)
 _C_STEPS = 8
 _C_FUEL = 9
@@ -237,6 +238,21 @@ _C_WACCESSES = 14
 # a binary64 ALU op: the softfloat routines its handlers ran, counted
 # along the path taken as _C_STEPS is, those a rollback discarded too
 _C_SOFTFLOAT = 15
+# written at exit, per launch (never read), by a kernel whose image has
+# v128: the instructions of a v128 class (CLS_VCONST .. CLS_VSTORE) its
+# handlers and fused blocks ran, counted along the path taken as
+# _C_STEPS is, those a rollback discarded too.  The row is full at 16,
+# so it is one column wider for such an image and for no other.
+_C_SIMD = 16
+_CTRL_W = 16
+
+
+def ctrl_width(simd: bool) -> int:
+    """Columns of a ctrl row: what the kernel's ctrl output, the pass
+    record and the hosts' rows are all sized by."""
+    return _CTRL_W + 1 if simd else _CTRL_W
+
+
 _SNAP_MIN = 256
 
 
@@ -812,6 +828,12 @@ _DIV64_SUBS = {ALU2_I64_BASE + _I32_BIN.index(n) for n in
 _DIVS_SUBS = {ALU2_I32_BASE + _I32_BIN.index("div_s"),
               ALU2_I64_BASE + _I32_BIN.index("div_s")}
 # trapping ALU1 subs come from the shared table (laneops.alu1_trap_fns)
+# fused-block ops of a v128 class, and the ops at which a straight run
+# of a block ends (mk_block's emit counts the former a run at a time)
+_SIMD_BLOCK_OPS = frozenset(("v2", "v1", "vtest", "vshift", "vsplat",
+                             "vextract", "vreplace", "vconst", "vshuffle",
+                             "vbitsel"))
+_RUN_ENDS = frozenset(("guardz", "guardnz", "loadi", "storei", "jump"))
 # ALU subs that run a binary64 routine of batch/softfloat.py (a
 # reinterpret moves bits and runs none)
 _F64_ALU2_SUBS = frozenset(range(ALU2_F64_BASE, NUM_ALU2))
@@ -985,6 +1007,7 @@ def _build_kernel(used_hids: tuple, D: int, CD: int, W: int, L: int,
                                         next(it_), next(it_))
         turns = next(it_)
         sfc = next(it_) if softfloat else None
+        sdc = next(it_) if simd else None
         blk = pl.program_id(0)
         lo = blk * Lblk
         # lane-block slices of the (wrapper-reshaped) HBM planes: in
@@ -2730,6 +2753,12 @@ def _build_kernel(used_hids: tuple, D: int, CD: int, W: int, L: int,
             if softfloat and sub in subs:
                 sfc[...] = sfc[...] + 1
 
+        def count_simd(n=1):
+            """`n` more instructions of a v128 class run (only a kernel
+            whose image has v128 holds one): a vreg in VMEM again."""
+            if n:
+                sdc[...] = sdc[...] + n
+
         def mk_alu2(sub):
             fn = alu2[sub]
             can_trap = sub in _DIV32_SUBS or sub in _DIV64_SUBS
@@ -2952,7 +2981,24 @@ def _build_kernel(used_hids: tuple, D: int, CD: int, W: int, L: int,
                     return keep(cb, steps=cb[0] + at.r, pc=at.slot(),
                                 sp=vs.sp(), status=I32(ST_DIVERGED))
 
+                def simd_in_run(j):
+                    """The v128 ops of the straight run that starts at
+                    `j`, if one starts there: a path leaves a block
+                    only at a guard, an inline load or store or its
+                    last op, so a run is retired whole or not at all
+                    and one add counts it along the path taken."""
+                    if j.i and j.seq[j.i - 1][0] not in _RUN_ENDS:
+                        return 0
+                    n = 0
+                    for op in j.seq[j.i:]:
+                        if op[0] in _RUN_ENDS or op[0] == "term":
+                            break
+                        n += op[0] in _SIMD_BLOCK_OPS
+                    return n
+
                 def emit(j, cb, vs, pend_l, pend_g):
+                    if simd:
+                        count_simd(simd_in_run(j))
                     if j.i == len(j.seq):
                         # the path falls off its last op
                         vs.flush()
@@ -3608,27 +3654,35 @@ def _build_kernel(used_hids: tuple, D: int, CD: int, W: int, L: int,
             H_MEMFILL: h_memfill, H_MEMCOPY: h_memcopy,
         }
 
+        def simd_handler_for(hid):
+            if hid >= H_VREPLACE_BASE:
+                return mk_vreplace(hid - H_VREPLACE_BASE)
+            if hid >= H_VEXTRACT_BASE:
+                return mk_vextract(hid - H_VEXTRACT_BASE)
+            if hid >= H_VSPLAT_BASE:
+                return mk_vsplat(hid - H_VSPLAT_BASE)
+            if hid >= H_VSHIFT_BASE:
+                return mk_vshift(hid - H_VSHIFT_BASE)
+            if hid >= H_VTEST_BASE:
+                return mk_vtest(hid - H_VTEST_BASE)
+            if hid >= H_V1_BASE:
+                return mk_v1(hid - H_V1_BASE)
+            if hid >= H_V2_BASE:
+                return mk_v2(hid - H_V2_BASE)
+            return {H_VCONST: h_vconst, H_VSHUFFLE: h_vshuffle,
+                    H_VBITSEL: h_vbitsel, H_VLOAD: h_vload,
+                    H_VSTORE: h_vstore}[hid]
+
         def handler_for(hid):
             if hid >= H_BLOCK_BASE:
                 return mk_block(block_shapes[hid - H_BLOCK_BASE])
             if simd and hid >= H_VCONST:
-                if hid >= H_VREPLACE_BASE:
-                    return mk_vreplace(hid - H_VREPLACE_BASE)
-                if hid >= H_VEXTRACT_BASE:
-                    return mk_vextract(hid - H_VEXTRACT_BASE)
-                if hid >= H_VSPLAT_BASE:
-                    return mk_vsplat(hid - H_VSPLAT_BASE)
-                if hid >= H_VSHIFT_BASE:
-                    return mk_vshift(hid - H_VSHIFT_BASE)
-                if hid >= H_VTEST_BASE:
-                    return mk_vtest(hid - H_VTEST_BASE)
-                if hid >= H_V1_BASE:
-                    return mk_v1(hid - H_V1_BASE)
-                if hid >= H_V2_BASE:
-                    return mk_v2(hid - H_V2_BASE)
-                return {H_VCONST: h_vconst, H_VSHUFFLE: h_vshuffle,
-                        H_VBITSEL: h_vbitsel, H_VLOAD: h_vload,
-                        H_VSTORE: h_vstore}[hid]
+                h = simd_handler_for(hid)
+
+                def counted(c):
+                    count_simd()
+                    return h(c)
+                return counted
             if hid in (H_LOAD_W, H_LOAD_D, H_STORE_W, H_STORE_D):
                 # width-specialized paths exist for the hbm+optimistic
                 # kernel; everywhere else they alias the generic ops
@@ -3766,6 +3820,8 @@ def _build_kernel(used_hids: tuple, D: int, CD: int, W: int, L: int,
         turns[...] = jnp.zeros_like(turns)
         if softfloat:
             sfc[...] = jnp.zeros_like(sfc)
+        if simd:
+            sdc[...] = jnp.zeros_like(sdc)
         if optimistic:
             init = init + (I32(0),)  # ls: last-snapshot step count
             # entry state was validated at the previous exit: it IS the
@@ -3839,6 +3895,8 @@ def _build_kernel(used_hids: tuple, D: int, CD: int, W: int, L: int,
             ctrl_out[blk, _C_WACCESSES] = wacc[0, 0]
         if softfloat:
             ctrl_out[blk, _C_SOFTFLOAT] = sfc[0, 0]
+        if simd:
+            ctrl_out[blk, _C_SIMD] = sdc[0, 0]
 
         outs = [dma(0, slo, lslice(s_lo_out)),
                 dma(1, shi, lslice(s_hi_out)),
@@ -3914,10 +3972,12 @@ def _build_kernel(used_hids: tuple, D: int, CD: int, W: int, L: int,
             + [pltpu.VMEM((8, 128), jnp.int32)]         # turns
             + ([pltpu.VMEM((8, 128), jnp.int32)]        # sfc (softfloat)
                if softfloat else [])
+            + ([pltpu.VMEM((8, 128), jnp.int32)]        # sdc (v128 ops)
+               if simd else [])
         ),
     )
     out_shape = [
-        jax.ShapeDtypeStruct((nblk, 16), jnp.int32),    # ctrl
+        jax.ShapeDtypeStruct((nblk, ctrl_width(simd)), jnp.int32),  # ctrl
         jax.ShapeDtypeStruct((nblk, 3, CD), jnp.int32),  # frames
         jax.ShapeDtypeStruct(p3((D, L)), jnp.int32),    # stack_lo
         jax.ShapeDtypeStruct(p3((D, L)), jnp.int32),    # stack_hi
@@ -4049,7 +4109,7 @@ class PassRecord(NamedTuple):
     host's own copies (the scheduler writes its mirrors); the rest are
     read-only views of the downloaded buffer."""
 
-    ctrl: np.ndarray      # [nblk, 16]
+    ctrl: np.ndarray      # [nblk, ctrl_width]
     frames: np.ndarray    # [nblk, 3, CD]
     trap: np.ndarray      # [L]: the trap plane's one row
     res_lo: np.ndarray    # [nres, L]: rows [:nres] of stack_lo
@@ -4075,14 +4135,15 @@ def _pass_record_fn():
 
 
 def _split_pass_record(flat: np.ndarray, nblk: int, cd: int, lanes: int,
-                       nres: int) -> PassRecord:
+                       nres: int, ctrl_w: int = _CTRL_W) -> PassRecord:
     """`pack`'s layout read back: it follows from the shapes alone."""
-    sizes = (nblk * 16, nblk * 3 * cd, lanes, nres * lanes, nres * lanes)
+    sizes = (nblk * ctrl_w, nblk * 3 * cd, lanes, nres * lanes,
+             nres * lanes)
     if flat.shape != (sum(sizes),):
         raise ValueError(f"pass record of {flat.shape}, not {sum(sizes)}")
     ctrl, frames, trap, res_lo, res_hi = np.split(
         flat, np.cumsum(sizes)[:-1])
-    return PassRecord(ctrl.reshape(nblk, 16).copy(),
+    return PassRecord(ctrl.reshape(nblk, ctrl_w).copy(),
                       frames.reshape(nblk, 3, cd).copy(), trap,
                       res_lo.reshape(nres, lanes),
                       res_hi.reshape(nres, lanes))
@@ -4187,6 +4248,12 @@ class PallasUniformEngine:
         self.counts_softfloat = holds_softfloat(self.img)
         self.softfloat_ops = None
         self.softfloat_share = None
+        # likewise the instructions of a v128 class (CLS_VCONST ..
+        # CLS_VSTORE), which a kernel whose image has v128 counts in the
+        # one ctrl column that only its rows have
+        self.ctrl_width = ctrl_width(bool(self.img.has_simd))
+        self.simd_ops = None
+        self.simd_share = None
         # forward edges the newest kernel's blocks run through, by kind
         self.superblock_edges = None
         # None = no tpu.aot fused section attached; set by _build when a
@@ -4487,7 +4554,7 @@ class PallasUniformEngine:
         import numpy as _np
 
         specs = [i32(t.shape, t.dtype) for t in self._tables]
-        specs += [i32((nblk, 16), _np.int32),
+        specs += [i32((nblk, self.ctrl_width), _np.int32),
                   i32((nblk, 3, CD), _np.int32),
                   i32((D, L), _np.int32), i32((D, L), _np.int32),
                   i32((NGp, L), _np.int32), i32((NGp, L), _np.int32),
@@ -4532,7 +4599,7 @@ class PallasUniformEngine:
         record was enqueued behind has ended."""
         return _split_pass_record(
             link.d2h("pass", record), self.lanes // self._geom[3],
-            self._geom[1], self.lanes, nres)
+            self._geom[1], self.lanes, nres, self.ctrl_width)
 
     def shadow_planes(self):
         """Fresh rollback-shadow planes matching this geometry (appended
@@ -4588,7 +4655,7 @@ class PallasUniformEngine:
                             "(call_depth != 0)")
         fuel_v = np.asarray(simt_state.fuel)
         fuel_on = self.cfg.fuel_per_launch is not None
-        ctrl = np.zeros((nblk, 16), np.int32)
+        ctrl = np.zeros((nblk, self.ctrl_width), np.int32)
         for b in range(nblk):
             sl = slice(b * Lblk, (b + 1) * Lblk)
             for col, vec in ((_C_PC, pc), (_C_SP, sp), (_C_FP, fp),
@@ -4882,6 +4949,11 @@ class PallasUniformEngine:
                 sched.softfloat_ops / sched.kernel_steps \
                 if sched.kernel_steps else None
             self.obs.add_softfloat_counts(sched.softfloat_ops)
+        if self.img.has_simd:
+            self.simd_ops = sched.simd_ops
+            self.simd_share = sched.simd_ops / sched.kernel_steps \
+                if sched.kernel_steps else None
+            self.obs.add_simd_counts(sched.simd_ops)
         return sched.result()
 
     def _serve_hostcalls(self, state, ctrl_np, valid_blocks=None):

@@ -63,6 +63,7 @@ from wasmedge_tpu.batch.pallas_engine import (
     _C_SNAP,
     _C_CHUNK,
     _C_DISPATCHES,
+    _C_SIMD,
     _C_SOFTFLOAT,
     _C_FP,
     _C_FUEL,
@@ -162,7 +163,7 @@ class _Rows:
 class _Pending:
     """A control-uniform lane group waiting for a free block slot."""
 
-    ctrl: np.ndarray              # [16] int32
+    ctrl: np.ndarray              # [eng.ctrl_width] int32
     frames: np.ndarray            # [3, CD] int32
     cols: Dict[str, np.ndarray]   # plane name -> [rows, w] device columns,
     #                               w = _pad_width(n): the first n real
@@ -268,6 +269,9 @@ class BlockScheduler:
         # the softfloat routines they ran (a kernel whose image holds a
         # binary64 ALU op counts them; zero for any other)
         self.softfloat_ops = 0
+        # and the instructions of a v128 class (a kernel whose image has
+        # v128 counts them in the column only its ctrl rows have)
+        self.simd_ops = 0
         self.quarantined = 0
         self._t_launch = 0.0
         self._plane_idx = _PLANE_IDX_SIMD if outer.img.has_simd \
@@ -433,7 +437,7 @@ class BlockScheduler:
             n = min(img.mem_init.shape[0], W)
             mem = mem.at[:n].set(jnp.broadcast_to(
                 h2d("mem_init", img.mem_init[:n])[:, None], (n, L)))
-        ctrl = np.zeros((self.nblk, 16), np.int32)
+        ctrl = np.zeros((self.nblk, eng.ctrl_width), np.int32)
         ctrl[:, _C_PC] = meta.entry_pc
         ctrl[:, _C_SP] = meta.nlocals
         ctrl[:, _C_OB] = meta.nlocals
@@ -675,6 +679,8 @@ class BlockScheduler:
                 ctrl_np[blocks, _C_WACCESSES].sum())
         if self.eng.counts_softfloat:
             self.softfloat_ops += int(ctrl_np[blocks, _C_SOFTFLOAT].sum())
+        if self.eng.img.has_simd:
+            self.simd_ops += int(ctrl_np[blocks, _C_SIMD].sum())
 
     def _count_commits(self, ctrl_np, blocks):
         """Add the periodic commits the launch that just ran implies in

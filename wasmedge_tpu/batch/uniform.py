@@ -996,6 +996,9 @@ class UniformBatchEngine:
             sfs = getattr(self.pallas, "softfloat_share", None)
             if sfs is not None:
                 span.set(softfloat_share=round(sfs, 6))
+            sds = getattr(self.pallas, "simd_share", None)
+            if sds is not None:
+                span.set(simd_share=round(sds, 6))
             if getattr(self.pallas, "splits", 0):
                 span.set(splits=self.pallas.splits,
                          launches=self.pallas.launches,

@@ -24,6 +24,8 @@ from wasmedge_tpu.models.programs import (
 from wasmedge_tpu.models.programs import build_memory_batch  # noqa: F401
 # likewise the guest of polybench-gemm-4096 (benchmark/drivers/batch_seeded.py)
 from wasmedge_tpu.models.programs import build_polybench_gemm  # noqa: F401
+# and of chacha20-simd-4096 (benchmark/drivers/batch_seeded_simd.py)
+from wasmedge_tpu.models.programs import build_chacha20  # noqa: F401
 
 __all__ = [
     "build_fib",

@@ -217,6 +217,165 @@ def build_polybench_gemm(ni: int = 60, nj: int = 70, nk: int = 80) -> bytes:
     return b.build()
 
 
+# ChaCha20 (RFC 8439) as a 128-bit implementation holds it: the state's
+# four rows in four v128, the diagonal round as lane rotations of rows
+# b, c, d.  Addresses of the guest's page 0, a compiled module's layout:
+# read-only data from 1024, the shadow stack's one frame under 65536.
+CHACHA_SIGMA = 1024             # "expand 32-byte k", a data segment
+CHACHA_CTX = 65536 - 64         # uint32_t input[16]: the context
+CHACHA_MSG = 65536              # the message, pages 1..3 at 3072 blocks
+# the seed's recurrence (`assumed` in the configuration): Numerical
+# Recipes' w' = w * 1664525 + 1013904223 mod 2**32, from w = seed, gives
+# the eight key words and the three nonce words in turn; the twelfth
+# value, splatted, times CHACHA_MSG_MUL plus CHACHA_MSG_ADD is the
+# message's first 16 bytes, and each next 16 the same step lane by lane
+CHACHA_LCG = (1664525, 1013904223)
+CHACHA_MSG_MUL = (0x9E3779B1, 0x85EBCA6B, 0xC2B2AE35, 0x27D4EB2F)
+CHACHA_MSG_ADD = (1, 2, 3, 4)
+
+
+def build_chacha20(blocks: int = 3072) -> bytes:
+    """ChaCha20 encryption of `blocks` 64-byte blocks in place, RFC 8439
+    sections 2.1, 2.3 and 2.4 (20 rounds, 256-bit key, 96-bit nonce,
+    32-bit block counter from 1), v128 from end to end.  Export
+    `chacha20(seed: i32) -> i64`:
+
+        (a) key, nonce and message from the seed by i32 arithmetic (the
+            recurrence above), the message 16 bytes a `v128.store`;
+        (b) per block: the context's four rows into v128 locals, ten
+            double rounds (a column round on the rows, rows b, c, d
+            rotated by one, two, three lanes with `i8x16.shuffle`, the
+            same round, the rotation back), the add of the input rows,
+            four `v128.load` / `v128.xor` / `v128.store`, the counter
+            bumped by `i32x4.add` of (1, 0, 0, 0);
+        (c) acc = rotl(acc, 1) ^ i64.load(p) over the ciphertext.
+
+    The seed changes data and never control flow.  Lowered as portable
+    vector code (`uint32_t __attribute__((vector_size(16)))`) comes out
+    of clang -O2 -msimd128 as far as can be said without a toolchain:
+    rotations as shl / shr_u / or, the four stores of a block's rows
+    unrolled, bottom-tested loops on a pointer or a down-counter."""
+    def v(*lanes):
+        return ("v128.const", b"".join(
+            (x & 0xFFFFFFFF).to_bytes(4, "little") for x in lanes))
+
+    def lanes_left(n):
+        """The shuffle mask that moves 32-bit lane i + n to lane i."""
+        return ("i8x16.shuffle", [4 * ((i + n) % 4) + k
+                                  for i in range(4) for k in range(4)])
+
+    end = CHACHA_MSG + 64 * blocks
+    SEED, P, I, W, ACC = range(5)
+    S = [5, 6, 7, 8]            # the input rows: constants, key, key,
+    X = [9, 10, 11, 12]         # counter and nonce; the working rows
+    T, M = 13, 14
+    mul, add = CHACHA_LCG
+
+    def lcg():
+        return [("local.get", W), ("i32.const", mul), "i32.mul",
+                ("i32.const", add), "i32.add", ("local.tee", W)]
+
+    def bump(ptr, by, bound):
+        """... while ((ptr += by) != bound)"""
+        return [("local.get", ptr), ("i32.const", by), "i32.add",
+                ("local.tee", ptr), ("i32.const", bound), "i32.ne",
+                ("br_if", 0)]
+
+    def step(a, b, d, n):
+        """a += b; d ^= a; d <<<= n, on rows"""
+        return [("local.get", a), ("local.get", b), "i32x4.add",
+                ("local.set", a),
+                ("local.get", d), ("local.get", a), "v128.xor",
+                ("local.tee", T), ("i32.const", n), "i32x4.shl",
+                ("local.get", T), ("i32.const", 32 - n), "i32x4.shr_u",
+                "v128.or", ("local.set", d)]
+
+    a, b, c, d = X
+    quarter = step(a, b, d, 16) + step(c, d, b, 12) \
+        + step(a, b, d, 8) + step(c, d, b, 7)
+
+    def rotate(row, n):
+        return [("local.get", row), ("local.get", row), lanes_left(n),
+                ("local.set", row)]
+
+    body = [
+        # (a) the context: the constants out of .rodata, eight key
+        # words, the counter, three nonce words
+        ("i32.const", CHACHA_CTX), ("i32.const", CHACHA_SIGMA),
+        ("v128.load", 4, 0), ("v128.store", 4, 0),
+        ("local.get", SEED), ("local.set", W),
+    ]
+    for i in range(8):
+        body += [("i32.const", CHACHA_CTX), *lcg(),
+                 ("i32.store", 2, 16 + 4 * i)]
+    body += [("i32.const", CHACHA_CTX), ("i32.const", 1),
+             ("i32.store", 2, 48)]
+    for i in range(3):
+        body += [("i32.const", CHACHA_CTX), *lcg(),
+                 ("i32.store", 2, 52 + 4 * i)]
+    body += [
+        # the message, a block a turn
+        *lcg(), "i32x4.splat", v(*CHACHA_MSG_MUL), "i32x4.mul",
+        v(*CHACHA_MSG_ADD), "i32x4.add", ("local.set", M),
+        ("i32.const", CHACHA_MSG), ("local.set", P),
+        ("loop", None),
+    ]
+    for k in range(4):
+        body += [("local.get", P), ("local.get", M),
+                 ("v128.store", 4, 16 * k),
+                 ("local.get", M), v(*[mul] * 4), "i32x4.mul",
+                 v(*[add] * 4), "i32x4.add", ("local.set", M)]
+    body += [*bump(P, 64, end), "end"]
+    # (b) encryption in place
+    for k in range(4):
+        body += [("i32.const", CHACHA_CTX), ("v128.load", 4, 16 * k),
+                 ("local.set", S[k])]
+    body += [("i32.const", CHACHA_MSG), ("local.set", P),
+             ("loop", None)]
+    for k in range(4):
+        body += [("local.get", S[k]), ("local.set", X[k])]
+    body += [
+        ("i32.const", 10), ("local.set", I),
+        ("loop", None),
+        *quarter,
+        *rotate(b, 1), *rotate(c, 2), *rotate(d, 3),
+        *quarter,
+        *rotate(b, 3), *rotate(c, 2), *rotate(d, 1),
+        ("local.get", I), ("i32.const", 1), "i32.sub", ("local.tee", I),
+        ("br_if", 0), "end",
+    ]
+    for k in range(4):
+        body += [("local.get", X[k]), ("local.get", S[k]), "i32x4.add",
+                 ("local.set", X[k])]
+    for k in range(4):
+        body += [("local.get", P),
+                 ("local.get", P), ("v128.load", 4, 16 * k),
+                 ("local.get", X[k]), "v128.xor",
+                 ("v128.store", 4, 16 * k)]
+    body += [
+        ("local.get", S[3]), v(1, 0, 0, 0), "i32x4.add",
+        ("local.set", S[3]),
+        *bump(P, 64, end), "end",
+        # (c) the fold of every bit of the ciphertext
+        ("i32.const", CHACHA_MSG), ("local.set", P),
+        ("loop", None),
+        ("local.get", ACC), ("i64.const", 1), "i64.rotl",
+        ("local.get", P), ("i64.load", 3, 0), "i64.xor",
+        ("local.set", ACC),
+        *bump(P, 8, end), "end",
+        ("local.get", ACC),
+    ]
+    mb = ModuleBuilder()
+    pages = -(-end // 65536)
+    mb.add_memory(pages, pages)
+    mb.add_active_data(0, [("i32.const", CHACHA_SIGMA)],
+                       b"expand 32-byte k")
+    mb.add_function(["i32"], ["i64"],
+                    ["i32"] * 3 + ["i64"] + ["v128"] * 10, body,
+                    export="chacha20")
+    return mb.build()
+
+
 def build_counted_loop(n: int = 64) -> bytes:
     """Latch-tested counted loop with a CONSTANT limit — the canonical
     shape the absint trip analysis (analysis/absint.py) bounds

@@ -603,6 +603,13 @@ def render_prometheus(recorder=None, stats=None, hostcall_stats=None,
                    "conversions; a reinterpret is none), a lane-block "
                    "step each, summed over lane blocks and launches.")
             w.sample("wasmedge_softfloat_ops_total", None, sfo)
+        sdo = getattr(recorder, "simd_ops", 0)
+        if sdo:
+            w.head("wasmedge_simd_ops_total", "counter",
+                   "Instructions of a v128 class (v128.const through "
+                   "v128.store) the Pallas kernels ran, a lane-block "
+                   "step each, summed over lane blocks and launches.")
+            w.sample("wasmedge_simd_ops_total", None, sdo)
         sc = getattr(recorder, "split_counts", None)
         if sc and sc["launches"]:   # stays once the scheduler ran
             for key, name, text in (

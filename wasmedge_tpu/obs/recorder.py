@@ -215,6 +215,9 @@ class NullRecorder:
     def add_softfloat_counts(self, ops):
         pass
 
+    def add_simd_counts(self, ops):
+        pass
+
     def add_split_counts(self, splits=0, launches=0, rechecks=0,
                          careful_steps=0, surgery_programs=0,
                          snap_restored=0, snap_commits=0,
@@ -325,6 +328,8 @@ class FlightRecorder:
         self.pallas_dispatches = 0
         # the binary64 routines those kernels ran (softfloat.py)
         self.softfloat_ops = 0
+        # and the instructions of a v128 class they ran
+        self.simd_ops = 0
         # what the block scheduler did, folded after each run: blocks
         # split, launches of the optimistic kernel, rounds of the
         # careful one, the block-steps those rounds retired, and the
@@ -503,6 +508,13 @@ class FlightRecorder:
         15, which only a kernel whose image holds a binary64 ALU op
         writes; summed by batch/scheduler.py)."""
         self.softfloat_ops += int(ops)
+
+    def add_simd_counts(self, ops):
+        """Fold the instructions of a v128 class (CLS_VCONST ..
+        CLS_VSTORE) the Pallas kernels ran in one run, a lane-block
+        step each (ctrl column 16, which only the rows of a kernel
+        whose image has v128 hold; summed by batch/scheduler.py)."""
+        self.simd_ops += int(ops)
 
     def add_split_counts(self, splits=0, launches=0, rechecks=0,
                          careful_steps=0, surgery_programs=0,
