@@ -230,7 +230,28 @@ def test_pallas_kernel_compiles_for_v5e(case, one_chip):
         (tail,) = [op[1] for op in head if op[0] == "guardz"]
         assert tail[-1] == ("term", H_CALL)
     fn = eng._fn_careful() if careful else eng._fn
-    _compile_kernel(eng, fn, one_chip, careful=careful)
+    compiled = _compile_kernel(eng, fn, one_chip, careful=careful)
+    _CODE_BYTES[case] = \
+        compiled.memory_analysis().generated_code_size_in_bytes
+
+
+_CODE_BYTES = {}    # kernel -> bytes of code, as each compile above left it
+# What the chip's compiler makes of a kernel's size (PR 39, read from its
+# log here and timed on the chip): it cuts a program into instruction
+# overlays, the ChaCha20 kernel into 5 up to 107,369 bundles (6.90 MB of
+# code) and into 10 to 18 from 109,161 (7.03 MB) on, and then the hot
+# loop crosses overlays: a job took 0.529 s at 118,121 bundles and 6.37 s
+# at 110,735 for 0.199 s at 105,577 and under.  The snapshot's copies are
+# inlined at every windowed access, so a whole-plane DMA more there (a
+# descriptor a row) is 7,000 bundles.  The kernel is at 6.02 MB.
+_CODE_BYTES_LIMIT = 6_500_000
+
+
+@pytest.mark.parametrize("case", ["chacha20-auto", "v128"])
+def test_a_v128_kernel_stays_under_the_overlay_cliff(case, one_chip):
+    if case not in _CODE_BYTES:     # run alone, or on another worker
+        test_pallas_kernel_compiles_for_v5e(case, one_chip)
+    assert 0 < _CODE_BYTES[case] < _CODE_BYTES_LIMIT
 
 
 def _inner(eqn):

@@ -564,3 +564,12 @@ def test_shape_budget_falls_back_to_the_plain_block(monkeypatch):
     hid1, shapes1 = fuse_blocks(hid_plane(img), img)
     assert shapes1 == (plain,)
     assert np.flatnonzero(hid1 >= H_BLOCK_BASE).tolist() == [0, fb]
+
+
+# tests/test_optimistic.py is a slow suite by its file name
+# (tests/conftest.py), so the rollback test every change to the v128
+# planes' snapshot leans on is collected here as well, where the
+# tier-1 run (-m 'not slow') counts it.
+from tests.test_optimistic import (  # noqa: E402,F401
+    test_v128_rollbacks_across_commits_stay_lane_exact,
+)

@@ -212,3 +212,94 @@ def test_rollbacks_across_commits_stay_lane_exact(hbm, monkeypatch):
     assert np.asarray(res.results[0]).tolist() == ns.tolist()
     assert (np.asarray(res.trap) == -1).all()
     assert eng.recheck_rounds >= 1
+
+
+V128_ROLLBACK_SPAN = 120 * 16      # bytes the longest lane's stream writes
+
+
+def v128_rollback_guest() -> bytes:
+    """f(n: i32) -> i64: n turns of a loop whose exit test runs with two
+    v128 operands on the operand stack above two v128 locals, and whose
+    body moves all four 32-bit lanes of both locals and streams one of
+    them out 16 bytes a turn; then a fold of every byte any lane wrote
+    and of both locals.  A rollback that loses an `e2` / `e3` row of a
+    local or an operand, or a store the snapshot held, changes the
+    result."""
+    def v(*lanes):
+        return ("v128.const", b"".join(
+            (x & 0xFFFFFFFF).to_bytes(4, "little") for x in lanes))
+
+    N, I, P, ACC, A, B = range(6)
+    body = [
+        ("local.get", N), "i32x4.splat",
+        v(0x9E3779B9, 0x85EBCA6B, 0xC2B2AE35, 0x27D4EB2F), "i32x4.mul",
+        ("local.set", A),
+        v(0x01000193, 0x811C9DC5, 0xDEADBEEF, 0x7F4A7C15), ("local.set", B),
+        ("block", None),
+        ("loop", None),
+        # the two operands are live across the exit test, which is
+        # where the lanes disagree
+        ("local.get", A), ("local.get", B),
+        ("local.get", I), ("local.get", N), "i32.ge_u", ("br_if", 1),
+        "i32x4.add", ("local.set", A),
+        ("local.get", B), ("local.get", B),
+        ("i8x16.shuffle", [4, 5, 6, 7, 8, 9, 10, 11,
+                           12, 13, 14, 15, 0, 1, 2, 3]),
+        ("local.get", A), "v128.xor",
+        v(0x00010001, 0x00030003, 0x00050005, 0x00070007), "i32x4.add",
+        ("local.set", B),
+        ("local.get", I), ("i32.const", 16), "i32.mul",
+        ("local.get", A), ("v128.store", 4, 0),
+        ("local.get", I), ("i32.const", 1), "i32.add", ("local.set", I),
+        ("br", 0),
+        "end", "end",
+        ("loop", None),
+        ("local.get", ACC), ("i64.const", 1), "i64.rotl",
+        ("local.get", P), ("i64.load", 3, 0), "i64.xor", ("local.set", ACC),
+        ("local.get", P), ("i32.const", 8), "i32.add", ("local.tee", P),
+        ("i32.const", V128_ROLLBACK_SPAN), "i32.ne", ("br_if", 0),
+        "end",
+    ]
+    for row in (A, B):
+        for half in (0, 1):
+            body += [("local.get", ACC), ("i64.const", 7), "i64.rotl",
+                     ("local.get", row), ("i64x2.extract_lane", half),
+                     "i64.xor", ("local.set", ACC)]
+    body += [("local.get", ACC)]
+    b = ModuleBuilder()
+    b.add_memory(1, 1)
+    b.add_function(["i32"], ["i64"], ["i32", "i32", "i64", "v128", "v128"],
+                   body, export="f")
+    return b.build()
+
+
+@pytest.mark.parametrize("hbm", [False, True])
+def test_v128_rollbacks_across_commits_stay_lane_exact(hbm, monkeypatch):
+    """The same path with v128 values on the stack: lanes leave the loop
+    at different turns while two v128 locals and two v128 operands are
+    live and differ in all four 32-bit lanes from commit to commit, and
+    (behind the window) the `v128.store` stream evicts dirty windows,
+    each a commit point of its own.  Every lane's result, which folds
+    its memory and both locals, is the scalar engine's bit for bit."""
+    from wasmedge_tpu.batch.pallas_engine import PallasUniformEngine
+
+    monkeypatch.setattr(PallasUniformEngine, "SNAP_STEPS", 64)
+    data = v128_rollback_guest()
+    ex, store, inst, eng = make_engine(data, hbm=hbm)
+    assert eng.img.has_simd and eng.optimistic
+    ns = np.array([120, 120, 37, 120, 75, 75, 120, 9], np.int64)
+    res = eng.run("f", [ns], max_steps=2_000_000)
+    assert not eng.fell_back_to_simt
+    assert (np.asarray(res.trap) == -1).all()
+    want = {}
+    for n in sorted(set(ns.tolist())):
+        s_ex, s_store, s_inst = instantiate(data, Configure())
+        want[n] = int(s_ex.invoke(s_store, s_inst.find_func("f"),
+                                  [n])[0]) & (2**64 - 1)
+    got = [int(x) & (2**64 - 1) for x in np.asarray(res.results[0])]
+    assert got == [want[n] for n in ns.tolist()]
+    assert len(set(want.values())) == len(want)
+    assert eng.recheck_rounds >= 1
+    if hbm:
+        assert eng.mem_static["mem_mode"] == "hbm_window"
+        assert eng.window_writebacks >= 8

@@ -1049,7 +1049,9 @@ def _build_kernel(used_hids: tuple, D: int, CD: int, W: int, L: int,
         if simd:
             # sems 6/7 are reused for the e2/e3 planes here and in the
             # snapshot paths: window DMAs (the other users of 6/7) are
-            # never in flight across those batches
+            # never in flight across those batches, and semaphores of
+            # their own change nothing (PR 39's control on the chip: a
+            # ChaCha20 job 0.531 s for 0.528)
             ins += [dma(6, lslice(se2_in), se2s),
                     dma(7, lslice(se3_in), se3s)]
         for c in ins:
@@ -1220,6 +1222,38 @@ def _build_kernel(used_hids: tuple, D: int, CD: int, W: int, L: int,
                     (vec != 0) != (s != 0), I32(1), I32(0)))
                 return s
 
+            def copy_e_planes(restore):
+                """The v128 planes e2/e3 to their shadows, or back, in
+                8-row pieces: a loop of fixed-shape DMAs.  One DMA a
+                plane lowers to a descriptor a row wherever a snapshot
+                is inlined (every windowed access has one), and with
+                four stack planes that put the ChaCha20 kernel at
+                118,121 bundles: over about 108,000 the TPU compiler
+                cuts this program into 10 to 18 instruction overlays
+                for 5, the hot loop crosses them, and a job took
+                0.529 s for 0.199 (PR 39, PERF.md section 5;
+                tests/test_chip_compile.py holds the size).  The
+                bytes and the semaphores were never the cost."""
+                n = 8 if D % 8 == 0 else D
+
+                def piece(i, _):
+                    r0 = pl.multiple_of(i * n, n)
+                    cps = []
+                    for k, (plane, shadow) in enumerate(
+                            ((se2s, sh_se2), (se3s, sh_se3))):
+                        src, dst = (plane.at[pl.ds(r0, n)],
+                                    lsliceR(shadow, r0, n))
+                        if restore:
+                            src, dst = dst, src
+                        cps.append(dma(6 + k, src, dst))
+                    for cp_ in cps:
+                        cp_.start()
+                    for cp_ in cps:
+                        cp_.wait()
+                    return 0
+
+                lax.fori_loop(0, D // n, piece, 0)
+
             def do_snapshot(c):
                 """Record the rollback point = the CURRENT (validated)
                 state: planes -> shadow HBM, live frames + carry ->
@@ -1231,13 +1265,12 @@ def _build_kernel(used_hids: tuple, D: int, CD: int, W: int, L: int,
                        dma(5, trapr, lslice(sh_trap))]
                 if not mem_hbm and W > 1:
                     cps.append(dma(4, memr, lslice(sh_mem)))
-                if simd:
-                    cps += [dma(6, se2s, lslice(sh_se2)),
-                            dma(7, se3s, lslice(sh_se3))]
                 for cp_ in cps:
                     cp_.start()
                 for cp_ in cps:
                     cp_.wait()
+                if simd:
+                    copy_e_planes(restore=False)
                 cd_now = c[IDX["cd"]]
 
                 def cpf(i, _):
@@ -1259,13 +1292,12 @@ def _build_kernel(used_hids: tuple, D: int, CD: int, W: int, L: int,
                        dma(5, lslice(sh_trap), trapr)]
                 if not mem_hbm and W > 1:
                     cps.append(dma(4, lslice(sh_mem), memr))
-                if simd:
-                    cps += [dma(6, lslice(sh_se2), se2s),
-                            dma(7, lslice(sh_se3), se3s)]
                 for cp_ in cps:
                     cp_.start()
                 for cp_ in cps:
                     cp_.wait()
+                if simd:
+                    copy_e_planes(restore=True)
                 cd_snap = snapc[IDX["cd"]]
 
                 def cpf(i, _):
