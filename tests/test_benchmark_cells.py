@@ -52,6 +52,15 @@ CHACHA_CELL_METRICS = [
     + ["simd_ops_per_job.batch"]
 
 
+# and the cell of the WASI command (PR 40): the ChaCha20 cell's, and the
+# hostcall serve's rounds, calls, bytes, vectorised share and spans
+WASI_CELL_METRICS = CHACHA_CELL_METRICS + [
+    "hostcall_rounds_per_job.batch", "hostcalls_per_job.batch",
+    "hostcall_out_bytes_per_job.batch", "hostcall_vectorized_share.batch",
+    "hostcall_begin_ms.batch", "hostcall_finish_ms.batch",
+    "host_hostcall_ms.batch"]
+
+
 # the self times and counts of the scheduler's transfers and enqueues
 # (PR 36), read by `readers/trace_span_self.py` in every batch cell
 HOST_LINK_METRICS = [
@@ -63,7 +72,8 @@ HOST_LINK_METRICS = [
 def test_the_manifest_lists_the_batch_cells():
     assert CELLS == ["batch-fib30-uniform", "batch-mem-uniform",
                      "batch-fib-divergent", "batch-fib-split",
-                     "batch-gemm-small", "batch-chacha20-192k"]
+                     "batch-gemm-small", "batch-chacha20-192k",
+                     "batch-chacha20-write8k"]
     used = {w["config"] for w in MANIFEST["workloads"]}
     assert used == {c["name"] for c in MANIFEST["configs"]}
     # every batch cell reports what the uniform fib cell reports
@@ -90,6 +100,9 @@ def test_the_manifest_lists_the_batch_cells():
     # the ChaCha20 cell the same but softfloat's for its own one
     assert sorted(reported("batch-chacha20-192k")) == sorted(
         reported(CELLS[0]) + CHACHA_CELL_METRICS)
+    # the WASI command's cell all of that and the serve's seven
+    assert sorted(reported("batch-chacha20-write8k")) == sorted(
+        reported(CELLS[0]) + WASI_CELL_METRICS)
     # the host's account (PR 36) is every batch cell's
     assert set(HOST_LINK_METRICS) <= set(reported(CELLS[0]))
     for m in MANIFEST["per_layer"]:
@@ -97,8 +110,12 @@ def test_the_manifest_lists_the_batch_cells():
                 (SPLIT_CELL_METRICS, ["batch-fib-split"]),
                 (["softfloat_ops_per_job.batch"], ["batch-gemm-small"]),
                 (set(GEMM_CELL_METRICS) & set(CHACHA_CELL_METRICS),
-                 ["batch-gemm-small", "batch-chacha20-192k"]),
-                (["simd_ops_per_job.batch"], ["batch-chacha20-192k"])):
+                 ["batch-gemm-small", "batch-chacha20-192k",
+                  "batch-chacha20-write8k"]),
+                (["simd_ops_per_job.batch"],
+                 ["batch-chacha20-192k", "batch-chacha20-write8k"]),
+                (WASI_CELL_METRICS[len(CHACHA_CELL_METRICS):],
+                 ["batch-chacha20-write8k"])):
             if m["name"] in own:
                 assert m["workloads"] == cells
                 assert m["moves"] == "batch_ginstr_per_s"
@@ -147,9 +164,11 @@ def test_batch_cell_names_a_guest_the_program_has(name):
     assert set(config["geometry"]) == {
         "value_stack_depth", "call_stack_depth", "steps_per_launch"}
     # exact results, completion, the scalar engine's count; the cell
-    # that splits and the one with 4096 arguments add that nothing falls
-    # back to the per-step engine
+    # that splits and the ones with 4096 arguments add that nothing falls
+    # back; the WASI command's cell, that every acknowledged write is
+    # read back and that the vectorised serve took every call
     assert len(config["guarantees"]) == (
+        5 if name == "batch-chacha20-write8k" else
         4 if name in ("batch-fib-split", "batch-gemm-small",
                       "batch-chacha20-192k") else 3)
 
@@ -178,6 +197,9 @@ _CELL_GUESTS = {
     "build_chacha20": ({"blocks": 3072}, (
         1341, "d66ea7d01a6c52b2c26ad42759e19780"
               "31ad524be70bd25dcf60daf91575caf7")),
+    "build_chacha20_wasi": ({"blocks": 3072, "chunk_blocks": 128}, (
+        1472, "b2cec3411c9aeb0737a90d75498373c2"
+              "fd6dceb36fc492404ba7dc36e8a2626d")),
 }
 
 

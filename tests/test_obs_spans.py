@@ -48,6 +48,9 @@ SERVE_SPANS = {"serve/step_enter", "serve/step_exit", "serve/round",
                "serve/harvest", "serve/park", "simt/chunk"}
 # opened only where a block splits in flight: tests/test_split_batch_config.py
 SPLIT_SPANS = {"batch/recheck", "batch/split", "batch/install"}
+# opened only where a block parks at a host call:
+# tests/test_chacha20_wasi_config.py
+HOSTCALL_SPANS = {"batch/hostcall_begin", "batch/hostcall_finish"}
 # child -> the span it must lie inside
 PARENTS = {"batch/plan": "batch/run", "batch/launch": "batch/run",
            "batch/sync": "batch/run", "batch/statuses": "batch/run",
@@ -487,7 +490,7 @@ def test_span_metrics_name_spans_the_program_opens():
 
     known = {SPAN_PREFIX + n
              for n in BATCH_SPANS | SERVE_SPANS | SPLIT_SPANS
-             | {"serve/drive_wait"}}
+             | HOSTCALL_SPANS | {"serve/drive_wait"}}
     seen = {"trace_program_span": 0, "trace_span_self": 0}
     for path in glob.glob(os.path.join(BENCH, "layer_metrics", "*.json")):
         with open(path) as f:
@@ -497,5 +500,6 @@ def test_span_metrics_name_spans_the_program_opens():
             assert spec["args"].get("root", "wasm/batch/run") in known
             seen[spec["reader"]] += 1
     # PR 26's fifteen and the three of the serve loop's gap; the host's
-    # account of a batch job: five self times and three counts
-    assert seen == {"trace_program_span": 18, "trace_span_self": 8}
+    # account of a batch job: five self times and three counts; the
+    # hostcall serve's two halves and the finish's self time (PR 40)
+    assert seen == {"trace_program_span": 20, "trace_span_self": 9}

@@ -274,7 +274,7 @@ def test_a_child_harvested_running_right_after_its_install_reads_the_plane(
 
 @pytest.mark.parametrize("turns,want", [
     # one block: no kernel runs between the capture and the finish
-    ([1] * 8, ["pass", "trap", "pass"]),
+    ([1] * 8, ["pass", "slab_lo", "slab_hi", "trap", "pass"]),
     # two blocks of eight: the second is still looping, launch after
     # launch, while the first one's calls are served, so a record is out
     # when the finish writes
@@ -326,11 +326,13 @@ def test_a_serves_finish_drops_the_mirrors_of_the_planes_it_wrote(
     assert (np.asarray(res.results[0])[~bad] == 2 * xs[~bad] + 1).all()
     assert not eng.fell_back_to_simt and eng.splits == lanes // 8
     if want is None:
-        assert eng.launches > 4 and set(downloads) == {"pass", "trap"}
+        assert eng.launches > 4 and set(downloads) == {
+            "pass", "slab_lo", "slab_hi", "trap"}
         assert downloads.count("trap") == 2
     else:
-        # the first launch's record, the trap plane after the serve
-        # wrote it, the record of the launch that ran seven lanes on
+        # the first launch's record, the serve's two stack slabs (on the
+        # link since PR 40), the trap plane after the serve wrote it,
+        # the record of the launch that ran seven lanes on
         assert downloads == want
 
 

@@ -243,11 +243,12 @@ def new_hostcall_stats() -> dict:
     tier0_* are in-kernel retirements (zero device<->host round trips),
     tier1_calls is lanes drained through the outcall channel, and
     serve_rounds counts park->drain->re-arm cycles (each one is at
-    least one device<->host round trip)."""
+    least one device<->host round trip); out_bytes is what the calls
+    the Pallas block serve drained handed to an fd."""
     return {"tier0_clock": 0, "tier0_random": 0, "tier0_fd_write": 0,
             "tier0_sys": 0, "tier0_calls": 0,
             "tier1_calls": 0, "tier1_vectorized": 0, "serve_rounds": 0,
-            "stdout_flushes": 0, "stdout_bytes": 0}
+            "stdout_flushes": 0, "stdout_bytes": 0, "out_bytes": 0}
 
 
 def t0_effective_kinds(img: DeviceImage, cfg) -> Optional[np.ndarray]:

@@ -274,7 +274,7 @@ def flush_stdout_buffers(engine, state):
             continue  # fd vanished (tier-0 gating makes this unreachable)
         data = b"".join(per_fd[fd])
         if data:
-            _write_all(e, data)
+            env.bytes_written += _write_all(e, data)
     stats = getattr(engine, "hostcall_stats", None)
     if stats is not None:
         stats["stdout_flushes"] += 1
@@ -482,22 +482,147 @@ class PlaneMemoryCache:
 
     CHUNK_ROWS = 1024  # 4 KiB of guest memory per chunk
 
-    def __init__(self, mem_dev):
+    def __init__(self, mem_dev, d2h=None, read_rows=None):
         self.dev = mem_dev
         self.W = int(mem_dev.shape[0])
         self.L = int(mem_dev.shape[1])
+        # how a chunk comes down: `HostLink.d2h` where the serve is on a
+        # link (the Pallas block serve), else a plain download
+        self._d2h = d2h
+        # read_rows(w0, k, lane_major) -> rows [w0, w0 + k) of every
+        # lane cut (and transposed) on the device, [k, L] or [L, k]: the
+        # way around the chunks for an access at ONE address in every
+        # lane (the Pallas block serve); None: chunks only
+        self._read_rows = read_rows
         self._chunks = {}
-        self._dirty = set()
-        self._writes = {}  # lane -> [(off, n)] for pad-lane replay
+        self._dirty = {}     # chunk -> [first, last + 1) rows written
+        self._patches = {}   # row -> int32[L] written whole, chunk not here
+        self._writes = {}    # lane -> [(off, n)] for pad-lane replay
+        self._vec_writes = []  # (lanes, offs, n) of the vector stores
 
-    def _chunk(self, ci: int) -> np.ndarray:
+    def _chunk(self, ci: int, write: bool = False) -> np.ndarray:
         c = self._chunks.get(ci)
         if c is None:
             lo = ci * self.CHUNK_ROWS
             hi = min(lo + self.CHUNK_ROWS, self.W)
-            c = np.array(self.dev[lo:hi, :])  # one all-lane download
-            self._chunks[ci] = c
+            # one all-lane download; a backend may hand out its own
+            # read-only buffer, copied only if the round writes to it
+            c = self._chunks[ci] = \
+                np.asarray(self.dev[lo:hi, :]) if self._d2h is None \
+                else self._d2h("mem_chunk", self.dev, np.s_[lo:hi])
+            for r in [r for r in self._patches if lo <= r < hi]:
+                # a row written whole before its chunk was here
+                write = True
+                self._touch(ci, r - lo, r - lo + 1)
+                self._own(ci)[r - lo] = self._patches.pop(r)
+        return self._own(ci) if write else self._chunks[ci]
+
+    def _own(self, ci: int) -> np.ndarray:
+        """Chunk `ci` as the cache's own, writable copy."""
+        c = self._chunks[ci]
+        if not (c.flags.owndata and c.flags.writeable):
+            c = self._chunks[ci] = c.copy()
         return c
+
+    def _touch(self, ci: int, lo: int, hi: int):
+        """Rows [lo, hi) of chunk `ci` were written."""
+        d = self._dirty.get(ci)
+        self._dirty[ci] = (lo, hi) if d is None \
+            else (min(d[0], lo), max(d[1], hi))
+
+    def _chunks_of(self, w0: int, k: int):
+        return range(w0 // self.CHUNK_ROWS,
+                     (w0 + k - 1) // self.CHUNK_ROWS + 1)
+
+    def row_span(self, w0: int, k: int, lane_major: bool = False):
+        """Rows [w0, w0 + k) of every lane: [k, L], or [L, k]
+        contiguous.  Cut on the device where that is offered, the rows'
+        chunks are not all here already and none of them was written
+        this round (a patched row is laid over what comes down); out of
+        the chunks otherwise."""
+        covering = self._chunks_of(w0, k)
+        if self._read_rows is not None \
+                and not all(ci in self._chunks for ci in covering) \
+                and not any(ci in self._dirty for ci in covering):
+            out = self._read_rows(w0, k, lane_major)
+            patched = [r for r in self._patches if w0 <= r < w0 + k]
+            if patched:     # rows written whole this round lie over it
+                out = np.array(out)
+                for r in patched:
+                    out[(slice(None), r - w0) if lane_major
+                        else r - w0] = self._patches[r]
+            return out
+        out = np.empty((self.L, k) if lane_major else (k, self.L),
+                       np.int32)
+        cr = self.CHUNK_ROWS
+        for ci in covering:
+            chunk = self._chunk(ci)
+            a = max(w0, ci * cr)
+            b = min(w0 + k, ci * cr + chunk.shape[0])
+            rows = chunk[a - ci * cr:b - ci * cr]
+            if lane_major:
+                out[:, a - w0:b - w0] = rows.T
+            else:
+                out[a - w0:b - w0] = rows
+        return out
+
+    def store_words(self, widx, vals, lanes):
+        """Scatter int32 words: plane[widx[r, j], lanes[j]] = vals[r, j]
+        for widx, vals [k, m], lanes [m] (one numpy assignment a
+        touched chunk, not a call a lane).  Where every lane stores at
+        ONE address and its chunk is not here, the rows are kept whole
+        as patches and no chunk comes down for them."""
+        cr = self.CHUNK_ROWS
+        lanes = np.asarray(lanes, np.int64)
+        k = widx.shape[0]
+        self._vec_writes.append((lanes, 4 * widx[0], 4 * k))
+        w0 = int(widx[0, 0])
+        if self._read_rows is not None and w0 + k <= self.W \
+                and bool((widx == widx[:, :1]).all()) \
+                and not any(ci in self._chunks
+                            for ci in self._chunks_of(w0, k)):
+            whole = lanes.size == self.L and \
+                bool((lanes == np.arange(self.L)).all())
+            for i in range(k):
+                row = self._patches.get(w0 + i)
+                if row is None and not whole:
+                    row = np.array(self._read_rows(w0 + i, 1, False)[0])
+                if row is None:
+                    row = np.array(vals[i], np.int32)
+                else:
+                    row[lanes] = vals[i]
+                self._patches[w0 + i] = row
+            return
+        cis = widx // cr
+        cols = np.broadcast_to(lanes[None, :], widx.shape)
+        for ci in np.unique(cis):
+            ci = int(ci)
+            m = cis == ci
+            rows = widx[m] - ci * cr
+            self._chunk(ci, write=True)[rows, cols[m]] = vals[m]
+            self._touch(ci, int(rows.min()), int(rows.max()) + 1)
+
+    def dirty_rows(self):
+        """[(first plane row, int32[n, L])] to write back: each dirty
+        chunk's written rows, widened to an aligned power-of-two count
+        (so the program that sets them compiles for few shapes), and
+        each run of patched rows as it is."""
+        out = []
+        for ci in sorted(self._dirty):
+            lo, hi = self._dirty[ci]
+            n = 1
+            while (lo // n) * n + n < hi:
+                n *= 2
+            chunk = self._chunks[ci]
+            n = min(n, chunk.shape[0])
+            a = (lo // n) * n
+            out.append((ci * self.CHUNK_ROWS + a, chunk[a:a + n]))
+        rows = np.array(sorted(self._patches), np.int64)
+        for run in np.split(rows, np.flatnonzero(np.diff(rows) != 1) + 1):
+            if run.size:
+                out.append((int(run[0]), np.stack(
+                    [self._patches[int(r)] for r in run])))
+        return out
 
     def read_bytes(self, lane: int, off: int, n: int) -> bytes:
         if n == 0:
@@ -519,7 +644,10 @@ class PlaneMemoryCache:
 
     def writes_of(self, lane: int):
         """(off, n) write extents recorded for a lane this round."""
-        return list(self._writes.get(lane, ()))
+        out = list(self._writes.get(lane, ()))
+        for lanes, offs, n in self._vec_writes:
+            out += [(int(o), n) for o in offs[lanes == lane]]
+        return out
 
     def write_bytes(self, lane: int, off: int, data: bytes):
         n = len(data)
@@ -537,14 +665,15 @@ class PlaneMemoryCache:
         while w <= w1:
             ci = w // self.CHUNK_ROWS
             base = ci * self.CHUNK_ROWS
-            chunk = self._chunk(ci)
+            chunk = self._chunk(ci, write=True)
             upto = min(w1 + 1, base + chunk.shape[0])
             chunk[w - base:upto - base, lane] = words[w - w0:upto - w0]
-            self._dirty.add(ci)
+            self._touch(ci, w - base, upto - base)
             w = upto
 
     def flush(self):
         """Apply dirty chunks device-side; returns the updated array."""
+        assert not self._patches    # only with read_rows, not here
         dev = self.dev
         for ci in sorted(self._dirty):
             lo = ci * self.CHUNK_ROWS
@@ -638,6 +767,9 @@ def make_cached_view(cache: PlaneMemoryCache, lanes, pages):
         def __init__(self):
             super().__init__(lanes, pages)
             self.cache = cache
+            # the view's lanes are the plane's columns, in order
+            self._every_lane = self.n == cache.L and bool(
+                (self.lanes == np.arange(cache.L)).all())
 
         def _words(self, widx):
             widx = np.clip(np.asarray(widx, np.int64), 0, cache.W - 1)
@@ -651,6 +783,18 @@ def make_cached_view(cache: PlaneMemoryCache, lanes, pages):
                 m = cis == ci
                 out[m] = chunk[widx[m] - int(ci) * cr, cols[m]]
             return out
+
+        def _row_span(self, w0, k, lane_major=False):
+            if w0 + k > cache.W:    # past the plane: clipped, as _words
+                return super()._row_span(w0, k, lane_major)
+            out = cache.row_span(w0, k, lane_major)
+            if self._every_lane:
+                return out
+            return np.ascontiguousarray(out[self.lanes]) if lane_major \
+                else out[:, self.lanes]
+
+        def _store_words(self, widx, vals, sel):
+            cache.store_words(widx, vals, self.lanes[sel])
 
         def _store_bytes_one(self, i, off, data):
             cache.write_bytes(int(self.lanes[i]), off, bytes(data))

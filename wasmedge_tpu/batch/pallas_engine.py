@@ -4181,6 +4181,79 @@ def _split_pass_record(flat: np.ndarray, nblk: int, cd: int, lanes: int,
                       res_hi.reshape(nres, lanes))
 
 
+def jit_in_place(fn, *planes):
+    """jit(fn) with the arguments `planes` donated, so that a program
+    which sets a few rows or columns of a plane writes it in place and
+    the caller rebinds the result.  Not on the CPU with a persistent
+    compile cache: a deserialized executable can lose its input/output
+    aliasing there (the carve-out of `serve/recycle.py:_install_fn`)."""
+    import jax
+
+    if jax.default_backend() == "cpu" and \
+            getattr(jax.config, "jax_compilation_cache_dir", None):
+        planes = ()
+    return jax.jit(fn, donate_argnums=planes)
+
+
+def _hostcall_fns():
+    """-> {name: program}, the compiled programs of a hostcall serve
+    (`_serve_hostcalls_begin`, `_finish`), one set an engine: jit keys
+    their variants by the planes' shapes and the static counts.
+
+    gather(plane, idx)                 the columns `idx` of the memory
+                                       plane, a buffer of its own
+    rows(plane, w0, k, lane_major, pieces)
+                                       rows [w0, w0 + k) of every lane,
+                                       [k, L], or transposed on the
+                                       device to [L, k]: a lane's bytes
+                                       end to end; as a tuple of
+                                       `pieces` arrays, the first axis
+                                       cut into near-equal runs (`k`,
+                                       `lane_major`, `pieces` static)
+    set_rows(plane, rows, r0, c0)      the plane with `rows` set at
+                                       (r0, c0), in place
+    results(lo, hi, res_lo, res_hi, ob, c0)
+                                       both stacks with the result rows
+                                       set at (ob, c0), in place
+    trap(plane, codes, c0)             the trap row's columns from c0
+                                       raised to `codes`, in place
+
+    The three that write take their planes donated (`jit_in_place`:
+    the caller rebinds them)."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    def gather(plane, idx):
+        return plane[:, idx]
+
+    def rows(plane, w0, k, lane_major, pieces):
+        out = lax.dynamic_slice(plane, (w0, 0), (k, plane.shape[1]))
+        out = out.T if lane_major else out
+        step = -(-out.shape[0] // pieces)
+        return tuple(out[a:a + step]
+                     for a in range(0, out.shape[0], step))
+
+    def set_rows(plane, new, r0, c0):
+        return lax.dynamic_update_slice(plane, new, (r0, c0))
+
+    def results(lo, hi, res_lo, res_hi, ob, c0):
+        return (lax.dynamic_update_slice(lo, res_lo, (ob, c0)),
+                lax.dynamic_update_slice(hi, res_hi, (ob, c0)))
+
+    def trap(plane, codes, c0):
+        zero = jnp.zeros((), c0.dtype)
+        old = lax.dynamic_slice(plane, (zero, c0), (1, codes.shape[0]))
+        return lax.dynamic_update_slice(
+            plane, jnp.maximum(old, codes[None, :]), (zero, c0))
+
+    return {"gather": jax.jit(gather),
+            "rows": jax.jit(rows, static_argnums=(2, 3, 4)),
+            "set_rows": jit_in_place(set_rows, 0),
+            "results": jit_in_place(results, 0, 1),
+            "trap": jit_in_place(trap, 0)}
+
+
 class PallasUniformEngine:
     """Block-converged engine running the dispatch loop on-device.
 
@@ -4235,6 +4308,8 @@ class PallasUniformEngine:
         self._fn = None
         self._fn_careful_cache = None
         self._pack_cache = None
+        self._hostcall_fns_cache = None
+        self._rows_buffers = {}     # `_read_plane_rows`' own, by shape
         self._tables = None
         self._blk_cap = None  # lane-block ceiling (multi-tenant alignment)
         self.fell_back_to_simt = False
@@ -4253,6 +4328,13 @@ class PallasUniformEngine:
         self.d2h_transfers = 0
         self.h2d_transfers = 0
         self.programs_enqueued = 0
+        # the last run()'s hostcall serves: rounds of park, drain and
+        # re-arm, lanes drained, those a vectorised implementation
+        # served, bytes the calls handed to an fd
+        self.hostcall_rounds = 0
+        self.hostcall_calls = 0
+        self.hostcall_vectorized = 0
+        self.hostcall_out_bytes = 0
         # (expected, max) branches a dispatch walks in the kernel's
         # tree (plan_dispatch_tree), known once a kernel was built
         self.dispatch_depth = None
@@ -4929,16 +5011,21 @@ class PallasUniformEngine:
         only the genuinely per-lane residue finishes on SIMT.
         `splits`, `launches`, `rechecks` (`recheck_rounds` is the same
         number), `careful_steps`, `surgery_programs`, `d2h_transfers`,
-        `h2d_transfers` and `programs_enqueued` are this run's; the
-        cached per-geometry engines keep their own growing
-        `recheck_rounds`."""
+        `h2d_transfers` and `programs_enqueued` are this run's, and so
+        are `hostcall_rounds`, `hostcall_calls`, `hostcall_vectorized`
+        and `hostcall_out_bytes` (park, drain and re-arm cycles, lanes
+        drained, those a vectorised implementation served, bytes the
+        calls handed to an fd); the cached per-geometry engines keep
+        their own growing `recheck_rounds`."""
         ex = self.inst.exports.get(func_name)
         if ex is None or ex[0] != 0:
             raise KeyError(f"no exported function {func_name}")
         if not self.eligible:
             return self.simt.run(func_name, args_lanes, max_steps)
+        from wasmedge_tpu.batch.engine import new_hostcall_stats
         from wasmedge_tpu.batch.scheduler import BlockScheduler
 
+        self.simt.hostcall_stats = new_hostcall_stats()   # this run's
         sched = BlockScheduler(self, func_name, args_lanes, max_steps)
         sched.run()
         self.fell_back_to_simt = sched.fell_back_to_simt
@@ -4958,6 +5045,17 @@ class PallasUniformEngine:
                                   d2h_transfers=link.d2h_transfers,
                                   h2d_transfers=link.h2d_transfers,
                                   programs_enqueued=link.programs_enqueued)
+        # what the run's hostcall serves counted (the scheduler binds
+        # its engine's `hostcall_stats` to this engine's for the run)
+        hc = getattr(self.simt, "hostcall_stats", None) or {}
+        self.hostcall_rounds = hc.get("serve_rounds", 0)
+        self.hostcall_calls = hc.get("tier1_calls", 0)
+        self.hostcall_vectorized = hc.get("tier1_vectorized", 0)
+        self.hostcall_out_bytes = hc.get("out_bytes", 0)
+        if self.hostcall_rounds:
+            self.obs.add_hostcall_counts(
+                self.hostcall_rounds, self.hostcall_calls,
+                self.hostcall_vectorized, self.hostcall_out_bytes)
         self.aot_fused_verified = sched.eng.aot_fused_verified
         self.dispatch_depth = sched.eng.dispatch_depth
         self.mem_static = sched.eng.mem_static
@@ -4993,34 +5091,84 @@ class PallasUniformEngine:
         re-arm them (synchronous composition of the begin/finish halves
         below — the block scheduler calls the halves directly so host
         service of parked blocks OVERLAPS the next kernel launch)."""
-        import jax.numpy as jnp
-
+        link = HostLink(functools.partial(self.obs.timed, cat="scheduler"))
         pending = self._serve_hostcalls_begin(state, ctrl_np,
-                                              valid_blocks)
+                                              valid_blocks, link)
         state, rearms = self._serve_hostcalls_finish(state, pending)
         ctrl = ctrl_np.copy()
         for b, row in rearms.items():
             ctrl[b] = row
-        state[0] = jnp.asarray(ctrl)
+        state[0] = link.h2d("ctrl", ctrl)
         return state
 
-    def _serve_hostcalls_begin(self, state, ctrl_np, valid_blocks=None):
+    def _hostcall_programs(self):
+        if self._hostcall_fns_cache is None:
+            self._hostcall_fns_cache = _hostcall_fns()
+        return self._hostcall_fns_cache
+
+    # A download of more than this comes down in pieces (below)
+    ROWS_PIECE_BYTES = 8 << 20
+
+    def _read_plane_rows(self, link, plane, w0, k, lane_major):
+        """Rows [w0, w0 + k) of every lane of `plane` on the host: [k, L],
+        or [L, k] with `lane_major`, cut (and transposed) on the device.
+
+        A payload (a `fd_write` of 8 KiB a lane is 32 MiB at 4096
+        lanes) comes down in pieces of at most `ROWS_PIECE_BYTES`, cut
+        by the one program, their copies started at once, each laid into
+        a buffer the engine keeps from round to round.  One download of
+        the whole would land in 32 MiB the allocator maps anew every
+        round (over its mmap threshold, whatever it is set to), and the
+        page faults of that cost more than the link does: 17 ms against
+        4.4 for the pieces on a v5e host, where a fresh 32 MiB touched
+        once a page costs 34 (PR 40).  The buffer is the caller's until
+        the next read of that shape: a serve has written it out by
+        then."""
+        lanes = int(plane.shape[1])
+        shape = (lanes, k) if lane_major else (k, lanes)
+        pieces = min(-(-4 * k * lanes // self.ROWS_PIECE_BYTES), shape[0])
+        parts = link.enqueue(
+            "hc_rows", self._hostcall_programs()["rows"], plane,
+            np.int32(w0), k, lane_major, max(pieces, 1))
+        if len(parts) == 1:
+            return link.d2h("mem_rows", parts[0])
+        for part in parts:
+            part.copy_to_host_async()
+        out = self._rows_buffers.get(shape)
+        if out is None:
+            out = self._rows_buffers[shape] = np.empty(shape, np.int32)
+        a = 0
+        for part in parts:
+            out[a:a + part.shape[0]] = link.d2h("mem_rows", part)
+            a += part.shape[0]
+        return out
+
+    def _serve_hostcalls_begin(self, state, ctrl_np, valid_blocks=None,
+                               link=None):
         """Phase 1 of the outcall serve: capture every device-side read
-        the serve needs — parked blocks' metas and ctrl rows, ONE
-        stack-slab download covering all argument rows, and a device-
-        side gather of the parked blocks' memory columns into a fresh
-        (non-donated) array.  After this returns, the caller may launch
-        the next kernel round; phase 2 never touches the launched
-        planes for reads.
+        the serve needs — parked blocks' metas and ctrl rows, the two
+        stack slabs covering all argument rows, and the parked blocks'
+        memory columns as an array the next launch's donation cannot
+        invalidate.  After this returns, the caller may launch the next
+        kernel round; phase 2 never touches the launched planes for
+        reads.
+
+        Where every column of the plane is parked, no block is left to
+        launch before phase 2, so the live plane itself is that array
+        and nothing is copied; otherwise the columns are gathered on
+        the device into a buffer of their own.
 
         Transfer discipline (each host-link transfer pays a fixed
-        latency): the slab is one download, guest memory goes
-        through a PlaneMemoryCache over the gathered columns whose
-        4 KiB row chunks are fetched for ALL lanes at once and written
-        back dirty-chunks-only — per-lane data never rides the link
-        alone (the "vectorized memory views" serve, SURVEY §5.8/§7(d))."""
-        import jax.numpy as jnp
-
+        latency, and each is a leaf span of `link`, the caller's
+        HostLink): guest memory goes through a PlaneMemoryCache over
+        those columns, which cuts (and transposes) the rows of an access
+        at one address in every lane on the device, fetches 4 KiB row
+        chunks for ALL lanes at once for any other, and writes back the
+        written rows only — per-lane data never rides the link alone
+        (the "vectorized memory views" serve, SURVEY §5.8/§7(d))."""
+        if link is None:
+            link = HostLink(functools.partial(self.obs.timed,
+                                              cat="scheduler"))
         img = self.img
         D, CD, W, Lblk = self._geom
         t_begin = self.obs.now()
@@ -5040,14 +5188,18 @@ class PallasUniformEngine:
             max_row = max(max_row, int(ctrl_np[b, _C_FP]) + nargs)
         has_mem = img.has_memory and bool(blocks)
         cols = np.concatenate(
-            [np.arange(b * Lblk, (b + 1) * Lblk, dtype=np.int64)
-             for b in blocks]) if blocks else np.zeros(0, np.int64)
-        # device-side column gather: a fresh array the next launch's
-        # donation cannot invalidate (chunk downloads happen lazily in
-        # phase 2, overlapping the kernel)
-        mem_cols = state[6][:, jnp.asarray(cols)] if has_mem else None
-        slab_lo = np.asarray(state[2][:max_row]) if max_row else None
-        slab_hi = np.asarray(state[3][:max_row]) if max_row else None
+            [np.arange(b * Lblk, (b + 1) * Lblk, dtype=np.int32)
+             for b in blocks]) if blocks else np.zeros(0, np.int32)
+        mem_cols = None
+        if has_mem:
+            mem_cols = state[6] if cols.size == self.lanes else \
+                link.enqueue("hc_gather",
+                             self._hostcall_programs()["gather"],
+                             state[6], cols)
+        slab_lo = link.d2h("slab_lo", state[2], np.s_[:max_row]) \
+            if max_row else None
+        slab_hi = link.d2h("slab_hi", state[3], np.s_[:max_row]) \
+            if max_row else None
         obs = self.obs
         if obs.enabled and blocks:
             obs.span("serve_begin", t_begin, cat="scheduler",
@@ -5059,26 +5211,31 @@ class PallasUniformEngine:
             obs.counter("hostcall_queue_depth", sum(
                 int(vb[b].sum()) if vb.get(b) is not None else Lblk
                 for b in blocks))
-        return {"blocks": blocks, "metas": metas, "cols": cols,
+        return {"blocks": blocks, "metas": metas,
                 "mem_cols": mem_cols, "slab_lo": slab_lo,
-                "slab_hi": slab_hi, "Lblk": Lblk,
+                "slab_hi": slab_hi, "Lblk": Lblk, "link": link,
                 "valid_blocks": valid_blocks or {}}
 
     def _serve_hostcalls_finish(self, state, pending):
         """Phase 2: run the host functions (vectorized per block where
         a tier-1 SoA WASI implementation exists, per-lane otherwise)
-        and apply the results — result rows, trap columns, and dirty
-        memory chunks go back as device column updates; re-armed ctrl
-        rows are RETURNED for the caller to fold into its ctrl mirror
-        (the kernel may be mid-flight on the other blocks).
+        and apply the results — result rows, trap columns, and written
+        memory rows go back through compiled programs that set them in
+        place; re-armed ctrl rows are RETURNED for the caller to fold
+        into its ctrl mirror (the kernel may be mid-flight on the other
+        blocks).  Every crossing goes through the link of phase 1.
 
         valid_blocks: {block: bool[Lblk]} from the scheduler — pad
         (clone) lanes are NOT served (a host function's side effects
         must fire once per real instance, never for padding); their
         result columns and memory writes are replayed from the block's
-        first valid lane (their clone source), keeping them converged."""
-        import jax.numpy as jnp
+        first valid lane (their clone source), keeping them converged.
 
+        The run's counts (`hostcall_stats` of the engine's SIMT twin,
+        which the block scheduler binds to the outer engine's):
+        `serve_rounds`, `tier1_calls`, `tier1_vectorized`, and
+        `out_bytes`, what the calls handed to an fd (the environ's own
+        count, whichever path served them)."""
         from wasmedge_tpu.batch.hostcall import (
             PlaneMemoryCache,
             _CachedLaneMemory,
@@ -5091,11 +5248,22 @@ class PallasUniformEngine:
         img = self.img
         D, CD, W, Lblk = self._geom
         metas = pending["metas"]
+        link = pending["link"]
+        fns = self._hostcall_programs()
         valid_blocks = pending["valid_blocks"]
         slab_lo = pending["slab_lo"]
         slab_hi = pending["slab_hi"]
         has_mem = img.has_memory and pending["mem_cols"] is not None
-        cache = PlaneMemoryCache(pending["mem_cols"]) if has_mem else None
+        cache = None
+        if has_mem:
+            mem_cols = pending["mem_cols"]
+
+            def read_rows(w0, k, lane_major):
+                return self._read_plane_rows(link, mem_cols, w0, k,
+                                             lane_major)
+
+            cache = PlaneMemoryCache(mem_cols, d2h=link.d2h,
+                                     read_rows=read_rows)
         plane_cap = (W // _PAGE_WORDS) if has_mem else 0
         if img.mem_pages_max > 0:
             max_pages = min(img.mem_pages_max, plane_cap)
@@ -5110,6 +5278,13 @@ class PallasUniformEngine:
 
         prev_rec = set_drain_recorder(obs)
 
+        def set_results(lo_col, ob, nres, res_lo, res_hi):
+            if nres:
+                state[2], state[3] = link.enqueue(
+                    "hc_results", fns["results"], state[2], state[3],
+                    res_lo[:nres], res_hi[:nres], np.int32(ob),
+                    np.int32(lo_col))
+
         try:
             for bi, (b, pc, k, fi, nargs, fp, ob, pages, cc) in \
                     enumerate(metas):
@@ -5121,13 +5296,15 @@ class PallasUniformEngine:
                 res_hi = np.zeros((max(nres, 1), Lblk), np.int32)
                 trap_codes = np.zeros(Lblk, np.int32)
                 new_pages = np.full(Lblk, pages, np.int32)
+                env = getattr(getattr(fi, "host", None), "_env", None)
+                out0 = getattr(env, "bytes_written", 0)
                 if stats is not None:
                     n_real = int(vmask.sum()) if vmask is not None else Lblk
                     stats["serve_rounds"] += 1 if bi == 0 else 0
                     stats["tier1_calls"] += n_real
                 served_vec = False
                 if use_vec and has_mem and getattr(fi, "kind", None) == "host":
-                    vecfn, env = vec_impl_for(fi)
+                    vecfn, venv = vec_impl_for(fi)
                     if vecfn is not None:
                         from wasmedge_tpu.batch.hostcall import \
                             gather_arg_cells
@@ -5141,7 +5318,7 @@ class PallasUniformEngine:
                         view = make_cached_view(cache, loc + vsel,
                                                 np.full(vsel.size, pages))
                         try:
-                            cells, codes = vecfn(env, view, args)
+                            cells, codes = vecfn(venv, view, args)
                             served_vec = True
                         except NotVectorizable:
                             served_vec = False
@@ -5188,6 +5365,9 @@ class PallasUniformEngine:
                         n_real = int(vmask.sum()) if vmask is not None else Lblk
                         obs.hostcall(hostcall_kind(fi), obs.now() - t_drain,
                                      lanes=n_real, vectorized=False)
+                if stats is not None:
+                    stats["out_bytes"] += \
+                        getattr(env, "bytes_written", 0) - out0
                 if vmask is not None and not vmask.all():
                     src = int(np.argmax(vmask))  # first valid = clone source
                     pads = np.nonzero(~vmask)[0]
@@ -5208,8 +5388,9 @@ class PallasUniformEngine:
                     # served lanes' results applied (their host calls MUST
                     # NOT re-run), then leave the block DIVERGED for the
                     # scheduler to partition per lane.
-                    state[7] = state[7].at[0, lo_col:lo_col + Lblk].max(
-                        jnp.asarray(trap_codes))
+                    state[7] = link.enqueue(
+                        "hc_trap", fns["trap"], state[7], trap_codes,
+                        np.int32(lo_col))
                     if grew.any():
                         self._pages_override[b] = new_pages.copy()
                     if (trap_codes != 0).all() and \
@@ -5217,43 +5398,31 @@ class PallasUniformEngine:
                         cc[_C_STATUS] = ST_TRAPPED_BASE + int(trap_codes[0])
                         rearms[b] = cc
                         continue
-                    if nres:
-                        state[2] = state[2].at[ob:ob + nres,
-                                               lo_col:lo_col + Lblk].set(
-                            jnp.asarray(res_lo[:nres]))
-                        state[3] = state[3].at[ob:ob + nres,
-                                               lo_col:lo_col + Lblk].set(
-                            jnp.asarray(res_hi[:nres]))
+                    set_results(lo_col, ob, nres, res_lo, res_hi)
                     cc[_C_PC] = pc + 1
                     cc[_C_SP] = ob + nres
                     cc[_C_STATUS] = ST_DIVERGED
                     rearms[b] = cc
                     continue
-                if nres:
-                    state[2] = state[2].at[ob:ob + nres,
-                                           lo_col:lo_col + Lblk].set(
-                        jnp.asarray(res_lo[:nres]))
-                    state[3] = state[3].at[ob:ob + nres,
-                                           lo_col:lo_col + Lblk].set(
-                        jnp.asarray(res_hi[:nres]))
+                set_results(lo_col, ob, nres, res_lo, res_hi)
                 cc[_C_PC] = pc + 1
                 cc[_C_SP] = ob + nres
                 cc[_C_STATUS] = ST_RUNNING
                 rearms[b] = cc
         finally:
             set_drain_recorder(prev_rec)
-        if has_mem and cache._dirty:
-            # dirty chunks go back to the live plane as column updates
-            colsj = jnp.asarray(pending["cols"])
-            cr = PlaneMemoryCache.CHUNK_ROWS
-            for ci in sorted(cache._dirty):
-                lo = ci * cr
-                ch = cache._chunks[ci]
-                state[6] = state[6].at[lo:lo + ch.shape[0], colsj].set(
-                    jnp.asarray(ch))
-            cache._dirty.clear()
+        if has_mem:
+            # the rows written this round go back to the live plane,
+            # each block's columns in place
+            for row0, rows in cache.dirty_rows():
+                for bi, meta in enumerate(metas):
+                    part = rows if len(metas) == 1 else \
+                        np.ascontiguousarray(
+                            rows[:, bi * Lblk:(bi + 1) * Lblk])
+                    state[6] = link.enqueue(
+                        "hc_scatter", fns["set_rows"], state[6], part,
+                        np.int32(row0), np.int32(meta[0] * Lblk))
         if obs.enabled and metas:
             obs.span("serve_finish", t_finish, cat="scheduler",
                      track="serve", blocks=len(metas))
         return state, rearms
-

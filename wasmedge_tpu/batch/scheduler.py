@@ -80,6 +80,7 @@ from wasmedge_tpu.batch.pallas_engine import (
     _PAGE_WORDS,
     HostLink,
     PallasUniformEngine,
+    jit_in_place,
 )
 
 # host-side block slot states
@@ -120,9 +121,7 @@ def _surgery_fns():
     extract(planes, idx[w]) -> the columns `idx` of every plane.
     install(planes, cols, sel[Lblk], lo) -> the planes with columns
     lo..lo+Lblk set to `cols[:, sel]`, in place: the planes are donated
-    (the caller rebinds them), with the same cpu + persistent-cache
-    carve-out as `serve/recycle.py:_install_fn` (a deserialized
-    executable can lose input/output aliasing there)."""
+    (`jit_in_place`; the caller rebinds them)."""
     import jax
     from jax import lax
 
@@ -133,11 +132,7 @@ def _surgery_fns():
         return tuple(lax.dynamic_update_slice(p, c[:, sel], (0, lo))
                      for p, c in zip(planes, cols))
 
-    donate = (0,)
-    if jax.default_backend() == "cpu" and \
-            getattr(jax.config, "jax_compilation_cache_dir", None):
-        donate = ()
-    return jax.jit(extract), jax.jit(install, donate_argnums=donate)
+    return jax.jit(extract), jit_in_place(install, 0)
 
 
 class _Rows:
@@ -371,6 +366,9 @@ class BlockScheduler:
             eng._surgery = _surgery_fns()
             cache[(L, lblk)] = eng
         self.eng = eng
+        # a hostcall serve counts into its engine's SIMT twin: this
+        # run's dict, not the cached engine's own growing one
+        eng.simt.hostcall_stats = outer.simt.hostcall_stats
         self.block_lanes = np.stack(blocks)  # [nblk, lblk]
         self.block_state = np.full(self.nblk, _B_LIVE, np.int32)
         self.block_steps = np.zeros(self.nblk, np.int64)
@@ -496,8 +494,14 @@ class BlockScheduler:
         if p is None:
             return False
         self._pending_serve = None
-        self.state, rearms = self.eng._serve_hostcalls_finish(
-            self.state, p)
+        stats = self.eng.simt.hostcall_stats
+        calls, out = stats["tier1_calls"], stats["out_bytes"]
+        with self._phase("batch/hostcall_finish",
+                         blocks=len(p["blocks"])) as span:
+            self.state, rearms = self.eng._serve_hostcalls_finish(
+                self.state, p)
+            span.set(calls=stats["tier1_calls"] - calls,
+                     bytes=stats["out_bytes"] - out)
         self._serve_rearms = rearms
         return True
 
@@ -519,7 +523,12 @@ class BlockScheduler:
             self._upload_frames()
             if self._launched:
                 self.launches += 1
-                self._live_at_launch = live
+                # the blocks this launch runs: the kernel hands a row
+                # that is not RUNNING back as it got it, the steps and
+                # counts of the launch that stopped it included, so a
+                # block parked through a launch (a serve that overlaps
+                # it) must not be counted again at the sync
+                self._ran_at_launch = runnable
                 self._t_launch = self.obs.now()
                 out = self.link.enqueue(
                     "optimistic", self.eng._fn, *self.eng._tables,
@@ -617,11 +626,18 @@ class BlockScheduler:
                 ctrl_np[b] = row
                 if row[_C_STATUS] == ST_DIVERGED:
                     self._served_stops.add(b)
+                # an import's stub is two synthetic instructions, the
+                # HOSTCALL the kernel counted when it parked the block
+                # and the RETURN it counts once re-armed: neither is
+                # the guest's, whose `call` retired already, so a lane's
+                # count stays the scalar engine's through every round
+                self.block_steps[b] -= \
+                    1 if row[_C_STATUS] >= ST_TRAPPED_BASE else 2
             self._serve_rearms = None
             self._ctrl_dirty = True
             served = True
         if self._launched:
-            live = self._live_at_launch
+            live = self._ran_at_launch
             new_steps = ctrl_np[:, _C_STEPS].astype(np.int64)
             self.block_steps[live] += new_steps[live]
             self._count_kernel(ctrl_np, live)
@@ -766,8 +782,13 @@ class BlockScheduler:
             # (non-RUNNING) blocks through untouched with zero steps,
             # so the deferred writebacks land on unchanged columns.
             valid = {b: self.block_lanes[b] >= 0 for b in hostcall_blocks}
-            self._pending_serve = self.eng._serve_hostcalls_begin(
-                self.state, ctrl_np, valid_blocks=valid)
+            with self._phase("batch/hostcall_begin",
+                             blocks=len(hostcall_blocks),
+                             lanes=int(sum(v.sum()
+                                           for v in valid.values()))):
+                self._pending_serve = self.eng._serve_hostcalls_begin(
+                    self.state, ctrl_np, valid_blocks=valid,
+                    link=self.link)
             progress = True
         # a prior serve's re-arms may have left per-lane outcomes
         # (folded into ctrl_np by process): DIVERGED/trapped re-armed

@@ -999,6 +999,8 @@ class UniformBatchEngine:
             sds = getattr(self.pallas, "simd_share", None)
             if sds is not None:
                 span.set(simd_share=round(sds, 6))
+            if getattr(self.pallas, "hostcall_rounds", 0):
+                span.set(hostcall_rounds=self.pallas.hostcall_rounds)
             if getattr(self.pallas, "splits", 0):
                 span.set(splits=self.pallas.splits,
                          launches=self.pallas.launches,

@@ -89,6 +89,11 @@ class WasiEnviron:
         self.exit_code: int = 0
         self.exited: bool = False
         self._next_fd = 3
+        # bytes `fd_write` / `fd_pwrite` handed to an fd, over the
+        # environ's life and whichever path served the call (the scalar
+        # function, the batch engines' vectorised drain, their tier-0
+        # stdout flush): what a hostcall serve reads its output from
+        self.bytes_written: int = 0
 
     # -- lifecycle (environ.h init/fini) -----------------------------------
     def init(self, dirs: Optional[List[str]] = None, prog_name: str = "wasm",

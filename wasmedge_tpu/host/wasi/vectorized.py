@@ -65,6 +65,24 @@ class MemView:
         """Store bytes into view-lane i's memory at byte offset off."""
         raise NotImplementedError
 
+    def _row_span(self, w0: int, k: int, lane_major: bool = False):
+        """Words of rows [w0, w0 + k) of every view lane: [k, n], or
+        with `lane_major` [n, k] contiguous.  What `_words` gives for a
+        row index that is the same in every lane, without building the
+        index; a backend may slice, or transpose where it is cheap."""
+        words = self._words(np.broadcast_to(
+            np.arange(w0, w0 + k, dtype=np.int64)[:, None], (k, self.n)))
+        return np.ascontiguousarray(words.T) if lane_major else words
+
+    def _store_words(self, widx: np.ndarray, vals: np.ndarray, sel):
+        """Store int32 words: widx, vals [k, m] for the view lanes `sel`
+        [m], one aligned word each.  A backend may scatter."""
+        for j, i in enumerate(sel):
+            for r in range(widx.shape[0]):
+                self._store_bytes_one(
+                    int(i), 4 * int(widx[r, j]),
+                    int(np.uint32(vals[r, j])).to_bytes(4, "little"))
+
     # -- shared vectorized layer --------------------------------------------
     def bounds_ok(self, off, ln) -> np.ndarray:
         off = np.asarray(off, np.uint64)
@@ -73,14 +91,28 @@ class MemView:
         return (end >= off) & (end <= self.pages.astype(np.uint64)
                                * np.uint64(65536))
 
-    def load_u32(self, off) -> np.ndarray:
+    def _unaligned_u32(self, off, count: int):
+        """`count` u32 from byte `off` on, a lane: uint32 [count, n]
+        (count + 1 words a lane; rows, not an index, where every lane
+        reads at one address)."""
         off = np.asarray(off, np.int64)
         w0 = off >> 2
-        ws = self._words(np.stack([w0, w0 + 1]))
-        lo = ws[0].view(np.uint32).astype(np.uint64)
-        hi = ws[1].view(np.uint32).astype(np.uint64)
+        if self.n and int(off.min()) == int(off.max()):
+            ws = self._row_span(int(w0[0]), count + 1)
+        else:
+            ws = self._words(np.stack([w0 + i for i in range(count + 1)]))
+        w = [x.view(np.uint32).astype(np.uint64) for x in ws]
         sh = ((off & 3) * 8).astype(np.uint64)
-        return ((lo | (hi << np.uint64(32))) >> sh).astype(np.uint32)
+        return [((w[i] | (w[i + 1] << np.uint64(32))) >> sh).astype(
+            np.uint32) for i in range(count)]
+
+    def load_u32(self, off) -> np.ndarray:
+        return self._unaligned_u32(off, 1)[0]
+
+    def load_u32x2(self, off):
+        """(u32 at off, u32 at off + 4): an iovec's pointer and length,
+        or any two adjacent words, in one gather."""
+        return tuple(self._unaligned_u32(off, 2))
 
     def gather_bytes(self, off, ln) -> list:
         """Per-lane bytes objects for ranges [off, off+ln); caller has
@@ -93,14 +125,40 @@ class MemView:
         if maxb == 0:
             return [b""] * self.n
         maxw = (maxb + 3) // 4
-        idx = (off >> 2)[None, :] + np.arange(maxw, dtype=np.int64)[:, None]
-        words = self._words(idx)                       # [maxw, n]
-        raw = np.ascontiguousarray(words.T).view(np.uint8)  # [n, maxw*4]
+        raw = self._lane_major(off, maxw)               # [n, maxw*4]
         out = []
         for i in range(self.n):
             s = int(off[i] & 3)
             out.append(raw[i, s:s + int(ln[i])].tobytes())
         return out
+
+    def _lane_major(self, off, maxw: int) -> np.ndarray:
+        """uint8 [n, 4 * maxw]: lane i's `maxw` words from the word that
+        holds byte `off[i]`.  One fancy gather covers every lane; where
+        every lane reads at the same offset it is a slice of rows."""
+        w0 = off >> 2
+        if int(w0.min()) == int(w0.max()):
+            return self._row_span(int(w0[0]), maxw, True).view(np.uint8)
+        words = self._words(
+            w0[None, :] + np.arange(maxw, dtype=np.int64)[:, None])
+        return np.ascontiguousarray(words.T).view(np.uint8)
+
+    def gather_matrix(self, off, ln):
+        """uint8 [n, ln] of the ranges [off, off + ln) where every lane
+        reads the same, non-zero length (caller has bounds-checked): the
+        lanes' bytes end to end, which is what one write of them in
+        lane order hands to an fd.  None where the lengths differ."""
+        off = np.asarray(off, np.int64)
+        ln = np.asarray(ln, np.int64)
+        if self.n == 0 or int(ln.min()) != int(ln.max()) or int(ln[0]) == 0:
+            return None
+        n1 = int(ln[0])
+        s0 = off & 3
+        raw = self._lane_major(off, (int(s0.max()) + n1 + 3) // 4)
+        if not s0.any():
+            return raw[:, :n1]
+        cols = s0[:, None] + np.arange(n1, dtype=np.int64)[None, :]
+        return np.take_along_axis(raw, cols, axis=1)
 
     def store_u32(self, off, vals, mask=None):
         self._store_scalar(off, np.asarray(vals, np.uint64), 4, mask)
@@ -113,7 +171,19 @@ class MemView:
         m = np.ones(self.n, bool) if mask is None \
             else np.asarray(mask, bool).copy()
         m &= np.asarray(self.bounds_ok(off, nbytes))
-        for i in np.nonzero(m)[0]:
+        sel = np.nonzero(m)[0]
+        if sel.size and not (off[sel] & 3).any():
+            # aligned: whole words, one scatter for every lane
+            k = nbytes // 4
+            v = np.asarray(vals, np.uint64)[sel]
+            words = np.stack([
+                ((v >> np.uint64(32 * r)) & np.uint64(MASK32)).astype(
+                    np.uint32).view(np.int32) for r in range(k)])
+            self._store_words(
+                (off[sel] >> 2)[None, :]
+                + np.arange(k, dtype=np.int64)[:, None], words, sel)
+            return
+        for i in sel:
             self._store_bytes_one(
                 int(i), int(off[i]),
                 int(vals[i]).to_bytes(nbytes, "little"))
@@ -139,6 +209,10 @@ class SoAMemView(MemView):
     def _words(self, widx):
         w = np.clip(widx, 0, self.W - 1)
         return self.plane[w, self.lanes[None, :]]
+
+    def _store_words(self, widx, vals, sel):
+        self.plane[widx, self.lanes[sel][None, :]] = vals
+        self.dirty = True
 
     def _store_bytes_one(self, i, off, data):
         lane = int(self.lanes[i])
@@ -292,14 +366,17 @@ def vec_fd_write(env: WasiEnviron, view: MemView, args):
     res[live & ~arr_ok] = int(Errno.FAULT)
     live &= arr_ok
 
-    datas = [[] for _ in range(n)]
+    # iovec j of every lane: a [n, len] byte matrix where every lane
+    # hands over the same length (a buffered writer's block: the usual
+    # case, and then no byte is touched lane by lane), else a list
+    pieces = []
     total = np.zeros(n, np.int64)
     for j in range(int(cnt.max(initial=0))):
         has = live & (j < cnt)
         if not has.any():
             break
-        bufs = view.load_u32(iovs + 8 * j).astype(np.int64)
-        lens = view.load_u32(iovs + 8 * j + 4).astype(np.int64)
+        bufs, lens = (x.astype(np.int64)
+                      for x in view.load_u32x2(iovs + 8 * j))
         lens = np.where(has, lens, 0)
         dok = np.asarray(view.bounds_ok(bufs, lens))
         bad = has & ~dok
@@ -308,17 +385,24 @@ def vec_fd_write(env: WasiEnviron, view: MemView, args):
         res[bad] = int(Errno.FAULT)
         live &= dok | ~has
         lens = np.where(has & dok, lens, 0)
-        chunks = view.gather_bytes(bufs, lens)
-        for i in np.nonzero(has & dok)[0]:
-            if chunks[i]:
-                datas[i].append(chunks[i])
-                total[i] += len(chunks[i])
+        piece = view.gather_matrix(bufs, lens)
+        if piece is None:
+            piece = view.gather_bytes(bufs, lens)
+        pieces.append(piece)
+        total += lens
 
     # one write per fd, lane-ascending (matches per-lane serve order)
     for fd, e in sorted(entries.items()):
-        out = b"".join(b"".join(datas[i])
-                       for i in np.nonzero(fds == fd)[0])
-        _write_all(e, out)
+        sel = np.nonzero(fds == fd)[0]
+        if len(pieces) == 1 and isinstance(pieces[0], np.ndarray):
+            block = pieces[0] if sel.size == n else pieces[0][sel]
+            env.bytes_written += _write_all(
+                e, np.ascontiguousarray(block).reshape(-1))
+            continue
+        rows = [p if isinstance(p, list) else
+                [memoryview(r) for r in p] for p in pieces]
+        env.bytes_written += _write_all(
+            e, b"".join(r[i] for i in sel for r in rows))
 
     wrote = total.astype(np.uint64)
     np_ok = np.asarray(view.bounds_ok(nwp, 4))
@@ -327,7 +411,10 @@ def vec_fd_write(env: WasiEnviron, view: MemView, args):
     return res.reshape(1, n), np.zeros(n, np.int32)
 
 
-def _write_all(entry, data: bytes):
+def _write_all(entry, data):
+    """All of `data` (bytes, or a flat uint8 array: no copy) to the fd;
+    the environ counts what its fds were handed."""
     off = 0
     while off < len(data):
         off += os.write(entry.os_fd, data[off:])
+    return off

@@ -372,6 +372,8 @@ def _do_write(env, mem, fd, iovs, iovs_len, nw_ptr, offset=None):
                 break
     except OSError as ex:
         return from_oserror(ex)
+    finally:
+        env.bytes_written += total
     mem.store(nw_ptr & MASK32, 4, total)
     return Errno.SUCCESS
 

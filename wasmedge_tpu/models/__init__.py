@@ -26,6 +26,8 @@ from wasmedge_tpu.models.programs import build_memory_batch  # noqa: F401
 from wasmedge_tpu.models.programs import build_polybench_gemm  # noqa: F401
 # and of chacha20-simd-4096 (benchmark/drivers/batch_seeded_simd.py)
 from wasmedge_tpu.models.programs import build_chacha20  # noqa: F401
+# and of chacha20-wasi-4096 (benchmark/drivers/batch_wasi.py)
+from wasmedge_tpu.models.programs import build_chacha20_wasi  # noqa: F401
 
 __all__ = [
     "build_fib",

@@ -224,6 +224,7 @@ def build_polybench_gemm(ni: int = 60, nj: int = 70, nk: int = 80) -> bytes:
 CHACHA_SIGMA = 1024             # "expand 32-byte k", a data segment
 CHACHA_CTX = 65536 - 64         # uint32_t input[16]: the context
 CHACHA_MSG = 65536              # the message, pages 1..3 at 3072 blocks
+CHACHA_IOV = CHACHA_CTX - 16    # the WASI guest's iovec and nwritten
 # the seed's recurrence (`assumed` in the configuration): Numerical
 # Recipes' w' = w * 1664525 + 1013904223 mod 2**32, from w = seed, gives
 # the eight key words and the three nonce words in turn; the twelfth
@@ -234,27 +235,12 @@ CHACHA_MSG_MUL = (0x9E3779B1, 0x85EBCA6B, 0xC2B2AE35, 0x27D4EB2F)
 CHACHA_MSG_ADD = (1, 2, 3, 4)
 
 
-def build_chacha20(blocks: int = 3072) -> bytes:
-    """ChaCha20 encryption of `blocks` 64-byte blocks in place, RFC 8439
-    sections 2.1, 2.3 and 2.4 (20 rounds, 256-bit key, 96-bit nonce,
-    32-bit block counter from 1), v128 from end to end.  Export
-    `chacha20(seed: i32) -> i64`:
-
-        (a) key, nonce and message from the seed by i32 arithmetic (the
-            recurrence above), the message 16 bytes a `v128.store`;
-        (b) per block: the context's four rows into v128 locals, ten
-            double rounds (a column round on the rows, rows b, c, d
-            rotated by one, two, three lanes with `i8x16.shuffle`, the
-            same round, the rotation back), the add of the input rows,
-            four `v128.load` / `v128.xor` / `v128.store`, the counter
-            bumped by `i32x4.add` of (1, 0, 0, 0);
-        (c) acc = rotl(acc, 1) ^ i64.load(p) over the ciphertext.
-
-    The seed changes data and never control flow.  Lowered as portable
-    vector code (`uint32_t __attribute__((vector_size(16)))`) comes out
-    of clang -O2 -msimd128 as far as can be said without a toolchain:
-    rotations as shl / shr_u / or, the four stores of a block's rows
-    unrolled, bottom-tested loops on a pointer or a down-counter."""
+def _chacha20_module(blocks: int, export: str,
+                     chunk_blocks: int = 0) -> bytes:
+    """The one source of the two ChaCha20 guests: `build_chacha20`'s
+    module, or with `chunk_blocks` `build_chacha20_wasi`'s, which is the
+    same but for the block loop cut into chunks, each handed to
+    `fd_write` once it is encrypted."""
     def v(*lanes):
         return ("v128.const", b"".join(
             (x & 0xFFFFFFFF).to_bytes(4, "little") for x in lanes))
@@ -269,6 +255,7 @@ def build_chacha20(blocks: int = 3072) -> bytes:
     S = [5, 6, 7, 8]            # the input rows: constants, key, key,
     X = [9, 10, 11, 12]         # counter and nonce; the working rows
     T, M = 13, 14
+    CE = 15                     # the chunk's end (the WASI guest only)
     mul, add = CHACHA_LCG
 
     def lcg():
@@ -278,8 +265,7 @@ def build_chacha20(blocks: int = 3072) -> bytes:
     def bump(ptr, by, bound):
         """... while ((ptr += by) != bound)"""
         return [("local.get", ptr), ("i32.const", by), "i32.add",
-                ("local.tee", ptr), ("i32.const", bound), "i32.ne",
-                ("br_if", 0)]
+                ("local.tee", ptr), bound, "i32.ne", ("br_if", 0)]
 
     def step(a, b, d, n):
         """a += b; d ^= a; d <<<= n, on rows"""
@@ -325,13 +311,18 @@ def build_chacha20(blocks: int = 3072) -> bytes:
                  ("v128.store", 4, 16 * k),
                  ("local.get", M), v(*[mul] * 4), "i32x4.mul",
                  v(*[add] * 4), "i32x4.add", ("local.set", M)]
-    body += [*bump(P, 64, end), "end"]
+    body += [*bump(P, 64, ("i32.const", end)), "end"]
     # (b) encryption in place
     for k in range(4):
         body += [("i32.const", CHACHA_CTX), ("v128.load", 4, 16 * k),
                  ("local.set", S[k])]
-    body += [("i32.const", CHACHA_MSG), ("local.set", P),
-             ("loop", None)]
+    body += [("i32.const", CHACHA_MSG), ("local.set", P)]
+    if chunk_blocks:
+        # a chunk a turn of the outer loop: its end, then its blocks
+        body += [("loop", None),
+                 ("local.get", P), ("i32.const", 64 * chunk_blocks),
+                 "i32.add", ("local.set", CE)]
+    body += [("loop", None)]
     for k in range(4):
         body += [("local.get", S[k]), ("local.set", X[k])]
     body += [
@@ -355,25 +346,92 @@ def build_chacha20(blocks: int = 3072) -> bytes:
     body += [
         ("local.get", S[3]), v(1, 0, 0, 0), "i32x4.add",
         ("local.set", S[3]),
-        *bump(P, 64, end), "end",
+        *bump(P, 64, ("local.get", CE) if chunk_blocks
+              else ("i32.const", end)), "end",
+    ]
+    if chunk_blocks:
+        nbytes = 64 * chunk_blocks
+        body += [
+            # write(1, chunk, nbytes): one iovec in the frame, the call,
+            # and abort() unless all of it was taken
+            ("i32.const", CHACHA_IOV), ("local.get", P),
+            ("i32.const", nbytes), "i32.sub", ("i32.store", 2, 0),
+            ("i32.const", CHACHA_IOV), ("i32.const", nbytes),
+            ("i32.store", 2, 4),
+            ("i32.const", 1), ("i32.const", CHACHA_IOV), ("i32.const", 1),
+            ("i32.const", CHACHA_IOV + 8), ("call", 0),
+            ("if", None), "unreachable", "end",
+            ("i32.const", CHACHA_IOV), ("i32.load", 2, 8),
+            ("i32.const", nbytes), "i32.ne",
+            ("if", None), "unreachable", "end",
+            ("local.get", P), ("i32.const", end), "i32.ne", ("br_if", 0),
+            "end",
+        ]
+    body += [
         # (c) the fold of every bit of the ciphertext
         ("i32.const", CHACHA_MSG), ("local.set", P),
         ("loop", None),
         ("local.get", ACC), ("i64.const", 1), "i64.rotl",
         ("local.get", P), ("i64.load", 3, 0), "i64.xor",
         ("local.set", ACC),
-        *bump(P, 8, end), "end",
+        *bump(P, 8, ("i32.const", end)), "end",
         ("local.get", ACC),
     ]
     mb = ModuleBuilder()
+    if chunk_blocks:
+        mb.import_func("wasi_snapshot_preview1", "fd_write",
+                       ["i32"] * 4, ["i32"])
     pages = -(-end // 65536)
     mb.add_memory(pages, pages)
     mb.add_active_data(0, [("i32.const", CHACHA_SIGMA)],
                        b"expand 32-byte k")
     mb.add_function(["i32"], ["i64"],
-                    ["i32"] * 3 + ["i64"] + ["v128"] * 10, body,
-                    export="chacha20")
+                    ["i32"] * 3 + ["i64"] + ["v128"] * 10
+                    + ["i32"] * bool(chunk_blocks), body, export=export)
     return mb.build()
+
+
+def build_chacha20(blocks: int = 3072) -> bytes:
+    """ChaCha20 encryption of `blocks` 64-byte blocks in place, RFC 8439
+    sections 2.1, 2.3 and 2.4 (20 rounds, 256-bit key, 96-bit nonce,
+    32-bit block counter from 1), v128 from end to end.  Export
+    `chacha20(seed: i32) -> i64`:
+
+        (a) key, nonce and message from the seed by i32 arithmetic (the
+            recurrence above), the message 16 bytes a `v128.store`;
+        (b) per block: the context's four rows into v128 locals, ten
+            double rounds (a column round on the rows, rows b, c, d
+            rotated by one, two, three lanes with `i8x16.shuffle`, the
+            same round, the rotation back), the add of the input rows,
+            four `v128.load` / `v128.xor` / `v128.store`, the counter
+            bumped by `i32x4.add` of (1, 0, 0, 0);
+        (c) acc = rotl(acc, 1) ^ i64.load(p) over the ciphertext.
+
+    The seed changes data and never control flow.  Lowered as portable
+    vector code (`uint32_t __attribute__((vector_size(16)))`) comes out
+    of clang -O2 -msimd128 as far as can be said without a toolchain:
+    rotations as shl / shr_u / or, the four stores of a block's rows
+    unrolled, bottom-tested loops on a pointer or a down-counter."""
+    return _chacha20_module(blocks, "chacha20")
+
+
+def build_chacha20_wasi(blocks: int = 3072,
+                        chunk_blocks: int = 128) -> bytes:
+    """`build_chacha20` as a WASI command that writes its output: the
+    same key, nonce, message, block function and fold (one source), and
+    after every `chunk_blocks` blocks encrypted in place one
+    `fd_write(1, iov, 1, nwp)` with one iovec over the ciphertext just
+    produced (64 * chunk_blocks bytes: Rust's `std::io` moves
+    `DEFAULT_BUF_SIZE` = 8 KiB a `write`, and wasm32-wasi's `write` is
+    one `fd_write`), the iovec and `nwritten` in the frame under the
+    context; `unreachable` unless the errno is 0 and all of it was
+    taken.  Imports `wasi_snapshot_preview1.fd_write`, exports
+    `chacha20_write(seed: i32) -> i64`, whose answer is `chacha20`'s
+    for the same seed."""
+    if chunk_blocks <= 0 or blocks % chunk_blocks:
+        raise ValueError(f"blocks {blocks} is no multiple of "
+                         f"chunk_blocks {chunk_blocks}")
+    return _chacha20_module(blocks, "chacha20_write", chunk_blocks)
 
 
 def build_counted_loop(n: int = 64) -> bytes:

@@ -610,6 +610,27 @@ def render_prometheus(recorder=None, stats=None, hostcall_stats=None,
                    "v128.store) the Pallas kernels ran, a lane-block "
                    "step each, summed over lane blocks and launches.")
             w.sample("wasmedge_simd_ops_total", None, sdo)
+        hcc = getattr(recorder, "hostcall_counts", None)
+        if hcc and hcc["rounds"]:   # there once a run parked
+            w.head("wasmedge_hostcall_rounds_total", "counter",
+                   "Rounds of park, drain and re-arm the Pallas block "
+                   "serve made: a kernel exit, the host's drain of the "
+                   "parked blocks and a relaunch each.")
+            w.sample("wasmedge_hostcall_rounds_total", None, hcc["rounds"])
+            w.head("wasmedge_hostcall_calls_total", "counter",
+                   "Host calls those rounds drained, a lane each, by the "
+                   "path that served them: a tier-1 vectorised "
+                   "implementation, or the per-lane loop.")
+            w.sample("wasmedge_hostcall_calls_total",
+                     {"path": "vectorized"}, hcc["vectorized"])
+            w.sample("wasmedge_hostcall_calls_total",
+                     {"path": "per_lane"},
+                     hcc["calls"] - hcc["vectorized"])
+            w.head("wasmedge_hostcall_out_bytes_total", "counter",
+                   "Bytes those calls handed to an fd (fd_write, "
+                   "fd_pwrite), as the WASI environ counts them.")
+            w.sample("wasmedge_hostcall_out_bytes_total", None,
+                     hcc["out_bytes"])
         sc = getattr(recorder, "split_counts", None)
         if sc and sc["launches"]:   # stays once the scheduler ran
             for key, name, text in (

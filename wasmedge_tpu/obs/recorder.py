@@ -218,6 +218,9 @@ class NullRecorder:
     def add_simd_counts(self, ops):
         pass
 
+    def add_hostcall_counts(self, rounds, calls, vectorized, out_bytes):
+        pass
+
     def add_split_counts(self, splits=0, launches=0, rechecks=0,
                          careful_steps=0, surgery_programs=0,
                          snap_restored=0, snap_commits=0,
@@ -330,6 +333,12 @@ class FlightRecorder:
         self.softfloat_ops = 0
         # and the instructions of a v128 class they ran
         self.simd_ops = 0
+        # what the Pallas block serve drained, folded after each run
+        # that parked: park, drain and re-arm cycles, lanes drained,
+        # those a vectorised implementation served, bytes the calls
+        # handed to an fd
+        self.hostcall_counts = {"rounds": 0, "calls": 0, "vectorized": 0,
+                                "out_bytes": 0}
         # what the block scheduler did, folded after each run: blocks
         # split, launches of the optimistic kernel, rounds of the
         # careful one, the block-steps those rounds retired, and the
@@ -515,6 +524,19 @@ class FlightRecorder:
         step each (ctrl column 16, which only the rows of a kernel
         whose image has v128 hold; summed by batch/scheduler.py)."""
         self.simd_ops += int(ops)
+
+    def add_hostcall_counts(self, rounds, calls, vectorized, out_bytes):
+        """Fold what one run's hostcall serves on the Pallas path
+        counted (batch/pallas_engine.py `_serve_hostcalls_finish`, out
+        of the run's `hostcall_stats`): rounds of park, drain and
+        re-arm, lanes drained, those of them a tier-1 vectorised
+        implementation served, and the bytes the calls handed to an fd
+        (the WASI environ's own count)."""
+        hc = self.hostcall_counts
+        hc["rounds"] += int(rounds)
+        hc["calls"] += int(calls)
+        hc["vectorized"] += int(vectorized)
+        hc["out_bytes"] += int(out_bytes)
 
     def add_split_counts(self, splits=0, launches=0, rechecks=0,
                          careful_steps=0, surgery_programs=0,
