@@ -78,7 +78,7 @@ def pytest_addoption(parser):
 # `pytestmark = pytest.mark.slow` themselves (the marker is the
 # mechanism, this list is back-compat).
 _SLOW_FILES = {
-    "test_spec.py", "test_batch_parity.py", "test_batch_simd.py",
+    "test_spec.py", "test_batch_parity.py",
     "test_pallas_engine.py", "test_pallas_hbm.py", "test_optimistic.py",
     "test_mesh.py", "test_simd.py",
 }

@@ -1,11 +1,14 @@
 """v128 on the batch (SIMT) engine: lane-parallel parity vs the scalar
-oracle.
+oracle; and `i8x16.shuffle` in the Pallas kernel's fused blocks, whose
+mask is read at build time where it moves whole 32-bit lanes.
 
 BASELINE config 3's requirement ("v128 lane ops in the *batched* numeric
 path").  The op bodies are GENERATED from batch/simdops.py's supported-op
 tables, so any op added to the batch subset is automatically parity-
 checked here; each module chains every op of a family and folds the
-results into one i64 accumulator, so one compile covers the family."""
+results into one i64 accumulator, so one compile covers the family.
+Those sweeps are minutes-scale and carry `pytest.mark.slow` one by one;
+the Pallas shuffle tests at the end are seconds each and run in tier 1."""
 
 import numpy as np
 import pytest
@@ -108,6 +111,7 @@ def _chunks(names):
     return [names[i:i + _CHUNK] for i in range(0, len(names), _CHUNK)]
 
 
+@pytest.mark.slow
 @pytest.mark.parametrize("ops", _chunks(V2_NAMES),
                          ids=lambda c: c[0].replace(".", "_"))
 def test_v2_family_parity(ops):
@@ -115,6 +119,7 @@ def test_v2_family_parity(ops):
     check_parity(build_sweep(bodies), rand_args(1))
 
 
+@pytest.mark.slow
 @pytest.mark.parametrize("ops", _chunks(V1_NAMES),
                          ids=lambda c: c[0].replace(".", "_"))
 def test_v1_family_parity(ops):
@@ -122,6 +127,7 @@ def test_v1_family_parity(ops):
     check_parity(build_sweep(bodies), rand_args(2))
 
 
+@pytest.mark.slow
 def test_vtest_family_parity():
     # vtest produce i32: wrap into a splat so fold() sees a v128
     bodies = [[("local.get", 2), op, "i32x4.splat"] for op in VTEST_NAMES]
@@ -161,6 +167,7 @@ def build_float_sweep(op_bodies):
     return b.build()
 
 
+@pytest.mark.slow
 def test_float_f32_family_parity():
     """Every f32x4 op (incl. the FTZ-sensitive arithmetic) with
     normal-range inputs, bit-exact against the scalar oracle."""
@@ -177,6 +184,7 @@ def test_float_f32_family_parity():
     check_parity(build_float_sweep(bodies), [a32, b32])
 
 
+@pytest.mark.slow
 @pytest.mark.parametrize("half", [0, 1])
 def test_float_f64_family_parity(half):
     f64_v2 = [n for n in V2_NAMES if n.startswith("f64x2.")]
@@ -195,6 +203,7 @@ def test_float_f64_family_parity(half):
     check_parity(build_float_sweep(bodies), [a64, b64])
 
 
+@pytest.mark.slow
 def test_shift_and_splat_family_parity():
     bodies = []
     for i, op in enumerate(VSHIFT_NAMES):
@@ -214,6 +223,7 @@ def test_shift_and_splat_family_parity():
     check_parity(build_sweep(bodies), rand_args(3))
 
 
+@pytest.mark.slow
 def test_lane_ops_shuffle_swizzle_bitselect_parity():
     k1 = int.from_bytes(bytes(range(16)), "little")
     shuf = [0, 17, 2, 19, 4, 21, 6, 23, 8, 25, 10, 27, 12, 29, 14, 31]
@@ -245,6 +255,7 @@ def test_lane_ops_shuffle_swizzle_bitselect_parity():
     check_parity(build_sweep(bodies), rand_args(4))
 
 
+@pytest.mark.slow
 def test_v128_memory_roundtrip_parity():
     b = ModuleBuilder()
     b.add_memory(1, 1)
@@ -270,6 +281,7 @@ def test_v128_memory_roundtrip_parity():
     check_parity(b.build(), rand_args(5))
 
 
+@pytest.mark.slow
 def test_v128_oob_load_traps():
     b = ModuleBuilder()
     b.add_memory(1, 1)
@@ -294,6 +306,7 @@ def test_v128_oob_load_traps():
     assert (res.trap[~oob] == -1).all()
 
 
+@pytest.mark.slow
 def test_simd_module_falls_off_pallas_to_simt():
     from wasmedge_tpu.batch.uniform import UniformBatchEngine
 
@@ -311,3 +324,123 @@ def test_simd_module_falls_off_pallas_to_simt():
     assert (res.trap == -1).all()
     assert (np.asarray(res.results[0]) ==
             np.asarray([int(np.int32(x)) for x in xs])).all()
+
+
+# -- i8x16.shuffle in the Pallas kernel's fused blocks ----------------------
+
+def _lanes_left(n):
+    """programs.py's mask: 32-bit lane i + n of one operand to lane i."""
+    return [4 * ((i + n) % 4) + k for i in range(4) for k in range(4)]
+
+
+def _words(*sel):
+    return [4 * s + k for s in sel for k in range(4)]
+
+
+# mask -> the selectors fuse_blocks must read from it (None: the mask
+# is byte-granular and stays on vshuffle_dyn)
+_SHUFFLE_MASKS = {
+    "identity": (list(range(16)), (0, 1, 2, 3)),
+    "lanes-left-1": (_lanes_left(1), (1, 2, 3, 0)),
+    "lanes-left-2": (_lanes_left(2), (2, 3, 0, 1)),
+    "lanes-left-3": (_lanes_left(3), (3, 0, 1, 2)),
+    "word-interleave": (_words(0, 4, 1, 5), (0, 4, 1, 5)),
+    "word-broadcast": (_words(5, 5, 5, 5), (5, 5, 5, 5)),
+    # the interleave of test_lane_ops_shuffle_swizzle_bitselect_parity
+    "byte-interleave": ([0, 17, 2, 19, 4, 21, 6, 23,
+                         8, 25, 10, 27, 12, 29, 14, 31], None),
+    # a rotate by 16 bits within each word, as a pshufb build has it
+    "rotate16-in-words": ([2, 3, 0, 1, 6, 7, 4, 5,
+                           10, 11, 8, 9, 14, 15, 12, 13], None),
+}
+
+
+def _shuffle_ops(shapes):
+    return [op for shape in shapes for op in shape
+            if op[0] in ("vshuffle", "vshufflew")]
+
+
+@pytest.mark.parametrize("optimistic", [True, False],
+                         ids=["optimistic", "careful"])
+@pytest.mark.parametrize("name", sorted(_SHUFFLE_MASKS))
+def test_pallas_shuffle_parity_and_lowering(name, optimistic):
+    """Both Pallas kernels against the scalar engine, bit for bit, with
+    the lowering fuse_blocks chose for the mask and the static count."""
+    from wasmedge_tpu.batch.pallas_engine import fuse_blocks, hid_plane
+    from wasmedge_tpu.batch.uniform import UniformBatchEngine
+
+    mask, words = _SHUFFLE_MASKS[name]
+    data = build_sweep([[("local.get", 2), ("local.get", 5),
+                         ("i8x16.shuffle", mask)]])
+    conf = Configure()
+    conf.batch.interpret = True
+    conf.batch.optimistic = optimistic
+    conf.batch.steps_per_launch = 50_000
+    conf.batch.value_stack_depth = 32
+    conf.batch.call_stack_depth = 8
+    _ex, store, inst = instantiate(data, conf)
+    eng = UniformBatchEngine(inst, store=store, conf=conf, lanes=LANES)
+    args = rand_args(6)
+    res = eng.run("f", args, max_steps=100_000)
+    assert not eng.fell_back_to_simt and eng.pallas.optimistic is optimistic
+    assert np.all(np.asarray(res.trap) == -1)
+    s_ex, s_store, s_inst = instantiate(data, Configure())
+    for lane in range(LANES):
+        (want,) = s_ex.invoke(s_store, s_inst.find_func("f"),
+                              [int(a[lane]) for a in args])
+        assert int(res.results[0][lane]) & (2**64 - 1) == \
+            int(want) & (2**64 - 1), lane
+    img = eng.pallas.img
+    _hid, shapes = fuse_blocks(hid_plane(img), img)
+    if words is None:
+        assert _shuffle_ops(shapes) == [("vshuffle",)]
+        assert eng.pallas.shuffle_sites == {"word": 0, "dynamic": 1}
+    else:
+        assert _shuffle_ops(shapes) == [("vshufflew", words)]
+        assert eng.pallas.shuffle_sites == {"word": 1, "dynamic": 0}
+
+
+def test_blocks_equal_but_for_a_word_mask_get_a_shape_each():
+    """The selectors are part of a block's shape as an ALU sub is: two
+    blocks that differ in a word-granular mask alone are two shapes,
+    with the same mask one, and fuse_blocks is a function of the image."""
+    from wasmedge_tpu.batch.pallas_engine import (
+        H_BLOCK_BASE, fuse_blocks, hid_plane, shuffle_sites)
+    from wasmedge_tpu.batch.uniform import UniformBatchEngine
+
+    def module(mask_f, mask_g):
+        b = ModuleBuilder()
+        for export, mask in (("f", mask_f), ("g", mask_g)):
+            b.add_function(["i64", "i64"], ["i64"], [], [
+                ("local.get", 0), "i64x2.splat",
+                ("local.get", 1), "i64x2.splat",
+                ("i8x16.shuffle", mask), ("i64x2.extract_lane", 1),
+            ], export=export)
+        return b.build()
+
+    def fused(data):
+        conf = Configure()
+        conf.batch.interpret = True    # a Pallas engine on the CPU; none runs
+        _ex, store, inst = instantiate(data, conf)
+        img = UniformBatchEngine(inst, store=store, conf=conf,
+                                 lanes=LANES).pallas.img
+        hid, shapes = fuse_blocks(hid_plane(img), img)
+        again, shapes_again = fuse_blocks(hid_plane(img), img)
+        assert np.array_equal(hid, again) and shapes == shapes_again
+        heads = [int(hid[int(pc)]) for pc in img.f_entry]
+        assert all(h >= H_BLOCK_BASE for h in heads)
+        return heads, shapes, shuffle_sites(hid, shapes, img)
+
+    (f, g), shapes, sites = fused(module(_lanes_left(1), _lanes_left(3)))
+    assert f != g and sites == {"word": 2, "dynamic": 0}
+    assert _shuffle_ops(shapes) == [("vshufflew", (1, 2, 3, 0)),
+                                    ("vshufflew", (3, 0, 1, 2))]
+    (f, g), shapes, sites = fused(module(_lanes_left(1), _lanes_left(1)))
+    assert f == g and len(_shuffle_ops(shapes)) == 1
+    assert sites == {"word": 2, "dynamic": 0}
+    # byte-granular masks are data: one shape whatever they hold
+    (f, g), shapes, sites = fused(module(
+        _SHUFFLE_MASKS["byte-interleave"][0],
+        _SHUFFLE_MASKS["rotate16-in-words"][0]))
+    assert f == g and _shuffle_ops(shapes) == [("vshuffle",)]
+    assert sites == {"word": 0, "dynamic": 2}

@@ -155,6 +155,8 @@ def test_pallas_kernel_matches_the_reference(ref, mem_hbm):
         pytest.approx(simd_ops(blocks) / retired(blocks))
     assert pallas.softfloat_ops is None
     assert pallas.dispatches == dispatches(blocks)
+    # the six shuffles move whole 32-bit lanes: row moves in their blocks
+    assert pallas.shuffle_sites == {"word": 6, "dynamic": 0}
     assert pallas.mem_static["mem_mode"] == \
         ("hbm_window" if mem_hbm else "resident")
     # the count reaches /metrics and its share the run's span
@@ -167,6 +169,9 @@ def test_pallas_kernel_matches_the_reference(ref, mem_hbm):
                if e["name"] == "batch/run"]
     assert span["simd_share"] == round(pallas.simd_share, 6)
     assert "softfloat_share" not in span
+    assert span["shuffle_sites"] == "6/0"
+    assert {dict(labels)["kind"]: v for (name, labels), v in parsed.items()
+            if name == "wasmedge_shuffle_sites"} == {"word": 6, "dynamic": 0}
     if mem_hbm:
         # every load and store of the guest went through the window: 20
         # a block (4 stores of message, 4 loads and 4 stores encrypting,
@@ -198,6 +203,7 @@ def test_a_guest_without_v128_counts_none_and_keeps_sixteen_columns():
         assert eng.pallas.img.has_simd is expect
         assert eng.pallas.ctrl_width == (17 if expect else 16)
         assert eng.pallas.simd_ops is None
+        assert eng.pallas.shuffle_sites is None     # before a build
 
 
 def test_the_folded_answer_moves_with_every_fault(ref):

@@ -214,6 +214,8 @@ def test_pallas_job_matches_the_reference(ref, blocks, chunk, seed,
     nblk = 2 if (blocks, chunk) == (4, 4) else 1
     assert (LANES, LANES // nblk) in eng.simt._sched_cache
     assert pallas.mem_static["mem_mode"] == "hbm_window"
+    # the guest's six shuffles, as in `build_chacha20`: all row moves
+    assert pallas.shuffle_sites == {"word": 6, "dynamic": 0}
     # the host link's counts are the leaf spans of this run, the serve's
     # among them; its phases lie under spans of their own
     mine = list(eng.obs.events)[events0:]
@@ -243,9 +245,12 @@ def test_pallas_job_matches_the_reference(ref, blocks, chunk, seed,
         [(LANES, LANES * 64 * chunk)] * calls
     (run,) = [e["args"] for e in mine if e["name"] == "batch/run"]
     assert run["hostcall_rounds"] == calls
+    assert run["shuffle_sites"] == "6/0"
     from wasmedge_tpu.obs import parse_prometheus, render_prometheus
 
     parsed = parse_prometheus(render_prometheus(recorder=eng.obs))
+    assert {dict(labels)["kind"]: v for (name, labels), v in parsed.items()
+            if name == "wasmedge_shuffle_sites"} == {"word": 6, "dynamic": 0}
     got = {(name, tuple(sorted(labels))): v
            for (name, labels), v in parsed.items()
            if name.startswith("wasmedge_hostcall_") and "drain" not in name}

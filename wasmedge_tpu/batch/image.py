@@ -63,7 +63,12 @@ CLS_VSHIFT = 30    # sub = VSHIFT_SUB id: pop (v128, i32) push v128
 CLS_VSPLAT = 31    # sub = VSPLAT_SUB id: pop scalar push v128
 CLS_VEXTRACT = 32  # sub = VEXTRACT_SUB id, a = lane: pop v128 push scalar
 CLS_VREPLACE = 33  # sub = VREPLACE_SUB id, a = lane: pop2 push v128
-CLS_VSHUFFLE = 34  # a = v128 table idx (16-byte mask): pop2 push1
+CLS_VSHUFFLE = 34  # a = v128 table idx (16-byte mask): pop2 push1.  The
+#   mask is read at RUN time (simdops.vshuffle_dyn: the SIMT engine, the
+#   Pallas kernel's own handler, a fused block with a byte-granular
+#   mask) but where it moves whole 32-bit lanes and a fused block of the
+#   Pallas kernel absorbs the op: pallas_engine.fuse_blocks reads it at
+#   BUILD time and the block re-orders rows
 CLS_VBITSEL = 35   # pop3 push1
 CLS_VLOAD = 36     # a = offset: pop addr push v128
 CLS_VSTORE = 37    # a = offset: pop (addr, v128)

@@ -379,6 +379,18 @@ def _path_nesting(ops, call_nest, depth=0):
     return deepest
 
 
+def word_selectors(mask):
+    """(s0, s1, s2, s3) if the `i8x16.shuffle` mask `mask` (four int32
+    words, sixteen selector bytes) moves whole 32-bit lanes: output word
+    k is source word s_k of the eight of both operands; else None."""
+    sel = np.asarray(mask, np.int32).view(np.uint8).reshape(4, 4)
+    words = sel[:, 0].astype(np.int32) // 4
+    if np.any(words >= 8) or \
+            np.any(sel != 4 * words[:, None] + np.arange(4)):
+        return None
+    return tuple(int(w) for w in words)
+
+
 def fuse_blocks(hid, img):
     """Rewrite block-head hids to H_BLOCK_BASE + shape id.
 
@@ -391,6 +403,7 @@ def fuse_blocks(hid, img):
       ("alu2", sub) ("alu1", sub)            using different locals in
       ("loadi", nbytes, flags)               the same pattern share)
       ("storei", nbytes)
+      ("vshuffle",) ("vshufflew", (s0, s1, s2, s3))
       ("guardz", tail) ("guardnz", tail)     tail = a shape of its own
       ("jump", nkeep)
       ("term", flat_hid)
@@ -424,10 +437,18 @@ def fuse_blocks(hid, img):
 
     Immediates/indices are NOT in the shape (handlers read them from
     the SMEM planes at the op's own original slot), except local/global
-    ordinals, whose equality structure decides value forwarding, and
-    alu subs, which pick the compute fn.  Deterministic: tpu.aot
-    artifacts verify the persisted hid plane by regeneration
-    (aot/__init__.py)."""
+    ordinals, whose equality structure decides value forwarding, alu
+    subs, which pick the compute fn, and the mask of an `i8x16.shuffle`
+    that moves whole 32-bit lanes.  That mask is read HERE, at build
+    time, from the image's v128 table (img.v128[img.a[pc]], so a
+    multitenant image's rebased index is followed): where each output
+    word k takes the four bytes of one source word s_k (0..3 the first
+    operand's, 4..7 the second's) the op is ("vshufflew", (s0..s3)) and
+    the block re-orders the operands' rows at trace time; any other mask
+    stays ("vshuffle",), fetched at RUN time from the table by the block
+    as by the unfused handler (simdops.vshuffle_dyn).  Deterministic, a
+    function of the image alone: tpu.aot artifacts verify the persisted
+    hid plane by regeneration (aot/__init__.py)."""
     n = img.code_len
     targets = _jump_targets(img)
     # call-return / hostcall-re-arm / trap-partial-resume addresses need
@@ -485,7 +506,8 @@ def fuse_blocks(hid, img):
         if cl == CLS_VCONST:
             return ("vconst",)
         if cl == CLS_VSHUFFLE:
-            return ("vshuffle",)
+            words = word_selectors(img.v128[int(img.a[pc])])
+            return ("vshuffle",) if words is None else ("vshufflew", words)
         if cl == CLS_VBITSEL:
             return ("vbitsel",)
         if cl == CLS_LOAD:
@@ -654,6 +676,26 @@ def superblock_edges(hid, shapes, img) -> dict:
             elif op[0] in ("guardz", "guardnz") and op[1]:
                 edges["guard_tail"] += 1
     return edges
+
+
+def shuffle_sites(hid, shapes, img) -> Optional[dict]:
+    """The image's `i8x16.shuffle` slots by the lowering they run under:
+    "word" the slots a fused block runs as row moves (a slot once,
+    however many tails duplicate it), "dynamic" the rest, which fetch
+    their mask at run time (a byte-granular mask, or a slot that no
+    block absorbed and its own handler runs).  None for an image
+    without one."""
+    total = int(np.count_nonzero(
+        np.asarray(img.cls[:img.code_len]) == CLS_VSHUFFLE))
+    if not total:
+        return None
+    word = set()
+    for head in np.flatnonzero(hid >= H_BLOCK_BASE):
+        for op, slot, _in_tail in walk_shape(
+                shapes[int(hid[head]) - H_BLOCK_BASE], int(head), img):
+            if op[0] == "vshufflew":
+                word.add(slot)
+    return {"word": len(word), "dynamic": total - len(word)}
 
 
 def entry_slots(hid, shapes, img) -> np.ndarray:
@@ -832,7 +874,7 @@ _DIVS_SUBS = {ALU2_I32_BASE + _I32_BIN.index("div_s"),
 # of a block ends (mk_block's emit counts the former a run at a time)
 _SIMD_BLOCK_OPS = frozenset(("v2", "v1", "vtest", "vshift", "vsplat",
                              "vextract", "vreplace", "vconst", "vshuffle",
-                             "vbitsel"))
+                             "vshufflew", "vbitsel"))
 _RUN_ENDS = frozenset(("guardz", "guardnz", "loadi", "storei", "jump"))
 # ALU subs that run a binary64 routine of batch/softfloat.py (a
 # reinterpret moves bits and runs none)
@@ -3155,6 +3197,14 @@ def _build_kernel(used_hids: tuple, D: int, CD: int, W: int, L: int,
                         vs = vs.push(sops.vshuffle_dyn()(
                             x, y, _vconst4(a_r[pcj])))
                         return emit(j.next(), cb, vs, pend_l, pend_g)
+                    if kind == "vshufflew":
+                        # a mask that moves whole 32-bit lanes: the
+                        # eight rows of x and y, re-ordered
+                        y, vs = vs.pop()
+                        x, vs = vs.pop()
+                        rows = tuple(x) + tuple(y)
+                        vs = vs.push(tuple(rows[s] for s in op[1]))
+                        return emit(j.next(), cb, vs, pend_l, pend_g)
                     if kind == "vbitsel":
                         y, vs = vs.pop()
                         x, vs = vs.pop()
@@ -4370,6 +4420,9 @@ class PallasUniformEngine:
         self.simd_share = None
         # forward edges the newest kernel's blocks run through, by kind
         self.superblock_edges = None
+        # the image's i8x16.shuffle slots by lowering ({"word",
+        # "dynamic"}); None without one
+        self.shuffle_sites = None
         # None = no tpu.aot fused section attached; set by _build when a
         # loaded artifact carries one (True = matched regeneration)
         self.aot_fused_verified = None
@@ -4532,6 +4585,9 @@ class PallasUniformEngine:
         self.obs.set_dispatch_static(*self.dispatch_depth)
         self.superblock_edges = superblock_edges(hid, block_shapes, img)
         self.obs.set_superblock_static(self.superblock_edges)
+        self.shuffle_sites = shuffle_sites(hid, block_shapes, img)
+        if self.shuffle_sites:
+            self.obs.set_shuffle_static(self.shuffle_sites)
         # host-side view of the fused encoding: the block scheduler's
         # divergence splitter evaluates the stopped instruction from
         # these.  _np_hid_orig is the UNfused plane: a block whose
@@ -5069,6 +5125,7 @@ class PallasUniformEngine:
                                    sched.window_writebacks,
                                    sched.window_accesses)
         self.superblock_edges = sched.eng.superblock_edges
+        self.shuffle_sites = sched.eng.shuffle_sites
         self.dispatches = sched.dispatches
         self.instr_per_dispatch = sched.kernel_steps / sched.dispatches \
             if sched.dispatches else None

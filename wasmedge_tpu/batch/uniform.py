@@ -1011,14 +1011,19 @@ class UniformBatchEngine:
 
     def _kernel_args(self):
         """What the Pallas kernel was built with, as span arguments:
-        its dispatch tree and how it holds linear memory (`mem_mode`,
-        and with a memory `lane_block` and the window's rows x ways);
-        nothing before a kernel exists."""
+        its dispatch tree, how it holds linear memory (`mem_mode`, and
+        with a memory `lane_block` and the window's rows x ways) and,
+        where the image holds an `i8x16.shuffle`, its slots by lowering
+        ("word/dynamic"); nothing before a kernel exists."""
         depth = getattr(self.pallas, "dispatch_depth", None)
         if depth is None:
             return {}
-        return {"dispatch_depth": f"{depth[0]:.2f}/{depth[1]}",
+        args = {"dispatch_depth": f"{depth[0]:.2f}/{depth[1]}",
                 **self.pallas.mem_static}
+        sites = self.pallas.shuffle_sites
+        if sites:
+            args["shuffle_sites"] = f"{sites['word']}/{sites['dynamic']}"
+        return args
 
     def _run(self, func_name, args_lanes, max_steps):
         import numpy as np

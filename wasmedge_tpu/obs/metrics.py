@@ -588,6 +588,18 @@ def render_prometheus(recorder=None, stats=None, hostcall_stats=None,
             for kind in ("jump", "guard_tail"):
                 w.sample("wasmedge_superblock_edges", {"kind": kind},
                          int(sbs.get(kind, 0)))
+        shs = getattr(recorder, "shuffle_static", None)
+        if shs is not None:
+            w.head("wasmedge_shuffle_sites", "gauge",
+                   "The `i8x16.shuffle` slots of the newest Pallas "
+                   "kernel's image by lowering: `word` where the mask "
+                   "moves whole 32-bit lanes and a fused block "
+                   "re-orders rows at build time, `dynamic` where the "
+                   "mask is fetched at run time "
+                   "(batch/pallas_engine.py shuffle_sites).")
+            for kind in ("word", "dynamic"):
+                w.sample("wasmedge_shuffle_sites", {"kind": kind},
+                         int(shs.get(kind, 0)))
         pdc = getattr(recorder, "pallas_dispatches", 0)
         if pdc:
             w.head("wasmedge_pallas_dispatches_total", "counter",

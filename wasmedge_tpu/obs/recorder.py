@@ -209,6 +209,9 @@ class NullRecorder:
     def set_superblock_static(self, edges):
         pass
 
+    def set_shuffle_static(self, sites):
+        pass
+
     def add_dispatch_counts(self, dispatches):
         pass
 
@@ -329,6 +332,9 @@ class FlightRecorder:
         # ({"jump", "guard_tail"}), and the handlers its loop dispatched
         self.superblock_static = None
         self.pallas_dispatches = 0
+        # its image's i8x16.shuffle slots by lowering ({"word",
+        # "dynamic"}), where the image holds one
+        self.shuffle_static = None
         # the binary64 routines those kernels ran (softfloat.py)
         self.softfloat_ops = 0
         # and the instructions of a v128 class they ran
@@ -504,6 +510,13 @@ class FlightRecorder:
         run through instead of ending at (batch/pallas_engine.py
         fuse_blocks): absorbed `br`s and guards with a tail."""
         self.superblock_static = dict(edges)
+
+    def set_shuffle_static(self, sites):
+        """Record the `i8x16.shuffle` slots of the newest Pallas
+        kernel's image by lowering (batch/pallas_engine.py
+        shuffle_sites): "word" run as row moves inside a fused block,
+        "dynamic" fetch their mask at run time."""
+        self.shuffle_static = dict(sites)
 
     def add_dispatch_counts(self, dispatches):
         """Fold the handlers the Pallas kernels dispatched in one run
