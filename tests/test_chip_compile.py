@@ -550,11 +550,11 @@ def test_exported_kernel_compiles_for_v5e(case, one_chip):
     import jax
     import jax.export as jexport
 
+    from wasmedge_tpu.batch import jit_in_place
     from wasmedge_tpu.batch.pallas_engine import (
         _DONATED_PLANES,
         _PLANE_ARG0,
         donated_planes,
-        jit_in_place,
     )
 
     wasm, depth, cdepth, _expect = _DEPTHS[case]

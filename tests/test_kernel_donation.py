@@ -15,6 +15,7 @@ import types
 import numpy as np
 import pytest
 
+from wasmedge_tpu.batch import jit_in_place
 from wasmedge_tpu.batch import pallas_engine as pe
 
 # the memory plane (the fifth) large, the others small
@@ -92,7 +93,7 @@ def test_exported_launch_donates_planes(cache, tmp_path, persistent):
 
     cache(str(tmp_path) if persistent else None)
     exp = _exported()
-    launch = pe.jit_in_place(exp.call, *pe._DONATED_PLANES)
+    launch = jit_in_place(exp.call, *pe._DONATED_PLANES)
     n = pe.donated_planes(launch, _specs())
     text = launch.lower(*_specs()).compile().as_text()
     want = jax.jit(exp.call)(*_args())

@@ -270,8 +270,8 @@ def cascade(request):
     try:
         for _ in range(2):
             res = eng.run(func, [ns], max_steps=2_000_000)
-            (inner,) = eng.simt._sched_cache.values()
-            variants.append(tuple(f._cache_size() for f in inner._surgery))
+            (surgery,) = eng.simt._surgery_cache.values()
+            variants.append(tuple(f._cache_size() for f in surgery))
     finally:
         BlockScheduler._extract_cols = extract
     assert not eng.fell_back_to_simt and (res.trap == -1).all()

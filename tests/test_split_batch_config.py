@@ -395,35 +395,35 @@ def test_a_block_that_rolls_back_keeps_its_own_halving(monkeypatch):
 
 def test_careful_recheck_crosses_a_link_from_the_engines_own_drive_too(
         monkeypatch):
-    """`careful_recheck` has two callers; from `engine._drive`
-    (`PallasUniformEngine._run_recheck`, the multi-tenant path) its two
-    uploads, its two enqueues (the careful kernel, then the pack of its
-    pass record behind it) and its one download lie under the same three
-    leaf spans, opened on the engine's own recorder."""
+    """`careful_recheck`'s one caller is the scheduler's drive
+    (`BlockScheduler._run_recheck`): its two uploads, its two enqueues
+    (the careful kernel, then the pack of its pass record behind it) and
+    its one download cross the scheduler's link in that order, and
+    nothing else does: the intervals `_SnapPolicy` gives the round ride
+    the ctrl it uploads."""
     import contextlib
 
     sched = _two_blocks(monkeypatch)
     sched.launch()
     assert sched.process()
-    inner = sched.eng
     opened = []
 
-    class Spy:
-        def timed(self, name, cat="", track=None, **args):
-            opened.append((name, args.get("what") or args.get("program")))
-            return contextlib.nullcontext(self)
-
+    class Span:
         def set(self, **args):
             pass
 
-    monkeypatch.setattr(inner, "obs", Spy())
-    ctrl_np = sched._ctrl().copy()
-    ctrl_np[0, _C_STATUS] = ST_RECHECK
-    _state, ctrl = inner._run_recheck(list(sched.state), ctrl_np)
+    def timed(name, **args):
+        opened.append((name, args.get("what") or args.get("program")))
+        return contextlib.nullcontext(Span())
+
+    monkeypatch.setattr(sched.link, "_timed", timed)
+    sched._ctrl()[0, _C_STATUS] = ST_RECHECK
+    ctrl = sched._run_recheck(np.ones(2, bool))
     assert opened == [("batch/h2d", "ctrl"), ("batch/enqueue", "careful"),
                       ("batch/enqueue", "pack"), ("batch/d2h", "pass"),
                       ("batch/h2d", "ctrl")]
     assert ctrl[0, _C_STATUS] != ST_RECHECK
+    assert ctrl[:, _C_SNAP].tolist() == [512, 1024]
 
 
 def test_commits_of_one_launch_follow_the_formula(monkeypatch):

@@ -58,6 +58,23 @@ def ensure_jax_backend():
     jax.devices()
 
 
+def jit_in_place(fn, *planes, **jit_kw):
+    """jax.jit(fn, **jit_kw) with the arguments `planes` donated, so
+    that a program which sets a few rows or columns of a plane (or
+    carries the whole state) writes it in place and the caller rebinds
+    the result.  Not on the CPU with a persistent compile cache (the
+    directory `ensure_jax_backend` sets): an executable deserialized
+    from it there can lose its input/output aliasing and serve garbage
+    outputs (jax 0.4.x), and on the CPU donation saves only allocator
+    churn."""
+    import jax
+
+    if jax.default_backend() == "cpu" and \
+            getattr(jax.config, "jax_compilation_cache_dir", None):
+        planes = ()
+    return jax.jit(fn, donate_argnums=planes, **jit_kw)
+
+
 def make_engine(inst, store=None, conf=None, lanes=None, mesh=None):
     """Engine-selection seam: uniform fast path (with SIMT fallback) when
     Configure.batch.uniform is set, plain SIMT otherwise."""

@@ -273,8 +273,9 @@ def make_permute(lane_names):
     jit-purity lint target (tools/lint_jit_purity.py): everything
     nested here runs under trace.
     """
-    import jax
     import jax.numpy as jnp
+
+    from wasmedge_tpu.batch import jit_in_place
 
     names = tuple(lane_names)
 
@@ -285,11 +286,7 @@ def make_permute(lane_names):
             updates[name] = jnp.take(plane, perm, axis=-1)
         return state._replace(**updates)
 
-    donate = (0,)
-    if jax.default_backend() == "cpu" and \
-            getattr(jax.config, "jax_compilation_cache_dir", None):
-        donate = ()
-    return jax.jit(permute, donate_argnums=donate)
+    return jit_in_place(permute, 0)
 
 
 class LaneCompactor:

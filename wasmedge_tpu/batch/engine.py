@@ -2182,12 +2182,11 @@ class BatchEngine:
 
     # -- execution ---------------------------------------------------------
     def _build(self):
-        from wasmedge_tpu.batch import ensure_jax_backend
+        from wasmedge_tpu.batch import ensure_jax_backend, jit_in_place
 
         self._plan_fusion()
         self._plan_tierup()
         ensure_jax_backend()
-        import jax
         import jax.numpy as jnp
         from jax import lax
 
@@ -2211,16 +2210,7 @@ class BatchEngine:
             i, state = lax.while_loop(cond, body, (jnp.int32(0), state))
             return i, state
 
-        # jax 0.4.x CPU: an executable deserialized from the persistent
-        # compilation cache can lose input/output aliasing for donated
-        # carries and serve garbage outputs (observed with the r06
-        # tier-0 planes in the carry).  Donation only saves allocator
-        # churn on CPU; keep it for accelerator backends where it keeps
-        # the big planes in place.
-        donate = (0,)
-        if jax.default_backend() == "cpu" and \
-                getattr(jax.config, "jax_compilation_cache_dir", None):
-            donate = ()
+        # the carry donated (`jit_in_place`): the big planes stay in place
         if self.mesh is not None:
             # single-program mesh drive: ONE jitted program over the
             # named mesh, lane planes sharded on the `lanes` axis — the
@@ -2230,9 +2220,9 @@ class BatchEngine:
 
             probe = self.initial_state(0, [])
             self._run_chunk = _build_shard_chunk(run_chunk, self.mesh,
-                                                 probe, donate)
+                                                 probe)
         else:
-            self._run_chunk = jax.jit(run_chunk, donate_argnums=donate)
+            self._run_chunk = jit_in_place(run_chunk, 0)
         self._step = step
 
     def _build_narrow_chunk(self, width: int):
@@ -2249,10 +2239,9 @@ class BatchEngine:
         jit-purity lint target (tools/lint_jit_purity.py): everything
         nested here runs under trace.
         """
-        from wasmedge_tpu.batch import ensure_jax_backend
+        from wasmedge_tpu.batch import ensure_jax_backend, jit_in_place
 
         ensure_jax_backend()
-        import jax
         import jax.numpy as jnp
         from jax import lax
 
@@ -2296,11 +2285,7 @@ class BatchEngine:
                     updates[name] = getattr(ns, name)
             return i, state._replace(**updates)
 
-        donate = (0,)
-        if jax.default_backend() == "cpu" and \
-                getattr(jax.config, "jax_compilation_cache_dir", None):
-            donate = ()
-        return jax.jit(run_chunk_narrow, donate_argnums=donate)
+        return jit_in_place(run_chunk_narrow, 0)
 
     def initial_state(self, func_idx: int, args_lanes: List[np.ndarray]):
         import jax.numpy as jnp

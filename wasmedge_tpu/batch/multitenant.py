@@ -574,27 +574,6 @@ class MultiTenantBatchEngine(BatchEngine):
             out["ddrop"] = jnp.asarray(dd)
         return out
 
-    def _try_pallas(self):
-        """Pallas fast path when every tenant\'s lane count aligns to the
-        kernel\'s lane blocks (tenant blocks are block-uniform control,
-        which is exactly the kernel\'s convergence model)."""
-        from wasmedge_tpu.batch.pallas_engine import (
-            PallasUniformEngine, pallas_enabled)
-
-        if not pallas_enabled(self.cfg):
-            return None
-        eng = PallasUniformEngine(self.tenants[0].inst, conf=self.conf,
-                                  simt=self,
-                                  interpret=self.cfg.interpret or None)
-        eng._blk_cap = min(t.lanes for t in self.tenants)
-        eng.ineligible_reason = eng._eligibility()
-        if not eng.eligible:
-            return None
-        Lblk = eng._lane_block()
-        if Lblk is None or any(t.lanes % Lblk for t in self.tenants):
-            return None
-        return eng
-
     def _try_schedulers(self, max_steps):
         """Per-tenant Pallas engines driven by interleaved block
         schedulers.  Tenants are share-nothing, so each gets its OWN
@@ -604,7 +583,8 @@ class MultiTenantBatchEngine(BatchEngine):
         tenant's host side processes results, the others' kernels run —
         the (module, PC)-bucket scheduling SURVEY §7 step 8 prescribes.
         Returns {tenant_index: BlockScheduler} for the eligible tenants,
-        or None when the Pallas path is off."""
+        or None when the Pallas path is off or no tenant is eligible:
+        the whole batch then runs on this SIMT engine."""
         from wasmedge_tpu.batch.pallas_engine import (
             PallasUniformEngine, pallas_enabled)
         from wasmedge_tpu.batch.scheduler import BlockScheduler
@@ -659,18 +639,9 @@ class MultiTenantBatchEngine(BatchEngine):
         from wasmedge_tpu.batch.compact import arm
 
         arm(self)   # fresh per-run lane-compaction mapping (off = None)
-        state = self.initial_state()
-        total = 0
-        pallas = self._try_pallas()
-        self.used_pallas = pallas is not None
-        if pallas is not None:
-            state, steps_per_block, fell_back = pallas.run_blocks(
-                state, max_steps)
-            total = int(steps_per_block.max())
-            if fell_back or (np.asarray(state.trap) == 0).any():
-                state, total = self.run_from_state(state, total, max_steps)
-        else:
-            state, total = self.run_from_state(state, 0, max_steps)
+        self.used_pallas = False
+        state, total = self.run_from_state(self.initial_state(), 0,
+                                           max_steps)
         return self.results_from_state(state, total)
 
     def results_from_state(self, state: BatchState, total: int

@@ -105,7 +105,6 @@ from wasmedge_tpu.batch.image import (
     CLS_VSTORE,
     CLS_VTEST,
     DeviceImage,
-    TRAP_DONE,
     _I32_BIN,
 )
 
@@ -220,8 +219,9 @@ _C_STEPS = 8
 _C_FUEL = 9
 # per-block optimistic snapshot interval (adaptive: the host halves it
 # when a block rolls back — bounding the run-up a divergent block
-# discards — and doubles it back toward SNAP_STEPS on clean launches).
-# 0 means "use the kernel's build-time snap_steps".
+# discards — and doubles it back toward SNAP_STEPS on clean launches;
+# batch/scheduler.py `_SnapPolicy`).  0 means "use the kernel's
+# build-time snap_steps".
 _C_SNAP = 10
 # written by the mem_hbm kernel at exit, per launch (never read by it):
 # window fills and dirty-window write-backs, each one CW-row DMA
@@ -251,26 +251,6 @@ def ctrl_width(simd: bool) -> int:
     """Columns of a ctrl row: what the kernel's ctrl output, the pass
     record and the hosts' rows are all sized by."""
     return _CTRL_W + 1 if simd else _CTRL_W
-
-
-_SNAP_MIN = 256
-
-
-def merge_block_status_into_trap(trap_v: np.ndarray, ctrl: np.ndarray,
-                                 Lblk: int) -> np.ndarray:
-    """Fold per-block exit status into the per-lane trap plane:
-    DONE blocks -> TRAP_DONE sentinel, trapped blocks -> their code on
-    lanes that have no more specific per-lane code yet."""
-    for b in range(ctrl.shape[0]):
-        status = int(ctrl[b, _C_STATUS])
-        sl = slice(b * Lblk, (b + 1) * Lblk)
-        if status == ST_DONE:
-            trap_v[sl] = TRAP_DONE
-        elif status >= ST_TRAPPED_BASE:
-            seg = trap_v[sl]
-            seg[seg == 0] = status - ST_TRAPPED_BASE
-            trap_v[sl] = seg
-    return trap_v
 
 
 def decode_result_rows(stack_lo: np.ndarray, stack_hi: np.ndarray,
@@ -4278,20 +4258,6 @@ def _split_pass_record(flat: np.ndarray, nblk: int, cd: int, lanes: int,
                       res_hi.reshape(nres, lanes))
 
 
-def jit_in_place(fn, *planes):
-    """jit(fn) with the arguments `planes` donated, so that a program
-    which sets a few rows or columns of a plane writes it in place and
-    the caller rebinds the result.  Not on the CPU with a persistent
-    compile cache: a deserialized executable can lose its input/output
-    aliasing there (the carve-out of `serve/recycle.py:_install_fn`)."""
-    import jax
-
-    if jax.default_backend() == "cpu" and \
-            getattr(jax.config, "jax_compilation_cache_dir", None):
-        planes = ()
-    return jax.jit(fn, donate_argnums=planes)
-
-
 def donated_planes(fn, specs) -> int:
     """How many of the kernel's plane arguments (from `_PLANE_ARG0`)
     the launch `fn` donates (`_DONATED_PLANES`, or none under
@@ -4331,6 +4297,8 @@ def _hostcall_fns():
     import jax
     import jax.numpy as jnp
     from jax import lax
+
+    from wasmedge_tpu.batch import jit_in_place
 
     def gather(plane, idx):
         return take_cols(plane, idx)
@@ -4393,12 +4361,12 @@ class PallasUniformEngine:
     # (512 steps — genuinely divergent blocks diverge inside it) and
     # diverges late discards + carefully re-executes up to this many
     # steps ONCE (~0.2 s at 4096 lanes); its per-block interval then
-    # halves adaptively (careful_recheck) down to _SNAP_MIN, so
+    # halves adaptively (batch/scheduler.py `_SnapPolicy`), so
     # repeated rollbacks are geometrically cheaper.
     SNAP_STEPS = 131072
 
     def __init__(self, inst, store=None, conf=None, lanes=None, mesh=None,
-                 interpret=None, simt=None):
+                 interpret=None, simt=None, blk_cap=None):
         from wasmedge_tpu.batch.engine import BatchEngine
 
         self.simt = simt if simt is not None else BatchEngine(
@@ -4417,7 +4385,7 @@ class PallasUniformEngine:
         self._hostcall_fns_cache = None
         self._rows_buffers = {}     # `_read_plane_rows`' own, by shape
         self._tables = None
-        self._blk_cap = None  # lane-block ceiling (multi-tenant alignment)
+        self._blk_cap = blk_cap   # the largest lane block (None: lanes)
         self.fell_back_to_simt = False
         self.splits = 0  # block-scheduler split count from the last run()
         self.recheck_rounds = 0  # careful-kernel rounds (optimistic mode)
@@ -4767,6 +4735,8 @@ class PallasUniformEngine:
                 from wasmedge_tpu.utils.fsio import atomic_write_bytes
 
                 atomic_write_bytes(path, exp.serialize())
+            from wasmedge_tpu.batch import jit_in_place
+
             return jit_in_place(exp.call, *_DONATED_PLANES)
         except Exception as e:
             import warnings
@@ -4866,145 +4836,17 @@ class PallasUniformEngine:
         return [jnp.zeros(self._plane(D), jnp.int32),
                 jnp.zeros(self._plane(D), jnp.int32)]
 
-    # -- state ------------------------------------------------------------
-    def _from_simt_state(self, simt_state):
-        """Build pallas-geometry state from a block-uniform SIMT state
-        (every control scalar identical within each lane block) — the
-        multi-tenant entry path: tenants occupy whole blocks, so their
-        heterogeneous entries are per-block ctrl rows."""
-        import jax.numpy as jnp
-
-        D, CD, W, Lblk = self._geom
-        L = self.lanes
-        nblk = L // Lblk
-        pc = np.asarray(simt_state.pc)
-        sp = np.asarray(simt_state.sp)
-        fp = np.asarray(simt_state.fp)
-        ob = np.asarray(simt_state.opbase)
-        cd = np.asarray(simt_state.call_depth)
-        pages = np.asarray(simt_state.mem_pages)
-        if (cd != 0).any():
-            # the converter drops the SIMT frame planes; entering with
-            # live frames would corrupt the first return
-            raise ValueError("cannot enter the pallas engine mid-call "
-                            "(call_depth != 0)")
-        fuel_v = np.asarray(simt_state.fuel)
-        fuel_on = self.cfg.fuel_per_launch is not None
-        ctrl = np.zeros((nblk, self.ctrl_width), np.int32)
-        for b in range(nblk):
-            sl = slice(b * Lblk, (b + 1) * Lblk)
-            for col, vec in ((_C_PC, pc), (_C_SP, sp), (_C_FP, fp),
-                             (_C_OB, ob), (_C_CD, cd), (_C_PAGES, pages)):
-                seg = vec[sl]
-                if not (seg == seg[0]).all():
-                    raise ValueError(
-                        f"block {b} not control-uniform; cannot enter the "
-                        f"pallas engine")
-                ctrl[b, col] = seg[0]
-            if fuel_on:
-                seg = fuel_v[sl]
-                if not (seg == seg[0]).all():
-                    raise ValueError(
-                        f"block {b} fuel not uniform; cannot enter the "
-                        f"pallas engine")
-                ctrl[b, _C_FUEL] = seg[0]
-            else:
-                ctrl[b, _C_FUEL] = _FUEL_OFF
-        cap_pages = W // _PAGE_WORDS
-        if self.img.has_memory and (pages > cap_pages).any():
-            raise ValueError(
-                "state has grown beyond the watermark plane; cannot enter "
-                "the pallas engine")
-        ctrl[:, _C_CHUNK] = self.cfg.steps_per_launch
-        stack_lo = np.asarray(simt_state.stack_lo)[:D]
-        stack_hi = np.asarray(simt_state.stack_hi)[:D]
-        mem = np.asarray(simt_state.mem)
-        if mem.shape[0] < W:
-            mem = np.concatenate(
-                [mem, np.zeros((W - mem.shape[0], L), np.int32)], axis=0)
-        mem = mem[:W]
-        NGp = max(self.img.globals_lo.shape[0], 1)
-        glo = np.asarray(simt_state.glob_lo)
-        ghi = np.asarray(simt_state.glob_hi)
-        if glo.shape[0] < NGp:
-            pad = np.zeros((NGp - glo.shape[0], L), np.int32)
-            glo = np.concatenate([glo, pad], axis=0)
-            ghi = np.concatenate([ghi, pad], axis=0)
-        trap = np.asarray(simt_state.trap)[None, :]
-
-        def up(x):   # a host [rows, L] plane, in the kernel's layout
-            return jnp.asarray(x.reshape(self._plane(x.shape[0])))
-
-        state = [jnp.asarray(ctrl), jnp.zeros((nblk, 3, CD), jnp.int32),
-                 up(stack_lo), up(stack_hi), up(glo[:NGp]), up(ghi[:NGp]),
-                 up(mem), up(trap)] + self.shadow_planes()
-        if self.img.has_simd:
-            for plane in (simt_state.stack_e2, simt_state.stack_e3):
-                state.append(up(np.asarray(plane)[:D] if plane is not None
-                                else np.zeros((D, L), np.int32)))
-            state += self._shadow_simd_planes()
-        return state
-
-    def run_blocks(self, simt_state, max_steps: int = 10_000_000):
-        """Run from a block-uniform SIMT state; returns (simt_state,
-        steps_per_block, fell_back). Used by the multi-tenant engine."""
-        if self._fn is None:
-            self._build()
-        state = self._from_simt_state(simt_state)
-        self._pages_override = {}
-        state, steps_per_block, statuses = self._drive(state, max_steps)
-        fell_back = ((statuses == ST_DIVERGED) |
-                     (statuses == ST_REGROW)).any()
-        self.fell_back_to_simt = bool(fell_back)
-        return (self._to_simt_state(state, steps_per_block),
-                steps_per_block, bool(fell_back))
-
-    def _drive(self, state, max_steps):
-        """Launch loop: run chunks, serve host outcalls, stop when no
-        block is runnable or max_steps is reached."""
-        nblk = state[0].shape[0]
-        steps_per_block = np.zeros(nblk, np.int64)
-        while True:
-            out = self._fn(*self._tables, state[0], state[1], *state[2:])
-            state = list(out)
-            ctrl_np = np.asarray(state[0])
-            steps_per_block += ctrl_np[:, _C_STEPS].astype(np.int64)
-            statuses = ctrl_np[:, _C_STATUS]
-            if (statuses == ST_RECHECK).any():
-                state, ctrl_np = self._run_recheck(state, ctrl_np)
-                steps_per_block += ctrl_np[:, _C_STEPS].astype(np.int64)
-                statuses = ctrl_np[:, _C_STATUS]
-            else:
-                # adaptive window growth: a launch with no rollback
-                # doubles a shrunken snapshot interval back toward
-                # SNAP_STEPS (careful_recheck is the halving side)
-                snap = ctrl_np[:, _C_SNAP]
-                if (snap > 0).any() and (snap < self.SNAP_STEPS).any():
-                    import jax.numpy as jnp
-
-                    ctrl_np = ctrl_np.copy()
-                    ctrl_np[:, _C_SNAP] = np.where(
-                        snap > 0,
-                        np.minimum(snap * 2, self.SNAP_STEPS), snap)
-                    state[0] = jnp.asarray(ctrl_np)
-            if (statuses == ST_HOSTCALL).any() and \
-                    int(steps_per_block.max()) < max_steps:
-                state = self._serve_hostcalls(state, ctrl_np)
-                continue
-            if (statuses == ST_RUNNING).any() and \
-                    int(steps_per_block.max()) < max_steps:
-                continue
-            return state, steps_per_block, statuses
-
-    def careful_recheck(self, state, ctrl_np, recheck_mask, link, nres=0):
-        """ONE recheck protocol for both drive paths (engine._drive and
-        BlockScheduler): re-run ST_RECHECK blocks on the careful kernel
-        for one short chunk.  An optimistic rollback rewound them to
-        their last validated snapshot; exact per-step checking reaches
-        the divergent instruction and stops there with the precise
-        status (DIVERGED/trap/...), after which normal handling
-        proceeds.  Non-recheck blocks get chunk=0 (zero steps, state
-        untouched).  Returns (state, record): the round's pass record
+    def careful_recheck(self, state, ctrl_np, chunk, snap, link, nres=0):
+        """The block scheduler's recheck round: re-run the blocks whose
+        `chunk` is not 0 (those a rollback left ST_RECHECK) on the
+        careful kernel for that many steps.  An optimistic rollback
+        rewound them to their last validated snapshot; exact per-step
+        checking reaches the divergent instruction and stops there with
+        the precise status (DIVERGED/trap/...), after which normal
+        handling proceeds.  The other blocks run zero steps, their state
+        untouched.  `snap` is every block's snapshot interval from here
+        on (the scheduler's `_SnapPolicy`), written into the ctrl the
+        round uploads.  Returns (state, record): the round's pass record
         (`PassRecord`, with rows [:nres] of the stacks), packed behind
         the careful kernel and downloaded once, its `ctrl` with the
         saved chunk restored and non-recheck step counts zeroed so
@@ -5013,17 +4855,11 @@ class PallasUniformEngine:
         uploads, its download and its two enqueues go through the
         caller's `link` (HostLink)."""
         self.recheck_rounds += 1
+        recheck_mask = chunk > 0
         ctrl = ctrl_np.copy()
         saved_chunk = ctrl[:, _C_CHUNK].copy()
-        # adaptive window: a block that just rolled back gets half its
-        # snapshot interval next time (down to _SNAP_MIN), so the run-up
-        # a genuinely divergent block discards shrinks geometrically;
-        # clean launches grow it back (engine._drive / BlockScheduler)
-        snap = np.where(ctrl[:, _C_SNAP] > 0, ctrl[:, _C_SNAP],
-                        self.SNAP_STEPS)
-        ctrl[:, _C_SNAP] = np.where(
-            recheck_mask, np.maximum(snap // 2, _SNAP_MIN), snap)
-        ctrl[:, _C_CHUNK] = np.where(recheck_mask, snap + 64, 0)
+        ctrl[:, _C_SNAP] = snap
+        ctrl[:, _C_CHUNK] = chunk
         ctrl[:, _C_STATUS] = np.where(recheck_mask, ST_RUNNING,
                                       ctrl[:, _C_STATUS])
         state[0] = link.h2d("ctrl", ctrl)
@@ -5039,89 +4875,6 @@ class PallasUniformEngine:
         ctrl[:, _C_STEPS] = np.where(recheck_mask, ctrl[:, _C_STEPS], 0)
         state[0] = link.h2d("ctrl", ctrl)
         return state, rec
-
-    def _run_recheck(self, state, ctrl_np):
-        recheck = ctrl_np[:, _C_STATUS] == ST_RECHECK
-        state, rec = self.careful_recheck(
-            state, ctrl_np, recheck,
-            HostLink(functools.partial(self.obs.timed, cat="scheduler")))
-        return state, rec.ctrl
-
-    def _to_simt_state(self, state, steps_per_block):
-        """Expand per-block scalars to the SIMT engine's per-lane layout."""
-        import jax.numpy as jnp
-
-        from wasmedge_tpu.batch.engine import BatchState
-
-        cfg = self.cfg
-        L = self.lanes
-        D, CD, W, Lblk = self._geom
-        ctrl = np.asarray(state[0])
-        frames = np.asarray(state[1])
-        nblk = ctrl.shape[0]
-        D_s, CD_s = cfg.value_stack_depth, cfg.call_stack_depth
-        simd = self.img.has_simd
-        # the planes read below, lane by lane, on the host
-        state = {i: lanes_of(np.asarray(state[i]))
-                 for i in (2, 3, 4, 5, 6, 7) + ((14, 15) if simd else ())}
-
-        def pad_rows(x, target):
-            if x.shape[0] >= target:
-                return x[:target]
-            return np.concatenate(
-                [x, np.zeros((target - x.shape[0], L), x.dtype)], axis=0)
-
-        def per_lane(col):
-            return np.repeat(ctrl[:, col].astype(np.int32), Lblk)
-
-        pages_v = per_lane(_C_PAGES)
-        for b, arr in self._pages_override.items():
-            pages_v[b * Lblk:(b + 1) * Lblk] = arr
-
-        trap_v = merge_block_status_into_trap(
-            np.asarray(state[7])[0].copy(), ctrl, Lblk)
-        fr = np.zeros((3, CD_s, L), np.int32)
-        ncd = min(CD, CD_s)
-        for b in range(nblk):
-            fr[:, :ncd, b * Lblk:(b + 1) * Lblk] = \
-                frames[b][:, :ncd, None]
-        fuel_on = cfg.fuel_per_launch is not None
-        retired = np.repeat(np.asarray(steps_per_block, np.int64), Lblk)
-        fuel_v = np.maximum(per_lane(_C_FUEL), 0) if fuel_on \
-            else np.zeros(L, np.int32)
-        # The SIMT engine's plane is sized by the declared/effective max,
-        # not the watermark — pad rows so grow works over there.
-        mem_np = np.asarray(state[6])
-        simt_w = max(self.img.mem_pages_max * _PAGE_WORDS, 1) \
-            if self.img.has_memory else mem_np.shape[0]
-        if mem_np.shape[0] < simt_w:
-            mem_np = np.concatenate(
-                [mem_np, np.zeros((simt_w - mem_np.shape[0], L), np.int32)],
-                axis=0)
-        from wasmedge_tpu.batch.engine import t0_state_planes
-
-        return BatchState(
-            **t0_state_planes(self.img, cfg, L,
-                              getattr(self.simt, "_t0kinds", None)),
-            pc=jnp.asarray(per_lane(_C_PC)), sp=jnp.asarray(per_lane(_C_SP)),
-            fp=jnp.asarray(per_lane(_C_FP)),
-            opbase=jnp.asarray(per_lane(_C_OB)),
-            call_depth=jnp.asarray(per_lane(_C_CD)),
-            trap=jnp.asarray(trap_v),
-            retired=jnp.asarray(retired.astype(np.int32)),
-            fuel=jnp.asarray(fuel_v.astype(np.int32)),
-            mem_pages=jnp.asarray(pages_v),
-            stack_lo=jnp.asarray(pad_rows(state[2], D_s)),
-            stack_hi=jnp.asarray(pad_rows(state[3], D_s)),
-            fr_ret_pc=jnp.asarray(fr[0]), fr_fp=jnp.asarray(fr[1]),
-            fr_opbase=jnp.asarray(fr[2]),
-            glob_lo=jnp.asarray(state[4]), glob_hi=jnp.asarray(state[5]),
-            mem=jnp.asarray(mem_np),
-            stack_e2=jnp.asarray(pad_rows(state[14], D_s)) if simd
-            else None,
-            stack_e3=jnp.asarray(pad_rows(state[15], D_s)) if simd
-            else None,
-        )
 
     # -- run --------------------------------------------------------------
     def run(self, func_name: str, args_lanes: List,
@@ -5209,21 +4962,6 @@ class PallasUniformEngine:
             self.obs.add_simd_counts(sched.simd_ops)
         return sched.result()
 
-    def _serve_hostcalls(self, state, ctrl_np, valid_blocks=None):
-        """Drain parked blocks through the host outcall channel and
-        re-arm them (synchronous composition of the begin/finish halves
-        below — the block scheduler calls the halves directly so host
-        service of parked blocks OVERLAPS the next kernel launch)."""
-        link = HostLink(functools.partial(self.obs.timed, cat="scheduler"))
-        pending = self._serve_hostcalls_begin(state, ctrl_np,
-                                              valid_blocks, link)
-        state, rearms = self._serve_hostcalls_finish(state, pending)
-        ctrl = ctrl_np.copy()
-        for b, row in rearms.items():
-            ctrl[b] = row
-        state[0] = link.h2d("ctrl", ctrl)
-        return state
-
     def _hostcall_programs(self):
         if self._hostcall_fns_cache is None:
             self._hostcall_fns_cache = _hostcall_fns()
@@ -5266,8 +5004,7 @@ class PallasUniformEngine:
             a += part.shape[0]
         return out
 
-    def _serve_hostcalls_begin(self, state, ctrl_np, valid_blocks=None,
-                               link=None):
+    def _serve_hostcalls_begin(self, state, ctrl_np, valid_blocks, link):
         """Phase 1 of the outcall serve: capture every device-side read
         the serve needs — parked blocks' metas and ctrl rows, the two
         stack slabs covering all argument rows, and the parked blocks'
@@ -5289,9 +5026,6 @@ class PallasUniformEngine:
         chunks for ALL lanes at once for any other, and writes back the
         written rows only — per-lane data never rides the link alone
         (the "vectorized memory views" serve, SURVEY §5.8/§7(d))."""
-        if link is None:
-            link = HostLink(functools.partial(self.obs.timed,
-                                              cat="scheduler"))
         img = self.img
         D, CD, W, Lblk = self._geom
         t_begin = self.obs.now()
@@ -5330,14 +5064,14 @@ class PallasUniformEngine:
             # queue depth counts REAL parked lanes: pad (clone) lanes
             # are never served, so a near-empty block must not inflate
             # the counter track by Lblk
-            vb = valid_blocks or {}
             obs.counter("hostcall_queue_depth", sum(
-                int(vb[b].sum()) if vb.get(b) is not None else Lblk
+                int(valid_blocks[b].sum())
+                if valid_blocks.get(b) is not None else Lblk
                 for b in blocks))
         return {"blocks": blocks, "metas": metas,
                 "mem_cols": mem_cols, "slab_lo": slab_lo,
                 "slab_hi": slab_hi, "Lblk": Lblk, "link": link,
-                "valid_blocks": valid_blocks or {}}
+                "valid_blocks": valid_blocks}
 
     def _serve_hostcalls_finish(self, state, pending):
         """Phase 2: run the host functions (vectorized per block where

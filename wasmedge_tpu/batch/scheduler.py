@@ -80,7 +80,6 @@ from wasmedge_tpu.batch.pallas_engine import (
     _PAGE_WORDS,
     HostLink,
     PallasUniformEngine,
-    jit_in_place,
     lanes_of,
     put_cols,
     take_cols,
@@ -128,6 +127,8 @@ def _surgery_fns():
     (`jit_in_place`; the caller rebinds them)."""
     import jax
 
+    from wasmedge_tpu.batch import jit_in_place
+
     def extract(planes, idx):
         return tuple(take_cols(p, idx) for p in planes)
 
@@ -173,6 +174,59 @@ class _Pending:
     steps0: int = 0               # instructions already retired
     pages: np.ndarray = None      # [n] per-lane page counts when a host
     #                               outcall grew memory (else ctrl value)
+
+
+class _SnapPolicy:
+    """Every value the host gives a block's snapshot interval
+    (`_C_SNAP`: the steps between the optimistic kernel's commits, 0
+    read as the engine's full `SNAP_STEPS`, here `full`; the kernel
+    hands the column back as it got it).  A block that rolls back gets
+    half its interval, down to `MIN`, so the run-up a genuinely
+    divergent block discards shrinks geometrically; a clean launch
+    doubles it back up to `full`; a split's child starts at `full`,
+    never at its parent's."""
+
+    MIN = 256
+    # `commit_due`'s first interval after a launch (the kernel's own
+    # min(512, snap_steps)): short, to bound that run-up
+    FIRST = 512
+
+    def __init__(self, full: int):
+        self.full = full
+
+    def ran(self, snap):
+        """The interval each block of the column `snap` ran under."""
+        return np.where(snap > 0, snap, self.full)
+
+    def rolled_back(self, snap, rolled):
+        """-> (chunk, snap), the columns of a careful recheck of the
+        blocks `rolled`: the careful kernel replays the interval a block
+        ran under and 64 steps past it, and the block goes on at half
+        that interval; the other blocks run no step and keep theirs."""
+        ran = self.ran(snap)
+        return (np.where(rolled, ran + 64, 0),
+                np.where(rolled, np.maximum(ran // 2, self.MIN), ran))
+
+    def grown(self, snap, clean):
+        """The column after a launch the blocks `clean` ran without a
+        rollback: a halved interval doubles, up to `full`."""
+        grow = clean & (snap > 0) & (snap < self.full)
+        return np.where(grow, np.minimum(snap * 2, self.full), snap)
+
+    def restores(self, snap) -> int:
+        """1 where a child's `full` interval (the value itself, not the
+        0 the kernel reads as it) gives back a halved `snap`."""
+        return int(0 < snap < self.full)
+
+    def commits(self, steps, snap) -> int:
+        """The periodic commits a launch of `steps` under the intervals
+        `snap` implies, by `commit_due`'s rule: the first falls due
+        after min(FIRST, interval) steps, the others an interval
+        apart."""
+        snap = self.ran(snap.astype(np.int64))
+        first = np.minimum(min(self.FIRST, self.full), snap)
+        return int(np.where(steps < first, 0,
+                            1 + (steps - first) // snap).sum())
 
 
 class BlockScheduler:
@@ -346,33 +400,34 @@ class BlockScheduler:
         # splits that outgrow this budget route to SIMT instead of
         # thrashing the host with block surgery
         self.split_budget = 4 * self.nblk + 16
-        # internal engine at the scheduler's geometry, cached on the
-        # long-lived SIMT engine per (L, Lblk) so repeated run() calls
-        # reuse the image, the fused tables, the jitted kernel and the
-        # two surgery programs
-        cache = getattr(outer.simt, "_sched_cache", None)
-        if cache is None:
-            cache = outer.simt._sched_cache = {}
-        eng = cache.get((L, lblk))
+        # internal engine at the scheduler's geometry and the two
+        # surgery programs, cached on the long-lived SIMT engine per
+        # (L, Lblk) so repeated run() calls reuse the image, the fused
+        # tables, the jitted kernel and the surgery
+        owner = outer.simt
+        if not hasattr(owner, "_sched_cache"):
+            owner._sched_cache, owner._surgery_cache = {}, {}
+        key = (L, lblk)
+        eng = owner._sched_cache.get(key)
         if eng is None:
             from wasmedge_tpu.batch.engine import BatchEngine
 
-            simt = BatchEngine(self.inst, store=outer.simt.store,
-                               conf=outer.simt.conf, lanes=L,
-                               img=outer.img)
+            simt = BatchEngine(self.inst, store=owner.store,
+                               conf=owner.conf, lanes=L, img=outer.img)
             eng = PallasUniformEngine(self.inst, simt=simt,
-                                      interpret=outer.interpret)
-            eng._blk_cap = lblk
-            eng.ineligible_reason = eng._eligibility()
+                                      interpret=outer.interpret,
+                                      blk_cap=lblk)
             if not eng.eligible:
                 raise RuntimeError(
                     f"scheduler geometry ineligible: "
                     f"{eng.ineligible_reason}")
             eng._build()
             assert eng._geom[3] == lblk, (eng._geom, lblk)
-            eng._surgery = _surgery_fns()
-            cache[(L, lblk)] = eng
+            owner._sched_cache[key] = eng
+            owner._surgery_cache[key] = _surgery_fns()
         self.eng = eng
+        self._surgery = owner._surgery_cache[key]
+        self._snap = _SnapPolicy(eng.SNAP_STEPS)
         # a hostcall serve counts into its engine's SIMT twin: this
         # run's dict, not the cached engine's own growing one
         eng.simt.hostcall_stats = outer.simt.hostcall_stats
@@ -676,17 +731,11 @@ class BlockScheduler:
                 with self._phase("batch/statuses", splits=self.splits):
                     ctrl_np = self._run_recheck(live)
             else:
-                # who moves a block's snapshot interval: careful_recheck
-                # halves it when the block rolls back, a clean launch
-                # doubles it here, and a split's child starts at the
-                # full one (_install_children), never at its parent's
                 snap = ctrl_np[:, _C_SNAP]
-                grow = live & (snap > 0) & (snap < self.eng.SNAP_STEPS)
-                if grow.any():
+                grown = self._snap.grown(snap, live)
+                if (grown != snap).any():
                     cc = self._ctrl()
-                    cc[:, _C_SNAP] = np.where(
-                        grow, np.minimum(snap * 2, self.eng.SNAP_STEPS),
-                        snap)
+                    cc[:, _C_SNAP] = grown
                     self._ctrl_dirty = True
                     ctrl_np = cc
             self._handle_statuses(ctrl_np)
@@ -717,32 +766,27 @@ class BlockScheduler:
 
     def _count_commits(self, ctrl_np, blocks):
         """Add the periodic commits the launch that just ran implies in
-        `blocks`, by `commit_due`'s rule (batch/pallas_engine.py): the
-        first falls due after min(512, interval) steps, the others an
-        interval apart.  Computed from each block's `_C_STEPS` (a
-        rollback rewound it to the last commit) and the `_C_SNAP` it
-        ran under, which the kernel hands back as it got it; a fused
-        block may overshoot a boundary by its length, so a long run can
-        take a commit fewer than this says."""
-        if not self.eng.optimistic:
-            return
-        full = self.eng.SNAP_STEPS
-        steps = ctrl_np[blocks, _C_STEPS].astype(np.int64)
-        snap = ctrl_np[blocks, _C_SNAP].astype(np.int64)
-        snap = np.where(snap > 0, snap, full)
-        first = np.minimum(min(512, full), snap)
-        self.snap_commits += int(np.where(
-            steps < first, 0, 1 + (steps - first) // snap).sum())
+        `blocks` (`_SnapPolicy.commits`), from each block's `_C_STEPS`
+        (a rollback rewound it to the last commit) and the `_C_SNAP` it
+        ran under; a fused block may overshoot a boundary by its length,
+        so a long run can take a commit fewer than this says."""
+        if self.eng.optimistic:
+            self.snap_commits += self._snap.commits(
+                ctrl_np[blocks, _C_STEPS].astype(np.int64),
+                ctrl_np[blocks, _C_SNAP])
 
     def _run_recheck(self, live) -> np.ndarray:
-        """Re-run ST_RECHECK blocks on the careful kernel (synchronous)
-        via the engine's shared careful_recheck protocol, then stops
-        with the precise status which _handle_statuses splits/serves."""
-        recheck = live & (self._ctrl()[:, _C_STATUS] == ST_RECHECK)
+        """Re-run ST_RECHECK blocks on the careful kernel (synchronous,
+        `careful_recheck`) with the intervals `_SnapPolicy` gives a
+        rollback, then stops with the precise status which
+        _handle_statuses splits/serves."""
+        ctrl = self._ctrl()
+        recheck = live & (ctrl[:, _C_STATUS] == ST_RECHECK)
         with self._phase("batch/recheck", blocks=int(recheck.sum())):
+            chunk, snap = self._snap.rolled_back(ctrl[:, _C_SNAP], recheck)
             self._upload_frames()
             self.state, rec = self.eng.careful_recheck(
-                self.state, self._ctrl(), recheck, self.link, self.nres)
+                self.state, ctrl, chunk, snap, self.link, self.nres)
         ctrl = rec.ctrl
         self.rechecks += 1
         self.careful_steps += int(ctrl[recheck, _C_STEPS].sum())
@@ -1095,7 +1139,6 @@ class BlockScheduler:
         without counting it (`_split`)."""
         ids = self.block_lanes[b]
         steps0 = int(self.block_steps[b]) + resolved
-        full = self.eng.SNAP_STEPS
         for (cc, fr, cols, writes) in children:
             lane_ids = ids[cols]
             sel = lane_ids >= 0
@@ -1110,13 +1153,12 @@ class BlockScheduler:
             vcols = cols[sel]
             child_cols = self._extract_cols(b, vcols, writes, sel)
             cc[_C_CHUNK] = self.cfg.steps_per_launch
-            # a child starts fresh, as a block planned at entry does:
-            # the engine's full interval (the value itself, not the 0
-            # the kernel reads as it), whatever halvings its parent's
-            # row carries.  The kernel's short first interval bounds
-            # the run-up if the child diverges again at once.
-            self.snap_restored += int(0 < cc[_C_SNAP] < full)
-            cc[_C_SNAP] = full
+            # a child starts fresh, as a block planned at entry does,
+            # whatever halvings its parent's row carries.  The kernel's
+            # short first interval bounds the run-up if the child
+            # diverges again at once.
+            self.snap_restored += self._snap.restores(cc[_C_SNAP])
+            cc[_C_SNAP] = self._snap.full
             self._pending.append(_Pending(
                 ctrl=cc, frames=fr, cols=child_cols,
                 lane_ids=lane_ids[sel].astype(np.int64), steps0=steps0))
@@ -1138,7 +1180,7 @@ class BlockScheduler:
         idx = (b * self.Lblk + np.asarray(cols)[pad]).astype(np.int32)
         self.surgery_programs += 1
         out = dict(zip(self._plane_idx, self.link.enqueue(
-            "extract", self.eng._surgery[0],
+            "extract", self._surgery[0],
             tuple(self.state[i] for i in self._plane_idx.values()), idx)))
         for key, val in writes.items():
             row = key[1]
@@ -1178,7 +1220,7 @@ class BlockScheduler:
             n = len(p.lane_ids)
             self.surgery_programs += 1
             out = self.link.enqueue(
-                "install", self.eng._surgery[1],
+                "install", self._surgery[1],
                 tuple(self.state[i] for i in planes),
                 tuple(p.cols[name] for name in self._plane_idx),
                 _clone_pad(n, Lblk), np.int32(b * Lblk))

@@ -884,10 +884,9 @@ class UniformBatchEngine:
         return eng if eng.eligible else None
 
     def _build_uniform(self):
-        from wasmedge_tpu.batch import ensure_jax_backend
+        from wasmedge_tpu.batch import ensure_jax_backend, jit_in_place
 
         ensure_jax_backend()
-        import jax
         import jax.numpy as jnp
         from jax import lax
 
@@ -908,13 +907,7 @@ class UniformBatchEngine:
             _, st = lax.while_loop(cond, body, (jnp.int32(0), st))
             return st
 
-        # same donation guard as the SIMT chunk (persistent-cache CPU
-        # deserialization can drop input/output aliasing)
-        donate = (0,)
-        if jax.default_backend() == "cpu" and \
-                getattr(jax.config, "jax_compilation_cache_dir", None):
-            donate = ()
-        self._uchunk = jax.jit(run_chunk, donate_argnums=donate)
+        self._uchunk = jit_in_place(run_chunk, 0)
 
     def _initial_uniform_state(self, func_idx, args_lanes):
         import jax.numpy as jnp

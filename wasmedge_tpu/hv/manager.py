@@ -660,8 +660,9 @@ def install_lane_columns(state, total_lanes: int, lanes_list, cols_list,
     (reshard) so the pass retraces at the new shapes.  Shared with the
     effects/ runtime: a parked session's unpark install is the exact
     code path of an hv swap-in."""
-    import jax
     import jax.numpy as jnp
+
+    from wasmedge_tpu.batch import jit_in_place
 
     if jit_cache[0] is None:
         def install(state, idx, cols):
@@ -674,11 +675,7 @@ def install_lane_columns(state, total_lanes: int, lanes_list, cols_list,
                     updates[name] = plane.at[:, idx].set(col)
             return state._replace(**updates)
 
-        donate = (0,)
-        if jax.default_backend() == "cpu" and \
-                getattr(jax.config, "jax_compilation_cache_dir", None):
-            donate = ()
-        jit_cache[0] = jax.jit(install, donate_argnums=donate)
+        jit_cache[0] = jit_in_place(install, 0)
     n = len(lanes_list)
     w = min(total_lanes, 1 << (n - 1).bit_length())
     idx = np.full(w, lanes_list[0], np.int64)

@@ -120,7 +120,7 @@ def regrow_state(state, old_lanes: int, idle_state, new_lanes: int):
     return jax.tree_util.tree_map(_combine, state, idle_state)
 
 
-def _build_shard_chunk(run_chunk, mesh, probe_state, donate):
+def _build_shard_chunk(run_chunk, mesh, probe_state):
     """Jit the chunk body as ONE program over the named mesh.
 
     `run_chunk` is the engine's traced chunk loop (the SAME body the
@@ -129,21 +129,18 @@ def _build_shard_chunk(run_chunk, mesh, probe_state, donate):
     BatchState pytree sharded on the `lanes` mesh axis in and out, the
     per-launch time base replicated.  XLA's SPMD partitioner then
     compiles one per-shard executable and the host issues ONE dispatch
-    per round regardless of device count.  `donate` is the caller's
-    donation tuple — BatchEngine._build owns the CPU/persistent-cache
-    carve-out, one copy for both branches.
+    per round regardless of device count.  The state is donated as on
+    one device (`jit_in_place`).
 
     jit-purity lint target (tools/lint_jit_purity.py): everything
     nested here runs under trace.
     """
-    import jax
-
+    from wasmedge_tpu.batch import jit_in_place
     from wasmedge_tpu.parallel.mesh import state_shardings
 
     shardings = state_shardings(mesh, probe_state)
-    return jax.jit(run_chunk, in_shardings=(shardings, None),
-                   out_shardings=(None, shardings),
-                   donate_argnums=donate)
+    return jit_in_place(run_chunk, 0, in_shardings=(shardings, None),
+                        out_shardings=(None, shardings))
 
 
 class ShardDrive:

@@ -109,8 +109,9 @@ class LaneRecycler:
         fn = self._install_fns.get((func_idx, nargs))
         if fn is not None:
             return fn
-        import jax
         import jax.numpy as jnp
+
+        from wasmedge_tpu.batch import jit_in_place
 
         tmpl = {name: jnp.asarray(col)
                 for name, col in self._capture(func_idx).items()}
@@ -137,14 +138,8 @@ class LaneRecycler:
 
         # donate the carried state so the column writes happen in place
         # instead of copying every plane (the caller always rebinds
-        # `self.state = install(self.state, ...)`), with the same
-        # cpu+persistent-cache carve-out as the engine's chunk loop (a
-        # deserialized executable can lose input/output aliasing there)
-        donate = (0,)
-        if jax.default_backend() == "cpu" and \
-                getattr(jax.config, "jax_compilation_cache_dir", None):
-            donate = ()
-        fn = jax.jit(install, donate_argnums=donate)
+        # `self.state = install(self.state, ...)`)
+        fn = jit_in_place(install, 0)
         self._install_fns[(func_idx, nargs)] = fn
         return fn
 
