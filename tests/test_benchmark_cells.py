@@ -61,6 +61,13 @@ WASI_CELL_METRICS = CHACHA_CELL_METRICS + [
     "host_hostcall_ms.batch"]
 
 
+# and the cell of CoreMark: the ChaCha20 cell's but the v128
+# instructions, and the br_table and call_indirect a job in their place
+COREMARK_CELL_METRICS = [
+    m for m in CHACHA_CELL_METRICS if m != "simd_ops_per_job.batch"] \
+    + ["indirect_ops_per_job.batch"]
+
+
 # the self times and counts of the scheduler's transfers and enqueues
 # (PR 36), read by `readers/trace_span_self.py` in every batch cell
 HOST_LINK_METRICS = [
@@ -73,7 +80,7 @@ def test_the_manifest_lists_the_batch_cells():
     assert CELLS == ["batch-fib30-uniform", "batch-mem-uniform",
                      "batch-fib-divergent", "batch-fib-split",
                      "batch-gemm-small", "batch-chacha20-192k",
-                     "batch-chacha20-write8k"]
+                     "batch-chacha20-write8k", "batch-coremark-2k"]
     used = {w["config"] for w in MANIFEST["workloads"]}
     assert used == {c["name"] for c in MANIFEST["configs"]}
     # every batch cell reports what the uniform fib cell reports
@@ -103,6 +110,9 @@ def test_the_manifest_lists_the_batch_cells():
     # the WASI command's cell all of that and the serve's seven
     assert sorted(reported("batch-chacha20-write8k")) == sorted(
         reported(CELLS[0]) + WASI_CELL_METRICS)
+    # the CoreMark cell the window's, the dispatch's and its own count
+    assert sorted(reported("batch-coremark-2k")) == sorted(
+        reported(CELLS[0]) + COREMARK_CELL_METRICS)
     # the host's account (PR 36) is every batch cell's
     assert set(HOST_LINK_METRICS) <= set(reported(CELLS[0]))
     for m in MANIFEST["per_layer"]:
@@ -111,11 +121,12 @@ def test_the_manifest_lists_the_batch_cells():
                 (["softfloat_ops_per_job.batch"], ["batch-gemm-small"]),
                 (set(GEMM_CELL_METRICS) & set(CHACHA_CELL_METRICS),
                  ["batch-gemm-small", "batch-chacha20-192k",
-                  "batch-chacha20-write8k"]),
+                  "batch-chacha20-write8k", "batch-coremark-2k"]),
                 (["simd_ops_per_job.batch"],
                  ["batch-chacha20-192k", "batch-chacha20-write8k"]),
                 (WASI_CELL_METRICS[len(CHACHA_CELL_METRICS):],
-                 ["batch-chacha20-write8k"])):
+                 ["batch-chacha20-write8k"]),
+                (["indirect_ops_per_job.batch"], ["batch-coremark-2k"])):
             if m["name"] in own:
                 assert m["workloads"] == cells
                 assert m["moves"] == "batch_ginstr_per_s"
@@ -170,7 +181,7 @@ def test_batch_cell_names_a_guest_the_program_has(name):
     assert len(config["guarantees"]) == (
         5 if name == "batch-chacha20-write8k" else
         4 if name in ("batch-fib-split", "batch-gemm-small",
-                      "batch-chacha20-192k") else 3)
+                      "batch-chacha20-192k", "batch-coremark-2k") else 3)
 
 
 # (length, sha256) of the two guests that moved out of the root's
@@ -200,6 +211,10 @@ _CELL_GUESTS = {
     "build_chacha20_wasi": ({"blocks": 3072, "chunk_blocks": 128}, (
         1472, "b2cec3411c9aeb0737a90d75498373c2"
               "fd6dceb36fc492404ba7dc36e8a2626d")),
+    "build_coremark": ({"total_data_size": 2000, "seed1": 0, "seed2": 0,
+                        "seed3": 102}, (
+        4933, "3647ef02d3b7419c34c595d0dd76ceb4"
+              "b0d8a119a5987b0e494a18be1a729bcd")),
 }
 
 

@@ -664,24 +664,32 @@ def test_the_cell_pins_the_sizes_and_the_counts():
     assert rehearse["guest"]["args"] == {"blocks": 8, "chunk_blocks": 2}
     assert rehearse["lanes"] == 16 and rehearse["geometry"]["mem_hbm"]
     manifest = _load(ROOT, "BENCHMARK.json")
-    assert manifest["workloads"][-1]["name"] == CELL
-    cell = manifest["workloads"][-1]
+    # the cell, its configuration and its seven metrics, in the place
+    # they were appended to (later entries come after them)
+    names = [w["name"] for w in manifest["workloads"]]
+    cell = manifest["workloads"][names.index(CELL)]
+    assert names[:names.index(CELL)] == [
+        "batch-fib30-uniform", "batch-mem-uniform", "batch-fib-divergent",
+        "batch-fib-split", "batch-gemm-small", "batch-chacha20-192k"]
     assert cell["chips"] == 4 and cell["config"] == config["name"]
     assert "steadiness alone" in cell["why"] and len(cell["why"]) <= 200
     assert cell["traffic"] == "chacha20-192k-write8k-distinct-seeds"
-    assert manifest["configs"][-1]["name"] == config["name"]
-    assert [m["name"] for m in manifest["per_layer"][-7:]] == [
+    assert [c["name"] for c in manifest["configs"]].index(
+        config["name"]) == 5
+    hostcall = [m for m in manifest["per_layer"]
+                if m["name"].startswith(("hostcall", "host_hostcall"))]
+    assert [m["name"] for m in hostcall] == [
         "hostcall_rounds_per_job.batch", "hostcalls_per_job.batch",
         "hostcall_out_bytes_per_job.batch",
         "hostcall_vectorized_share.batch", "hostcall_begin_ms.batch",
         "hostcall_finish_ms.batch", "host_hostcall_ms.batch"]
-    for m in manifest["per_layer"][-7:]:
+    for m in hostcall:
         assert m["workloads"] == [CELL]
         assert m["moves"] == "batch_ginstr_per_s"
     # whatever batch-chacha20-192k reports, this cell reports
     for m in manifest["end_to_end"] + manifest["per_layer"]:
         if "batch-chacha20-192k" in m.get("workloads", ()):
-            assert m["workloads"][-1] == CELL
+            assert CELL in m["workloads"]
 
 
 def test_the_guest_is_build_chacha20_with_the_write_added():

@@ -98,6 +98,18 @@ def _gemm_wasm():
     return build_polybench_gemm()
 
 
+def _coremark_wasm():
+    """The benchmark's coremark-2k-4096: EEMBC CoreMark 1.0's 2K
+    performance run, 141 handlers (br_table, call_indirect, 8- and
+    16-bit accesses, a shadow stack under a mutable global) behind the
+    HBM window, its loads and stores each on a dispatch of its own
+    (MAX_INLINE_ACCESSES), the br_table and call_indirect count in a
+    17th ctrl column."""
+    from wasmedge_tpu.models.programs import build_coremark
+
+    return build_coremark()
+
+
 def _chacha20_wasm():
     """The benchmark's chacha20-simd-4096 at 3,072 blocks: ten v128
     locals, blocks of 24 ops that hold v128 arithmetic and shuffles,
@@ -242,6 +254,13 @@ _KERNELS = {
                            (4096, True)),
     "chacha20-wasi-auto-careful": (_chacha20_wasi_wasm, 64, 16, None, None,
                                    True, (4096, True)),
+    # CoreMark: fifteen regions deep, which crashed the compiler
+    # on libtpu's default fiber stack (`import wasmedge_tpu` gives it a
+    # larger one), and 20.0 MB with its 109 loads and stores inline
+    "coremark-auto": (_coremark_wasm, 128, 64, None, None, False,
+                      (4096, True)),
+    "coremark-auto-careful": (_coremark_wasm, 128, 64, None, None, True,
+                              (4096, True)),
     # a superblock with a jump and a tail ending in `call`, behind the
     # HBM window: the guard for the eleven nested regions Mosaic's
     # layout inference survives (tails hold no memory op, so there is
@@ -312,6 +331,20 @@ def test_a_v128_kernel_stays_under_the_overlay_cliff(case, one_chip):
     if case not in _CODE_BYTES:     # run alone, or on another worker
         test_pallas_kernel_compiles_for_v5e(case, one_chip)
     assert 0 < _CODE_BYTES[case] < _CODE_BYTES_LIMIT
+
+
+@pytest.mark.parametrize("case", ["coremark-auto", "coremark-auto-careful"])
+def test_the_coremark_kernel_stays_under_the_overlay_cliff(case, one_chip):
+    """No load or store inline (the image would fuse 109), so the program
+    is some 2.4 MB (optimistic) where it was 20.0 MB."""
+    if case not in _CODE_BYTES:     # run alone, or on another worker
+        test_pallas_kernel_compiles_for_v5e(case, one_chip)
+    assert 0 < _CODE_BYTES[case] < _CODE_BYTES_LIMIT
+    eng = _pallas_engine(_coremark_wasm(), 128, 64)
+    shapes = eng._kargs[17]
+    assert len(shapes) == 96 and not any(
+        op[0] in ("loadi", "storei") for shape in shapes for op in shape)
+    assert eng.counts_indirect and eng.ctrl_width == 17
 
 
 def _inner(eqn):

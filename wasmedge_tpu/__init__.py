@@ -19,8 +19,23 @@ __version__ = "0.1.0"
 # r5's python_spawn_floor).  Heavy entry points are exposed lazily
 # below; tests/test_spawn_time.py asserts the invariant in a fresh
 # interpreter.
+import os as _os
+
 from wasmedge_tpu.common.configure import Configure, EngineKind
 from wasmedge_tpu.common.errors import ErrCode, TrapError, WasmError
+
+# The chip's compiler infers a Pallas kernel's vector layouts by
+# recursing over its nested regions on a fiber's stack, and libtpu's
+# default stack holds some twelve levels (tests/test_chip_compile.py):
+# a kernel of many handlers nests deeper (CoreMark's, fifteen) and
+# crashed the compiler.  A larger stack compiles the same program (the
+# gemm kernel's text is the same to the byte with it) and lets the
+# compiler go deeper.  Set before libtpu starts, which reads it once; a
+# setting the environment already makes is kept.
+_STACK_FLAG = "--fibers_default_thread_stack_size="
+if _STACK_FLAG not in _os.environ.get("LIBTPU_INIT_ARGS", ""):
+    _os.environ["LIBTPU_INIT_ARGS"] = " ".join(filter(None, (
+        _os.environ.get("LIBTPU_INIT_ARGS"), _STACK_FLAG + str(8 << 20))))
 
 _LAZY = {
     "VM": ("wasmedge_tpu.vm", "VM"),

@@ -212,8 +212,9 @@ ST_TRAPPED_BASE = 16
 _PAGE_WORDS = 65536 // 4
 _FUEL_OFF = 0x7FFFFFFF  # fuel column value when gas metering is disabled
 
-# ctrl row layout (SMEM, int32[nblk, ctrl_width(simd)]: 16 columns, 17
-# for an image with v128)
+# ctrl row layout (SMEM, int32[nblk, ctrl_width(simd, indirect)]: 16
+# columns, one more for an image with v128 and one more for an image with
+# br_table or call_indirect)
 _C_PC, _C_SP, _C_FP, _C_OB, _C_CD, _C_STATUS, _C_PAGES, _C_CHUNK = range(8)
 _C_STEPS = 8
 _C_FUEL = 9
@@ -247,10 +248,21 @@ _C_SIMD = 16
 _CTRL_W = 16
 
 
-def ctrl_width(simd: bool) -> int:
+def ctrl_width(simd: bool, indirect: bool = False) -> int:
     """Columns of a ctrl row: what the kernel's ctrl output, the pass
-    record and the hosts' rows are all sized by."""
-    return _CTRL_W + 1 if simd else _CTRL_W
+    record and the hosts' rows are all sized by.  16, and one more for
+    each counter the image asks for: v128 (_C_SIMD), then br_table and
+    call_indirect (`indirect_column`)."""
+    return _CTRL_W + int(simd) + int(indirect)
+
+
+def indirect_column(simd: bool) -> int:
+    """The ctrl column written at exit, per launch (never read), by a
+    kernel whose image holds a br_table or a call_indirect
+    (`holds_indirect`): the two it ran, at their handlers and as a fused
+    block's terminal, counted along the path taken as _C_STEPS is, those
+    a rollback discarded too.  The row's last: 16, or 17 after _C_SIMD."""
+    return _CTRL_W + int(simd)
 
 
 def decode_result_rows(stack_lo: np.ndarray, stack_hi: np.ndarray,
@@ -317,6 +329,14 @@ def _jump_targets(img) -> set:
 H_BLOCK_BASE = NUM_HANDLERS
 MAX_BLOCK_SHAPES = 96   # distinct block shapes compiled per kernel
 MAX_BLOCK_LEN = 24      # ops per block (incl. the terminal)
+# loads and stores fused inline into a kernel's blocks, all shapes
+# together: each compiles its own fast path and miss path into its
+# block (behind the HBM window, some 100 to 200 KB of a v5e program
+# each), and past the chip's overlay cliff (tests/test_chip_compile.py)
+# the dispatch loop runs across overlays.  An image whose blocks would
+# fuse more keeps every load and store on a dispatch of its own
+# (CoreMark: 109 inline, 20.0 MB of program; none inline, 2.4 MB)
+MAX_INLINE_ACCESSES = 32
 
 
 def _trapping_alu1_subs():
@@ -428,7 +448,22 @@ def fuse_blocks(hid, img):
     stays ("vshuffle",), fetched at RUN time from the table by the block
     as by the unfused handler (simdops.vshuffle_dyn).  Deterministic, a
     function of the image alone: tpu.aot artifacts verify the persisted
-    hid plane by regeneration (aot/__init__.py)."""
+    hid plane by regeneration (aot/__init__.py).
+
+    Where the blocks would hold more than MAX_INLINE_ACCESSES inline
+    loads and stores in all, the plane is made again with none: a
+    block then ends before a load or a store (a cut, as for room), and
+    each runs its own handler."""
+    fused, shapes = _fuse_blocks(hid, img, inline_mem=True)
+    inline = sum(1 for shape in shapes for op in shape
+                 if op[0] in ("loadi", "storei"))
+    if inline > MAX_INLINE_ACCESSES:
+        return _fuse_blocks(hid, img, inline_mem=False)
+    return fused, shapes
+
+
+def _fuse_blocks(hid, img, inline_mem):
+    """fuse_blocks' plane, with loads and stores fused inline or not."""
     n = img.code_len
     targets = _jump_targets(img)
     # call-return / hostcall-re-arm / trap-partial-resume addresses need
@@ -514,7 +549,8 @@ def fuse_blocks(hid, img):
         while (j < n and len(path) < room - 1
                and (j == start or j not in targets)):
             d = pure_desc(j, lmap, gmap)
-            if d is None:
+            if d is None or (not inline_mem
+                             and d[0] in ("loadi", "storei")):
                 break
             guard = d[0] in ("guardz", "guardnz")
             path.append((d, j, (dict(lmap), dict(gmap)) if guard else None))
@@ -872,6 +908,13 @@ def holds_softfloat(img) -> bool:
         or np.any((cls == CLS_ALU1) & np.isin(sub, list(_F64_ALU1_SUBS))))
 
 
+def holds_indirect(img) -> bool:
+    """Whether the image holds a br_table or a call_indirect: its kernel
+    then counts the two it runs (`indirect_column`)."""
+    return bool(np.any(np.isin(np.asarray(img.cls),
+                               (CLS_BR_TABLE, CLS_CALL_INDIRECT))))
+
+
 def lane_stripe(Lblk: int, interpret: bool) -> int:
     """Lanes a stripe of the kernel's 8-sublane remap (`_build_kernel`):
     Lblk / 8 where the lane block splits into 8 stripes of whole lane
@@ -956,7 +999,7 @@ def _build_kernel(used_hids: tuple, D: int, CD: int, W: int, L: int,
                   simd: bool = False, NV: int = 1,
                   optimistic: bool = False, snap_steps: int = 8192,
                   shadow_full: bool = None, hid_weights: tuple = (),
-                  softfloat: bool = False):
+                  softfloat: bool = False, indirect: bool = False):
     """Compile the chunk-runner for one kernel geometry.
 
     Returns a jitted callable over
@@ -1098,6 +1141,7 @@ def _build_kernel(used_hids: tuple, D: int, CD: int, W: int, L: int,
         turns = next(it_)
         sfc = next(it_) if softfloat else None
         sdc = next(it_) if simd else None
+        idc = next(it_) if indirect else None
         blk = pl.program_id(0)
         lo = blk * Lblk
         # lane-block slices of the HBM planes (`plane_shape`): in
@@ -1583,6 +1627,7 @@ def _build_kernel(used_hids: tuple, D: int, CD: int, W: int, L: int,
             kept = top2 if top2 is not None else srow4(sp - 2)
             i0 = agree_i32(idx) if optimistic else scal(idx)
             agree = True if optimistic else allsame(idx, i0)
+            count_indirect(agree)
             base, n = a_r[pc], b_r[pc]
             ii = jnp.where(u_lt(n, i0), n, i0)
             e = (base + ii) * 3
@@ -1666,6 +1711,7 @@ def _build_kernel(used_hids: tuple, D: int, CD: int, W: int, L: int,
             idx = top1[0] if top1 is not None else srow(slo, sp - 1)
             i0 = agree_i32(idx) if optimistic else scal(idx)
             agree = True if optimistic else allsame(idx, i0)
+            count_indirect(agree)
             tb_size, tb_base = b_r[pc], c_r[pc]
             oob = ~u_lt(i0, tb_size)  # unsigned; tb_size == 0 always oob
             h = tbl_r[jnp.clip(tb_base + jnp.clip(i0, 0,
@@ -2881,6 +2927,21 @@ def _build_kernel(used_hids: tuple, D: int, CD: int, W: int, L: int,
             if n:
                 sdc[...] = sdc[...] + n
 
+        def count_indirect(agree):
+            """One more br_table or call_indirect run where the kernel
+            counts them (an image that holds one): a vreg in VMEM once
+            more.  One that diverges stops un-advanced and counts
+            nothing, as it retires no step."""
+            if not indirect:
+                return
+            if agree is True:
+                idc[...] = idc[...] + 1
+                return
+
+            @pl.when(agree)
+            def _():
+                idc[...] = idc[...] + 1
+
         def mk_alu2(sub):
             fn = alu2[sub]
             can_trap = sub in _DIV32_SUBS or sub in _DIV64_SUBS
@@ -3952,6 +4013,8 @@ def _build_kernel(used_hids: tuple, D: int, CD: int, W: int, L: int,
             sfc[...] = jnp.zeros_like(sfc)
         if simd:
             sdc[...] = jnp.zeros_like(sdc)
+        if indirect:
+            idc[...] = jnp.zeros_like(idc)
         if optimistic:
             init = init + (I32(0),)  # ls: last-snapshot step count
             # entry state was validated at the previous exit: it IS the
@@ -4027,6 +4090,8 @@ def _build_kernel(used_hids: tuple, D: int, CD: int, W: int, L: int,
             ctrl_out[blk, _C_SOFTFLOAT] = sfc[0, 0]
         if simd:
             ctrl_out[blk, _C_SIMD] = sdc[0, 0]
+        if indirect:
+            ctrl_out[blk, indirect_column(simd)] = idc[0, 0]
 
         outs = [dma(0, slo, lslice(s_lo_out)),
                 dma(1, shi, lslice(s_hi_out)),
@@ -4104,10 +4169,13 @@ def _build_kernel(used_hids: tuple, D: int, CD: int, W: int, L: int,
                if softfloat else [])
             + ([pltpu.VMEM((8, 128), jnp.int32)]        # sdc (v128 ops)
                if simd else [])
+            + ([pltpu.VMEM((8, 128), jnp.int32)]        # idc (indirect)
+               if indirect else [])
         ),
     )
     out_shape = [
-        jax.ShapeDtypeStruct((nblk, ctrl_width(simd)), jnp.int32),  # ctrl
+        jax.ShapeDtypeStruct((nblk, ctrl_width(simd, indirect)),
+                             jnp.int32),                      # ctrl
         jax.ShapeDtypeStruct((nblk, 3, CD), jnp.int32),  # frames
         jax.ShapeDtypeStruct(p3((D, L)), jnp.int32),    # stack_lo
         jax.ShapeDtypeStruct(p3((D, L)), jnp.int32),    # stack_hi
@@ -4438,10 +4506,15 @@ class PallasUniformEngine:
         self.softfloat_share = None
         # likewise the instructions of a v128 class (CLS_VCONST ..
         # CLS_VSTORE), which a kernel whose image has v128 counts in the
-        # one ctrl column that only its rows have
-        self.ctrl_width = ctrl_width(bool(self.img.has_simd))
+        # one ctrl column that only its rows have, and the br_table and
+        # call_indirect a kernel whose image holds one ran (one more
+        # column, `indirect_column`)
+        self.counts_indirect = holds_indirect(self.img)
+        self.ctrl_width = ctrl_width(bool(self.img.has_simd),
+                                     self.counts_indirect)
         self.simd_ops = None
         self.simd_share = None
+        self.indirect_ops = None
         # forward edges the newest kernel's blocks run through, by kind
         self.superblock_edges = None
         # the image's i8x16.shuffle slots by lowering ({"word",
@@ -4661,7 +4734,8 @@ class PallasUniformEngine:
                                   snap_steps=self.SNAP_STEPS,
                                   shadow_full=self.optimistic,
                                   hid_weights=self._hid_weights,
-                                  softfloat=self.counts_softfloat))
+                                  softfloat=self.counts_softfloat,
+                                  indirect=self.counts_indirect))
         self._fn_careful_cache = None if self.optimistic else self._fn
         self.donated_planes = donated_planes(self._fn, self._arg_specs())
         self.obs.set_donation_static(self.donated_planes)
@@ -4788,7 +4862,8 @@ class PallasUniformEngine:
                 *self._kargs, optimistic=False,
                 snap_steps=self.SNAP_STEPS, shadow_full=self.optimistic,
                 hid_weights=self._hid_weights,
-                softfloat=self.counts_softfloat)
+                softfloat=self.counts_softfloat,
+                indirect=self.counts_indirect)
         return self._fn_careful_cache
 
     def enqueue_pass_record(self, state, nres, link):
@@ -4960,6 +5035,9 @@ class PallasUniformEngine:
             self.simd_share = sched.simd_ops / sched.kernel_steps \
                 if sched.kernel_steps else None
             self.obs.add_simd_counts(sched.simd_ops)
+        if self.counts_indirect:
+            self.indirect_ops = sched.indirect_ops
+            self.obs.add_indirect_counts(sched.indirect_ops)
         return sched.result()
 
     def _hostcall_programs(self):

@@ -59,6 +59,7 @@ from wasmedge_tpu.batch.pallas_engine import (
     ST_REGROW,
     ST_RUNNING,
     ST_TRAPPED_BASE,
+    indirect_column,
     _C_CD,
     _C_SNAP,
     _C_CHUNK,
@@ -328,6 +329,9 @@ class BlockScheduler:
         # and the instructions of a v128 class (a kernel whose image has
         # v128 counts them in the column only its ctrl rows have)
         self.simd_ops = 0
+        # and the br_table and call_indirect (a kernel whose image holds
+        # one counts them, in the row's last column)
+        self.indirect_ops = 0
         self.quarantined = 0
         self._t_launch = 0.0
         self._plane_idx = _PLANE_IDX_SIMD if outer.img.has_simd \
@@ -763,6 +767,9 @@ class BlockScheduler:
             self.softfloat_ops += int(ctrl_np[blocks, _C_SOFTFLOAT].sum())
         if self.eng.img.has_simd:
             self.simd_ops += int(ctrl_np[blocks, _C_SIMD].sum())
+        if self.eng.counts_indirect:
+            self.indirect_ops += int(ctrl_np[
+                blocks, indirect_column(bool(self.eng.img.has_simd))].sum())
 
     def _count_commits(self, ctrl_np, blocks):
         """Add the periodic commits the launch that just ran implies in

@@ -2,7 +2,8 @@
 examples, /root/reference/tools/wasmedge/examples/). Built programmatically
 via utils.builder since the image has no wat2wasm and copying reference
 bytes is off-limits. These are the benchmark workloads from BASELINE.md:
-fib (config 1), a CoreMark-style integer/memory kernel (config 2 analog),
+fib (config 1), CoreMark 1.0 lowered from its C (`build_coremark`, config
+2), a small CoreMark-flavoured integer/memory probe (`build_coremark_kernel`),
 plus small modules exercising each subsystem.
 """
 
@@ -28,6 +29,9 @@ from wasmedge_tpu.models.programs import build_polybench_gemm  # noqa: F401
 from wasmedge_tpu.models.programs import build_chacha20  # noqa: F401
 # and of chacha20-wasi-4096 (benchmark/drivers/batch_wasi.py)
 from wasmedge_tpu.models.programs import build_chacha20_wasi  # noqa: F401
+# and CoreMark 1.0 itself, of coremark-2k-4096
+# (benchmark/drivers/batch_seeded_indirect.py)
+from wasmedge_tpu.models.programs import build_coremark  # noqa: F401
 
 __all__ = [
     "build_fib",

@@ -2,6 +2,9 @@
 
 from __future__ import annotations
 
+import os
+import struct
+
 from wasmedge_tpu.utils.builder import ModuleBuilder
 from wasmedge_tpu.utils.wat import parse_wat
 
@@ -601,11 +604,37 @@ def build_simd_memfuse_workload(n_vecs: int = 64,
     return b.build()
 
 
+def build_coremark(total_data_size: int = 2000, seed1: int = 0,
+                   seed2: int = 0, seed3: int = 0x66) -> bytes:
+    """EEMBC CoreMark 1.0, lowered by hand from its C one wasm function a
+    C function (coremark.wat beside this file): export
+    `coremark(iterations: i32) -> i64` runs what core_main.c's main runs
+    between portable_init and the report (the seeds, the three inits on
+    the MEM_STATIC block, `iterate`) and packs crcfinal | crclist << 16 |
+    crcmatrix << 32 | crcstate << 48.  The seeds are the build's
+    constants, the SEED_VOLATILE port's volatiles in a data segment; the
+    defaults are the 2K performance run (known_id 3).  Switches of four
+    cases or more are `br_table`, the sort's comparator a
+    `call_indirect`, the list and the matrices 16-bit accesses, the state
+    input bytes, and the frames of core_bench_list, core_list_init,
+    core_bench_state and main live on a shadow stack under a mutable
+    global.  One page: the block must fit below 64 KiB."""
+    if not 0 < total_data_size <= 65536 - 1248:
+        raise ValueError(f"TOTAL_DATA_SIZE {total_data_size} does not fit "
+                         f"one page above the data")
+    with open(os.path.join(os.path.dirname(__file__), "coremark.wat")) as f:
+        src = f.read()
+    volatiles = struct.pack("<5i", seed1, seed2, seed3, 0, 0)
+    src = src.replace("@TOTAL_DATA_SIZE@", str(total_data_size)).replace(
+        "@SEED_VOLATILES@", "".join(f"\\{b:02x}" for b in volatiles))
+    return parse_wat(src)
+
+
 def build_coremark_kernel() -> bytes:
     """CoreMark-flavored kernel: list-free core mix of matrix-multiply-ish
     integer MACs, state-machine branches, and CRC over linear memory.
-    Not the full CoreMark (no libc), but the same op mix — the config-2
-    stand-in until a wasm32 CoreMark binary is available offline."""
+    Not CoreMark (that is `build_coremark`): a small fixed op mix that
+    the engines' tests use as a quick integer/memory/`br_table` probe."""
     b = ModuleBuilder()
     b.add_memory(1, 16)
 

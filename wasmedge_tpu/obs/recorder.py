@@ -224,6 +224,9 @@ class NullRecorder:
     def add_simd_counts(self, ops):
         pass
 
+    def add_indirect_counts(self, ops):
+        pass
+
     def add_hostcall_counts(self, rounds, calls, vectorized, out_bytes):
         pass
 
@@ -344,6 +347,8 @@ class FlightRecorder:
         self.softfloat_ops = 0
         # and the instructions of a v128 class they ran
         self.simd_ops = 0
+        # and the br_table and call_indirect they ran
+        self.indirect_ops = 0
         # what the Pallas block serve drained, folded after each run
         # that parked: park, drain and re-arm cycles, lanes drained,
         # those a vectorised implementation served, bytes the calls
@@ -548,6 +553,14 @@ class FlightRecorder:
         step each (ctrl column 16, which only the rows of a kernel
         whose image has v128 hold; summed by batch/scheduler.py)."""
         self.simd_ops += int(ops)
+
+    def add_indirect_counts(self, ops):
+        """Fold the br_table and call_indirect the Pallas kernels ran in
+        one run, a lane-block step each, at their handlers and as fused
+        blocks' terminals (the last ctrl column, which only a kernel
+        whose image holds one of the two writes; summed by
+        batch/scheduler.py)."""
+        self.indirect_ops += int(ops)
 
     def add_hostcall_counts(self, rounds, calls, vectorized, out_bytes):
         """Fold what one run's hostcall serves on the Pallas path
