@@ -198,6 +198,17 @@ def test_the_pallas_kernel_agrees_and_counts_its_indirect_ops(ref):
     assert pallas.mem_static["mem_mode"] == "hbm_window"
     assert pallas.indirect_ops == indirect == 265
     assert eng.obs.indirect_ops == indirect
+    # the plane the loop-depth rule made, and the dispatches
+    # tests/test_block_shapes.py replays on it (38,895 in code order)
+    assert pallas.block_shapes == {"kept": 96, "wanted": 177}
+    assert pallas.dispatches == 28_504
+    from wasmedge_tpu.obs import parse_prometheus, render_prometheus
+
+    (run,) = [e for e in eng.obs.events if e["name"] == "batch/run"]
+    assert run["args"]["block_shapes"] == "96/177"
+    parsed = parse_prometheus(render_prometheus(recorder=eng.obs))
+    assert {dict(labels)["kind"]: v for (name, labels), v in parsed.items()
+            if name == "wasmedge_block_shapes"} == {"kept": 96, "wanted": 177}
 
 
 # A small guest of both classes: n turns of a loop whose br_table picks

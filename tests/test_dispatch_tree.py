@@ -257,7 +257,8 @@ def test_dispatch_depth_reaches_metrics_and_the_run_span():
     # the first run builds the kernel and learns the depth at its end;
     # a later one carries it from the start (a profiler trace has it)
     assert eng._kernel_args() == {"dispatch_depth": "2.00/6",
-                                  "mem_mode": "none", "donated_planes": 2}
+                                  "mem_mode": "none", "donated_planes": 2,
+                                  "block_shapes": "3/3"}
     eng.run("fib", [np.full(LANES, 5, np.int64)], max_steps=500_000)
     runs = [e for e in eng.obs.events if e["name"] == "batch/run"]
     assert [e["args"]["dispatch_depth"] for e in runs] == ["2.00/6"] * 2
@@ -265,6 +266,9 @@ def test_dispatch_depth_reaches_metrics_and_the_run_span():
     got = {dict(labels)["stat"]: v for (name, labels), v in parsed.items()
            if name == "wasmedge_dispatch_depth"}
     assert got == {"expected": 2.0, "max": 6.0}
+    assert eng.pallas.block_shapes == {"kept": 3, "wanted": 3}
+    assert {dict(labels)["kind"]: v for (name, labels), v in parsed.items()
+            if name == "wasmedge_block_shapes"} == {"kept": 3, "wanted": 3}
 
 
 def test_dispatches_reach_metrics_and_the_run_span():
@@ -541,29 +545,37 @@ def test_a_block_reached_only_through_a_tail_stays_hot():
 
 
 def test_shape_budget_falls_back_to_the_plain_block(monkeypatch):
-    """With MAX_BLOCK_SHAPES used up a head takes its plain shape if
-    that one is known.  fa's edges cannot be followed (both lead to a
-    `div`, which keeps its own dispatch), so its head registers the
-    plain shape; fb's head has the same plain block and edges that can."""
+    """Where a head's full shape is not kept it takes its plain shape if
+    that one is.  fa's edges cannot be followed (both lead to a `div`,
+    which keeps its own dispatch), so its head's full shape is the
+    plain one; fb's head has the same plain block and edges that can.
+    With two shapes kept, the rule keeps the `div` block (two heads in
+    each fa, 2 x 4) and fa's (one head in each, 3 x 2) over fb's full
+    shape (one head, 4): fb's head runs the plain block."""
     b = ModuleBuilder()
     div = [("i32.const", 6), ("i32.const", 3), "i32.div_u"]
-    b.add_function(["i32"], ["i32"], [], [
-        ("local.get", 0), ("if", "i32"), ("i32.const", 1), "else", *div,
-        "end", *div, "i32.add"], export="fa")
+    for name in ("fa", "fa2"):
+        b.add_function(["i32"], ["i32"], [], [
+            ("local.get", 0), ("if", "i32"), ("i32.const", 1), "else",
+            *div, "end", *div, "i32.add"], export=name)
     b.add_function(["i32"], ["i32"], [], [
         ("local.get", 0), ("if", "i32"), ("i32.const", 1), "else",
         ("i32.const", 2), "end"], export="fb")
     img = device_image(b.build())
     plain = (("lget", 0), ("guardz", ()), ("const",), ("term", pe.H_BR))
+    divide = (("const",), ("const",), ("term", int(hid_plane(img)[6])))
     hid, shapes = fuse_blocks(hid_plane(img), img)
-    fb = int(img.f_entry[1])
-    assert shapes[0] == plain and hid[0] == H_BLOCK_BASE
-    assert hid[fb] > H_BLOCK_BASE and ("jump", 1) in \
+    fb = int(img.f_entry[2])
+    assert shapes[:2] == (plain, divide) and hid[0] == H_BLOCK_BASE
+    assert hid[fb] > H_BLOCK_BASE + 1 and ("jump", 1) in \
         shapes[int(hid[fb]) - H_BLOCK_BASE]
-    monkeypatch.setattr(pe, "MAX_BLOCK_SHAPES", 1)
-    hid1, shapes1 = fuse_blocks(hid_plane(img), img)
-    assert shapes1 == (plain,)
-    assert np.flatnonzero(hid1 >= H_BLOCK_BASE).tolist() == [0, fb]
+    monkeypatch.setattr(pe, "MAX_BLOCK_SHAPES", 2)
+    hid2, shapes2, counts = pe.fuse_blocks_counted(hid_plane(img), img)
+    assert counts == {"kept": 2, "wanted": 5}
+    assert shapes2 == (plain, divide)
+    heads = np.flatnonzero(hid2 >= H_BLOCK_BASE)
+    assert heads.tolist() == [0, 4, 7, 12, 16, 19, fb]
+    assert (hid2[heads] - H_BLOCK_BASE).tolist() == [0, 1, 1, 0, 1, 1, 0]
 
 
 # tests/test_optimistic.py is a slow suite by its file name

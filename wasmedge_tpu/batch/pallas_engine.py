@@ -327,7 +327,10 @@ def _jump_targets(img) -> set:
 # original slot, so a bail, a rollback or a split child lands where it
 # always did.  fuse_blocks holds the rules.
 H_BLOCK_BASE = NUM_HANDLERS
-MAX_BLOCK_SHAPES = 96   # distinct block shapes compiled per kernel
+# distinct block shapes compiled per kernel: in code order where the
+# image's heads want no more, else the shapes that save the most
+# dispatches in the deepest loops (fuse_blocks, _hot_shapes)
+MAX_BLOCK_SHAPES = 96
 MAX_BLOCK_LEN = 24      # ops per block (incl. the terminal)
 # loads and stores fused inline into a kernel's blocks, all shapes
 # together: each compiles its own fast path and miss path into its
@@ -431,9 +434,9 @@ def fuse_blocks(hid, img):
     grows by 2x at most); a guard of a block that ends in a backward
     branch (a loop's exit) keeps the empty tail; and no path is nested
     deeper (_path_nesting) than the plain block it grew from, so a tail
-    is never nested deeper than the fall-through side of its guard.  Where MAX_BLOCK_SHAPES is
-    used up a head falls back to its plain shape before it goes
-    unfused.
+    is never nested deeper than the fall-through side of its guard.
+    Where its full shape is not among the shapes kept (below), a head
+    falls back to its plain shape before it goes unfused.
 
     Immediates/indices are NOT in the shape (handlers read them from
     the SMEM planes at the op's own original slot), except local/global
@@ -453,17 +456,78 @@ def fuse_blocks(hid, img):
     Where the blocks would hold more than MAX_INLINE_ACCESSES inline
     loads and stores in all, the plane is made again with none: a
     block then ends before a load or a store (a cut, as for room), and
-    each runs its own handler."""
-    fused, shapes = _fuse_blocks(hid, img, inline_mem=True)
-    inline = sum(1 for shape in shapes for op in shape
-                 if op[0] in ("loadi", "storei"))
-    if inline > MAX_INLINE_ACCESSES:
-        return _fuse_blocks(hid, img, inline_mem=False)
-    return fused, shapes
+    each runs its own handler.
+
+    SHAPE IDS: where the image's heads want at most MAX_BLOCK_SHAPES
+    distinct shapes, every head takes its full shape and ids go out in
+    code order.  Where they want more, the shapes kept are those that
+    save the most dispatches in the deepest loops (_hot_shapes: a full
+    shape scores, over the heads that want it, 16 ** loop_depth(head)
+    x (ops - 1), ties to the lowest head; loop_depth counts the
+    backward branches whose range holds the head), and the plane is
+    made again: a head takes its full shape if it is kept, else its
+    plain shape if that is, else no block.  (CoreMark's heads want
+    177; in code order the first 96 went to the cold functions that
+    come first in its file.)"""
+    return fuse_blocks_counted(hid, img)[:2]
 
 
-def _fuse_blocks(hid, img, inline_mem):
-    """fuse_blocks' plane, with loads and stores fused inline or not."""
+def fuse_blocks_counted(hid, img):
+    """fuse_blocks' (hid, shapes) and its shape budget, {"kept": the
+    shapes the plane holds, "wanted": the distinct full shapes of the
+    image's heads}: wanted > kept where _hot_shapes chose them.  The
+    inline accesses counted against MAX_INLINE_ACCESSES are those of
+    the shapes wanted, so the choice never decides whether they fuse."""
+    inline_mem = True
+    fused, shapes, heads = _fuse_blocks(hid, img, inline_mem)
+    if sum(1 for shape in shapes for op in shape
+           if op[0] in ("loadi", "storei")) > MAX_INLINE_ACCESSES:
+        inline_mem = False
+        fused, shapes, heads = _fuse_blocks(hid, img, inline_mem)
+    wanted = len(shapes)
+    if wanted > MAX_BLOCK_SHAPES:
+        fused, shapes, _heads = _fuse_blocks(
+            hid, img, inline_mem, _hot_shapes(heads, img))
+    return fused, shapes, {"kept": len(shapes), "wanted": wanted}
+
+
+def loop_depths(img) -> np.ndarray:
+    """Per slot, how many backward branches (`br`, `br_if` at slot s
+    with a[s] <= s: a loop's back-edge) hold it in their range
+    [a[s], s]."""
+    n = img.code_len
+    edge = np.zeros(n + 1, np.int64)
+    for s in range(n):
+        if int(img.cls[s]) in (CLS_BR, CLS_BRZ, CLS_BRNZ) and \
+                int(img.a[s]) <= s:
+            edge[int(img.a[s])] += 1
+            edge[s + 1] -= 1
+    return np.cumsum(edge)[:n]
+
+
+def _hot_shapes(heads, img) -> set:
+    """The MAX_BLOCK_SHAPES full shapes of `heads` (the (pc, full,
+    plain) of every head of the plane with no cap) that save the most
+    dispatches where loops nest deepest: a head adds 16 **
+    loop_depth(pc) x (ops - 1) to its full shape; a tie goes to the
+    shape with the lowest head, so the plane stays a function of the
+    image."""
+    depth = loop_depths(img)
+    score, first = {}, {}
+    for pc, full, _plain in heads:
+        score[full] = score.get(full, 0) + \
+            16 ** int(depth[pc]) * (len(full) - 1)
+        first.setdefault(full, pc)
+    ranked = sorted(score, key=lambda shape: (-score[shape], first[shape]))
+    return set(ranked[:MAX_BLOCK_SHAPES])
+
+
+def _fuse_blocks(hid, img, inline_mem, keep=None):
+    """fuse_blocks' plane, with loads and stores fused inline or not:
+    every head takes its full shape where `keep` is None, else its full
+    shape if `keep` holds it, else its plain one if that is held, else
+    no block.  -> (hid, shapes, heads): ids in code order, heads the
+    (pc, full shape, plain shape) of every head found."""
     n = img.code_len
     targets = _jump_targets(img)
     # call-return / hostcall-re-arm / trap-partial-resume addresses need
@@ -594,8 +658,8 @@ def _fuse_blocks(hid, img, inline_mem):
         return _path_nesting([p[0] for p in path], call_nest, depth)
 
     hid = hid.copy()
-    shapes = []
     shape_ids = {}
+    heads = []
     pc = 0
     while pc < n:
         # the plain block at pc: one segment, as before superblocks
@@ -632,19 +696,17 @@ def _fuse_blocks(hid, img, inline_mem):
                 continue
             tail = follow(tail, lm, gm, limit, depth, i + 1)
             ops[i] = (op[0], tuple(p[0] for p in tail))
-        for shape in (tuple(ops), plain):
-            if shape in shape_ids or len(shapes) < MAX_BLOCK_SHAPES:
-                sid = shape_ids.get(shape)
-                if sid is None:
-                    sid = len(shapes)
-                    shape_ids[shape] = sid
-                    shapes.append(shape)
+        full = tuple(ops)
+        heads.append((pc, full, plain))
+        for shape in (full, plain):
+            if keep is None or shape in keep:
+                sid = shape_ids.setdefault(shape, len(shape_ids))
                 hid[pc] = H_BLOCK_BASE + sid
                 pc += nfirst
                 break
         else:
             pc += 1
-    return hid, tuple(shapes)
+    return hid, tuple(shape_ids), heads
 
 
 def _successors(img, slot) -> set:
@@ -4517,6 +4579,8 @@ class PallasUniformEngine:
         self.indirect_ops = None
         # forward edges the newest kernel's blocks run through, by kind
         self.superblock_edges = None
+        # its block shapes ({"kept", "wanted"}: fuse_blocks_counted)
+        self.block_shapes = None
         # the image's i8x16.shuffle slots by lowering ({"word",
         # "dynamic"}); None without one
         self.shuffle_sites = None
@@ -4640,7 +4704,8 @@ class PallasUniformEngine:
 
         img = self.img
         interpret = self._interpret()
-        hid, block_shapes = fuse_blocks(hid_plane(img), img)
+        hid, block_shapes, self.block_shapes = fuse_blocks_counted(
+            hid_plane(img), img)
         # block fusion rewrites block-head hids only: the operand planes
         # are the image's own
         a_p, b_p, c_p = img.a, img.b, img.c
@@ -4684,6 +4749,7 @@ class PallasUniformEngine:
         self.obs.set_dispatch_static(*self.dispatch_depth)
         self.superblock_edges = superblock_edges(hid, block_shapes, img)
         self.obs.set_superblock_static(self.superblock_edges)
+        self.obs.set_block_shapes_static(self.block_shapes)
         self.shuffle_sites = shuffle_sites(hid, block_shapes, img)
         if self.shuffle_sites:
             self.obs.set_shuffle_static(self.shuffle_sites)
@@ -5018,6 +5084,7 @@ class PallasUniformEngine:
                                    sched.window_writebacks,
                                    sched.window_accesses)
         self.superblock_edges = sched.eng.superblock_edges
+        self.block_shapes = sched.eng.block_shapes
         self.shuffle_sites = sched.eng.shuffle_sites
         self.donated_planes = sched.eng.donated_planes
         self.dispatches = sched.dispatches

@@ -1006,15 +1006,18 @@ class UniformBatchEngine:
         """What the Pallas kernel was built with, as span arguments:
         its dispatch tree, how it holds linear memory (`mem_mode`, and
         with a memory `lane_block` and the window's rows x ways), the
-        plane arguments its launch donates and, where the image holds an
-        `i8x16.shuffle`, its slots by lowering ("word/dynamic"); nothing
-        before a kernel exists."""
+        plane arguments its launch donates, its block shapes
+        ("kept/wanted") and, where the image holds an `i8x16.shuffle`,
+        its slots by lowering ("word/dynamic"); nothing before a kernel
+        exists."""
         depth = getattr(self.pallas, "dispatch_depth", None)
         if depth is None:
             return {}
+        shapes = self.pallas.block_shapes
         args = {"dispatch_depth": f"{depth[0]:.2f}/{depth[1]}",
                 **self.pallas.mem_static,
-                "donated_planes": self.pallas.donated_planes}
+                "donated_planes": self.pallas.donated_planes,
+                "block_shapes": f"{shapes['kept']}/{shapes['wanted']}"}
         sites = self.pallas.shuffle_sites
         if sites:
             args["shuffle_sites"] = f"{sites['word']}/{sites['dynamic']}"

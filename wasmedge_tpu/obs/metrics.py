@@ -588,6 +588,18 @@ def render_prometheus(recorder=None, stats=None, hostcall_stats=None,
             for kind in ("jump", "guard_tail"):
                 w.sample("wasmedge_superblock_edges", {"kind": kind},
                          int(sbs.get(kind, 0)))
+        bss = getattr(recorder, "block_shapes_static", None)
+        if bss is not None:
+            w.head("wasmedge_block_shapes", "gauge",
+                   "Block shapes of the newest Pallas kernel: `kept`, "
+                   "those its plane holds, and `wanted`, the distinct "
+                   "shapes its image's heads want; more wanted than "
+                   "kept where MAX_BLOCK_SHAPES made fuse_blocks keep "
+                   "those of the deepest loops "
+                   "(batch/pallas_engine.py fuse_blocks_counted).")
+            for kind in ("kept", "wanted"):
+                w.sample("wasmedge_block_shapes", {"kind": kind},
+                         int(bss.get(kind, 0)))
         shs = getattr(recorder, "shuffle_static", None)
         if shs is not None:
             w.head("wasmedge_shuffle_sites", "gauge",

@@ -209,6 +209,9 @@ class NullRecorder:
     def set_superblock_static(self, edges):
         pass
 
+    def set_block_shapes_static(self, counts):
+        pass
+
     def set_shuffle_static(self, sites):
         pass
 
@@ -338,6 +341,8 @@ class FlightRecorder:
         # ({"jump", "guard_tail"}), and the handlers its loop dispatched
         self.superblock_static = None
         self.pallas_dispatches = 0
+        # its block shapes ({"kept", "wanted"})
+        self.block_shapes_static = None
         # its image's i8x16.shuffle slots by lowering ({"word",
         # "dynamic"}), where the image holds one
         self.shuffle_static = None
@@ -520,6 +525,14 @@ class FlightRecorder:
         run through instead of ending at (batch/pallas_engine.py
         fuse_blocks): absorbed `br`s and guards with a tail."""
         self.superblock_static = dict(edges)
+
+    def set_block_shapes_static(self, counts):
+        """Record the newest Pallas kernel's block shapes
+        (batch/pallas_engine.py fuse_blocks_counted): "kept", the shapes
+        its plane holds, and "wanted", the distinct shapes its image's
+        heads want; more wanted than kept where MAX_BLOCK_SHAPES made
+        fuse_blocks keep those of the deepest loops."""
+        self.block_shapes_static = dict(counts)
 
     def set_shuffle_static(self, sites):
         """Record the `i8x16.shuffle` slots of the newest Pallas
